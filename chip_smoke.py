@@ -1,0 +1,744 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py              one TPU chip: submit -> train -> promote
+                                      -> serve, at the full width and depth of
+                                      tinyllama-1.1b, through the API a user
+                                      would call
+    python chip_smoke.py --chips 4    four chips: train.cli with mesh fsdp=4
+                                      against the same spec on one chip, and
+                                      nothing else
+    JAX_PLATFORMS=cpu python chip_smoke.py --tiny [--chips 4]
+                                      the same control flow on the CPU with
+                                      tiny-test — a rehearsal, never a pass
+
+This process never imports JAX: a parent that has touched JAX holds the chip,
+and the trainer or serve worker it starts would then fail or hang.  It learns
+the device from what its children report (the trainer's ``train-started``
+event, the serve worker's ``runtime`` stats) and fails unless every one of
+them ran on a TPU.  Training and serving take the chip in turn; everything
+the script starts is stopped before it ends.
+
+One line per phase goes to stdout as it happens.  Any phase that fails ends
+the run with a non-zero exit code and no result line.  After a full pass the
+last line is exactly::
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}
+
+Compiled programs are cached where ``JAX_COMPILATION_CACHE_DIR`` says, else in
+``<checkout>/.cache/xla`` (``finetune_controller_tpu/platform.py``), so a
+second run in the same checkout shows warm compile times next to the first
+run's cold ones.
+"""
+
+from __future__ import annotations
+
+import argparse
+import concurrent.futures
+import contextlib
+import csv
+import json
+import os
+import random
+import shutil
+import signal
+import socket
+import statistics
+import subprocess
+import sys
+import tempfile
+import time
+import urllib.error
+import urllib.request
+import uuid
+from pathlib import Path
+
+REPO = Path(__file__).resolve().parent
+
+#: every process the script starts inherits this variable; whatever still
+#: carries it when the script ends is an orphan
+MARKER_ENV = "CHIP_SMOKE_RUN"
+
+#: per-step |loss(fsdp=4) - loss(1 chip)| the four-chip comparison allows.
+#: Same seed, data and global batch; what differs is the order of the bf16
+#: reductions (four batch shards, gathered parameters), which the optimizer
+#: then amplifies step by step.  Measured on one v5e chip between two runs
+#: that differ in nothing but reduction order (grad_accum_steps 1 against 4):
+#: 0.0008 over 24 steps at the learning rate used here — and 0.11 at ten
+#: times that rate, where step 6 lands on either side of an instability.
+FOUR_CHIP_LOSS_TOL = 0.005
+#: max |kernel - gather| the paged-attention parity child allows: two
+#: roundings of the storage dtype at the outputs' magnitude (unit-normal V
+#: rows average to |out| < 4, where one bf16 ulp is 2**-6)
+PAGED_TOL = {"bfloat16": 2 ** -5, "float32": 4e-6}
+
+#: runs in a child AFTER the server has gone (the chip is free again): the
+#: Pallas paged kernel against chunked_cache_attention over the gathered
+#: cache, compiled for whatever backend the child lands on
+PAGED_PARITY_SNIPPET = r"""
+import json, sys
+import jax, jax.numpy as jnp
+from finetune_controller_tpu.platform import device_report, enable_compile_cache
+from finetune_controller_tpu.ops.attention import (
+    chunked_cache_attention, paged_gather, paged_kernel_eligible)
+from finetune_controller_tpu.ops.pallas.paged_attention import paged_attention
+
+enable_compile_cache()
+shapes, dtype_name = json.loads(sys.argv[1]), sys.argv[2]
+dtype = jnp.dtype(dtype_name)
+on_tpu = jax.default_backend() == "tpu"
+oracle = jax.jit(lambda q, k, v, t, i: chunked_cache_attention(
+    q, paged_gather(k, t), paged_gather(v, t), i))
+rows = []
+for n, (b, s, h, hkv, d, t, mp) in enumerate(shapes):
+    ks = jax.random.split(jax.random.PRNGKey(n), 5)
+    pages = b * mp + 1
+    q = jax.random.normal(ks[0], (b, s, h, d), dtype)
+    k = jax.random.normal(ks[1], (pages, t, hkv, d), dtype)
+    v = jax.random.normal(ks[2], (pages, t, hkv, d), dtype)
+    table = jax.random.randint(ks[3], (b, mp), 0, pages, jnp.int32)
+    table = table.at[:, -1].set(0)  # unmaterialised tail -> scratch page
+    idx = jax.random.randint(ks[4], (b,), 0, mp * t - s + 1, jnp.int32)
+    eligible = bool(paged_kernel_eligible(q, k, v, table))
+    if on_tpu and not eligible:
+        raise SystemExit(f"auto would not pick the kernel at {shapes[n]}")
+    got = paged_attention(q, k, v, table, idx)  # compiled on a TPU
+    want = oracle(q, k, v, table, idx)
+    err = float(jnp.max(jnp.abs(
+        got.astype(jnp.float32) - want.astype(jnp.float32))))
+    finite = bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+    rows.append({"shape": shapes[n], "max_err": err, "finite": finite,
+                 "eligible": eligible})
+print(json.dumps({"device": device_report(), "dtype": dtype_name,
+                  "compiled": on_tpu, "cases": rows}))
+"""
+
+
+class SmokeFailure(Exception):
+    """A phase did not do what it had to; the run ends non-zero."""
+
+
+def say(phase: str, seconds: float, **fields) -> None:
+    print(f"phase {phase}: {seconds:.1f}s {json.dumps(fields, sort_keys=True)}",
+          flush=True)
+
+
+def check(cond: bool, message: str) -> None:
+    if not cond:
+        raise SmokeFailure(message)
+
+
+# ---------------------------------------------------------------------------
+# what each mode runs
+# ---------------------------------------------------------------------------
+
+
+def mode_config(tiny: bool, seed: int) -> dict:
+    """The one place the full run and the rehearsal differ."""
+    # 24 steps, one checkpoint.  At full depth the increment loss of a
+    # random-init base falls slowly under LoRA — 0.17 by step 24 on the chip,
+    # against a batch-to-batch spread of +-0.05 — so a handful of steps cannot
+    # tell a falling loss from noise, and each step costs 1.3 s.  At 0.002
+    # the loss falls as far as at 0.02 and the run is numerically steady (see
+    # FOUR_CHIP_LOSS_TOL); at 0.02 it overshoots around step 6.
+    train = {
+        "total_steps": 24, "warmup_steps": 1, "learning_rate": 0.002,
+        "log_every": 1, "checkpoint_every": 24, "seed": seed,
+    }
+    if tiny:
+        return {
+            "platform": "cpu", "model_name": "tiny-test-lora",
+            "preset": "tiny-test", "device": "cpu-test", "vocab": 256,
+            "arguments": {**train, "batch_size": 4, "seq_len": 64,
+                          "lora_rank": 4},
+            "attention_impl": "xla",
+            # interpret-mode parity at small shapes (b, s, h, hkv, d, t, mp)
+            "paged_shapes": [[2, 1, 4, 2, 16, 8, 5], [1, 12, 4, 2, 16, 8, 5]],
+        }
+    return {
+        "platform": "tpu", "model_name": "tinyllama-1.1b-lora",
+        "preset": "tinyllama-1.1b", "device": "v5e-1", "vocab": 32000,
+        # batch 8 x seq 2048 is where attention_impl="auto" takes the Pallas
+        # flash kernels forward and backward; the frozen base must be bf16
+        # for that shape to fit one 16 GB chip
+        "arguments": {**train, "batch_size": 8, "seq_len": 2048,
+                      "lora_rank": 8, "frozen_dtype": "bfloat16"},
+        "attention_impl": "pallas",
+        # tinyllama's serve shapes (decode + the 32/128/512 prefill buckets)
+        # and a head-dim-128 GQA model's decode and widest prefill
+        "paged_shapes": [
+            [8, 1, 32, 4, 64, 16, 40], [1, 32, 32, 4, 64, 16, 40],
+            [1, 128, 32, 4, 64, 16, 40], [1, 512, 32, 4, 64, 16, 40],
+            [8, 1, 32, 8, 128, 16, 40], [1, 512, 32, 8, 128, 16, 40],
+        ],
+    }
+
+
+def child_env(run_id: str, platform: str, extra: dict | None = None) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO) + (
+        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
+    env[MARKER_ENV] = run_id
+    # full mode pins the children to the chip: one that finds none fails at
+    # backend start-up instead of quietly running somewhere else
+    env["JAX_PLATFORMS"] = platform
+    env.update(extra or {})
+    return env
+
+
+def cache_state() -> tuple[str, int]:
+    """Where the children cache compiled programs, and how many entries
+    that directory holds now (the package's helper imports no JAX)."""
+    from finetune_controller_tpu.platform import compile_cache_dir
+
+    path = Path(compile_cache_dir())
+    return str(path), sum(1 for _ in path.iterdir()) if path.is_dir() else 0
+
+
+# ---------------------------------------------------------------------------
+# process hygiene
+# ---------------------------------------------------------------------------
+
+
+def marked_pids(run_id: str) -> list[int]:
+    """Live processes started by this run (they inherited the marker)."""
+    needle = f"{MARKER_ENV}={run_id}".encode()
+    found = []
+    for entry in os.listdir("/proc"):
+        if not entry.isdigit() or int(entry) == os.getpid():
+            continue
+        try:
+            with open(f"/proc/{entry}/environ", "rb") as f:
+                if needle in f.read().split(b"\0"):
+                    found.append(int(entry))
+        except OSError:
+            continue  # gone, or not ours to read
+    return found
+
+
+def wait_gone(run_id: str, timeout_s: float) -> list[int]:
+    deadline = time.monotonic() + timeout_s
+    while True:
+        left = marked_pids(run_id)
+        if not left or time.monotonic() >= deadline:
+            return left
+        time.sleep(0.2)
+
+
+def kill_marked(run_id: str) -> None:
+    for pid in marked_pids(run_id):
+        with contextlib.suppress(ProcessLookupError, PermissionError):
+            os.kill(pid, signal.SIGKILL)
+
+
+# ---------------------------------------------------------------------------
+# the API
+# ---------------------------------------------------------------------------
+
+
+class Api:
+    def __init__(self, port: int):
+        self.base = f"http://127.0.0.1:{port}/api/v1"
+
+    def call(self, method: str, path: str, body: dict | None = None,
+             timeout: float = 60.0) -> dict:
+        data = None if body is None else json.dumps(body).encode()
+        req = urllib.request.Request(
+            self.base + path, data=data, method=method,
+            headers={"Content-Type": "application/json"},
+        )
+        try:
+            with urllib.request.urlopen(req, timeout=timeout) as resp:
+                return json.loads(resp.read() or b"{}")
+        except urllib.error.HTTPError as e:
+            detail = e.read().decode(errors="replace")[:2000]
+            raise SmokeFailure(
+                f"{method} {path} -> HTTP {e.code}: {detail}") from None
+
+    def get(self, path: str, **kw) -> dict:
+        return self.call("GET", path, **kw)
+
+    def post(self, path: str, body: dict | None = None, **kw) -> dict:
+        return self.call("POST", path, body or {}, **kw)
+
+
+def check_server_off_jax(api: Api, after: str) -> None:
+    """The server must never hold the chip: its children need it."""
+    check(api.get("/health").get("jax_backend") is False,
+          f"the API server started a JAX backend during {after!r}: on a TPU "
+          "host it now holds the chip")
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def tail(path: Path, n: int = 40) -> str:
+    try:
+        lines = path.read_text(errors="replace").splitlines()
+    except OSError:
+        return f"<{path} unreadable>"
+    # the server logs one access line per poll: not what a failure is about
+    return "\n".join([l for l in lines if "aiohttp.access" not in l][-n:])
+
+
+# ---------------------------------------------------------------------------
+# one chip: submit -> train -> promote -> serve
+# ---------------------------------------------------------------------------
+
+
+def start_server(work: Path, run_id: str, platform: str) -> tuple:
+    port = free_port()
+    log = work / "server.log"
+    env = child_env(run_id, platform, {
+        "FTC_ENVIRONMENT": "local", "FTC_BACKEND": "local",
+        "FTC_MONITOR_IN_PROCESS": "true",
+        "FTC_STATE_DIR": str(work / "state"),
+        "FTC_OBJECT_STORE_ROOT": str(work / "objects"),
+        # an idle warm worker would hold the chip against the job
+        "FTC_WARM_WORKERS": "0",
+        "FTC_JOB_MONITOR_INTERVAL_S": "1",
+        "FTC_ARTIFACT_SYNC_INTERVAL_S": "2",
+        # a failed attempt is a failed phase, not something to retry past
+        "FTC_RETRY_MAX_ATTEMPTS": "0",
+        # the worker process owns the chip while serving, never the server
+        "FTC_SERVE_TRANSPORT": "process", "FTC_SERVE_PAGED_KV": "true",
+        "FTC_SERVE_AUTOLOAD": "false",
+        "FTC_SERVE_WORKER_SPAWN_TIMEOUT_S": "900",
+        "FTC_SERVE_REQUEST_TIMEOUT_S": "300",
+        "FTC_RATE_LIMIT_READ_PER_MIN": "6000",
+        "FTC_RATE_LIMIT_GENERATE_PER_MIN": "6000",
+    })
+    with open(log, "wb") as out:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "finetune_controller_tpu.controller.server",
+             "--port", str(port)],
+            cwd=str(work), env=env, stdout=out, stderr=subprocess.STDOUT,
+            start_new_session=True,
+        )
+    api = Api(port)
+    deadline = time.monotonic() + 120
+    while True:
+        if proc.poll() is not None:
+            raise SmokeFailure(
+                f"server exited with {proc.returncode}:\n{tail(log)}")
+        try:
+            api.get("/health", timeout=2)
+            return proc, api, log
+        except (SmokeFailure, OSError):
+            check(time.monotonic() < deadline,
+                  f"server not healthy in 120 s:\n{tail(log)}")
+            time.sleep(0.3)
+
+
+def training_summary(cfg: dict, who: str, started: dict, finished: dict,
+                     rows: list[dict]) -> dict:
+    """What a training run must show, from what the trainer wrote about
+    itself: the ``train-started`` / ``train-finished`` event attributes and
+    the metric rows, one per step, in step order.  Shared by the job the API
+    ran and the ``train.cli`` runs of ``--chips 4``."""
+    device = {k: started.get(k) for k in ("platform", "kind", "count")}
+    check(device["platform"] == cfg["platform"],
+          f"{who} ran on {device}, not on a {cfg['platform']}")
+    check(started.get("attention_impl") == cfg["attention_impl"],
+          f"{who}: attention resolved to {started.get('attention_impl')!r}, "
+          f"expected {cfg['attention_impl']!r}")
+    steps = cfg["arguments"]["total_steps"]
+    check(len(rows) == steps, f"{who}: {len(rows)} metric rows, {steps} steps")
+    losses = [float(r["loss"]) for r in rows]
+    check(all(x == x and abs(x) != float("inf") for x in losses),
+          f"{who}: non-finite loss in {losses}")
+    check(losses[-1] < losses[0],
+          f"{who}: loss did not fall: first {losses[0]}, last {losses[-1]}")
+    compute_s = [float(r["phase_compute_ms"]) / 1000 for r in rows]
+    return {
+        "device": device, "mesh": started.get("mesh"),
+        "attention_impl": started["attention_impl"], "losses": losses,
+        # the first step's compute time is the compile (cold, or a cache
+        # hit) plus one step; the median of the rest is a step
+        "first_step_s": round(compute_s[0], 2),
+        "steady_step_s": round(statistics.median(compute_s[1:]), 3),
+        "state_bytes": started.get("device_state_bytes"),
+        "peak_bytes": finished.get("device_peak_bytes"),
+    }
+
+
+def train_phase(api: Api, cfg: dict) -> tuple[str, dict]:
+    t0 = time.monotonic()
+    job_id = api.post("/jobs", {
+        "model_name": cfg["model_name"], "device": cfg["device"],
+        "arguments": cfg["arguments"],
+    })["job_id"]
+    say("submit", time.monotonic() - t0, job_id=job_id,
+        model=cfg["model_name"], flavor=cfg["device"],
+        arguments=cfg["arguments"])
+
+    deadline = time.monotonic() + 1000
+    while True:
+        job = api.get(f"/jobs/{job_id}")
+        status = str(job["status"]).lower()
+        if status == "succeeded":
+            break
+        if status in ("failed", "cancelled", "lost"):
+            lines = api.get(f"/jobs/{job_id}/logs?last_lines=30")["lines"]
+            raise SmokeFailure(
+                f"job {job_id} ended {status}: {job.get('metadata')}\n"
+                + "\n".join(lines))
+        check(time.monotonic() < deadline, f"job {job_id} still {status}")
+        time.sleep(1.0)
+    seconds = time.monotonic() - t0
+    meta = job.get("metadata") or {}
+    check(not meta.get("restarts"),
+          f"the trainer needed {meta.get('restarts')} restart(s)")
+
+    # what the trainer reported about itself: events.jsonl rides the
+    # artifact sync and the monitor ingests it, a tick or two behind the job
+    needed = ("train-started", "checkpoint-committed", "train-finished")
+    deadline = time.monotonic() + 60
+    while True:
+        events = {e["event"]: e for e in
+                  api.get(f"/jobs/{job_id}/timeline")["events"]}
+        if all(n in events for n in needed):
+            break
+        check(time.monotonic() < deadline,
+              f"timeline lacks {[n for n in needed if n not in events]}")
+        time.sleep(0.5)
+    records = sorted(api.get(f"/jobs/{job_id}/metrics")["records"],
+                     key=lambda r: float(r["step"]))
+    summary = training_summary(
+        cfg, "the trainer", events["train-started"]["attrs"],
+        events["train-finished"]["attrs"], records)
+
+    listing = {a["path"] for a in
+               api.get(f"/jobs/{job_id}/artifacts?list=1")["artifacts"]}
+    check("done.txt" in listing, "no done.txt among the artifacts")
+    manifest = (f"checkpoints/step_{cfg['arguments']['total_steps']}"
+                "/manifest.json")
+    check(manifest in listing, f"no {manifest} among the artifacts")
+    say("train", seconds, **summary, checkpoint=manifest)
+    return job_id, summary["device"]
+
+
+def promote_phase(api: Api, job_id: str) -> None:
+    t0 = time.monotonic()
+    api.post(f"/jobs/{job_id}/promote")
+    deadline = time.monotonic() + 300
+    while True:
+        job = api.get(f"/jobs/{job_id}")
+        status = str(job.get("promotion_status")).lower()
+        if status == "completed":
+            break
+        check(status != "failed", f"promotion failed: {job.get('metadata')}")
+        check(time.monotonic() < deadline, f"promotion still {status}")
+        time.sleep(0.5)
+    say("promote", time.monotonic() - t0, uri=job.get("promotion_uri"))
+
+
+def make_prompts(vocab: int, seed: int) -> list[list[int]]:
+    """Mixed lengths covering the 32/128/512 prefill buckets; the first
+    token is unique per prompt so no two share a prefix."""
+    rng = random.Random(seed)
+    lengths = [5, 24, 32, 60, 128, 129, 300, 512]
+    return [
+        [2 + i] + [rng.randrange(2, vocab) for _ in range(n - 1)]
+        for i, n in enumerate(lengths)
+    ]
+
+
+def serve_phase(api: Api, job_id: str, cfg: dict, seed: int) -> tuple:
+    t0 = time.monotonic()
+    api.post(f"/admin/serve/{job_id}/load", timeout=1000)
+    load_s = time.monotonic() - t0
+    session = api.get("/admin/serve")["sessions"][job_id]
+    check(session["transport"] == "process" and session["worker_pids"],
+          f"no serve worker process: {session.get('transport')}")
+    replicas = list(session["replicas"].values())
+    check(len(replicas) == 1, f"{len(replicas)} replicas, expected 1")
+    runtime = replicas[0].get("runtime") or {}
+    device = {k: runtime.get(k) for k in ("platform", "kind", "count")}
+    check(device["platform"] == cfg["platform"],
+          f"the serve worker ran on {device}, not on a {cfg['platform']}")
+    paged = runtime.get("paged_attention") or {}
+    check(bool(paged), "the serve engine is not paged")
+    if cfg["platform"] == "tpu":
+        check(set(paged.values()) == {"kernel"},
+              f"paged attention did not resolve to the kernel: {paged}")
+    say("serve-load", load_s, device=device, paged_attention=paged,
+        # every prefill bucket and the decode step, compiled (or loaded from
+        # the compile cache) and run once before the worker takes traffic
+        warm_start_s=runtime.get("warm_start_s"),
+        worker_pids=session["worker_pids"])
+
+    prompts = make_prompts(cfg["vocab"], seed)
+    new_tokens = 16
+
+    def generate(i: int, wave: str) -> list[int]:
+        out = api.post(f"/jobs/{job_id}/generate", {
+            "request_id": f"{wave}-{i}", "tokens": prompts[i],
+            "max_new_tokens": new_tokens, "timeout_s": 300,
+        }, timeout=330)
+        tokens = out["tokens"]
+        check(len(tokens) == new_tokens
+              and all(0 <= t < cfg["vocab"] for t in tokens),
+              f"request {wave}-{i} answered {tokens}")
+        return tokens
+
+    # first: every prompt once, one at a time, on a cold prefix cache
+    t1 = time.monotonic()
+    first = [generate(i, "first") for i in range(len(prompts))]
+    first_s = time.monotonic() - t1
+    # together: the same eight at once share the decode batch (and queue for
+    # the prefill), joining and leaving mid-flight
+    t2 = time.monotonic()
+    with concurrent.futures.ThreadPoolExecutor(len(prompts)) as pool:
+        together = list(pool.map(lambda i: generate(i, "together"),
+                                 range(len(prompts))))
+    together_s = time.monotonic() - t2
+    # alone: and once more one at a time.  "together" and "alone" both find
+    # their prompt in the prefix cache, so they run the same programs on the
+    # same cached pages and differ only in who else rides the batch
+    t3 = time.monotonic()
+    alone = [generate(i, "alone") for i in range(len(prompts))]
+    alone_s = time.monotonic() - t3
+    differ = [i for i in range(len(prompts)) if together[i] != alone[i]]
+    check(not differ,
+          "greedy output depends on batching for prompts "
+          f"{differ}: together {[together[i] for i in differ]} "
+          f"alone {[alone[i] for i in differ]}")
+    # reported, not required: a prefix hit prefills only the prompt's tail,
+    # through a smaller bucket's program than the cold prefill ran — equal
+    # bit for bit on the CPU, last-bit different on the chip, which can move
+    # a greedy near-tie of a random-weight model (docs/serving.md)
+    moved = [i for i in range(len(prompts)) if first[i] != alone[i]]
+    # the fleet's view of the worker is its last health probe: wait for one
+    # that has seen every request
+    deadline = time.monotonic() + 30
+    while True:
+        stats = api.get("/admin/serve")["sessions"][job_id]
+        if stats.get("requests_completed_total", 0) >= 3 * len(prompts):
+            break
+        check(time.monotonic() < deadline,
+              f"the worker reports {stats.get('requests_completed_total')} "
+              f"completed requests of {3 * len(prompts)}")
+        time.sleep(0.5)
+    check(stats.get("step_errors_total", 0) == 0,
+          f"{stats.get('step_errors_total')} decode step errors")
+    say("serve-generate", time.monotonic() - t1,
+        requests=3 * len(prompts), prompt_lengths=[len(p) for p in prompts],
+        tokens_generated=stats.get("tokens_generated_total"),
+        first_s=round(first_s, 2), together_s=round(together_s, 2),
+        alone_s=round(alone_s, 2), identical_alone_and_together=True,
+        prompts_moved_by_prefix_reuse=moved,
+        prefix_hits=stats.get("prefix_hits_total"),
+        compilations=stats.get("compilations"))
+    return device, session["worker_pids"]
+
+
+def stop_server(proc: subprocess.Popen, run_id: str, log: Path) -> None:
+    proc.terminate()
+    try:
+        proc.wait(timeout=60)
+    except subprocess.TimeoutExpired:
+        raise SmokeFailure(
+            f"server ignored SIGTERM for 60 s:\n{tail(log)}") from None
+    left = wait_gone(run_id, 30)
+    check(not left, f"processes survived the server's shutdown: {left}")
+
+
+def paged_parity_phase(run_id: str, cfg: dict) -> dict:
+    t0 = time.monotonic()
+    dtype = "bfloat16"
+    out = subprocess.run(
+        [sys.executable, "-c", PAGED_PARITY_SNIPPET,
+         json.dumps(cfg["paged_shapes"]), dtype],
+        env=child_env(run_id, cfg["platform"]), cwd=str(REPO),
+        capture_output=True, text=True, timeout=900,
+    )
+    check(out.returncode == 0,
+          f"paged parity child exited {out.returncode}:\n{out.stderr[-3000:]}")
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    check(rec["device"]["platform"] == cfg["platform"],
+          f"paged parity ran on {rec['device']}")
+    worst = max(c["max_err"] for c in rec["cases"])
+    check(all(c["finite"] for c in rec["cases"]), f"non-finite output: {rec}")
+    check(worst <= PAGED_TOL[dtype],
+          f"paged kernel off the gather path by {worst} > {PAGED_TOL[dtype]}:"
+          f" {rec['cases']}")
+    say("paged-parity", time.monotonic() - t0, compiled=rec["compiled"],
+        dtype=dtype, tolerance=PAGED_TOL[dtype], worst_max_err=worst,
+        max_err_by_shape={"x".join(map(str, c["shape"])): c["max_err"]
+                          for c in rec["cases"]})
+    return rec["device"]
+
+
+def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
+    _, entries0 = cache_state()
+    t0 = time.monotonic()
+    server, api, log = start_server(work, run_id, cfg["platform"])
+    say("server", time.monotonic() - t0, pid=server.pid, log=str(log))
+    try:
+        job_id, train_device = train_phase(api, cfg)
+        check_server_off_jax(api, "train")
+        # the trainer exited with its job; until the load below nothing
+        # holds the chip
+        promote_phase(api, job_id)
+        check_server_off_jax(api, "promote")
+        serve_device, worker_pids = serve_phase(api, job_id, cfg, seed)
+        check_server_off_jax(api, "serve")
+        t1 = time.monotonic()
+        api.post(f"/admin/serve/{job_id}/unload", timeout=120)
+        deadline = time.monotonic() + 60
+        while any(Path(f"/proc/{pid}").exists() for pid in worker_pids):
+            check(time.monotonic() < deadline,
+                  f"serve worker {worker_pids} outlived its unload")
+            time.sleep(0.2)
+    except BaseException:
+        # main() kills whatever is still running; say what the server saw
+        print(f"--- server log tail ---\n{tail(log, 60)}", file=sys.stderr)
+        raise
+    stop_server(server, run_id, log)
+    say("shutdown", time.monotonic() - t1, survivors=[])
+    parity_device = paged_parity_phase(run_id, cfg)
+    check(train_device == serve_device == parity_device,
+          f"children disagree on the device: trainer {train_device}, "
+          f"serve worker {serve_device}, parity child {parity_device}")
+    cache_dir, entries1 = cache_state()
+    say("compile-cache", 0.0, dir=cache_dir,
+        entries_before=entries0, entries_after=entries1)
+    return train_device
+
+
+# ---------------------------------------------------------------------------
+# four chips: train.cli on mesh fsdp=4 against the same spec on one chip
+# ---------------------------------------------------------------------------
+
+
+def run_trainer(work: Path, run_id: str, cfg: dict, name: str, fsdp: int,
+                extra_env: dict) -> dict:
+    art = work / name
+    spec = {
+        "job_id": name,
+        "model": {"preset": cfg["preset"],
+                  "lora": {"rank": cfg["arguments"]["lora_rank"]}},
+        "training": {"mode": "lora", **{
+            k: v for k, v in cfg["arguments"].items() if k != "lora_rank"}},
+        # fully specified: a mesh smaller than the host runs on a prefix of
+        # its devices (parallel/mesh.py), which is how one process holding
+        # four chips trains on one
+        "mesh": {"dp": 1, "fsdp": fsdp, "ep": 1, "pp": 1, "sp": 1, "tp": 1},
+        "dataset": {"synthetic": {"task": "increment"}},
+        "artifacts_dir": str(art),
+    }
+    art.mkdir(parents=True)
+    (work / f"{name}.json").write_text(json.dumps(spec, indent=1))
+    t0 = time.monotonic()
+    log = work / f"{name}.log"
+    with open(log, "wb") as out:
+        rc = subprocess.run(
+            [sys.executable, "-m", "finetune_controller_tpu.train.cli",
+             "--spec", str(work / f"{name}.json")],
+            cwd=str(work), env=child_env(run_id, cfg["platform"], extra_env),
+            stdout=out, stderr=subprocess.STDOUT, timeout=1500,
+        ).returncode
+    check(rc == 0, f"train.cli ({name}) exited {rc}:\n{tail(log, 60)}")
+    seconds = time.monotonic() - t0
+    with open(art / "metrics.csv") as f:
+        rows = sorted(csv.DictReader(f), key=lambda r: int(float(r["step"])))
+    events = {}
+    for line in (art / "events.jsonl").read_text().splitlines():
+        e = json.loads(line)
+        events[e["event"]] = e["attrs"]
+    check((art / "done.txt").exists(), f"{name}: no done.txt")
+    summary = training_summary(
+        cfg, name, events["train-started"], events["train-finished"], rows)
+    say(f"train-{name}", seconds, **summary)
+    return summary
+
+
+def run_four_chips(cfg: dict, work: Path, run_id: str) -> dict:
+    extra = {}
+    if cfg["platform"] == "cpu":  # rehearsal: four virtual devices
+        extra["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    four = run_trainer(work, run_id, cfg, "fsdp4", 4, extra)
+    one = run_trainer(work, run_id, cfg, "fsdp1", 1, extra)
+    check(four["device"] == one["device"] and four["device"]["count"] == 4,
+          f"expected four devices in both runs: {four['device']}, "
+          f"{one['device']}")
+    check(four["mesh"] == {"fsdp": 4} and one["mesh"] == {},
+          f"meshes {four['mesh']} / {one['mesh']}")
+    diffs = [abs(a - b) for a, b in zip(four["losses"], one["losses"])]
+    check(max(diffs) <= FOUR_CHIP_LOSS_TOL,
+          f"fsdp=4 and one-chip losses differ by {max(diffs)} > "
+          f"{FOUR_CHIP_LOSS_TOL}: {four['losses']} vs {one['losses']}")
+    split = None
+    if cfg["platform"] == "tpu":
+        # the frozen base dominates the state: sharded four ways, every
+        # device holds about a quarter of what the one-chip run holds on
+        # its device; copied, each would hold all of it
+        per_dev, whole = four["state_bytes"], one["state_bytes"][0]
+        check(len(per_dev) == 4, f"state bytes of {len(per_dev)} devices")
+        split = [round(b / whole, 3) for b in per_dev]
+        check(max(split) <= 0.35,
+              f"state is not split four ways: per-device share {split}")
+    say("four-chip-compare", 0.0, max_loss_diff=max(diffs),
+        tolerance=FOUR_CHIP_LOSS_TOL, loss_diffs=[round(d, 5) for d in diffs],
+        state_share_per_device=split if split else "not measured off the chip")
+    return four["device"]
+
+
+# ---------------------------------------------------------------------------
+
+
+def main(argv: list[str] | None = None) -> int:
+    p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    p.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                   help="4 = only the fsdp=4 training path and the one-chip "
+                        "run it is compared with")
+    p.add_argument("--tiny", action="store_true",
+                   help="CPU rehearsal with tiny-test; never a chip pass")
+    p.add_argument("--seed", type=int, default=0,
+                   help="seeds the weights, the synthetic data and the prompts")
+    args = p.parse_args(argv)
+    check((REPO / "finetune_controller_tpu").is_dir(),
+          f"{REPO} holds no finetune_controller_tpu package")
+
+    cfg = mode_config(args.tiny, args.seed)
+    run_id = uuid.uuid4().hex
+    work = Path(tempfile.mkdtemp(prefix="chip_smoke_"))
+    t0 = time.monotonic()
+    try:
+        if args.chips == 4:
+            device = run_four_chips(cfg, work, run_id)
+        else:
+            device = run_lifecycle(cfg, work, run_id, args.seed)
+        check(not marked_pids(run_id), "a child process is still alive")
+        check("jax" not in sys.modules, "the parent imported jax")
+    except BaseException:
+        # the server log and the sandboxes are what a failure is debugged from
+        print(f"work directory kept: {work}", file=sys.stderr)
+        raise
+    else:
+        shutil.rmtree(work, ignore_errors=True)
+    finally:
+        kill_marked(run_id)  # nothing outlives the script, pass or fail
+    say("total", time.monotonic() - t0, chips=args.chips)
+    if args.tiny:
+        # a rehearsal has no "ok" key: nothing here can be read as a chip pass
+        print(json.dumps({"rehearsal": "tiny", "passed": True,
+                          "device": device}))
+        return 0
+    check(device["platform"] == "tpu"
+          and (args.chips == 1 or device["count"] == 4),
+          f"expected {args.chips} TPU chip(s), the children saw {device}")
+    print(json.dumps({"ok": True, "device": device}))
+    return 0
+
+
+if __name__ == "__main__":
+    try:
+        sys.exit(main())
+    except SmokeFailure as failure:
+        print(f"FAILED: {failure}", file=sys.stderr)
+        sys.exit(1)

@@ -318,7 +318,10 @@ class LocalProcessBackend(TrainingBackend):
     def _runtime_env(self, flavor: DeviceFlavor, num_slices: int) -> dict[str, str]:
         """Runtime env for a job (or warm worker) on a flavor: CPU flavors get
         a virtual device mesh the size of the slice (the TPU-less test story,
-        SURVEY.md §4)."""
+        SURVEY.md §4); every other flavor is pinned to ``JAX_PLATFORMS=tpu``
+        whatever the server's own environment says, so a trainer that finds
+        no chip fails at backend start-up instead of training on the CPU
+        under a TPU flavor's name."""
         env = dict(os.environ)
         env.update(self.extra_env)
         # the subprocess runs with the sandbox as cwd — make our package
@@ -336,6 +339,8 @@ class LocalProcessBackend(TrainingBackend):
                 p for p in flags.split() if "host_platform_device_count" not in p
             )
             env["XLA_FLAGS"] = (flags + f" --xla_force_host_platform_device_count={n}").strip()
+        else:
+            env["JAX_PLATFORMS"] = "tpu"
         return env
 
     @staticmethod

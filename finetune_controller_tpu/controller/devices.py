@@ -142,6 +142,11 @@ def default_catalog() -> DeviceCatalog:
                 queue="cpu-queue", cpu="4", memory="8Gi", runtime="cpu",
             ),
             DeviceFlavor(
+                name="v5e-1", description="one v5e chip on one host",
+                generation="v5e", topology="1x1", hosts=1, chips_per_host=1,
+                queue="tpu-small-queue",
+            ),
+            DeviceFlavor(
                 name="v5e-4", description="single-host v5e slice",
                 generation="v5e", topology="2x2", hosts=1, chips_per_host=4,
                 queue="tpu-small-queue",
@@ -165,6 +170,9 @@ def default_catalog() -> DeviceCatalog:
         quotas=[
             FlavorQuota(flavor="cpu-test", nominal_chips=2),
             FlavorQuota(flavor="cpu-test-2", nominal_chips=4),
+            # one chip, one process: a second trainer (or a serve worker) on
+            # the same chip fails or hangs, so the quota is the machine
+            FlavorQuota(flavor="v5e-1", nominal_chips=1),
             FlavorQuota(flavor="v5e-4", nominal_chips=8),
             FlavorQuota(flavor="v5e-8", nominal_chips=16),
             FlavorQuota(flavor="v5e-16", nominal_chips=32),

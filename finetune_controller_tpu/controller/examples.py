@@ -65,6 +65,13 @@ class LoRASFTArguments(TrainingArguments):
         description="Checkpoint cadence (optimizer steps) — also the resume "
                     "granularity after preemption or a supervised retry",
     )
+    frozen_dtype: str = Field(
+        "", pattern="^(|bfloat16|float32)$",
+        description="Storage dtype of the frozen base in LoRA/QLoRA modes "
+                    "(empty = the model's float32): bfloat16 halves its HBM "
+                    "footprint — what lets tinyllama-1.1b at batch 8 x seq "
+                    "2048 fit one 16 GB v5e chip",
+    )
 
 
 class DPOArguments(LoRASFTArguments):
@@ -151,6 +158,9 @@ class TinyLlamaLoRA(BaseFineTuneJob):
     model_preset = "tinyllama-1.1b"
     default_device = "cpu-test"
     promotion_path = "models/tinyllama"
+    # smoke spec trains on synthetic data when no dataset is provided —
+    # how chip_smoke.py submits it at full size on the v5e-1 flavor
+    dataset = TrainingDataset(required=False, description="optional jsonl")
 
     training_arguments: LoRASFTArguments
 

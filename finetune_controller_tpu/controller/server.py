@@ -226,7 +226,19 @@ def _spawn_bg(app: web.Application, coro) -> None:
 
 
 async def health(request: web.Request) -> web.Response:
-    return web.json_response({"status": "ok"})
+    """Liveness, plus whether THIS process has started a JAX backend.  On a
+    TPU host a process with a backend holds the chip, and no trainer or
+    serve worker can start on it: with ``serve_transport=process`` this
+    stays false for the life of the server (``chip_smoke.py`` checks it
+    after every phase); with ``inproc`` it turns true at the first model
+    load, by design (docs/serving.md)."""
+    import sys
+
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    return web.json_response({
+        "status": "ok",
+        "jax_backend": bool(bridge and bridge.backends_are_initialized()),
+    })
 
 
 async def list_models(request: web.Request) -> web.Response:

@@ -31,9 +31,9 @@ def _parse_token_list(raw: str) -> list[int]:
 
 
 def main(argv: list[str] | None = None) -> int:
-    from ..platform import assert_platform_env
+    from ..platform import enable_compile_cache
 
-    assert_platform_env()
+    enable_compile_cache()
 
     p = argparse.ArgumentParser(
         prog="ftc-generate",

@@ -154,6 +154,46 @@ def _check_positive_int(name: str, raw) -> int:
     return val
 
 
+def _paged_vmem_budget() -> int:
+    """Bytes of VMEM the paged kernel may use: ``FTC_PAGED_VMEM_MB`` (MiB),
+    default ``ops.pallas.paged_attention.DEFAULT_VMEM_MB``.  One number is
+    both the dispatch budget and the ``vmem_limit_bytes`` the kernel hands
+    the compiler."""
+    import os
+
+    from .pallas.paged_attention import DEFAULT_VMEM_MB
+
+    return _check_positive_int(
+        "FTC_PAGED_VMEM_MB", os.environ.get("FTC_PAGED_VMEM_MB") or DEFAULT_VMEM_MB
+    ) << 20
+
+
+def paged_kernel_eligible(
+    q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, page_table: jax.Array
+) -> bool:
+    """Whether the chip's compiler takes the Pallas paged kernel for this
+    call: matching storage dtypes, tile-aligned page and head shapes
+    (``paged_attention_supported``), and a VMEM need
+    (``paged_attention_vmem_bytes``) within the ``FTC_PAGED_VMEM_MB`` budget
+    the kernel is compiled with.  Arguments need only ``.shape``/``.dtype``;
+    ``tests/test_chip_compile.py`` holds this predicate to real compiles."""
+    if q.dtype != k_pool.dtype or q.dtype != v_pool.dtype:
+        return False
+    from .pallas.paged_attention import (
+        paged_attention_supported,
+        paged_attention_vmem_bytes,
+    )
+
+    _, t, hkv, d = k_pool.shape
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    if not paged_attention_supported(t, hkv, d, itemsize):
+        return False
+    need = paged_attention_vmem_bytes(
+        q.shape, page_table.shape[1], t, hkv, itemsize
+    )
+    return need <= _paged_vmem_budget()
+
+
 def paged_attention_impl(
     q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, page_table: jax.Array
 ) -> str:
@@ -161,13 +201,11 @@ def paged_attention_impl(
     ``gather``.
 
     ``FTC_PAGED_ATTN`` ∈ {``auto`` (default), ``kernel``, ``gather``} —
-    ``auto`` picks the Pallas kernel on TPU when the shapes are eligible
-    (matching storage dtypes — the kernel's bit-identity contract needs
-    storage-dtype matmul inputs — and the per-lane gathered cache fits the
-    ``FTC_PAGED_VMEM_MB`` scratch budget, default 64), the gather oracle
-    otherwise.  Explicit ``kernel`` is the operator override and the CI
-    bit-identity hook: it forces the kernel everywhere, including
-    interpret mode on CPU.
+    ``auto`` picks the Pallas kernel on TPU when
+    :func:`paged_kernel_eligible` says the compiler takes it, the gather
+    path otherwise.  Explicit ``kernel`` is the operator override and the
+    CI hook: it forces the kernel everywhere (interpret mode on CPU) and
+    fails to compile on a TPU where ``auto`` would have declined.
     """
     import os
 
@@ -176,23 +214,11 @@ def paged_attention_impl(
     )
     if impl != "auto":
         return impl
-    if jax.default_backend() != "tpu":
-        return "gather"
-    if q.dtype != k_pool.dtype or q.dtype != v_pool.dtype:
-        return "gather"
-    from .pallas.paged_attention import paged_attention_vmem_bytes
-
-    budget_mb = _check_positive_int(
-        "FTC_PAGED_VMEM_MB", os.environ.get("FTC_PAGED_VMEM_MB") or 64
-    )
-    need = paged_attention_vmem_bytes(
-        q.shape,
-        page_table.shape[1],
-        k_pool.shape[1],
-        k_pool.shape[2],
-        k_pool.dtype.itemsize,
-    )
-    return "kernel" if need <= budget_mb << 20 else "gather"
+    if jax.default_backend() == "tpu" and paged_kernel_eligible(
+        q, k_pool, v_pool, page_table
+    ):
+        return "kernel"
+    return "gather"
 
 
 def paged_cache_attention(
@@ -207,18 +233,18 @@ def paged_cache_attention(
     Two implementations behind one seam, dispatched by
     :func:`paged_attention_impl` (``FTC_PAGED_ATTN``):
 
-    * ``gather`` — the reference oracle: per-lane logical caches are
-      gathered from the shared pools and the exact
-      :func:`chunked_cache_attention` numerics run over them, so a paged
-      decode/suffix-prefill is bit-identical to the unpaged one whenever
-      the gathered length equals the contiguous cache length (the engine
-      sizes ``MP*T == cache_len`` when the page size divides it; otherwise
-      the tail positions are masked exact-zeros like any other
-      beyond-index slot).
+    * ``gather`` — the reference: per-lane logical caches are gathered from
+      the shared pools and the exact :func:`chunked_cache_attention`
+      numerics run over them, so a paged decode/suffix-prefill is
+      bit-identical to the unpaged one whenever the gathered length equals
+      the contiguous cache length (the engine sizes ``MP*T == cache_len``
+      when the page size divides it; otherwise the tail positions are
+      masked exact-zeros like any other beyond-index slot).
     * ``kernel`` — ``ops.pallas.paged_attention``: walks the page table in
       the BlockSpec index map so each KV page is read from HBM once and
-      the gathered copy only ever exists in VMEM scratch.  Bit-identical
-      to the gather path by construction (CI proves it in interpret mode).
+      the gathered copy only ever exists in VMEM scratch.  f32-accumulated
+      MXU matmuls: agrees with the gather path to storage-dtype rounding
+      (a bf16 ulp), not bit for bit.
 
     S = 1 is the decode step, S > 1 a (bucket-padded) prefill or suffix
     prefill.
@@ -226,7 +252,10 @@ def paged_cache_attention(
     if paged_attention_impl(q, k_pool, v_pool, page_table) == "kernel":
         from .pallas.paged_attention import paged_attention
 
-        return paged_attention(q, k_pool, v_pool, page_table, idx)
+        return paged_attention(
+            q, k_pool, v_pool, page_table, idx,
+            vmem_limit_bytes=_paged_vmem_budget(),
+        )
     return chunked_cache_attention(
         q,
         paged_gather(k_pool, page_table),
@@ -253,8 +282,8 @@ def _check_exp_dtype(name: str, raw: str) -> str:
 
 def flash_tuning_kwargs(tuning: dict | None = None) -> dict:
     """Validated flash-kernel overrides — shared by every flash call site
-    (the plain dispatch and the ring inner), so a tuning sweep
-    (``scripts/tpu_session.py``) moves all of them together.
+    (the plain dispatch and the ring inner), so a tuning sweep moves all of
+    them together.
 
     Two sources, env over spec: the job's typed config
     (``LlamaConfig.kernel_tuning()`` — how API-submitted jobs carry the
@@ -285,6 +314,59 @@ def flash_tuning_kwargs(tuning: dict | None = None) -> dict:
     return kwargs
 
 
+def _flash_attention_on_mesh(q, k, v, segment_ids, kwargs: dict) -> jax.Array:
+    """The Pallas flash kernel, one call per device.
+
+    The chip's compiler cannot partition a Mosaic kernel, so under a mesh of
+    more than one device (the trainer's, installed by
+    ``parallel.ring.ring_mesh``) the call runs inside ``shard_map``: the
+    batch splits over ``dp``/``fsdp`` as the trainer already shards it, the
+    heads over ``tp``, and each device runs the kernel on its own shard — no
+    q/k/v is gathered.  Heads split only where ``tp`` divides both the query
+    and the KV head count (a shard then keeps whole GQA groups); otherwise
+    they stay whole on every ``tp`` member, which repeats the attention
+    ``tp`` times over but is still correct.  The other axes (``sp``/``ep``/
+    ``pp``) see replicated operands.  A single-device mesh, no mesh, and a
+    caller that is already inside a ``shard_map`` body (the pipeline stages)
+    get the bare kernel call.
+    """
+    from ..parallel.ring import get_ring_mesh
+    from .pallas.flash_attention import flash_attention
+
+    mesh = get_ring_mesh()
+    if (
+        mesh is None
+        or mesh.size == 1
+        or jax.sharding.get_abstract_mesh().manual_axes
+    ):
+        return flash_attention(q, k, v, segment_ids=segment_ids, **kwargs)
+
+    from jax.sharding import PartitionSpec as P
+
+    from ..parallel.mesh import AxisNames
+
+    tp = mesh.shape.get(AxisNames.TENSOR, 1)
+    heads = (
+        AxisNames.TENSOR
+        if tp > 1 and q.shape[2] % tp == 0 and k.shape[2] % tp == 0 else None
+    )
+    qkv_spec = P(AxisNames.BATCH_AXES, None, heads, None)
+    operands, in_specs = (q, k, v), (qkv_spec, qkv_spec, qkv_spec)
+    if segment_ids is not None:
+        operands += (segment_ids,)
+        in_specs += (P(AxisNames.BATCH_AXES, None),)
+
+    def local(q, k, v, segment_ids=None):
+        return flash_attention(q, k, v, segment_ids=segment_ids, **kwargs)
+
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec,
+        # the pallas_call declares no vma on its out_shapes, so the static
+        # varying-axes checker cannot track it (as in parallel/ring.py)
+        check_vma=False,
+    )(*operands)
+
+
 def causal_attention(
     q: jax.Array,
     k: jax.Array,
@@ -308,15 +390,8 @@ def causal_attention(
     if impl == "xla":
         return xla_causal_attention(q, k, v, segment_ids=segment_ids)
     if impl == "pallas":
-        try:
-            from .pallas.flash_attention import flash_attention
-        except ImportError as e:
-            raise NotImplementedError(
-                "attention impl='pallas' requires ops.pallas.flash_attention "
-                "(not built in this installation); use impl='xla'"
-            ) from e
-        return flash_attention(
-            q, k, v, segment_ids=segment_ids, **flash_tuning_kwargs(tuning)
+        return _flash_attention_on_mesh(
+            q, k, v, segment_ids, flash_tuning_kwargs(tuning)
         )
     if impl in ("ring", "ulysses"):
         from ..parallel.ring import get_ring_mesh, ring_attention_sharded

@@ -77,10 +77,7 @@ def _resolve_tuning(
 
 
 def _dimension_semantics(*sem):
-    # modern jax renamed TPUCompilerParams -> CompilerParams; support both so
-    # the container's baked-in 0.4.x toolchain runs these kernels unmodified
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return params_cls(dimension_semantics=sem)
+    return pltpu.CompilerParams(dimension_semantics=sem)
 
 
 def _segment_mask(qseg_ref, kseg_ref):

@@ -60,7 +60,7 @@ def collective_diagnostics(
     ``psum`` (gradient reduction), ``all_gather`` (FSDP parameter gather),
     ``ppermute`` ring step (ring attention / pipeline transfers).
     """
-    from .shard_map_compat import shard_map
+    from jax import shard_map
 
     devs = list(devices if devices is not None else jax.devices())
     n = len(devs)

@@ -49,7 +49,8 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from .shard_map_compat import axis_size, shard_map
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import AxisNames as Ax

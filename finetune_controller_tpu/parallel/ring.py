@@ -29,7 +29,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from .shard_map_compat import axis_size, pcast, shard_map
+from jax import shard_map
+from jax.lax import axis_size, pcast
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import AxisNames

@@ -34,7 +34,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from .shard_map_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import AxisNames

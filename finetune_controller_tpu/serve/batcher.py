@@ -597,4 +597,6 @@ class Batcher:
             "queue_depth_by_tenant": self.queue_depth_by_tenant(),
             "lanes_by_tenant": self.engine.active_by_tenant(),
             "tokens_by_tenant": dict(self.engine.tokens_by_tenant),
+            # device + warm-start + attention dispatch of this engine
+            "runtime": self.engine.runtime_info(),
         }

@@ -341,6 +341,44 @@ class BatchEngine:
         from ..analysis.transfer_guard import TransferGuard
 
         self._transfer_guard = TransferGuard.from_env(name="serve-decode")
+        #: seconds :func:`warm_engine` spent compiling (and once running)
+        #: every program this engine uses; None until it has run
+        self.warm_start_s: float | None = None
+        self._runtime = self._runtime_static()
+
+    def runtime_info(self) -> dict[str, Any]:
+        """Where this engine runs, for the stats every transport already
+        ships (``Batcher.stats`` → probe → ``GET /admin/serve``): the device
+        as JAX reports it, the warm-start seconds, and which paged-attention
+        implementation each compiled program resolves to.  A control plane
+        that stays off JAX learns the device from this."""
+        return {**self._runtime, "warm_start_s": self.warm_start_s}
+
+    def _runtime_static(self) -> dict[str, Any]:
+        from ..platform import device_report
+
+        info: dict[str, Any] = device_report()
+        if self.paged:
+            from ..ops.attention import paged_attention_impl
+
+            cfg, c = self._dcfg, self.config
+            pool = jax.ShapeDtypeStruct(
+                (c.effective_pool_pages, c.page_tokens, cfg.n_kv_heads,
+                 cfg.head_dim), cfg.dtype)
+
+            def impl(b: int, s: int) -> str:
+                return paged_attention_impl(
+                    jax.ShapeDtypeStruct(
+                        (b, s, cfg.n_heads, cfg.head_dim), cfg.dtype),
+                    pool, pool,
+                    jax.ShapeDtypeStruct((b, c.pages_per_lane), jnp.int32),
+                )
+
+            info["paged_attention"] = {
+                "decode": impl(c.slots, 1),
+                **{f"prefill_{s}": impl(1, s) for s in c.prompt_buckets},
+            }
+        return info
 
     # ---- mode helpers -----------------------------------------------------
 
@@ -1196,6 +1234,7 @@ def warm_engine(engine: "BatchEngine", *, warm_new: int | None = None) -> None:
     so the recompile guard stays armed and accurate."""
     new_tokens = warm_new if warm_new is not None \
         else min(2, engine.config.max_new_tokens)
+    t0 = time.perf_counter()
     for bucket in engine.config.prompt_buckets:
         engine.run([GenRequest(
             request_id=f"_warm-{bucket}", tokens=[1] * bucket,
@@ -1208,3 +1247,4 @@ def warm_engine(engine: "BatchEngine", *, warm_new: int | None = None) -> None:
     engine.prefix_misses_total = 0
     engine.prefill_tokens_saved_total = 0
     engine.tokens_by_tenant = {}
+    engine.warm_start_s = round(time.perf_counter() - t0, 3)

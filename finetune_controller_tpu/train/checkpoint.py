@@ -69,9 +69,19 @@ class CheckpointManager:
         self.keep = keep
         os.makedirs(self.directory, exist_ok=True)
         self._sweep_stale_tmp()
-        self._ckptr = ocp.StandardCheckpointer()
+        self._ckptr_obj: ocp.StandardCheckpointer | None = None
         self._pending: threading.Thread | None = None
         self._pending_error: list[BaseException] = []
+
+    @property
+    def _ckptr(self) -> ocp.StandardCheckpointer:
+        """Orbax's checkpointer, built on the first save or restore: its
+        constructor starts the JAX backend, and a manager that only lists
+        steps — the API server staging a promoted checkpoint for its worker
+        processes — must not take the chip."""
+        if self._ckptr_obj is None:
+            self._ckptr_obj = ocp.StandardCheckpointer()
+        return self._ckptr_obj
 
     def _sweep_stale_tmp(self) -> None:
         """Remove uncommitted ``step_N.tmp`` staging dirs left by a crash.
@@ -109,7 +119,8 @@ class CheckpointManager:
         if self._pending is not None:
             self._pending.join()
             self._pending = None
-        self._ckptr.wait_until_finished()
+        if self._ckptr_obj is not None:
+            self._ckptr_obj.wait_until_finished()
         if self._pending_error:
             err = self._pending_error.pop()
             raise RuntimeError(f"background checkpoint save failed: {err}") from err

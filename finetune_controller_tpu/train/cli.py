@@ -187,19 +187,19 @@ def run_job(spec: dict) -> None:
 
     import jax
 
-    from ..platform import assert_platform_env
+    from ..platform import enable_compile_cache
 
-    assert_platform_env()
+    cache_dir = enable_compile_cache()
     maybe_initialize_distributed()
 
     model_cfg = build_model_config(spec)
     train_cfg = build_train_config(spec)
     mesh = build_mesh(spec)
     logger.info(
-        "job %s: %s params=%.1fM mesh=%s devices=%d",
+        "job %s: %s params=%.1fM mesh=%s devices=%d (%s) compile_cache=%s",
         spec.get("job_id", "?"), spec.get("model", {}).get("preset"),
         model_cfg.param_count() / 1e6, dict(zip(mesh.axis_names, mesh.devices.shape)),
-        jax.device_count(),
+        jax.device_count(), jax.devices()[0].device_kind, cache_dir,
     )
     if is_rank_zero():
         with open(os.path.join(artifacts_dir, "resolved_config.json"), "w") as f:

@@ -1235,6 +1235,7 @@ class Trainer:
         events_log.emit(
             "train-started", step=start_step,
             resumed_from=start_step if start_step else None,
+            **self._runtime_attrs(),
         )
         # chaos hook (resilience/faults.py): a seeded kill-at-step armed via
         # FTC_FAULT_* env vars — None outside fault-injection runs
@@ -1537,6 +1538,34 @@ class Trainer:
                 )
                 if not propagating:
                     events_log.emit(
-                        "train-finished", step=self.cfg.total_steps
+                        "train-finished", step=self.cfg.total_steps,
+                        device_peak_bytes=self._device_bytes("peak_bytes_in_use"),
                     )
         return state
+
+    def _runtime_attrs(self) -> dict:
+        """Where this run landed, for the ``train-started`` event: the device
+        as JAX reports it, the mesh, which attention implementation the step
+        resolves to, and the bytes the freshly-initialised state holds on
+        each local device.  The control plane (and ``chip_smoke.py``) stays
+        off JAX and learns the device from this."""
+        from ..ops.kernel_bench import preferred_impl
+        from ..platform import device_report
+
+        impl = getattr(self.model_cfg, "attention_impl", None)
+        if impl == "auto":
+            impl = preferred_impl(self.cfg.seq_len)
+        return {
+            **device_report(),
+            "mesh": {k: v for k, v in self.mesh.shape.items() if v > 1},
+            "attention_impl": impl,
+            "device_state_bytes": self._device_bytes("bytes_in_use"),
+        }
+
+    def _device_bytes(self, stat: str) -> list[int] | None:
+        """``memory_stats()[stat]`` of every local device, or None where the
+        backend keeps no such statistics (the CPU)."""
+        stats = [d.memory_stats() for d in self.mesh.local_devices]
+        if not all(s and stat in s for s in stats):
+            return None
+        return [int(s[stat]) for s in stats]

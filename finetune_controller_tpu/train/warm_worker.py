@@ -29,12 +29,13 @@ import sys
 def main() -> int:
     # Platform config (JAX_PLATFORMS, XLA_FLAGS device count) comes from the
     # spawn env — the pool keys workers by it, so this matches the job's.
-    from ..platform import assert_platform_env
-
-    assert_platform_env()
-
+    # On a TPU flavor this process HOLDS the chip from here on: no job that
+    # does not claim this worker can start on it (docs/operator_guide.md).
     import jax
 
+    from ..platform import enable_compile_cache
+
+    enable_compile_cache()
     jax.devices()  # force backend init now, not at first trace
 
     # pre-import the whole training stack (flax/optax/orbax/models/data) —

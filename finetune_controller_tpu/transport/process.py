@@ -15,9 +15,11 @@ Spawn handshake::
 bounded by ``serve_worker_spawn_timeout_s``; a worker that dies or stalls
 during the handshake is killed and the log tail rides the raised error.
 
-The spawn env is the parent's env (so ``JAX_PLATFORMS``, compilation-cache
-settings and the chaos hand's ``FTC_FAULT_SERVE_*`` all cross the process
-boundary — the fault-injection satellite) plus per-worker overrides.  Ports:
+The spawn env is the parent's env (so ``JAX_PLATFORMS``,
+``JAX_COMPILATION_CACHE_DIR`` and the chaos hand's ``FTC_FAULT_SERVE_*`` all
+cross the process boundary — the fault-injection satellite) plus per-worker
+overrides; the parent itself never touches JAX, and each worker resolves its
+own compile cache (``platform.enable_compile_cache``).  Ports:
 ``serve_worker_port_base`` > 0 assigns ``base + n`` per spawn; 0 (default)
 binds ephemeral ports and reads the bound port back from ``transport.json``
 — collision-free on shared CI hosts.
@@ -46,25 +48,6 @@ from .client import RemoteReplica, _Connection
 from .worker import TRANSPORT_FILENAME
 
 logger = logging.getLogger(__name__)
-
-
-def _jax_cache_env() -> dict[str, str]:
-    """Forward the parent's persistent-compilation-cache config into worker
-    env: workers recompile the same tiny programs otherwise, and the test
-    suite's warm cache (tests/conftest.py) must reach spawned workers too."""
-    env: dict[str, str] = {}
-    try:
-        import jax
-
-        cache_dir = jax.config.jax_compilation_cache_dir
-        if cache_dir:
-            env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
-            env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = str(
-                jax.config.jax_persistent_cache_min_compile_time_secs
-            )
-    except Exception:  # pragma: no cover - jax config surface drift
-        logger.debug("jax cache env forwarding skipped", exc_info=True)
-    return env
 
 
 @dataclasses.dataclass
@@ -96,7 +79,6 @@ class ProcessTransport:
 
     def _spawn_env(self) -> dict[str, str]:
         env = dict(os.environ)
-        env.update(_jax_cache_env())
         # the worker runs `-m finetune_controller_tpu.transport.worker` from
         # its sandbox cwd: make sure the package resolves even when this
         # process imported it off sys.path (source checkout, test run)

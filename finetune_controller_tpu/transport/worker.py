@@ -147,6 +147,8 @@ class WorkerServer:
         self._server: asyncio.base_events.Server | None = None
         self.port: int | None = None
         self._exit_requested = asyncio.Event()
+        #: live connection handlers — stop() hangs up on them
+        self._conn_tasks: set[asyncio.Task] = set()
         self.exit_code = 0
         #: request_id -> future of the in-flight attempt (duplicates attach)
         self._inflight: dict[str, asyncio.Future] = {}
@@ -198,6 +200,11 @@ class WorkerServer:
             self._hb_task = None
         if self._server is not None:
             self._server.close()
+            # wait_closed() waits for every open connection (Python 3.12+),
+            # and the parent keeps its end open until this process has gone:
+            # hang up first, or a drained worker never exits by itself
+            for task in list(self._conn_tasks):
+                task.cancel()
             await self._server.wait_closed()
             self._server = None
         await self.batcher.close()
@@ -214,6 +221,8 @@ class WorkerServer:
 
         lock = asyncio.Lock()  # one response frame at a time per connection
         tasks: set[asyncio.Task] = set()
+        me = asyncio.current_task()
+        self._conn_tasks.add(me)
 
         async def respond(doc: dict) -> None:
             async with lock:
@@ -248,6 +257,7 @@ class WorkerServer:
                 tasks.add(task)
                 task.add_done_callback(tasks.discard)
         finally:
+            self._conn_tasks.discard(me)
             for t in tasks:
                 t.cancel()
             writer.close()
@@ -546,6 +556,9 @@ def main(argv: list[str] | None = None) -> int:
     )
     spec = WorkerSpec.load(ns.spec)
     os.makedirs(spec.sandbox, exist_ok=True)
+    from ..platform import enable_compile_cache
+
+    logger.info("compile cache: %s", enable_compile_cache())
     return asyncio.run(_amain(spec))
 
 

@@ -23,9 +23,9 @@ sys.path.insert(0, str(REPO))
 
 
 def main(argv: list[str] | None = None) -> int:
-    from finetune_controller_tpu.platform import assert_platform_env
+    from finetune_controller_tpu.platform import enable_compile_cache
 
-    assert_platform_env()
+    enable_compile_cache()
 
     p = argparse.ArgumentParser(prog="fidelity-proof")
     p.add_argument("--work-dir", default=str(REPO / "artifacts" / "fidelity"))
@@ -34,8 +34,7 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--corpus-bytes", type=int, default=400_000)
     p.add_argument("--max-new-tokens", type=int, default=48)
     p.add_argument("--session-log", default=str(REPO / "tpu_session.jsonl"),
-                   help="where the TPU-run record is appended "
-                        "(scripts/tpu_session.py passes its --log here)")
+                   help="where the TPU-run record is appended")
     args = p.parse_args(argv)
 
     import jax

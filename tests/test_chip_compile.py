@@ -1,0 +1,204 @@
+"""Compile the main path's kernels for a described TPU v5e — no chip attached.
+
+The TPU compiler is installed next to the CPU backend and compiles for a
+topology that is described, not attached (``v5e:2x2``).  Interpret-mode tests
+cannot see what it refuses: a matmul accumulator it does not take, a slice off
+the tiling, more VMEM than a kernel may use, a Mosaic kernel left for the
+partitioner to split.  Each case here is a compile of a second or two at the
+real serve/train widths; nothing runs, so nothing here says anything about
+results or times (``chip_smoke.py`` does).
+
+Code that asks ``jax.default_backend()`` still sees the CPU here, so the
+kernels are compiled directly with ``interpret=False`` rather than through
+their dispatch.  The persistent compilation cache is off around these
+compiles: an entry written for a described device cannot be read back without
+one, and the next run would only warn about it.
+"""
+
+from __future__ import annotations
+
+import os
+import re
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from finetune_controller_tpu.ops.attention import (
+    _flash_attention_on_mesh,
+    paged_kernel_eligible,
+)
+from finetune_controller_tpu.ops.pallas.flash_attention import flash_attention
+from finetune_controller_tpu.ops.pallas.paged_attention import (
+    DEFAULT_VMEM_MB,
+    _paged_attention,
+)
+from finetune_controller_tpu.parallel.mesh import MeshSpec
+from finetune_controller_tpu.parallel.ring import ring_mesh
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {exc}")
+    return topo.devices
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _custom_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+# ---------------------------------------------------------------------------
+# flash attention: the training kernels
+# ---------------------------------------------------------------------------
+
+#: (B, S, H, Hkv, D): the TinyLlama training shape chip_smoke.py runs, and a
+#: head-dim-128 GQA shape (Llama-3 / Mistral heads at a batch that fits)
+FLASH_SHAPES = {
+    "tinyllama-b8-s2048": (8, 2048, 32, 4, 64),
+    "d128-b2-s2048": (2, 2048, 32, 8, 128),
+}
+
+
+@pytest.mark.parametrize("shape,segments", [
+    ("tinyllama-b8-s2048", False), ("tinyllama-b8-s2048", True),
+    ("d128-b2-s2048", False),
+], ids=["tinyllama-plain", "tinyllama-segments", "d128-plain"])
+def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
+    b, s, h, hkv, d = FLASH_SHAPES[shape]
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=one)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), BF16, sharding=one)
+    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one)
+
+    def loss(q, k, v, seg):
+        out = flash_attention(
+            q, k, v, segment_ids=seg if segments else None, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, seg).compile()
+    assert _custom_calls(compiled) == 3  # forward + dQ + dK/dV
+
+
+# ---------------------------------------------------------------------------
+# paged attention: the serve kernel
+# ---------------------------------------------------------------------------
+
+#: (B, S, H, Hkv, D, T, MP) at the default serve config (16-token pages, 40
+#: pages per lane): tinyllama-1.1b decode and bucketed prefill, and a
+#: head-dim-128 model (Hkv=8) at decode and at the widest prefill bucket
+PAGED_SHAPES = {
+    "tinyllama-decode": (8, 1, 32, 4, 64, 16, 40),
+    "tinyllama-prefill-128": (1, 128, 32, 4, 64, 16, 40),
+    "tinyllama-prefill-512": (1, 512, 32, 4, 64, 16, 40),
+    "d128-decode": (8, 1, 32, 8, 128, 16, 40),
+    "d128-prefill-512": (1, 512, 32, 8, 128, 16, 40),
+    # a 8k-token lane: the scratch cache alone is 32 MiB of the 64 MiB budget
+    "d128-decode-8k": (8, 1, 32, 8, 128, 16, 512),
+}
+
+
+def _paged_args(device, b, s, h, hkv, d, t, mp):
+    one = SingleDeviceSharding(device)
+    pages = b * mp + 1
+    return (
+        jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=one),
+        jax.ShapeDtypeStruct((pages, t, hkv, d), BF16, sharding=one),
+        jax.ShapeDtypeStruct((pages, t, hkv, d), BF16, sharding=one),
+        jax.ShapeDtypeStruct((b, mp), jnp.int32, sharding=one),
+        jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one),
+    )
+
+
+@pytest.mark.parametrize("shape", list(PAGED_SHAPES), ids=list(PAGED_SHAPES))
+def test_paged_kernel_compiles_for_v5e_where_auto_picks_it(
+        v5e, shape, monkeypatch):
+    """Whatever ``auto`` would hand the kernel on a TPU, the compiler takes —
+    under exactly the VMEM limit the dispatch budgets against."""
+    monkeypatch.delenv("FTC_PAGED_VMEM_MB", raising=False)
+    args = _paged_args(v5e[0], *PAGED_SHAPES[shape])
+    assert paged_kernel_eligible(*args[:4])
+    compiled = _paged_attention.lower(
+        *args, interpret=False, vmem_limit_bytes=DEFAULT_VMEM_MB << 20,
+    ).compile()
+    assert _custom_calls(compiled) == 1
+
+
+def test_paged_kernel_is_refused_past_its_vmem_limit(v5e, monkeypatch):
+    """The limit handed to the compiler is a real one: the widest prefill
+    does not compile under 8 MiB — and at that budget ``auto`` declines it,
+    so the dispatch never asks for what the compiler would refuse."""
+    args = _paged_args(v5e[0], *PAGED_SHAPES["d128-prefill-512"])
+    monkeypatch.setenv("FTC_PAGED_VMEM_MB", "8")
+    assert not paged_kernel_eligible(*args[:4])
+    with pytest.raises(Exception, match="(?i)vmem"):
+        _paged_attention.lower(
+            *args, interpret=False, vmem_limit_bytes=8 << 20).compile()
+
+
+# ---------------------------------------------------------------------------
+# four chips: the flash kernel under the trainer's mesh
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "mesh_axes", [{"fsdp": 4}, {"fsdp": 2, "tp": 2}], ids=["fsdp4", "fsdp2-tp2"])
+def test_sharded_flash_compiles_for_four_chips_without_gathering(
+        v5e, mesh_axes):
+    """The Mosaic kernel cannot be partitioned by the compiler; under a mesh
+    of several devices ``_flash_attention_on_mesh`` wraps it in shard_map —
+    batch over dp/fsdp, heads over tp — so each chip runs it on its own
+    shard: the kernels are in the program and no q/k/v all-gather is."""
+    b, s, h, hkv, d = FLASH_SHAPES["tinyllama-b8-s2048"]
+    mesh = MeshSpec(**mesh_axes).build(v5e)
+    heads = "tp" if mesh_axes.get("tp", 1) > 1 else None
+    sharding = NamedSharding(mesh, P(("dp", "fsdp"), None, heads, None))
+    q = jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), BF16, sharding=sharding)
+
+    def loss(q, k, v):
+        out = _flash_attention_on_mesh(q, k, v, None, {"interpret": False})
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    with mesh, ring_mesh(mesh):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    text = compiled.as_text()
+    assert _custom_calls(compiled) == 3
+    assert not re.search(r"\ball-gather(-start)?\(", text), (
+        "q/k/v are gathered in front of the kernel")
+
+
+def test_bare_flash_under_a_mesh_is_what_the_compiler_refuses(v5e):
+    """Why the wrap exists: the same call left to the partitioner fails."""
+    b, s, h, hkv, d = FLASH_SHAPES["tinyllama-b8-s2048"]
+    mesh = MeshSpec(fsdp=4).build(v5e)
+    sharding = NamedSharding(mesh, P(("dp", "fsdp"), None, None, None))
+    q = jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((b, s, hkv, d), BF16, sharding=sharding)
+    with pytest.raises(Exception, match="shard_map"):
+        jax.jit(lambda q, k, v: flash_attention(q, k, v, interpret=False)
+                ).lower(q, kv, kv).compile()

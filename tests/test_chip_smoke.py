@@ -1,0 +1,360 @@
+"""chip_smoke.py off the chip: control flow, exit codes, and the last line.
+
+The full run needs a TPU (and is what the driver runs on one).  Here: the
+``--tiny`` rehearsal drives the same phases on the CPU once, a failed phase
+ends the run non-zero with no result line, the result line has exactly the
+shape the contract names and is never printed for anything but TPU children,
+and the things the smoke leans on hold without a chip — a TPU flavor pins its
+trainer to the TPU (which then fails here), and the API server starts no JAX
+backend of its own.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import importlib.util
+import json
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parents[1]
+SMOKE = REPO / "chip_smoke.py"
+
+PHASES = ["server", "submit", "train", "promote", "serve-load",
+          "serve-generate", "shutdown", "paged-parity", "compile-cache",
+          "total"]
+
+
+def _env(**overrides) -> dict:
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "FTC_DEVICE_CONFIG_FILE")}
+    env["JAX_PLATFORMS"] = "cpu"
+    env.update(overrides)
+    return env
+
+
+def _run(args, env, cwd=None, timeout=600):
+    return subprocess.run(
+        [sys.executable, str(SMOKE), *args], env=env, cwd=cwd or str(REPO),
+        capture_output=True, text=True, timeout=timeout,
+    )
+
+
+def _result_lines(stdout: str) -> list[dict]:
+    """Every stdout line that parses as a JSON object."""
+    out = []
+    for line in stdout.splitlines():
+        if line.startswith("{"):
+            out.append(json.loads(line))
+    return out
+
+
+@pytest.fixture(scope="module")
+def smoke():
+    spec = importlib.util.spec_from_file_location("chip_smoke_mod", SMOKE)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture
+def off_jax(monkeypatch):
+    """main() refuses to pass in a process that imported jax; pytest's has.
+    Hide the module for the duration of an in-process main() call."""
+    monkeypatch.delitem(sys.modules, "jax")
+
+
+# ---------------------------------------------------------------------------
+# subprocess runs
+# ---------------------------------------------------------------------------
+
+
+def test_tiny_rehearsal_runs_every_phase_and_is_not_a_chip_pass():
+    out = _run(["--tiny"], _env())
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = out.stdout.strip().splitlines()
+    phases = [l.split(":")[0][len("phase "):] for l in lines
+              if l.startswith("phase ")]
+    assert phases == PHASES
+    detail = {l.split(":")[0][len("phase "):]: json.loads(l[l.index("{"):])
+              for l in lines if l.startswith("phase ")}
+    losses = detail["train"]["losses"]
+    assert len(losses) == 24 and losses[-1] < losses[0]
+    assert detail["train"]["device"]["platform"] == "cpu"
+    assert detail["train"]["attention_impl"] == "xla"
+    assert detail["serve-load"]["paged_attention"]["decode"] == "gather"
+    assert detail["serve-load"]["warm_start_s"] > 0
+    gen = detail["serve-generate"]
+    assert gen["requests"] == 24 and gen["tokens_generated"] == 24 * 16
+    assert gen["identical_alone_and_together"] is True
+    assert gen["prefix_hits"] == 16  # waves two and three found their prompts
+    # on the CPU a prefix hit changes nothing, bit for bit
+    assert gen["prompts_moved_by_prefix_reuse"] == []
+    assert detail["shutdown"]["survivors"] == []
+    assert detail["paged-parity"]["worst_max_err"] <= \
+        detail["paged-parity"]["tolerance"]
+    # the last line is a rehearsal record: no "ok" anywhere on stdout
+    last = json.loads(lines[-1])
+    assert last == {"rehearsal": "tiny", "passed": True,
+                    "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    assert all("ok" not in rec for rec in _result_lines(out.stdout))
+
+
+def test_a_failed_phase_exits_nonzero_and_prints_no_result(tmp_path):
+    """Injected through the environment an operator controls: a device
+    catalog without the flavor the smoke submits on makes the submit phase
+    fail (HTTP 400).  The run ends there."""
+    catalog = tmp_path / "catalog.json"
+    catalog.write_text(json.dumps({
+        "flavors": [{"name": "elsewhere", "generation": "cpu", "hosts": 1,
+                     "chips_per_host": 1, "runtime": "cpu"}],
+        "quotas": [], "default_flavor": "elsewhere",
+    }))
+    out = _run(["--tiny"], _env(FTC_DEVICE_CONFIG_FILE=str(catalog)))
+    assert out.returncode == 1
+    # a failed run leaves its work directory (server log, sandboxes) behind
+    (kept,) = [l.split(": ", 1)[1] for l in out.stderr.splitlines()
+               if l.startswith("work directory kept: ")]
+    assert (Path(kept) / "server.log").exists()
+    shutil.rmtree(kept)
+    assert "FAILED: POST /jobs -> HTTP 400" in out.stderr
+    assert "unknown device 'cpu-test'" in out.stderr
+    phases = [l for l in out.stdout.splitlines() if l.startswith("phase ")]
+    assert [p.split(":")[0] for p in phases] == ["phase server"]
+    assert _result_lines(out.stdout) == []
+    # and nothing it started is left behind
+    leftovers = subprocess.run(
+        ["pgrep", "-f", "finetune_controller_tpu.controller.server"],
+        capture_output=True, text=True).stdout.split()
+    mine = [p for p in leftovers
+            if str(tmp_path) in Path(f"/proc/{p}/environ").read_text(
+                errors="replace")]
+    assert mine == []
+
+
+def test_the_script_alone_fails_without_a_result(tmp_path):
+    """In a directory that holds chip_smoke.py and nothing else of the repo."""
+    shutil.copy(SMOKE, tmp_path / "chip_smoke.py")
+    out = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=str(tmp_path), env=_env(),
+        capture_output=True, text=True, timeout=60,
+    )
+    assert out.returncode != 0
+    assert out.stdout.strip() == ""
+    assert "no finetune_controller_tpu package" in out.stderr
+
+
+@pytest.mark.slow
+def test_full_mode_fails_in_a_sandbox_without_a_chip():
+    """What the driver checks first: no accelerator -> non-zero, no result.
+    The v5e-1 flavor pins the trainer to the TPU, which is not there."""
+    out = _run([], _env())
+    assert out.returncode == 1
+    assert "Unable to initialize backend 'tpu'" in out.stderr
+    for line in out.stderr.splitlines():
+        if line.startswith("work directory kept: "):
+            shutil.rmtree(line.split(": ", 1)[1])
+    assert _result_lines(out.stdout) == []
+
+
+# ---------------------------------------------------------------------------
+# the last line (in-process: the parent is stdlib only)
+# ---------------------------------------------------------------------------
+
+V5E = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+
+
+def test_last_line_after_a_pass_is_exactly_the_contract(
+        smoke, monkeypatch, capsys, off_jax):
+    monkeypatch.setattr(smoke, "run_lifecycle", lambda *a, **k: dict(V5E))
+    assert smoke.main([]) == 0
+    last = capsys.readouterr().out.strip().splitlines()[-1]
+    assert last == ('{"ok": true, "device": {"platform": "tpu", '
+                    '"kind": "TPU v5 lite", "count": 1}}')
+
+
+@pytest.mark.parametrize("device", [
+    {"platform": "cpu", "kind": "cpu", "count": 1},
+    {"platform": "gpu", "kind": "A100", "count": 1},
+])
+def test_no_result_when_the_children_ran_anywhere_but_a_tpu(
+        smoke, monkeypatch, capsys, device, off_jax):
+    monkeypatch.setattr(smoke, "run_lifecycle", lambda *a, **k: dict(device))
+    with pytest.raises(smoke.SmokeFailure, match="expected 1 TPU chip"):
+        smoke.main([])
+    assert _result_lines(capsys.readouterr().out) == []
+
+
+def test_four_chip_option_runs_only_its_path_and_reports_count_4(
+        smoke, monkeypatch, capsys, off_jax):
+    def never(*a, **k):
+        raise AssertionError("--chips 4 ran the one-chip lifecycle")
+
+    monkeypatch.setattr(smoke, "run_lifecycle", never)
+    monkeypatch.setattr(smoke, "run_four_chips",
+                        lambda *a, **k: {**V5E, "count": 4})
+    assert smoke.main(["--chips", "4"]) == 0
+    last = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert last == {"ok": True, "device": {**V5E, "count": 4}}
+    # one chip seen where four were asked for is not a pass
+    monkeypatch.setattr(smoke, "run_four_chips", lambda *a, **k: dict(V5E))
+    with pytest.raises(smoke.SmokeFailure, match="expected 4 TPU chip"):
+        smoke.main(["--chips", "4"])
+    assert _result_lines(capsys.readouterr().out) == []
+
+
+def test_tiny_mode_never_prints_ok_even_for_a_tpu_device(
+        smoke, monkeypatch, capsys, off_jax):
+    monkeypatch.setattr(smoke, "run_lifecycle", lambda *a, **k: dict(V5E))
+    assert smoke.main(["--tiny"]) == 0
+    assert all("ok" not in rec
+               for rec in _result_lines(capsys.readouterr().out))
+
+
+def test_full_mode_runs_the_published_tinyllama_config(smoke):
+    """Full width AND depth of tinyllama-1.1b at the shape where ``auto``
+    takes the Pallas flash kernels, bf16 frozen base, rank 8, one checkpoint
+    at the last of 24 steps."""
+    from finetune_controller_tpu.controller.devices import default_catalog
+    from finetune_controller_tpu.controller.examples import BUILTIN_JOB_SPECS
+    from finetune_controller_tpu.models.llama import PRESETS
+    from finetune_controller_tpu.ops.kernel_bench import preferred_impl
+
+    cfg = smoke.mode_config(tiny=False, seed=0)
+    (spec_cls,) = [c for c in BUILTIN_JOB_SPECS
+                   if c.model_name == cfg["model_name"]]
+    assert spec_cls.model_preset == cfg["preset"] == "tinyllama-1.1b"
+    model = PRESETS[cfg["preset"]]
+    assert (model.n_layers, model.d_model, model.n_heads, model.n_kv_heads,
+            model.vocab_size) == (22, 2048, 32, 4, cfg["vocab"])
+    args = spec_cls(training_arguments=cfg["arguments"]).training_arguments
+    assert (args.batch_size, args.seq_len, args.lora_rank,
+            args.frozen_dtype) == (8, 2048, 8, "bfloat16")
+    assert args.checkpoint_every == args.total_steps == 24
+    assert preferred_impl(args.seq_len, backend="tpu") == \
+        cfg["attention_impl"] == "pallas"
+    flavor = default_catalog().get(cfg["device"])
+    assert flavor.runtime == "tpu" and flavor.total_chips == 1
+    assert default_catalog().quota_for(cfg["device"]) == 1
+
+
+# ---------------------------------------------------------------------------
+# what the smoke leans on
+# ---------------------------------------------------------------------------
+
+
+def test_tpu_flavor_pins_its_trainer_to_the_tpu(tmp_path):
+    """Only cpu flavors used to get a platform; a TPU flavor inherited the
+    server's (scripts/serve_local.sh exports cpu) and trained on the CPU
+    under a TPU flavor's name."""
+    from finetune_controller_tpu.controller.backends.local import (
+        LocalProcessBackend,
+    )
+    from finetune_controller_tpu.controller.devices import default_catalog
+    from finetune_controller_tpu.controller.objectstore import LocalObjectStore
+
+    catalog = default_catalog()
+    backend = LocalProcessBackend(
+        tmp_path / "sandbox", LocalObjectStore(tmp_path / "objects"), catalog,
+        extra_env={"JAX_PLATFORMS": "cpu"},  # the operator's env says cpu
+    )
+    assert backend._runtime_env(catalog.get("v5e-1"), 1)["JAX_PLATFORMS"] == "tpu"
+    assert backend._runtime_env(catalog.get("v5e-16"), 1)["JAX_PLATFORMS"] == "tpu"
+    cpu_env = backend._runtime_env(catalog.get("cpu-test-2"), 1)
+    assert cpu_env["JAX_PLATFORMS"] == "cpu"
+    assert "--xla_force_host_platform_device_count=2" in cpu_env["XLA_FLAGS"]
+
+
+def test_job_on_a_tpu_flavor_fails_when_its_trainer_finds_no_tpu(tmp_path):
+    from conftest import tiny_job_spec
+
+    from finetune_controller_tpu.controller.backends.local import (
+        LocalProcessBackend,
+    )
+    from finetune_controller_tpu.controller.devices import default_catalog
+    from finetune_controller_tpu.controller.objectstore import LocalObjectStore
+    from finetune_controller_tpu.controller.schemas import (
+        BackendJobState,
+        JobInput,
+    )
+
+    async def main():
+        catalog = default_catalog()
+        backend = LocalProcessBackend(
+            tmp_path / "sandbox", LocalObjectStore(tmp_path / "objects"),
+            catalog, sync_interval_s=0.2, backoff_limit=0,
+        )
+        await backend.submit(
+            JobInput(job_id="no-chip", user_id="u",
+                     model_name="tiny-test-lora", device="v5e-1", arguments={}),
+            tiny_job_spec(), catalog.get("v5e-1"), dataset_uri=None,
+            artifacts_uri="obj://artifacts/u/no-chip",
+        )
+        for _ in range(300):
+            report = await backend.get_job("no-chip")
+            if report.state in (BackendJobState.FAILED,
+                                BackendJobState.SUCCEEDED):
+                break
+            await asyncio.sleep(0.1)
+        logs = [line async for line in await backend.read_logs("no-chip")]
+        await backend.close()
+        return report, "\n".join(logs)
+
+    report, logs = asyncio.run(main())
+    assert report.state is BackendJobState.FAILED, logs[-2000:]
+    assert "Unable to initialize backend 'tpu'" in logs
+
+
+_SERVER_PROBE = r"""
+import asyncio, json, os, sys
+from pathlib import Path
+from aiohttp.test_utils import TestClient, TestServer
+from finetune_controller_tpu.controller import server
+from finetune_controller_tpu.controller.runtime import build_runtime
+from finetune_controller_tpu.serve.loader import stage_meta
+
+async def main():
+    app = server.build_app(build_runtime())
+    async with TestClient(TestServer(app)) as client:
+        assert (await client.get("/api/v1/models")).status == 200
+        assert (await client.get("/api/v1/admin/serve")).status == 200
+        assert (await client.get("/metrics")).status == 200
+        # what a process-transport load does in THIS process: read the staged
+        # spec and list the checkpoint steps (the workers load the weights)
+        staged = Path("staged")
+        (staged / "checkpoints" / "step_3").mkdir(parents=True)
+        (staged / "resolved_config.json").write_text(json.dumps({
+            "model": {"preset": "tiny-test", "lora": {"rank": 2}},
+            "training": {"mode": "lora"}}))
+        assert stage_meta(staged)["checkpoint_step"] == 3
+        return await (await client.get("/api/v1/health")).json()
+
+health = asyncio.run(main())
+initialised = False
+if "jax" in sys.modules:
+    from jax._src import xla_bridge
+    initialised = xla_bridge.backends_are_initialized()
+print(json.dumps({"health": health, "backend_initialised": initialised}))
+"""
+
+
+def test_starting_the_api_server_initialises_no_jax_backend(tmp_path):
+    """The server must never hold the chip: its trainers and serve workers
+    need it.  Import it, build it, serve requests — no backend comes up."""
+    out = subprocess.run(
+        [sys.executable, "-c", _SERVER_PROBE], cwd=str(tmp_path),
+        env=_env(PYTHONPATH=str(REPO), FTC_STATE_DIR=str(tmp_path / "state"),
+                 FTC_OBJECT_STORE_ROOT=str(tmp_path / "objects"),
+                 FTC_SERVE_TRANSPORT="process"),
+        capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-3000:]
+    rec = json.loads(out.stdout.strip().splitlines()[-1])
+    assert rec["backend_initialised"] is False, rec
+    # and the server says so itself: chip_smoke.py asks after every phase
+    assert rec["health"] == {"status": "ok", "jax_backend": False}

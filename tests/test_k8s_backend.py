@@ -160,8 +160,9 @@ def test_kueue_crds_from_catalog():
     assert sorted(covered) == [("cpu",), ("google.com/tpu",)]
     tpu_group = next(g for g in groups if g["coveredResources"] == ["google.com/tpu"])
     by_name = {f["name"]: f for f in tpu_group["flavors"]}
-    assert set(by_name) == {"v5e-4", "v5e-8", "v5e-16", "v5p-64"}
+    assert set(by_name) == {"v5e-1", "v5e-4", "v5e-8", "v5e-16", "v5p-64"}
     assert by_name["v5e-16"]["resources"][0]["nominalQuota"] == 32
+    assert by_name["v5e-1"]["resources"][0]["nominalQuota"] == 1  # the machine
     local_queues = [c for c in crds if c["kind"] == "LocalQueue"]
     assert {q["metadata"]["name"] for q in local_queues} == {
         f.queue for f in CATALOG.flavors

@@ -1,14 +1,18 @@
-"""Pallas paged-attention kernel: bit-identity + dispatch (ISSUE 16).
+"""Pallas paged-attention kernel: agreement with the gather path + dispatch.
 
 The acceptance anchors: the block-sparse kernel (``ops/pallas/
 paged_attention.py``) walks each lane's page list through the BlockSpec
-index map instead of materialising a gathered logical cache, and CI
-proves it BIT-IDENTICAL to the gather oracle in interpret mode — across
-dtypes, page-table shapes with scratch-page slots, per-row and scalar
-positions — and the serving engine under ``FTC_PAGED_ATTN=kernel``
-reproduces ``cached_generate`` bit-for-bit (greedy AND sampled, staggered
-mixed batches, page-boundary-straddling CoW splices) within the same
-compile budget as the gather path.
+index map instead of materialising a gathered logical cache.  Its matmuls
+accumulate in f32 (what the chip's compiler takes), so it agrees with the
+gather oracle to storage-dtype rounding, not bit for bit: CI pins that
+tolerance in interpret mode — across dtypes, page-table shapes with
+scratch-page slots, per-row and scalar positions, and the chip's own serve
+shapes — and the serving engine under ``FTC_PAGED_ATTN=kernel`` reproduces
+``cached_generate`` token for token on an f32 model (greedy AND sampled,
+staggered mixed batches, page-boundary-straddling CoW splices) within the
+same compile budget as the gather path.  That the kernel compiles for the
+chip is ``tests/test_chip_compile.py``; that it runs there is
+``chip_smoke.py``.
 """
 
 from __future__ import annotations
@@ -26,6 +30,7 @@ from finetune_controller_tpu.ops.attention import (
     paged_attention_impl,
     paged_cache_attention,
     paged_gather,
+    paged_kernel_eligible,
 )
 from finetune_controller_tpu.ops.pallas.paged_attention import (
     paged_attention,
@@ -47,14 +52,26 @@ def _gather_oracle(q, k_pool, v_pool, table, idx):
     )
 
 
-def _case(key, *, b, s, mp, t, h, hkv, pool_pages, dtype):
+#: max |kernel - gather| the contract allows: two roundings of the storage
+#: dtype at the outputs' magnitude (unit-normal V rows average to |out| < 4,
+#: where one bf16 ulp is 2**-6) — the same numbers chip_smoke.py holds the
+#: compiled kernel to on the chip
+TOLERANCE = {jnp.float32: 4e-6, jnp.bfloat16: 2 ** -5}
+
+
+def _max_err(got, want):
+    return float(jnp.max(jnp.abs(
+        got.astype(jnp.float32) - want.astype(jnp.float32))))
+
+
+def _case(key, *, b, s, mp, t, h, hkv, pool_pages, dtype, d=16):
     """Random pools (scratch page 0 holds garbage like the real pool),
     a random page table with some slots pointing at scratch, per-row
     positions that straddle page boundaries."""
     ks = jax.random.split(key, 5)
-    q = jax.random.normal(ks[0], (b, s, h, 16), dtype)
-    k_pool = jax.random.normal(ks[1], (pool_pages, t, hkv, 16), dtype)
-    v_pool = jax.random.normal(ks[2], (pool_pages, t, hkv, 16), dtype)
+    q = jax.random.normal(ks[0], (b, s, h, d), dtype)
+    k_pool = jax.random.normal(ks[1], (pool_pages, t, hkv, d), dtype)
+    v_pool = jax.random.normal(ks[2], (pool_pages, t, hkv, d), dtype)
     table = jax.random.randint(ks[3], (b, mp), 0, pool_pages, jnp.int32)
     # unmaterialised tail slots -> scratch page, like the engine's tables
     table = table.at[:, -1].set(0)
@@ -73,17 +90,38 @@ CASES = [
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("case", range(len(CASES)))
-def test_kernel_bit_identical_to_gather_oracle(case, dtype):
-    """The contract: not 'close', IDENTICAL — every bit, every shape."""
+def test_kernel_matches_gather_oracle(case, dtype):
+    """The contract: equal to the oracle up to one rounding of the storage
+    dtype — every shape, both dtypes."""
     spec = CASES[case]
     q, k, v, table, idx = _case(jax.random.PRNGKey(case), dtype=dtype, **spec)
     want = _gather_oracle(q, k, v, table, idx)
     got = paged_attention(q, k, v, table, idx, interpret=True)
-    assert got.dtype == want.dtype
-    assert jnp.array_equal(
-        got.view(jnp.uint16 if dtype == jnp.bfloat16 else jnp.uint32),
-        want.view(jnp.uint16 if dtype == jnp.bfloat16 else jnp.uint32),
-    ), f"kernel diverged from gather oracle on case {spec} {dtype}"
+    assert got.dtype == want.dtype and got.shape == want.shape
+    err = _max_err(got, want)
+    assert err <= TOLERANCE[dtype], (
+        f"kernel off the gather oracle by {err} on case {spec} {dtype}")
+
+
+#: the chip's serve shapes (tinyllama-1.1b and a head-dim-128 GQA model at the
+#: default buckets: 16-token pages, 40 pages per lane) — several query-row
+#: blocks, row padding (h=28: G=7), lane-sliced KV heads
+CHIP_CASES = [
+    dict(b=2, s=1, mp=40, t=16, h=32, hkv=4, pool_pages=90, d=64),
+    dict(b=1, s=200, mp=40, t=16, h=32, hkv=4, pool_pages=50, d=64),
+    dict(b=2, s=3, mp=40, t=16, h=28, hkv=4, pool_pages=90, d=128),
+]
+
+
+@pytest.mark.parametrize("case", range(len(CHIP_CASES)))
+def test_kernel_matches_gather_oracle_at_chip_shapes(case):
+    spec = CHIP_CASES[case]
+    q, k, v, table, idx = _case(
+        jax.random.PRNGKey(20 + case), dtype=jnp.bfloat16, **spec)
+    assert paged_kernel_eligible(q, k, v, table)
+    want = _gather_oracle(q, k, v, table, idx)
+    got = paged_attention(q, k, v, table, idx, interpret=True)
+    assert _max_err(got, want) <= TOLERANCE[jnp.bfloat16]
 
 
 def test_kernel_scalar_idx_matches_per_row():
@@ -101,21 +139,22 @@ def test_kernel_scalar_idx_matches_per_row():
 
 
 def test_kernel_batch_independence():
-    """The finalize step replays the oracle at batch 1, which is only
-    valid because ``chunked_cache_attention`` is batch-size-independent
-    under jit — re-prove that load-bearing assumption here, per lane."""
+    """A lane's result must not depend on which other lanes ride the batch
+    — the serving engine's batching invariance (a request decodes the same
+    alone or alongside others) rests on it.  Bit for bit, per lane."""
     q, k, v, table, idx = _case(
         jax.random.PRNGKey(11), b=4, s=2, mp=3, t=8, h=4, hkv=2,
         pool_pages=8, dtype=jnp.bfloat16,
     )
-    full = _gather_oracle(q, k, v, table, idx)
+    full = paged_attention(q, k, v, table, idx, interpret=True)
     for lane in range(4):
-        solo = _gather_oracle(
-            q[lane:lane + 1], k, v, table[lane:lane + 1], idx[lane:lane + 1]
+        solo = paged_attention(
+            q[lane:lane + 1], k, v, table[lane:lane + 1], idx[lane:lane + 1],
+            interpret=True,
         )
         assert jnp.array_equal(
             solo.view(jnp.uint16), full[lane:lane + 1].view(jnp.uint16)
-        ), f"oracle is batch-dependent at lane {lane}"
+        ), f"kernel is batch-dependent at lane {lane}"
 
 
 def test_kernel_dtype_mismatch_raises():
@@ -131,6 +170,30 @@ def test_vmem_budget_scales_with_pages():
     small = paged_attention_vmem_bytes((1, 1, 4, 16), 2, 8, 2, 2)
     big = paged_attention_vmem_bytes((1, 1, 4, 16), 64, 8, 2, 2)
     assert 0 < small < big
+
+
+def test_kernel_eligibility_is_shape_and_budget(monkeypatch):
+    """``auto`` only ever picks the kernel for calls the chip's compiler
+    takes: tile-aligned pages/heads, matching dtypes, and a VMEM need within
+    the budget the kernel is compiled with (FTC_PAGED_VMEM_MB)."""
+    monkeypatch.delenv("FTC_PAGED_VMEM_MB", raising=False)
+    S, bf = jax.ShapeDtypeStruct, jnp.bfloat16
+
+    def call(q, pool, mp, dtype=bf, pool_dtype=bf):
+        return paged_kernel_eligible(
+            S(q, dtype), S(pool, pool_dtype), S(pool, pool_dtype),
+            S((q[0], mp), jnp.int32))
+
+    assert call((8, 1, 32, 64), (512, 16, 4, 64), 40)       # tinyllama decode
+    assert call((1, 512, 32, 128), (512, 16, 8, 128), 40)   # d128 prefill
+    assert not call((8, 1, 32, 64), (512, 8, 4, 64), 40)    # half a bf16 tile
+    assert call((8, 1, 32, 64), (512, 8, 4, 64), 40, jnp.float32, jnp.float32)
+    assert not call((8, 1, 4, 16), (512, 16, 2, 16), 4)     # Hkv*D = 32 lanes
+    assert not call((8, 1, 32, 64), (512, 16, 4, 64), 40, bf, jnp.float32)
+    # a 16k-token lane at Hkv*D = 1024 needs > 64 MiB of scratch
+    assert not call((8, 1, 32, 128), (9000, 16, 8, 128), 1024)
+    monkeypatch.setenv("FTC_PAGED_VMEM_MB", "100")
+    assert call((8, 1, 32, 128), (9000, 16, 8, 128), 1024)
 
 
 # ---------------------------------------------------------------------------
@@ -156,8 +219,8 @@ def test_dispatch_auto_is_gather_off_tpu(monkeypatch):
 def test_dispatch_forced_kernel_everywhere(monkeypatch):
     monkeypatch.setenv("FTC_PAGED_ATTN", "kernel")
     assert paged_attention_impl(*_dispatch_args()) == "kernel"
-    # mixed dtypes would break the bit-identity contract in auto mode,
-    # but the explicit override is the operator's call
+    # auto declines mixed dtypes, but the explicit override is the
+    # operator's call
     q, k, v, table = _dispatch_args()
     assert paged_attention_impl(
         q.astype(jnp.bfloat16), k, v, table) == "kernel"
@@ -170,16 +233,18 @@ def test_dispatch_rejects_unknown_impl(monkeypatch):
 
 
 def test_dispatch_rejects_bad_vmem_budget(monkeypatch):
-    if jax.default_backend() != "tpu":
-        pytest.skip("VMEM budget is only consulted on TPU")
-    monkeypatch.delenv("FTC_PAGED_ATTN", raising=False)
     monkeypatch.setenv("FTC_PAGED_VMEM_MB", "-3")
+    q, k, v, table, _ = _case(
+        jax.random.PRNGKey(1), b=1, s=1, mp=2, t=16, h=4, hkv=2,
+        pool_pages=4, dtype=jnp.bfloat16, d=64,
+    )
     with pytest.raises(ValueError, match="FTC_PAGED_VMEM_MB"):
-        paged_attention_impl(*_dispatch_args())
+        paged_kernel_eligible(q, k, v, table)
 
 
 def test_paged_cache_attention_kernel_equals_gather(monkeypatch):
-    """The public seam: flipping FTC_PAGED_ATTN must not change a bit."""
+    """The public seam: flipping FTC_PAGED_ATTN moves the result by no more
+    than the storage dtype's rounding."""
     q, k, v, table, idx = _case(
         jax.random.PRNGKey(3), b=2, s=4, mp=3, t=8, h=4, hkv=2,
         pool_pages=7, dtype=jnp.bfloat16,
@@ -188,7 +253,7 @@ def test_paged_cache_attention_kernel_equals_gather(monkeypatch):
     want = jax.jit(paged_cache_attention)(q, k, v, table, idx)
     monkeypatch.setenv("FTC_PAGED_ATTN", "kernel")
     got = jax.jit(paged_cache_attention)(q, k, v, table, idx)
-    assert jnp.array_equal(got.view(jnp.uint16), want.view(jnp.uint16))
+    assert _max_err(got, want) <= TOLERANCE[jnp.bfloat16]
 
 
 # ---------------------------------------------------------------------------
@@ -198,7 +263,12 @@ def test_paged_cache_attention_kernel_equals_gather(monkeypatch):
 
 @pytest.fixture(scope="module")
 def tiny_model():
-    cfg = PRESETS["tiny-test"].replace(lora=LoRAConfig(rank=4))
+    # f32 compute: kernel and gather then differ in the last f32 bit, far
+    # below any logit gap, so token-for-token equality with cached_generate
+    # (which runs the gather numerics) tests the engine's page plumbing and
+    # not the luck of a bf16 near-tie
+    cfg = PRESETS["tiny-test"].replace(
+        lora=LoRAConfig(rank=4), dtype=jnp.float32)
     model = LlamaForCausalLM(cfg)
     variables = model.init(
         {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 4), jnp.int32)
@@ -221,9 +291,9 @@ def _kernel_engine(model, variables, **kw):
     return BatchEngine(model, variables, EngineConfig(**defaults))
 
 
-def test_engine_greedy_kernel_staggered_bit_identity(tiny_model, monkeypatch):
+def test_engine_greedy_kernel_staggered_identity(tiny_model, monkeypatch):
     """Greedy decode through the kernel — mixed prompt lengths joining
-    mid-flight — bit-identical to single-request cached_generate."""
+    mid-flight — token-identical to single-request cached_generate."""
     monkeypatch.setenv("FTC_PAGED_ATTN", "kernel")
     model, variables = tiny_model
     prompts = [
@@ -244,7 +314,7 @@ def test_engine_greedy_kernel_staggered_bit_identity(tiny_model, monkeypatch):
 
 def test_engine_sampled_kernel_reproducible(tiny_model, monkeypatch):
     """Sampled decode through the kernel reproduces the per-request
-    PRNGKey(seed) stream bit-for-bit."""
+    PRNGKey(seed) stream token for token."""
     monkeypatch.setenv("FTC_PAGED_ATTN", "kernel")
     model, variables = tiny_model
     reqs = [
@@ -264,7 +334,7 @@ def test_engine_sampled_kernel_reproducible(tiny_model, monkeypatch):
 
 def test_engine_kernel_page_boundary_cow_splice(tiny_model, monkeypatch):
     """Page size dividing neither bucket nor reuse length: the kernel
-    serves CoW boundary splices bit-identically, within the paged
+    serves CoW boundary splices token-identically, within the paged
     compile budget (len(buckets) + 1 — unchanged by the kernel)."""
     monkeypatch.setenv("FTC_PAGED_ATTN", "kernel")
     model, variables = tiny_model

@@ -414,8 +414,14 @@ def test_wedged_worker_fails_probe_lease_style(tmp_path):
     LeaseChecker pattern applied to serve workers."""
 
     async def main():
+        handlers = []
+
         async def black_hole(reader, writer):
-            await asyncio.sleep(3600)
+            handlers.append(asyncio.current_task())
+            try:
+                await asyncio.sleep(3600)
+            finally:
+                writer.close()
 
         server = await asyncio.start_server(black_hole, "127.0.0.1", 0)
         port = server.sockets[0].getsockname()[1]
@@ -436,6 +442,10 @@ def test_wedged_worker_fails_probe_lease_style(tmp_path):
             await replica.health_probe()
         await replica.close()
         server.close()
+        # Server.wait_closed() waits for every connection handler (Python
+        # 3.12+): the black hole never returns by itself
+        for task in handlers:
+            task.cancel()
         await server.wait_closed()
 
     run_async(main())
