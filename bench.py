@@ -213,7 +213,7 @@ def measure_mm_prefetch_ab(
         )
         it = prefetch_batches(
             raw, depth=leg_depth,
-            transfer=trainer._shard_batch if leg_depth else None,
+            transfer=trainer.shard_batch if leg_depth else None,
         )
         try:
             for _ in range(warmup):
@@ -2265,7 +2265,7 @@ def main() -> None:
 
     prefetch_depth = int(os.environ.get("BENCH_PREFETCH", "2"))
     batches = prefetch_batches(
-        batches, depth=prefetch_depth, transfer=trainer._shard_batch
+        batches, depth=prefetch_depth, transfer=trainer.shard_batch
     )
 
     # Warmup: first step compiles; two more reach dispatch steady-state.

@@ -86,10 +86,10 @@ def audit_topology(name: str) -> dict[str, Any]:
         mode="lora", batch_size=4, seq_len=32, total_steps=10
     )
     trainer = Trainer(model_cfg, train_cfg, mesh=mesh)
-    state_shapes = jax.eval_shape(trainer._raw_init, jax.random.PRNGKey(0))
+    state_shapes = jax.eval_shape(trainer.raw_init, jax.random.PRNGKey(0))
     abstract_state = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        state_shapes, trainer._state_shardings,
+        state_shapes, trainer.state_shardings,
     )
     b, s = train_cfg.batch_size, train_cfg.seq_len
     abstract_batch = {

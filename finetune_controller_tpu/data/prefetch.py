@@ -22,17 +22,21 @@ Contract:
   * **clean shutdown** — :meth:`close` (also on context-manager exit) stops
     the producer even when it is blocked on a full queue; the thread is a
     daemon so an unclosed iterator never wedges interpreter exit;
-  * **observable** — per-batch host-build / transfer seconds (producer side)
-    and consumer wait seconds are recorded; :meth:`pop_stats` drains
-    windowed aggregates for metrics/bench reporting.
+  * **observable** — inside a ``jax.profiler`` session the producer's
+    ``prefetch.build`` / ``prefetch.transfer`` / ``prefetch.put`` (blocked on
+    a full queue: the pipeline's headroom) and the consumer's
+    ``prefetch.take`` (with the queue depth it found) are spans on the
+    profiler's clock, beside the device's operations (``obs.annotate``);
+    outside a session nothing is recorded.
 """
 
 from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Any, Callable, Iterable, Iterator
+
+from ..obs.trace import annotate
 
 __all__ = ["PrefetchIterator", "prefetch_batches"]
 
@@ -52,7 +56,7 @@ class _Failure:
 class PrefetchIterator:
     """Wrap ``batches`` with a background producer thread (depth-bounded
     queue) and an optional ``transfer`` stage applied on the producer thread
-    (e.g. the trainer's ``_shard_batch`` — an async ``device_put`` with the
+    (e.g. the trainer's ``shard_batch`` — an async ``device_put`` with the
     step's shardings, so the copy overlaps the running step)."""
 
     def __init__(
@@ -69,14 +73,6 @@ class PrefetchIterator:
         self._stop = threading.Event()
         self._transfer = transfer
         self._exhausted = False
-        # consumer-visible timing (what the training step actually waited)
-        self.last_wait_s = 0.0
-        # producer-side timing for the batch most recently handed out
-        self.last_build_s = 0.0
-        self.last_transfer_s = 0.0
-        self._agg_lock = threading.Lock()
-        self._agg = {"batches": 0, "build_s": 0.0, "transfer_s": 0.0,
-                     "wait_s": 0.0}
         self._thread = threading.Thread(
             target=self._produce, name=name, daemon=True
         )
@@ -87,19 +83,18 @@ class PrefetchIterator:
     def _produce(self) -> None:
         try:
             while not self._stop.is_set():
-                t0 = time.perf_counter()
                 try:
-                    batch = next(self._inner)
+                    with annotate("prefetch.build"):
+                        batch = next(self._inner)
                 except StopIteration:
                     self._put(_DONE)
                     return
-                build_s = time.perf_counter() - t0
-                t1 = time.perf_counter()
                 if self._transfer is not None:
-                    batch = self._transfer(batch)
-                transfer_s = time.perf_counter() - t1
-                if not self._put((batch, build_s, transfer_s)):
-                    return  # closed while waiting for queue space
+                    with annotate("prefetch.transfer"):
+                        batch = self._transfer(batch)
+                with annotate("prefetch.put"):
+                    if not self._put(batch):
+                        return  # closed while waiting for queue space
         except BaseException as exc:  # noqa: BLE001  # ftc: ignore[silent-except] -- not swallowed: carried across the thread boundary and re-raised on the consumer in __next__
             self._put(_Failure(exc))
 
@@ -126,9 +121,8 @@ class PrefetchIterator:
             # closed: the producer exited without posting _DONE and the
             # queue was drained — a blocking get() here would hang forever
             raise StopIteration
-        t0 = time.perf_counter()
-        item = self._queue.get()
-        self.last_wait_s = time.perf_counter() - t0
+        with annotate("prefetch.take", depth=self._queue.qsize()):
+            item = self._queue.get()
         if item is _DONE:
             self._exhausted = True
             raise StopIteration
@@ -136,23 +130,7 @@ class PrefetchIterator:
             self._exhausted = True
             self.close()
             raise item.exc  # the original exception, original traceback
-        batch, self.last_build_s, self.last_transfer_s = item
-        with self._agg_lock:
-            self._agg["batches"] += 1
-            self._agg["build_s"] += self.last_build_s
-            self._agg["transfer_s"] += self.last_transfer_s
-            self._agg["wait_s"] += self.last_wait_s
-        return batch
-
-    def pop_stats(self) -> dict[str, float]:
-        """Drain the aggregate window: totals since the last pop —
-        ``batches``, producer-side ``build_s``/``transfer_s``, and
-        consumer-visible ``wait_s``."""
-        with self._agg_lock:
-            out = dict(self._agg)
-            for k in self._agg:
-                self._agg[k] = 0 if k == "batches" else 0.0
-        return out
+        return item
 
     # ---- lifecycle --------------------------------------------------------
 

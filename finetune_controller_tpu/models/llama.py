@@ -437,11 +437,12 @@ class Attention(nn.Module):
         q = _proj(cfg, "q_proj", cfg.n_heads * hd)(x, deterministic, adapter_ids)
         k = _proj(cfg, "k_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
         v = _proj(cfg, "v_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
-        inv_freqs = rope_inv_freqs(cfg)
-        q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), positions,
-                       inv_freqs=inv_freqs)
-        k = apply_rope(k.reshape(b, s, cfg.n_kv_heads, hd), positions,
-                       inv_freqs=inv_freqs)
+        with jax.named_scope("rope"):
+            inv_freqs = rope_inv_freqs(cfg)
+            q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), positions,
+                           inv_freqs=inv_freqs)
+            k = apply_rope(k.reshape(b, s, cfg.n_kv_heads, hd), positions,
+                           inv_freqs=inv_freqs)
         v = v.reshape(b, s, cfg.n_kv_heads, hd)
         if decode:
             return self._decode_attention(q, k, v, deterministic,

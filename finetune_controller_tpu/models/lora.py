@@ -12,6 +12,7 @@ import dataclasses
 from typing import Any, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 DEFAULT_TARGETS = (
@@ -77,13 +78,13 @@ class LoRADense(nn.Module):
                 self, "kernel", (in_features, self.features),
                 self.kernel_init, self.quant_block, self.dtype,
             )
-            y = x @ kernel
         else:
             kernel = self.param(
                 "kernel", self.kernel_init, (in_features, self.features),
                 self.param_dtype,
-            )
-            y = x @ kernel.astype(self.dtype)
+            ).astype(self.dtype)
+        with jax.named_scope("base_matmul"):
+            y = x @ kernel
         if self.use_bias:
             bias = self.param(
                 "bias", nn.initializers.zeros_init(), (self.features,), self.param_dtype
@@ -110,7 +111,8 @@ class LoRADense(nn.Module):
             if self.lora_dropout > 0.0 and not deterministic:
                 h = nn.Dropout(rate=self.lora_dropout, deterministic=False)(h)
             scale = self.lora_alpha / self.lora_rank
-            y = y + (h @ a.astype(self.dtype)) @ b.astype(self.dtype) * scale
+            with jax.named_scope("lora_delta"):
+                y = y + (h @ a.astype(self.dtype)) @ b.astype(self.dtype) * scale
         if self.tenant_slots > 0 and adapter_ids is not None:
             # per-row tenant adapters: y_b += scale[t_b] * (x_b @ A[t_b]) @
             # B[t_b] with t = adapter_ids — the unmerged-LoRA multiplexing

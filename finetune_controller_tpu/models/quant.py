@@ -54,15 +54,18 @@ def dequantize_int4(
     in_f = half * 2
     n_blocks = scales.shape[0]
     block_size = in_f // n_blocks
-    # unpack nibbles; sign-extend 4-bit two's complement
-    lo = (packed & 0x0F).astype(jnp.int8)
-    hi = (packed >> 4).astype(jnp.int8)
-    lo = jnp.where(lo > 7, lo - 16, lo)
-    hi = jnp.where(hi > 7, hi - 16, hi)
-    q = jnp.stack([lo, hi], axis=1).reshape(in_f, out_f)          # interleave
-    qb = q.reshape(n_blocks, block_size, out_f).astype(jnp.float32)
-    w = qb * scales[:, None, :].astype(jnp.float32)
-    return w.reshape(in_f, out_f).astype(dtype)
+    # the scope names this work in a profiler trace whoever calls it
+    # (LoRADense, MoE experts, the serve engine): docs/observability.md
+    with jax.named_scope("dequant_int4"):
+        # unpack nibbles; sign-extend 4-bit two's complement
+        lo = (packed & 0x0F).astype(jnp.int8)
+        hi = (packed >> 4).astype(jnp.int8)
+        lo = jnp.where(lo > 7, lo - 16, lo)
+        hi = jnp.where(hi > 7, hi - 16, hi)
+        q = jnp.stack([lo, hi], axis=1).reshape(in_f, out_f)      # interleave
+        qb = q.reshape(n_blocks, block_size, out_f).astype(jnp.float32)
+        w = qb * scales[:, None, :].astype(jnp.float32)
+        return w.reshape(in_f, out_f).astype(dtype)
 
 
 

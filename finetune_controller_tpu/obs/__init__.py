@@ -30,6 +30,7 @@ from .phase import PhaseClock
 from .prom import Histogram, ObsHub
 from .trace import (
     SpanRecorder,
+    annotate,
     build_trace,
     new_span_id,
     new_trace_id,
@@ -44,6 +45,7 @@ __all__ = [
     "ObsHub",
     "PhaseClock",
     "SpanRecorder",
+    "annotate",
     "build_trace",
     "make_event",
     "new_span_id",

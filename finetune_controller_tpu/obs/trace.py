@@ -22,10 +22,16 @@ without translation.  Two sources:
 ``GET /jobs/{id}/trace`` assembles both sources; the monitor exports the
 same assembly to ``{artifacts_uri}/trace/trace.json`` when a job reaches a
 terminal state, so traces survive control-plane restarts.
+
+A third clock is the PROFILER's: :func:`annotate` puts a host span into the
+open ``jax.profiler`` session, beside the device's operations, and into
+nothing else — that session's file is its only store
+(docs/observability.md §Names in a profile).
 """
 
 from __future__ import annotations
 
+import contextlib
 import json
 import logging
 import os
@@ -52,6 +58,23 @@ def new_trace_id() -> str:
 def new_span_id() -> str:
     """64-bit lowercase hex span id."""
     return uuid.uuid4().hex[:16]
+
+
+def annotate(name: str, **attrs: Any):
+    """A host span on the profiler's clock: ``with annotate("prefetch.take",
+    depth=2): ...``.  Recorded only while a ``jax.profiler`` session is open
+    (the trainer's ``profile_steps`` window, ``ftc-ctl profile JOB``, a
+    benchmark's traced run); outside one it costs a flag test.  A span that
+    carries ``step_num`` is the profiler's step marker
+    (``StepTraceAnnotation``).  JAX is imported here, not by this module:
+    the API server and pods without JAX import ``obs`` and get a null
+    context."""
+    try:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+    except ImportError:
+        return contextlib.nullcontext()
+    cls = StepTraceAnnotation if "step_num" in attrs else TraceAnnotation
+    return cls(name, **attrs)
 
 
 def make_span(
@@ -103,6 +126,8 @@ class SpanRecorder:
         self.enabled = enabled and bool(trace_id)
         self._clock_ns = _clock_ns
         self._lock = threading.Lock()
+        #: span_id -> the open profiler annotation of a started span
+        self._open: dict[str, Any] = {}
         self.write_failures = 0
 
     def start(self, name: str, *, parent: dict | None = None,
@@ -113,6 +138,12 @@ class SpanRecorder:
             parent_span_id=parent["span_id"] if parent else None,
             service=self.service, attempt=self.attempt or None, **attrs,
         )
+        # the same span on the profiler's clock (whether or not the JSONL log
+        # is enabled: a profile window is armed independently of FTC_TRACE)
+        ann = annotate(name)
+        ann.__enter__()
+        with self._lock:
+            self._open[span["span_id"]] = ann
         return span
 
     def finish(self, span: dict[str, Any], *, status: str = "ok",
@@ -123,21 +154,11 @@ class SpanRecorder:
             span["attributes"].update(
                 {k: v for k, v in attrs.items() if v is not None}
             )
-        if not self.enabled:
-            return
-        try:
-            with self._lock:
-                os.makedirs(self.dir, exist_ok=True)
-                with open(self.path, "a") as f:
-                    f.write(json.dumps(span) + "\n")
-                    f.flush()
-        except OSError:
-            with self._lock:  # finish() races itself across threads
-                self.write_failures += 1
-                failures = self.write_failures
-            level = logging.WARNING if failures == 1 else logging.DEBUG
-            logger.log(level, "span write to %s failed (%d so far)",
-                       self.path, failures, exc_info=True)
+        with self._lock:
+            ann = self._open.pop(span["span_id"], None)
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        self._write(span)
 
     def record(self, name: str, *, start_ns: int, end_ns: int,
                status: str = "ok", **attrs: Any) -> dict[str, Any]:
@@ -150,8 +171,12 @@ class SpanRecorder:
             start_ns=int(start_ns), end_ns=int(end_ns), status=status,
             service=self.service, attempt=self.attempt or None, **attrs,
         )
+        self._write(span)
+        return span
+
+    def _write(self, span: dict[str, Any]) -> None:
         if not self.enabled:
-            return span
+            return
         try:
             with self._lock:
                 os.makedirs(self.dir, exist_ok=True)
@@ -159,13 +184,12 @@ class SpanRecorder:
                     f.write(json.dumps(span) + "\n")
                     f.flush()
         except OSError:
-            with self._lock:
+            with self._lock:  # writers race across threads
                 self.write_failures += 1
                 failures = self.write_failures
             level = logging.WARNING if failures == 1 else logging.DEBUG
             logger.log(level, "span write to %s failed (%d so far)",
                        self.path, failures, exc_info=True)
-        return span
 
     class _SpanCtx:
         def __init__(self, recorder: "SpanRecorder", span: dict):

@@ -299,6 +299,7 @@ def _flash_forward(
             "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt, seg3, kseg3)
 
     return out.transpose(0, 2, 1, 3)[:, :s], lse
@@ -553,6 +554,7 @@ def _flash_backward(
             "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, kt, vt, dot, lse, delta, seg3, kseg3)
 
     # dK/dV: grid over KV heads; each instance owns one key block and the
@@ -602,6 +604,7 @@ def _flash_backward(
             "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(kt, vt, qt, dot, lse, delta, kseg3, seg3)
 
     dq = dq.transpose(0, 2, 1, 3)[:, :s]

@@ -217,6 +217,7 @@ def _paged_attention(
             vmem_limit_bytes=vmem_limit_bytes,
         ),
         interpret=interpret,
+        name="paged_decode",
     )(page_table, idx, qr, k_pool.reshape(p, t, hkv * d),
       v_pool.reshape(p, t, hkv * d))
     out = out[:, :, :rows].reshape(b, hkv, s, g, d).transpose(0, 2, 1, 3, 4)

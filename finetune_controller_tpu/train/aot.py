@@ -169,10 +169,10 @@ def aot_report(name: str) -> dict[str, Any]:
 
     # abstract state: shapes from eval_shape, shardings from the rule engine —
     # zero parameter memory is allocated anywhere in this function
-    state_shapes = jax.eval_shape(trainer._raw_init, jax.random.PRNGKey(0))
+    state_shapes = jax.eval_shape(trainer.raw_init, jax.random.PRNGKey(0))
     abstract_state = jax.tree.map(
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
-        state_shapes, trainer._state_shardings,
+        state_shapes, trainer.state_shardings,
     )
     b, s = spec["batch"], spec["seq"]
     abstract_batch = {
@@ -189,7 +189,7 @@ def aot_report(name: str) -> dict[str, Any]:
 
     # param sharding evidence: flatten specs with paths
     mesh_shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-    leaves = jax.tree_util.tree_leaves_with_path(trainer._state_shardings)
+    leaves = jax.tree_util.tree_leaves_with_path(trainer.state_shardings)
     spec_samples: dict[str, str] = {}
     state_bytes = 0.0
     shape_leaves = {

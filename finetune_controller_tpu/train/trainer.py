@@ -28,6 +28,7 @@ from flax.core import FrozenDict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig, LlamaForCausalLM
+from ..obs.trace import annotate
 from ..parallel.mesh import MeshSpec
 from ..parallel.sharding import (
     LLAMA_RULES,
@@ -319,6 +320,8 @@ class Trainer:
         )
         self._state_shardings = None
         self._init_jit = None
+        #: calls of step() so far: the profiler's step number
+        self._steps_enqueued = 0
         self._warned_eval_unsplit = False
         #: commit EVERY checkpoint synchronously (not just the final one).
         #: Async saves are the throughput default; the rlhf learner flips
@@ -371,7 +374,9 @@ class Trainer:
             out["lora"] = trainable
         return out
 
-    def _raw_init(self, rng: jax.Array) -> TrainState:
+    def raw_init(self, rng: jax.Array) -> TrainState:
+        """The unjitted, unsharded state constructor — what ``init_state``
+        jits; ``jax.eval_shape`` of it gives the state's shapes."""
         import math
 
         # dummy init batch must be divisible over the batch and sp axes (ring
@@ -395,6 +400,8 @@ class Trainer:
             opt_state=opt_state,
         )
 
+    _raw_init = raw_init
+
     def _cast_frozen(self, frozen: Any) -> Any:
         """Downcast float32 leaves of the frozen base to ``cfg.frozen_dtype``
         (lora mode only — full fine-tune keeps f32 master weights). Int4
@@ -415,13 +422,13 @@ class Trainer:
 
     def _build(self) -> None:
         rng = jax.random.PRNGKey(self.cfg.seed)
-        shapes = jax.eval_shape(self._raw_init, rng)
+        shapes = jax.eval_shape(self.raw_init, rng)
         self._state_shardings = sharding_for_tree(shapes, self.mesh, self.rules)
         self._batch_sharding = batch_sharding(self.mesh)
         from ..parallel.mesh import AxisNames as Ax
 
         self._pixel_sharding = NamedSharding(self.mesh, P(Ax.BATCH_AXES))
-        self._init_jit = jax.jit(self._raw_init, out_shardings=self._state_shardings)
+        self._init_jit = jax.jit(self.raw_init, out_shardings=self._state_shardings)
         # jitted steps are cached per batch structure (multimodal batches add
         # a rank-4 pixels leaf whose sharding differs from token arrays)
         self._step_jits: dict[tuple[str, ...], Any] = {}
@@ -463,6 +470,19 @@ class Trainer:
                 name="trainer-shard-audit"
             )
 
+    @property
+    def state_shardings(self) -> Any:
+        """The ``NamedSharding`` tree every ``TrainState`` of this trainer
+        carries (a ``TrainState`` of shardings)."""
+        return self._state_shardings
+
+    @property
+    def step_programs(self) -> dict[tuple[str, ...], Any]:
+        """The jitted step functions built so far, keyed by the batch's
+        sorted keys (``lower(...).compile()`` on one gives its
+        ``memory_analysis()``)."""
+        return self._step_jits
+
     def _audit_state_sharding(self, state: Any, label: str) -> None:
         """Shard-audit trap (analysis/shard_audit.py): at the
         checkpoint/restore boundaries, every live state leaf must still
@@ -503,7 +523,7 @@ class Trainer:
             if self._recompile_guard is not None:
                 fn = self._recompile_guard.wrap(fn, label=f"step:{','.join(key)}")
             if self._transfer_guard is not None:
-                # the guarded window is the DISPATCH only: _shard_batch has
+                # the guarded window is the DISPATCH only: shard_batch has
                 # already device_put the batch (explicitly — allowed), so a
                 # steady-state step moves nothing across the boundary
                 fn = self._transfer_guard.wrap(fn, label=f"step:{','.join(key)}")
@@ -615,11 +635,13 @@ class Trainer:
             zero_aux = jax.tree.map(
                 lambda s: jnp.zeros(s.shape, s.dtype), aux_shape
             )
-            carry, _ = jax.lax.scan(
-                body,
-                {"grads": zero_grads, "aux": zero_aux, "i": jnp.zeros((), jnp.int32)},
-                micro,
-            )
+            with jax.named_scope("grad_accum"):
+                carry, _ = jax.lax.scan(
+                    body,
+                    {"grads": zero_grads, "aux": zero_aux,
+                     "i": jnp.zeros((), jnp.int32)},
+                    micro,
+                )
             inv = 1.0 / accum
             grads = jax.tree.map(lambda g: g * inv, carry["grads"])
             # means average over microbatches; counts keep their exact sum
@@ -629,8 +651,9 @@ class Trainer:
             }
         else:
             (_, aux), grads = grad_fn(state.trainable, state.frozen, batch, dropout_rng)
-        updates, opt_state = self.tx.update(grads, state.opt_state, state.trainable)
-        trainable = optax.apply_updates(state.trainable, updates)
+        with jax.named_scope("optimizer"):  # clip included: it is in tx
+            updates, opt_state = self.tx.update(grads, state.opt_state, state.trainable)
+            trainable = optax.apply_updates(state.trainable, updates)
         metrics = {
             **aux,
             "grad_norm": optax.global_norm(grads),
@@ -710,7 +733,7 @@ class Trainer:
                     k: v[c * (rows // chunks):(c + 1) * (rows // chunks)]
                     for k, v in host_batch.items()
                 }
-                batch = self._shard_batch(piece)
+                batch = self.shard_batch(piece)
                 input_s += time.perf_counter() - t_in
                 fn = self._get_eval_jit(batch)
                 with self.mesh, ring_mesh(self.mesh):
@@ -744,11 +767,15 @@ class Trainer:
     def step(self, state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         from ..parallel.ring import ring_mesh
 
-        batch = self._shard_batch(batch)
-        step_fn = self._get_step_jit(batch)
-        # ring_mesh only matters at trace time (first call); harmless after
-        with self.mesh, ring_mesh(self.mesh):
-            return step_fn(state, batch)
+        # numbered on the host: reading state.step would wait for the device
+        self._steps_enqueued += 1
+        with annotate("trainer.step", step_num=self._steps_enqueued):
+            with annotate("trainer.shard_batch"):
+                batch = self.shard_batch(batch)
+            step_fn = self._get_step_jit(batch)
+            # ring_mesh only matters at trace time (first call); harmless after
+            with self.mesh, ring_mesh(self.mesh), annotate("trainer.enqueue"):
+                return step_fn(state, batch)
 
     @property
     def local_batch_size(self) -> int:
@@ -766,7 +793,10 @@ class Trainer:
             )
         return self.cfg.batch_size // n
 
-    def _shard_batch(self, batch: dict) -> dict:
+    def shard_batch(self, batch: dict) -> dict:
+        """The batch on the device(s) with the step's input shardings (an
+        async ``device_put``; leaves already placed so are passed through) —
+        what ``step`` does first, and what a prefetch thread does ahead."""
         def put(x):
             if isinstance(x, jax.Array):
                 # already transferred (the prefetch pipeline device_puts with
@@ -782,6 +812,8 @@ class Trainer:
             return jax.device_put(x, sh)
 
         return jax.tree.map(put, batch)
+
+    _shard_batch = shard_batch
 
     def load_pretrained(self, state: TrainState, ckpt_dir: str) -> TrainState:
         """Replace the base-model weights with a pretrained HF checkpoint
@@ -919,7 +951,7 @@ class Trainer:
                 # merged weights are deq(Q(W)) + delta, matching the
                 # single-host path's dequantized frozen leaves
                 shapes = jax.eval_shape(
-                    self._raw_init, jax.random.PRNGKey(self.cfg.seed)
+                    self.raw_init, jax.random.PRNGKey(self.cfg.seed)
                 )
                 loaded = _adapt_loaded_params(
                     loaded, shapes.frozen["params"],
@@ -1279,7 +1311,7 @@ class Trainer:
             # copy overlaps compute, double-buffered by the queue)
             it = PrefetchIterator(
                 it, depth=self.cfg.prefetch,
-                transfer=self._shard_batch if self.cfg.prefetch_to_device else None,
+                transfer=self.shard_batch if self.cfg.prefetch_to_device else None,
             )
             prefetch_its.append(it)
             if eval_it is not None and self.cfg.eval_every > 0:
@@ -1403,7 +1435,8 @@ class Trainer:
                 eval_elapsed = 0.0
                 if eval_now:
                     eval_t0 = time.perf_counter()
-                    eval_metrics = self.evaluate(state, eval_it)
+                    with spans.span("eval", parent=fit_span, step=step_idx + 1):
+                        eval_metrics = self.evaluate(state, eval_it)
                     eval_elapsed = time.perf_counter() - eval_t0
                     if obs_on:
                         phases.add("eval", eval_elapsed)
@@ -1415,7 +1448,9 @@ class Trainer:
                 # eval metrics ride ON a train log row (eval steps force one)
                 # so the CSV stays dense within each written row
                 if (step_idx + 1) % self.cfg.log_every == 0 or last or eval_now:
-                    metrics = {k: float(v) for k, v in metrics.items()}
+                    with annotate("trainer.log_sync"):
+                        # float() waits for the step that produced the row
+                        metrics = {k: float(v) for k, v in metrics.items()}
                     # the evaluation pause doesn't count against throughput
                     dt = time.perf_counter() - window_t0 - eval_elapsed
                     metrics["tokens_per_sec"] = window_tokens / max(dt, 1e-9)

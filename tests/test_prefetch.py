@@ -110,20 +110,42 @@ def test_transfer_stage_runs_on_producer_thread():
     assert all(t is not main for t in seen_threads)
 
 
-def test_stats_window_counts_build_and_wait():
+def test_a_profiler_session_holds_build_and_take_spans(tmp_path):
+    """The pipeline keeps no timing of its own: inside a profiler session
+    the producer's builds and the consumer's takes are spans on the
+    profiler's clock, one per batch (``obs.annotate``)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
     def slow_gen():
         for i in range(4):
             time.sleep(0.01)
             yield i
 
-    with PrefetchIterator(slow_gen(), depth=2) as it:
-        list(it)
-        stats = it.pop_stats()
-    assert stats["batches"] == 4
-    assert stats["build_s"] >= 0.03
-    assert stats["wait_s"] >= 0.0
-    # the pop drained the window
-    assert it.pop_stats()["batches"] == 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with PrefetchIterator(slow_gen(), depth=2) as it:
+            assert list(it) == [0, 1, 2, 3]
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    spans: dict[str, list] = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith("prefetch."):
+                        spans.setdefault(e.name, []).append(e)
+    # four batches and the end-of-data marker; no transfer stage was given
+    assert len(spans["prefetch.build"]) == 5
+    assert sum(e.duration_ns for e in spans["prefetch.build"]) >= 0.03e9
+    assert len(spans["prefetch.put"]) == 4
+    assert len(spans["prefetch.take"]) == 5
+    assert all(0 <= int(dict(e.stats)["depth"]) <= 2
+               for e in spans["prefetch.take"])
+    assert "prefetch.transfer" not in spans
 
 
 # ---------------------------------------------------------------------------
