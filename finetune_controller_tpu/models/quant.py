@@ -9,9 +9,15 @@ BASELINE config #3 (Mistral-7B QLoRA). TPU-first design choices:
   kernel is ``(in/2, out)`` uint8 + ``(in/block, out)`` scales: ~4.25
   bits/weight, which is what lets a 7B base fit one v5e chip's HBM next to
   optimizer-free LoRA adapters;
-- **dequantize-then-matmul** at apply time: the unpack + scale is elementwise
-  VPU work XLA fuses into the bf16 MXU matmul's operand load. The weights
-  never exist in f32 — params are created quantized at init.
+- **dequantize-then-matmul** at apply time, and what the chip's compiler
+  makes of it (``tests/test_chip_compile.py`` holds it to this): the packed
+  bytes are doubled along the input dim as bytes (the only relayout, at one
+  byte an element), one elementwise pass takes each row's nibble,
+  sign-extends it and multiplies by its block's scale, and the kernel is
+  written once, as bf16, for the bf16 MXU matmul to read.  Nothing of the
+  kernel's size exists in f32 and the scales are broadcast inside that pass,
+  never to an array of their own (what each step costs on the chip: PERF.md
+  section 5).
 
 Gradients: the base kernel is intentionally non-differentiable (it lives in
 ``params``, the frozen collection — only the ``lora`` collection trains), so
@@ -49,25 +55,35 @@ def quantize_int4(w: jax.Array, block_size: int = 64) -> tuple[jax.Array, jax.Ar
 def dequantize_int4(
     packed: jax.Array, scales: jax.Array, *, dtype=jnp.bfloat16
 ) -> jax.Array:
-    """Inverse of :func:`quantize_int4` → (in, out) in ``dtype``."""
+    """Inverse of :func:`quantize_int4` → (in, out) in ``dtype``.
+
+    A nibble in -7..7 times a bf16 scale is exact in f32, so the value is that
+    product rounded once to ``dtype``: a bf16 multiply gives exactly that (the
+    v5e's VPU and the CPU backend both multiply in f32 and round), and any
+    other ``dtype`` is computed in f32.
+    """
     half, out_f = packed.shape
     in_f = half * 2
     n_blocks = scales.shape[0]
     block_size = in_f // n_blocks
+    compute = jnp.bfloat16 if dtype == jnp.bfloat16 else jnp.float32
     # the scope names this work in a profiler trace whoever calls it
     # (LoRADense, MoE experts, the serve engine): docs/observability.md
     with jax.named_scope("dequant_int4"):
-        # unpack nibbles; sign-extend 4-bit two's complement
-        lo = (packed & 0x0F).astype(jnp.int8)
-        hi = (packed >> 4).astype(jnp.int8)
-        lo = jnp.where(lo > 7, lo - 16, lo)
-        hi = jnp.where(hi > 7, hi - 16, hi)
-        q = jnp.stack([lo, hi], axis=1).reshape(in_f, out_f)      # interleave
-        qb = q.reshape(n_blocks, block_size, out_f).astype(jnp.float32)
-        w = qb * scales[:, None, :].astype(jnp.float32)
-        return w.reshape(in_f, out_f).astype(dtype)
-
-
+        # interleave while the data is narrowest: every packed byte twice,
+        # row 2h takes its low nibble and row 2h+1 its high one (a block is
+        # even, so a row's parity is its parity inside the block)
+        rows = jnp.repeat(packed, 2, axis=0).reshape(n_blocks, block_size, out_f)
+        odd = jax.lax.broadcasted_iota(jnp.uint8, (1, block_size, 1), 1) & 1
+        q = ((rows >> (odd * 4)) & 0x0F).astype(jnp.int8)
+        q = jnp.where(q > 7, q - 16, q)           # 4-bit two's complement
+        w = q.astype(compute) * scales[:, None, :].astype(compute)
+        # the barrier keeps the pass above one fusion that writes the kernel
+        # once in ``dtype``; without it XLA moves the multiply into the
+        # matmul's operand and broadcasts the scales to a kernel-sized array
+        # of their own for it to read
+        w = jax.lax.optimization_barrier(w.astype(dtype))
+        return w.reshape(in_f, out_f)
 
 
 def quantized_param(module, name: str, shape: tuple, kernel_init,

@@ -17,6 +17,7 @@ one, and the next run would only warn about it.
 
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -202,3 +203,72 @@ def test_bare_flash_under_a_mesh_is_what_the_compiler_refuses(v5e):
     with pytest.raises(Exception, match="shard_map"):
         jax.jit(lambda q, k, v: flash_attention(q, k, v, interpret=False)
                 ).lower(q, kv, kv).compile()
+
+
+# ---------------------------------------------------------------------------
+# int4 dequantisation: what exists in HBM around a quantised projection
+# ---------------------------------------------------------------------------
+
+#: (in, out) of the Mistral-7B MLP projections at the train cell's 8 x 2048
+#: rows; until PR 25 each held 470 MB of float32 temporaries a product
+QLORA_SHAPES = {"up-4096x14336": (4096, 14336), "down-14336x4096": (14336, 4096)}
+_ARRAY = re.compile(r"\b(f32|bf16|s8|u8|s32|u32)\[([\d,]+)\]")
+
+
+def _arrays(text: str):
+    """(dtype, elements) of every instruction's result outside the fused
+    computations: what a fusion computes inside itself (the v5e's VPU
+    multiplies in f32) is never a buffer, what it returns is."""
+    fused = set(re.findall(r"\bfusion\(.*?calls=(%[\w.\-]+)", text))
+    inside = False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1) in fused
+        elif not inside and " = " in line:
+            result = re.split(r"\s[\w\-]+\(", line.split(" = ", 1)[1], 1)[0]
+            for m in _ARRAY.finditer(result):
+                yield m.group(1), math.prod(map(int, m.group(2).split(",")))
+
+
+@pytest.mark.parametrize("which", ["forward", "grad"])
+@pytest.mark.parametrize("shape", list(QLORA_SHAPES), ids=list(QLORA_SHAPES))
+def test_quantised_projection_holds_no_float32_kernel(v5e, shape, which):
+    """The dequantised kernel reaches the base matmul as bf16 and never
+    exists wider: no f32 array of even half a kernel's elements (values,
+    interleave or broadcast scales), temporaries under a quarter of the 470
+    MB the float32 round trip took, and the matmul's fusion reads a bf16
+    ``[in, out]`` operand."""
+    from finetune_controller_tpu.models.lora import LoRADense
+
+    in_f, out_f = QLORA_SHAPES[shape]
+    one = SingleDeviceSharding(v5e[0])
+    layer = LoRADense(features=out_f, lora_rank=16, quantize_base=True,
+                      dtype=BF16)
+    x = jax.ShapeDtypeStruct((8, 2048, in_f), BF16, sharding=one)
+    variables = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        jax.eval_shape(lambda: layer.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8, in_f), BF16))))
+
+    def loss(x, lora, params):
+        y = layer.apply({"params": params, "lora": lora}, x)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    if which == "forward":
+        compiled = jax.jit(layer.apply).lower(variables, x).compile()
+    else:
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            x, variables["lora"], variables["params"]).compile()
+    text = compiled.as_text()
+    wide = [(t, n) for t, n in _arrays(text)
+            if t in ("f32", "s32", "u32") and n >= in_f * out_f // 2]
+    assert not wide, f"kernel-sized 4-byte arrays in the program: {set(wide)}"
+    # the kernel is written once, as bf16, in the blocks' shape (a bitcast
+    # of [in, out]), by an operation of the dequant_int4 scope
+    assert re.search(
+        rf"bf16\[{in_f // 64},64,{out_f}\]\S* fusion\(.*dequant_int4", text)
+    # activations and results are out of this: x 134/470 MB, y 470/134 MB
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    acts = 2 * 8 * 2048 * (in_f + out_f) if which == "grad" else 0
+    assert temp - acts < 470e6 / 4, temp
