@@ -5,6 +5,10 @@ from __future__ import annotations
 
 import math
 
+import numpy as np
+
+from benchmarks.harness import weights
+
 
 class Comparison:
     def __init__(self) -> None:
@@ -25,9 +29,38 @@ class Comparison:
               flush=True)
         return bool(ok)
 
+    def compared(self) -> dict:
+        """Every number compared beside its limit, by name, for the result's
+        line (a ``require`` reads 0 where it held and 1 where not, against
+        the limit 0)."""
+        return {name: {"value": value, "limit": limit}
+                for name, value, limit, _ok in self.rows}
+
     @property
     def correct(self) -> bool:
         return bool(self.rows) and all(r[3] for r in self.rows)
+
+
+def layer_norms(flat: dict) -> dict:
+    """Norm of every leaf, by canonical name; a leaf of the scanned stack
+    (``weights.is_stacked``) per layer, ``name[l] -> ||.||``, any other leaf
+    (an adapter on a layer outside the stack, on the head) as one entry."""
+    out = {}
+    for name, arr in flat.items():
+        a = np.asarray(arr, np.float64)
+        if weights.is_stacked(name):
+            for l in range(a.shape[0]):
+                out[f"{name}[{l}]"] = float(np.sqrt(np.sum(a[l] ** 2)))
+        else:
+            out[name] = float(np.sqrt(np.sum(a ** 2)))
+    return out
+
+
+def host(tree):
+    """``tree`` on the host in float32."""
+    import jax
+
+    return jax.tree.map(lambda x: np.asarray(x, np.float32), tree)
 
 
 def worst_leaf_gap(prog: dict, ref: dict) -> float:

@@ -101,7 +101,7 @@ def build_engine(run):
         BatchEngine, EngineConfig, warm_engine)
 
     eng = run.workload["engine"]
-    model = LlamaForCausalLM(program.llama_config(
+    model = LlamaForCausalLM(run.manifest.program(run.conf).model_config(
         run.conf, max_seq_len=max(eng["prompt_buckets"]) + eng["max_new_tokens"]))
     run.stage("program imports, model built")
     variables = program.seeded_serving_variables(model, run.seed)
@@ -307,7 +307,8 @@ def run(run):
         cmp.check("served_token_widest_logit_gap", gaps["widest"],
                   wl["limits"]["served_token_widest_logit_gap"])
 
-    out = {"correct": cmp.correct, "attempted": attempted, "failed": failed,
+    out = {"correct": cmp.correct, "compared": cmp.compared(),
+           "attempted": attempted, "failed": failed,
            "device": {"memory_peak_bytes": peak}}
     if run.trace_on:
         trace_mod.attach(run, out, str(run.scratch / "trace"), HOST_SPANS)

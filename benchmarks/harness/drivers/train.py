@@ -6,7 +6,10 @@ thread, ``Trainer.step``), and hands that same object to the window.  The
 window dispatches steps the way an uninstrumented training loop does — at
 most two in flight — and ends in ``block_until_ready`` on the whole state.
 After the window the state is freed and the plain reference follows the
-first steps from the seed (``reference/train.py``).
+first steps from the seed.  The program's model configuration and the
+reference are the modules the configuration's ``run`` names
+(``manifest.py``); this file is the one definition of a training cell's
+window and of ``correct``, whatever the architecture.
 """
 
 from __future__ import annotations
@@ -16,17 +19,10 @@ import time
 
 import numpy as np
 
-from benchmarks.harness import compare, data, program, result, trace as trace_mod, weights
+from benchmarks.harness import compare, data, program, result, trace as trace_mod
 from benchmarks.harness.compiles import CompileCounter
-from benchmarks.reference import model as ref_model, train as ref_train
 
 HOST_SPANS = ("input", "dispatch", "wait")
-
-
-def _host(tree):
-    import jax
-
-    return jax.tree.map(lambda x: np.asarray(x, np.float32), tree)
 
 
 def _flat(tree) -> dict:
@@ -34,16 +30,6 @@ def _flat(tree) -> dict:
 
     return {program.canonical(path): leaf for path, leaf in
             jax.tree_util.tree_flatten_with_path(tree)[0]}
-
-
-def _layer_norms(flat: dict) -> dict:
-    """Norm of every leaf per layer: ``name[l] -> ||.||``."""
-    out = {}
-    for name, arr in flat.items():
-        a = np.asarray(arr, np.float64)
-        for l in range(a.shape[0]):
-            out[f"{name}[{l}]"] = float(np.sqrt(np.sum(a[l] ** 2)))
-    return out
 
 
 def _adam_mu(opt_state):
@@ -54,31 +40,6 @@ def _adam_mu(opt_state):
         if hasattr(node, "mu"):
             return node.mu
     raise ValueError("no Adam state found in the optimizer state")
-
-
-def reference_numbers(conf, wl, seed, token_batches, *, q=ref_model.identity,
-                      precision="highest", steps=None, n_layers=None,
-                      devices=None):
-    """Follow the first steps with the plain reference: per-step loss, the
-    first clipped gradient's per-layer norms, the adapters' change."""
-    import jax
-
-    arch = ref_model.Arch.from_config(conf, n_layers)
-    key = weights.root_key(seed)
-    lora0 = ref_model.init_lora(arch, key)
-    fn = ref_train.make_loss_and_grads(
-        arch, q, precision, rows_per_block=wl.get("reference_rows", 2))
-    opt = ref_train.AdamW(wl["lr"], weight_decay=0.0, clip_norm=wl["clip_norm"])
-    lora, losses, g1 = lora0, [], None
-    for k in range(steps or wl["reference_steps"]):
-        loss, grads = fn(key, lora, token_batches[k], devices)
-        losses.append(float(loss))
-        lora, clipped = opt.update(lora, grads)
-        if k == 0:
-            g1 = _layer_norms(_host(clipped))
-    delta = jax.tree.map(lambda a, b: a - b, lora, lora0)
-    return {"losses": losses, "grad_norms": g1,
-            "delta_norms": _layer_norms(_host(delta))}
 
 
 def judge(cmp: compare.Comparison, limits: dict, prog: dict, ref: dict) -> None:
@@ -92,18 +53,21 @@ def judge(cmp: compare.Comparison, limits: dict, prog: dict, ref: dict) -> None:
               limits["param_change_norm_gap"])
 
 
-def build_trainer(run):
+def build_trainer(run, devices=None):
     """The program's trainer for this cell: the published widths and full
     depth, its guards armed so a recompile, a stray transfer or a lost
-    sharding aborts the run."""
+    sharding aborts the run.  ``devices``: described ones, for a compile
+    without the chip (``tools/compile_step.py``)."""
     import jax
 
     from finetune_controller_tpu.parallel.mesh import MeshSpec
     from finetune_controller_tpu.train.trainer import TrainConfig, Trainer
 
     conf, wl = run.conf, run.workload
-    model_cfg = program.llama_config(conf, max_seq_len=wl["seq"])
-    mesh = MeshSpec(**conf["run"]["mesh"]).build(jax.devices()[:run.chips])
+    model_cfg = run.manifest.program(conf).model_config(
+        conf, max_seq_len=wl["seq"])
+    mesh = MeshSpec(**conf["run"]["mesh"]).build(
+        devices or jax.devices()[:run.chips])
     return Trainer(model_cfg, TrainConfig(
         mode="lora", batch_size=wl["batch"], seq_len=wl["seq"],
         learning_rate=wl["lr"], warmup_steps=0, schedule="constant",
@@ -139,7 +103,7 @@ def first_steps(run, trainer, seed: int):
 
     feed = prefetch_batches(recorded(), depth=wl["prefetch"],
                             transfer=trainer._shard_batch)
-    lora0 = _flat(_host(state.trainable))
+    lora0 = _flat(compare.host(state.trainable))
 
     def step(state):
         with rec.span("input"):
@@ -156,13 +120,13 @@ def first_steps(run, trainer, seed: int):
         step_s.append(time.perf_counter() - t)
         prog["losses"].append(float(m["loss"]))
         if k == 0:
-            mu = _flat(_host(_adam_mu(state.opt_state)))
-            prog["grad_norms"] = _layer_norms(
+            mu = _flat(compare.host(_adam_mu(state.opt_state)))
+            prog["grad_norms"] = compare.layer_norms(
                 {n: a / (1.0 - 0.9) for n, a in mu.items()})
             run.stage("first step (compile or cache hit, one step)")
         if k == n_ref - 1:
-            now = _flat(_host(state.trainable))
-            prog["delta_norms"] = _layer_norms(
+            now = _flat(compare.host(state.trainable))
+            prog["delta_norms"] = compare.layer_norms(
                 {n: now[n] - lora0[n] for n in now})
     prog["losses"] = prog["losses"][:n_ref]
     run.stage(f"steps 2..{n_first}")
@@ -266,13 +230,15 @@ def run(run):
 
     # ---- the plain reference follows the first steps --------------------------
     t = time.perf_counter()
-    ref = reference_numbers(conf, wl, run.seed, first_tokens,
-                            devices=devices if run.chips > 1 else None)
+    ref = run.manifest.reference(conf).reference_numbers(
+        conf, wl, run.seed, first_tokens,
+        devices=devices if run.chips > 1 else None)
     print(f"reference: {n_ref} step(s) in {time.perf_counter() - t:.1f} s",
           flush=True)
     judge(cmp, wl["limits"], prog, ref)
 
-    out = {"correct": cmp.correct, "attempted": n_steps, "failed": failed,
+    out = {"correct": cmp.correct, "compared": cmp.compared(),
+           "attempted": n_steps, "failed": failed,
            "device": {"memory_peak_bytes": peak}}
     if run.trace_on:
         trace_mod.attach(run, out, trace_dir, HOST_SPANS)

@@ -7,6 +7,35 @@ in a file of its own under one of the manifest's ``paths``:
 ``<path>/harness/drivers/<driver>.py`` (or ``<path>/drivers/``); a
 configuration's file is the manifest's ``file``.  A later PR adds files and
 entries and edits none.
+
+**Another architecture comes by files too.**  A configuration's ``run``
+section may name, each found under ``paths`` like a reducer:
+
+* ``"program"`` (default ``"llama"``): ``<path>/harness/programs/<name>.py``
+  (or ``<path>/programs/``), which gives ``model_config(conf, **overrides)``,
+  the program's model configuration built from the file's published keys;
+* ``"reference"`` (default ``"llama"``): ``<path>/reference/<name>.py``,
+  which gives ``reference_numbers(conf, wl, seed, token_batches, *,
+  devices=None) -> {"losses", "grad_norms", "delta_norms"}``: the plain
+  reference following a training cell's first steps, its norms by
+  ``compare.layer_norms`` under the leaf names ``program.canonical`` gives
+  the program's (``tools/limit_readings.py`` passes the keywords ``q`` and
+  ``precision`` besides, for the lower-precision control).
+
+``drivers/train.py`` calls both and stays the one definition of a training
+cell's window and of ``correct``.  The reducers ``mfu``, ``scope_roofline``
+and ``flash_roofline`` take an optional ``"counts"`` argument in a metric's
+file: ``<path>/harness/counting/<name>.py`` (or ``<path>/counting/``), whose
+functions they call by name in place of ``counts.py`` / ``scope_counts.py``.
+
+**A cut configuration states its cut** (``config_problems``).  Every key in
+``reduced`` is in the file with the value held here; an object ``published``
+gives the source's value of each; ``layout`` is an object with ``deployment``
+(what the cut stands for) and ``chips_sharing_a_layer`` (and
+``leading_dense_layers`` where the file has no ``first_k_dense_replace``).
+No width may be listed, and the floors of the ``model-configs`` guide's
+section 4 hold: at least four layers after the leading dense ones, at least
+8 of any count of experts, at least an eighth of the vocabulary.
 """
 
 from __future__ import annotations
@@ -58,10 +87,13 @@ class Manifest:
     def layer_metric(self, name: str) -> dict:
         return self._json(f"layer_metrics/{name}.json")
 
-    def _module(self, kind: str, name: str):
+    def _module_path(self, kind: str, name: str) -> Path:
         if not NAME.match(name):
             raise ValueError(f"bad {kind} name {name!r}")
-        path = self._find(f"harness/{kind}/{name}.py", f"{kind}/{name}.py")
+        return self._find(f"harness/{kind}/{name}.py", f"{kind}/{name}.py")
+
+    def _module(self, kind: str, name: str):
+        path = self._module_path(kind, name)
         spec = importlib.util.spec_from_file_location(
             f"benchmarks_{kind}_{name.replace('-', '_')}", path)
         mod = importlib.util.module_from_spec(spec)
@@ -73,6 +105,18 @@ class Manifest:
 
     def driver(self, name: str):
         return self._module("drivers", name).run
+
+    def program(self, conf: dict):
+        """The module that builds the program's model configuration."""
+        return self._module("programs", _run_name(conf, "program"))
+
+    def reference(self, conf: dict):
+        """The module that holds this configuration's plain reference."""
+        return self._module("reference", _run_name(conf, "reference"))
+
+    def counts(self, name: str):
+        """A module of counts named by a per-layer metric's file."""
+        return self._module("counting", name)
 
     # ---- what a cell reports ------------------------------------------------
 
@@ -145,9 +189,8 @@ class Manifest:
                 cell = self.workload(w["name"])
                 if cell["config"] != w["config"]:
                     bad.append(f"{w['name']}: cell file names another config")
-                self._find(f"harness/drivers/{cell['driver']}.py",
-                           f"drivers/{cell['driver']}.py")
-            except (FileNotFoundError, KeyError) as e:
+                self._module_path("drivers", cell["driver"])
+            except (FileNotFoundError, KeyError, ValueError) as e:
                 bad.append(f"{w['name']}: {e}")
         used = {w["config"] for w in self.workloads.values()}
         for c in self.configs.values():
@@ -155,8 +198,15 @@ class Manifest:
                 bad.append(f"config {c['name']} used by no cell")
             if not (ROOT / c["file"]).exists():
                 bad.append(f"config {c['name']}: no file {c['file']}")
-            elif sorted(self.config(c["name"]).get("reduced", [])) != sorted(c["reduced"]):
-                bad.append(f"config {c['name']}: reduced differs from its file")
+                continue
+            conf = self.config(c["name"])
+            bad += config_problems(c, conf)
+            for kind, key in (("programs", "program"),
+                              ("reference", "reference")):
+                try:
+                    self._module_path(kind, _run_name(conf, key))
+                except (FileNotFoundError, ValueError) as e:
+                    bad.append(f"config {c['name']}: {key}: {e}")
         four = sum(1 for w in self.workloads.values() if w["chips"] == 4)
         if four > max(1, len(self.workloads) // 4):
             bad.append("too many four-chip cells")
@@ -174,8 +224,68 @@ class Manifest:
                 for k in ("layer", "unit", "moves"):
                     if spec[k] != m[k]:
                         bad.append(f"{m['name']}: {k} differs from its file")
-                self._find(f"harness/reducers/{spec['reducer']}.py",
-                           f"reducers/{spec['reducer']}.py")
-            except (FileNotFoundError, KeyError) as e:
+                self._module_path("reducers", spec["reducer"])
+                if "counts" in spec.get("args", {}):
+                    self._module_path("counting", spec["args"]["counts"])
+            except (FileNotFoundError, KeyError, ValueError) as e:
                 bad.append(f"{m['name']}: {e}")
         return bad
+
+
+def _run_name(conf: dict, key: str) -> str:
+    """The module a configuration's ``run`` names for ``key``; the Llama one
+    where it names none."""
+    return conf.get("run", {}).get(key, "llama")
+
+
+#: what every decoder's configuration file states
+CONFIG_KEYS = ("hidden_size", "num_hidden_layers", "num_attention_heads",
+               "vocab_size", "rms_norm_eps", "source", "reduced", "assumed",
+               "layout", "run")
+#: what the Llama program and reference read besides
+LLAMA_KEYS = ("intermediate_size", "num_key_value_heads", "rope_theta")
+_WIDTH = re.compile(r"(_dim|_rank|_size|_width|_factor)$|per_tok|top_k")
+
+
+def config_problems(entry: dict, conf: dict) -> list[str]:
+    """What is wrong with a configuration's file beside its manifest entry:
+    a key every decoder has left out, an entry that disagrees with the file,
+    or a cut that is hidden, names a width or goes under a floor."""
+    name = entry["name"]
+    bad = [f"config {name}: no key {k}" for k in CONFIG_KEYS if k not in conf]
+    if _run_name(conf, "program") == "llama":
+        bad += [f"config {name}: no key {k}" for k in LLAMA_KEYS
+                if k not in conf]
+    if conf.get("source") != entry["source"]:
+        bad.append(f"config {name}: source differs from its file")
+    reduced = conf.get("reduced", [])
+    if sorted(reduced) != sorted(entry["reduced"]):
+        bad.append(f"config {name}: reduced differs from its file")
+    if bad or not reduced:
+        return bad
+    published, layout = conf.get("published", {}), conf["layout"]
+    if not (isinstance(layout, dict)
+            and isinstance(layout.get("deployment"), str)
+            and isinstance(layout.get("chips_sharing_a_layer"), int)
+            and layout["chips_sharing_a_layer"] >= 1):
+        bad.append(f"config {name}: a cut configuration's layout names its "
+                   "deployment and chips_sharing_a_layer")
+        layout = {}
+    for key in reduced:
+        if _WIDTH.search(key) and key != "vocab_size":
+            bad.append(f"config {name}: reduced names a width, {key}")
+        elif key not in conf:
+            bad.append(f"config {name}: reduced key {key} is not in the file")
+        elif key not in published or published[key] == conf[key]:
+            bad.append(f"config {name}: no published value of {key}")
+        elif "expert" in key and conf[key] < 8:
+            bad.append(f"config {name}: {key} {conf[key]} is under the floor of 8")
+    if "vocab_size" in reduced and "vocab_size" in published \
+            and 8 * conf["vocab_size"] < published["vocab_size"]:
+        bad.append(f"config {name}: vocab_size is under an eighth of the "
+                   "published vocabulary")
+    dense = layout.get("leading_dense_layers", conf.get("first_k_dense_replace", 0))
+    if "num_hidden_layers" in reduced and conf["num_hidden_layers"] - dense < 4:
+        bad.append(f"config {name}: fewer than four layers after the "
+                   f"{dense} leading dense one(s)")
+    return bad

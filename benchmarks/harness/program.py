@@ -1,11 +1,10 @@
-"""The one place the benchmark touches the program's constructors.
-
-Builds the program's ``LlamaConfig`` from a configuration file's PUBLISHED
-keys (never from a preset a later PR may edit), and fills the program's
-variable trees with the benchmark's seeded weights (``weights.py``) in one
-jitted call.  Everything else under ``benchmarks/`` is the program's caller,
-not its user: drivers call ``Trainer.step`` / ``Batcher.submit`` and read
-results, spans and counters.
+"""Where the benchmark touches the program's constructors: this file fills
+the program's variable trees with the benchmark's seeded weights
+(``weights.py``) in one jitted call, and ``programs/<name>.py`` (found by the
+name in a configuration's ``run``, ``manifest.py``) builds the program's
+model configuration from the file's PUBLISHED keys.  Everything else under
+``benchmarks/`` is the program's caller, not its user: drivers call
+``Trainer.step`` / ``Batcher.submit`` and read results, spans and counters.
 """
 
 from __future__ import annotations
@@ -25,48 +24,7 @@ def canonical(path) -> str:
     return "/".join(p for p in parts if p not in _DROP)
 
 
-def llama_config(conf: dict, **overrides):
-    """The program's model config from the published keys plus the file's
-    ``run`` section (how this benchmark runs the model)."""
-    import jax.numpy as jnp
-
-    from finetune_controller_tpu.models.llama import LlamaConfig
-    from finetune_controller_tpu.models.lora import LoRAConfig
-
-    run = conf["run"]
-    if conf.get("sliding_window") and conf.get("use_sliding_window", True):
-        raise ValueError("a windowed configuration needs a windowed program")
-    kw: dict[str, Any] = dict(
-        vocab_size=conf["vocab_size"],
-        d_model=conf["hidden_size"],
-        n_layers=conf["num_hidden_layers"],
-        n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"],
-        rope_theta=float(conf["rope_theta"]),
-        rms_eps=float(conf["rms_norm_eps"]),
-        max_seq_len=int(run["max_seq_len"]),
-        tie_embeddings=bool(conf.get("tie_word_embeddings", False)),
-        attention_qkv_bias=bool(run.get("attention_qkv_bias", False)),
-        dtype=jnp.dtype(run["compute_dtype"]),
-        param_dtype=jnp.float32,
-        logits_dtype=jnp.dtype(run["logits_dtype"]),
-        attention_impl=run["attention_impl"],
-        remat_policy=run["remat_policy"],
-        quantize_base=bool(run["quantize_base"]),
-        quant_block=int(run.get("quant_block", 64)),
-        lora=LoRAConfig(rank=int(run["lora_rank"]),
-                        alpha=float(run["lora_alpha"]),
-                        targets=tuple(run["lora_targets"])),
-    )
-    if conf["hidden_size"] // conf["num_attention_heads"] != conf.get(
-            "head_dim", conf["hidden_size"] // conf["num_attention_heads"]):
-        kw["head_dim_override"] = conf["head_dim"]
-    kw.update(overrides)
-    return LlamaConfig(**kw)
-
-
-def fill(shapes: Any, key, quant_block: int, stacked_marker: str = "blocks"):
+def fill(shapes: Any, key, quant_block: int):
     """A tree like ``shapes`` (of ShapeDtypeStruct) holding the weights of
     ``key`` (``weights.root_key(seed)``).  Trace this inside a jit, with the
     key an ARGUMENT of it: nothing is made on the host, and one compiled
@@ -77,7 +35,7 @@ def fill(shapes: Any, key, quant_block: int, stacked_marker: str = "blocks"):
     def one(path, s):
         name = canonical(path)
         return weights.leaf(key, name, s.shape, s.dtype,
-                            stacked=name.split("/")[0] == stacked_marker,
+                            stacked=weights.is_stacked(name),
                             quant_block=quant_block)
 
     return jax.tree_util.tree_map_with_path(one, shapes)

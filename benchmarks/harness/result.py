@@ -33,7 +33,10 @@ def allocator_peak_bytes(chips: int) -> int:
 
 
 def emit(*, correct: bool, attempted: int, failed: int, metrics: dict,
-         units: dict, device: dict, breakdown: dict | None = None) -> None:
+         units: dict, device: dict, breakdown: dict | None = None,
+         compared: dict | None = None) -> None:
+    """``compared``: each number that decided ``correct`` beside its limit;
+    printed as the last lines of standard error, and last in the line."""
     for name, value in metrics.items():
         if not isinstance(value, (int, float)) or not math.isfinite(value):
             sys.exit(f"benchmark: metric {name} is not a finite number: {value!r}")
@@ -50,5 +53,11 @@ def emit(*, correct: bool, attempted: int, failed: int, metrics: dict,
     }
     if breakdown:
         line["breakdown"] = breakdown
+    if compared:
+        line["compared"] = compared
+        for name, row in compared.items():
+            print(f"compared {name}: {row['value']:.6g} (limit {row['limit']:g})",
+                  file=sys.stderr)
+        sys.stderr.flush()
     sys.stdout.flush()
     print(json.dumps(line), flush=True)

@@ -99,6 +99,15 @@ def _uniform(k, shape, lo: float, hi: float):
     return lo + (hi - lo) * u
 
 
+#: first component of the canonical name of a leaf that carries the layer
+#: axis first (the program's scanned stack); every other leaf is one array
+STACKED = "blocks"
+
+
+def is_stacked(name: str) -> bool:
+    return name.split("/")[0] == STACKED
+
+
 RESIDUAL_WRITERS = ("o_proj", "down_proj")
 RESIDUAL_SCALE = 0.125
 

@@ -127,30 +127,33 @@ def dequant_int4(packed, scales, block: int):
     return w * s
 
 
-def layer_weights(arch: Arch, key, layer) -> dict[str, Any]:
-    """One layer's frozen weights in float32, regenerated from the seed."""
+def layer_weights(arch: Arch, key, layer,
+                  prefix: str = weights.STACKED) -> dict[str, Any]:
+    """One layer's frozen weights in float32, regenerated from the seed:
+    layer ``layer`` of the scanned stack, or with another ``prefix`` a layer
+    that lies outside it under that name (``layer`` 0 then)."""
     base = jnp.dtype(arch.base_dtype)
     out: dict[str, Any] = {}
     for norm in ("attn_norm", "mlp_norm"):
         out[norm] = weights.layer_leaf(
-            key, f"blocks/{norm}/scale", layer, (arch.hidden_size,), base
+            key, f"{prefix}/{norm}/scale", layer, (arch.hidden_size,), base
         ).astype(jnp.float32)
     for name, (i, o) in arch.proj_shapes().items():
         if arch.quantized:
             packed = weights.layer_leaf(
-                key, f"blocks/{name}/kernel_packed", layer, (i // 2, o),
+                key, f"{prefix}/{name}/kernel_packed", layer, (i // 2, o),
                 jnp.uint8)
             scales = weights.layer_leaf(
-                key, f"blocks/{name}/kernel_scales", layer,
+                key, f"{prefix}/{name}/kernel_scales", layer,
                 (i // arch.quant_block, o), jnp.bfloat16, arch.quant_block)
             out[name] = dequant_int4(packed, scales, arch.quant_block)
         else:
             out[name] = weights.layer_leaf(
-                key, f"blocks/{name}/kernel", layer, (i, o), base
+                key, f"{prefix}/{name}/kernel", layer, (i, o), base
             ).astype(jnp.float32)
         if arch.qkv_bias and name.split("/")[1] in ("q_proj", "k_proj", "v_proj"):
             out[name + "/bias"] = weights.layer_leaf(
-                key, f"blocks/{name}/bias", layer, (o,), base
+                key, f"{prefix}/{name}/bias", layer, (o,), base
             ).astype(jnp.float32)
     return out
 
@@ -196,6 +199,20 @@ def rope(x, positions, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
+#: the largest float32 score array (B, heads, S, S) the reference makes in
+#: one piece: 2 rows x 32 heads at 2,048 fill it exactly, and one row at
+#: 8,192 (8.6 GB whole, more under its ``vjp``) goes four heads at a time
+SCORE_BYTES = 1 << 30
+
+
+def heads_per_block(rows: int, heads: int, seq: int) -> int:
+    """How many heads attend at once, from shapes alone: all of them where
+    their scores fit ``SCORE_BYTES``, else the largest divisor of ``heads``
+    that does."""
+    fit = max(1, SCORE_BYTES // (rows * seq * seq * 4))
+    return max(h for h in range(1, heads + 1) if heads % h == 0 and h <= fit)
+
+
 def layer_forward(arch: Arch, w: dict, lora_l: dict, x, positions,
                   q: Callable = identity):
     """One decoder layer.  ``lora_l``: this layer's adapters by full name
@@ -223,11 +240,27 @@ def layer_forward(arch: Arch, w: dict, lora_l: dict, x, positions,
     g = nh // nkv
     kh = jnp.repeat(kh, g, axis=2)
     vh = jnp.repeat(vh, g, axis=2)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q(qh), q(kh)) * hd ** -0.5
     causal = positions[:, None, :, None] >= positions[:, None, None, :]
-    scores = jnp.where(causal, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    ctx = jnp.einsum("bhqk,bkhd->bqhd", q(probs), q(vh)).reshape(bsz, s, nh * hd)
+
+    def attend(qb, kb, vb):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q(qb), q(kb)) * hd ** -0.5
+        scores = jnp.where(causal, scores, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", q(probs), q(vb))
+
+    hb = heads_per_block(bsz, nh, s)
+    if hb == nh:
+        ctx = attend(qh, kh, vh)
+    else:
+        # heads are independent: one block of them at a time, its scores
+        # recomputed on the way back, so no (B, H, S, S) array ever exists
+        def split(t):
+            return jnp.moveaxis(t.reshape(bsz, s, nh // hb, hb, hd), 2, 0)
+
+        ctx = jax.lax.map(lambda b: jax.checkpoint(attend)(*b),
+                          (split(qh), split(kh), split(vh)))
+        ctx = jnp.moveaxis(ctx, 0, 2)
+    ctx = ctx.reshape(bsz, s, nh * hd)
     x = x + proj("attn/o_proj", ctx)
     h = rms_norm(x, w["mlp_norm"], arch.rms_eps)
     act = jax.nn.silu(proj("mlp/gate_proj", h)) * proj("mlp/up_proj", h)

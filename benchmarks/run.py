@@ -128,7 +128,8 @@ def main(argv=None, manifest_path=None, allow_cpu: bool = False) -> dict:
         units = {n: manifest.end_to_end[n]["unit"] for n in names}
     line = dict(correct=out["correct"], attempted=out["attempted"],
                 failed=out["failed"], metrics=metrics, units=units,
-                device=device, breakdown=out.get("breakdown"))
+                device=device, breakdown=out.get("breakdown"),
+                compared=out.get("compared"))
     result.emit(**line)
     return line
 

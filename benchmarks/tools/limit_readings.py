@@ -52,20 +52,20 @@ def train_readings(run, seeds, control_seeds):
     from benchmarks.harness.drivers import train
 
     trainer = train.build_trainer(run)
+    reference = run.manifest.reference(run.conf).reference_numbers
     rows = []
     for seed in seeds:
         state, _step, feed, prog, tokens, step_s = train.first_steps(run, trainer, seed)
         feed.close()
         del state, _step
         t = time.perf_counter()
-        ref = train.reference_numbers(run.conf, run.workload, seed, tokens)
+        ref = reference(run.conf, run.workload, seed, tokens)
         row = {"seed": seed, "program": gaps(prog, ref),
                "reference_s": time.perf_counter() - t, "step_s": step_s}
         if seed in control_seeds:
             t = time.perf_counter()
-            control = train.reference_numbers(
-                run.conf, run.workload, seed, tokens, q=ref_model.to_fp8,
-                precision="default")
+            control = reference(run.conf, run.workload, seed, tokens,
+                                q=ref_model.to_fp8, precision="default")
             row["control_fp8"] = gaps(control, ref)
             row["control_s"] = time.perf_counter() - t
         print(json.dumps(row), flush=True)

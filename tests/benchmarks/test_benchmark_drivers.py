@@ -17,13 +17,14 @@ from benchmarks.harness import compare  # noqa: E402
 from benchmarks.harness.manifest import Manifest  # noqa: E402
 
 TINY = ROOT / "tests/benchmarks/fixtures/BENCHMARK.tiny.json"
+CUT = ROOT / "tests/benchmarks/fixtures/BENCHMARK.cut.json"
 SEED = 2**31 + 17
 
 
-def drive(cell, trace=0, seconds=1.0, seed=SEED):
+def drive(cell, trace=0, seconds=1.0, seed=SEED, manifest=TINY):
     return runner.main(["--workload", cell, "--seed", str(seed), "--seconds",
                         str(seconds), "--trace", str(trace)],
-                       manifest_path=TINY, allow_cpu=True)
+                       manifest_path=manifest, allow_cpu=True)
 
 
 def last_line(capsys):
@@ -38,7 +39,11 @@ def last_line(capsys):
 def test_cell_runs_and_prints_the_contract_line(cell, metric, capsys):
     drive(cell, seconds=2.0 if "serve" in cell else 0.5)
     line = last_line(capsys)
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics", "device",
+                          "compared"]
+    # every number compared stands beside its limit, last in the line
+    assert all(set(row) == {"value", "limit"} for row in line["compared"].values())
+    assert "no_compile_in_window" in line["compared"]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     assert set(line["metrics"]) == set(Manifest(TINY).cell_end_to_end(cell))
@@ -60,6 +65,57 @@ def test_traced_run_reports_the_cells_per_layer_metrics(cell, has, lacks, capsys
         assert metrics[name]["value"] >= 0, name
     for name in lacks:
         assert name not in metrics
+
+
+def test_a_cut_cell_of_another_program_runs_through_the_one_train_driver(
+        monkeypatch, capsys):
+    """ISSUE 26: program, reference and configuration come by files and
+    entries alone (``fixtures/programs``, ``fixtures/reference``,
+    ``configs/tiny-cut.json``); the window and ``correct`` are
+    ``drivers/train.py``'s.  The program's layers are unrolled, so every
+    adapter leaf lies outside the scanned stack and is compared as ONE
+    entry, with no layer axis read into it."""
+    seen = []
+    real = compare.worst_leaf_gap
+    monkeypatch.setattr(compare, "worst_leaf_gap",
+                        lambda prog, ref: seen.append((prog, ref)) or real(prog, ref))
+    drive("tiny-cut.train-tiny", seconds=0.3, manifest=CUT)
+    line = last_line(capsys)
+    assert line["correct"] is True and line["attempted"] > 0
+    assert set(line["compared"]) == {
+        "losses_finite", "no_compile_in_window", "loss_step1_gap",
+        "loss_step2_gap", "first_grad_norm_gap", "param_change_norm_gap"}
+    assert len(seen) == 2
+    for prog, ref in seen:
+        assert set(prog) == set(ref) and len(ref) == 4 * 7 * 2
+        assert "layer_3/mlp/down_proj/lora_b" in ref
+        assert not any("[" in name or name.startswith("blocks") for name in ref)
+
+
+def test_the_cut_cells_step_stuck_is_not_correct(monkeypatch, capsys):
+    """The unstacked leaves are really compared: with the adapters held
+    where they were, their change reads 1 against the reference's."""
+    from finetune_controller_tpu.train.trainer import Trainer
+
+    real = Trainer.step
+    monkeypatch.setattr(
+        Trainer, "step", lambda self, state, batch: (
+            lambda new, m: (new.replace(trainable=state.trainable), m))(
+                *real(self, state, batch)))
+    drive("tiny-cut.train-tiny", seconds=0.3, manifest=CUT)
+    line = last_line(capsys)
+    assert line["correct"] is False
+    assert line["compared"]["param_change_norm_gap"]["value"] == pytest.approx(1.0, abs=0.05)
+
+
+def test_compared_numbers_are_the_last_lines_on_standard_error(capsys):
+    drive("tiny-qlora.train-tiny", seconds=0.3)
+    out, err = capsys.readouterr()
+    line = json.loads(out.strip().splitlines()[-1])
+    tail = err.strip().splitlines()[-len(line["compared"]):]
+    assert [t.split(":")[0] for t in tail] == [
+        f"compared {name}" for name in line["compared"]]
+    assert all("(limit " in t for t in tail)
 
 
 def test_cli_refuses_a_cpu():
@@ -115,11 +171,12 @@ def test_train_control_in_lower_precision_fails_a_limit(seed):
     gradient is a real reading (a bare cast underflows the cotangent to
     zero, which reads exactly 1 and says nothing)."""
     from benchmarks.harness import data
-    from benchmarks.harness.drivers.train import judge, reference_numbers
+    from benchmarks.harness.drivers.train import judge
     from benchmarks.reference import model as ref_model
 
     m = Manifest(TINY)
     conf, wl = m.config("tiny-qlora"), m.workload("tiny-qlora.train-tiny")
+    reference_numbers = m.reference(conf).reference_numbers
     gen = data.increment_batches(wl["batch"], wl["seq"], conf["vocab_size"], seed)
     tokens = [next(gen)["tokens"] for _ in range(wl["reference_steps"])]
     ref = reference_numbers(conf, wl, seed, tokens)
@@ -150,6 +207,15 @@ def test_serve_control_in_lower_precision_reads_a_wide_gap():
     assert (gaps["mean"] > limits["served_token_mean_logit_gap"]
             or gaps["widest"] > limits["served_token_widest_logit_gap"]), gaps
     print(gaps)
+
+
+def test_layer_norms_index_the_scanned_stack_only():
+    flat = {"blocks/attn/q_proj/lora_a": np.array([[3.0, 4.0], [0.0, 1.0]]),
+            "dense_0/mlp/up_proj/lora_b": np.array([[3.0, 4.0], [0.0, 12.0]]),
+            "lm_head/lora_a": np.array([2.0])}
+    assert compare.layer_norms(flat) == {
+        "blocks/attn/q_proj/lora_a[0]": 5.0, "blocks/attn/q_proj/lora_a[1]": 1.0,
+        "dense_0/mlp/up_proj/lora_b": 13.0, "lm_head/lora_a": 2.0}
 
 
 def test_worst_leaf_gap_uses_the_median_leaf_as_floor():

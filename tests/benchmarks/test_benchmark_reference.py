@@ -14,6 +14,7 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
 from benchmarks.harness import program, weights  # noqa: E402
+from benchmarks.harness.programs import llama as llama_program  # noqa: E402
 from benchmarks.reference import model as ref  # noqa: E402
 from benchmarks.reference import train as rtrain  # noqa: E402
 from finetune_controller_tpu.models.llama import PRESETS, LlamaForCausalLM  # noqa: E402
@@ -31,7 +32,7 @@ def load(name):
 @pytest.fixture(scope="module", params=sorted(CASES))
 def case(request):
     conf = load(request.param)
-    cfg = program.llama_config(conf)
+    cfg = llama_program.model_config(conf)
     model = LlamaForCausalLM(cfg)
     variables = program.seeded_serving_variables(model, SEED)
     arch = ref.Arch.from_config(conf)
@@ -160,3 +161,60 @@ def test_reference_adamw_equals_optax():
     for k in params:
         np.testing.assert_allclose(np.asarray(got[k]), np.asarray(want[k]),
                                    rtol=1e-6, atol=1e-7)
+
+
+# ---- attention in blocks of heads (ISSUE 26: the 8k cell's reference) ----------
+
+@pytest.mark.parametrize("rows,heads,seq,want", [
+    (2, 32, 2048, 32),      # the 2k cell: exactly the budget, unblocked
+    (1, 32, 8192, 4),       # the 8k cell: 8.6 GB whole, four heads at a time
+    (1, 32, 2304, 32),      # the serve check's longest padded length
+    (2, 32, 8192, 2),
+    (1, 28, 8192, 4),       # a divisor of the heads, never a ragged block
+    (1, 7, 8192, 1),
+    (1, 4, 32768, 1),       # never less than one head
+    (4, 4, 32, 4),
+])
+def test_heads_per_block_from_shapes(rows, heads, seq, want):
+    assert ref.heads_per_block(rows, heads, seq) == want
+
+
+def test_attention_in_blocks_of_heads_is_the_same_function(monkeypatch):
+    """Forward, and the gradients to the adapters and to the input, with the
+    scores made two heads at a time against all four at once."""
+    conf = load("tiny-qlora")
+    arch, key = ref.Arch.from_config(conf), weights.root_key(SEED)
+    lora_l = ref._layer_lora(ref.init_lora(arch, key), 1)
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 32, 64), jnp.float32)
+    pos = jnp.broadcast_to(jnp.arange(32), (2, 32))
+
+    def passes():
+        def f(ll, xx):
+            with jax.default_matmul_precision("highest"):
+                return ref.layer_forward(arch, ref.layer_weights(arch, key, 1),
+                                         ll, xx, pos)
+        y, vjp = jax.vjp(f, lora_l, x)
+        return y, vjp(jnp.cos(y))
+
+    whole = passes()
+    monkeypatch.setattr(ref, "SCORE_BYTES", 2 * 2 * 32 * 32 * 4)
+    assert ref.heads_per_block(2, 4, 32) == 2
+    blocked = passes()
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(blocked)):
+        scale = float(jnp.max(jnp.abs(a)))
+        assert float(jnp.max(jnp.abs(a - b))) <= 2e-6 * scale
+
+
+def test_a_layer_outside_the_stack_is_drawn_under_its_own_name():
+    conf = load("tiny-qlora")
+    arch, key = ref.Arch.from_config(conf), weights.root_key(SEED)
+    stacked = ref.layer_weights(arch, key, 0)
+    again = ref.layer_weights(arch, key, 0, "blocks")
+    outside = ref.layer_weights(arch, key, 0, "dense_0")
+    assert set(stacked) == set(outside)
+    for name in stacked:
+        assert np.array_equal(stacked[name], again[name])
+        assert not np.array_equal(stacked[name], outside[name]), name
+    want = weights.leaf(key, "dense_0/attn_norm/scale", (64,), jnp.bfloat16,
+                        stacked=False).astype(jnp.float32)
+    assert np.array_equal(outside["attn_norm"], want)

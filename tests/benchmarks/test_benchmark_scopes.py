@@ -31,6 +31,8 @@ from benchmarks.harness.manifest import Manifest  # noqa: E402
 FIXTURE = Path(__file__).parent / "fixtures" / "trace_scopes.txt"
 REAL = Manifest()
 CELL = "mistral-7b-qlora.train-sft-2k"
+CELLS = [CELL, "mistral-7b-qlora.train-sft-8k"]
+CUT = Manifest(ROOT / "tests/benchmarks/fixtures/BENCHMARK.cut.json")
 TINY_CONF = ROOT / "tests/benchmarks/fixtures/configs/tiny-qlora.json"
 NEW = ["step.forward_share_pct", "step.recompute_share_pct",
        "step.backward_share_pct", "step.optimizer_share_pct",
@@ -71,9 +73,9 @@ def reduce(run, metric: str):
 @pytest.mark.parametrize("metric", NEW)
 def test_manifest_registers_and_loads_every_new_metric(metric):
     entry, spec = REAL.per_layer[metric], REAL.layer_metric(metric)
-    assert entry["workloads"] == [CELL]
+    assert entry["workloads"] == CELLS
     assert entry["moves"] == "train_tokens_per_s_chip"
-    assert metric in REAL.cell_per_layer(CELL)
+    assert all(metric in REAL.cell_per_layer(cell) for cell in CELLS)
     assert callable(REAL.reducer(spec["reducer"]))
     assert spec["source"] == entry["source"]
     assert REAL.problems() == []
@@ -181,6 +183,64 @@ def test_roofline_counts_the_need_not_the_recompute(run, capsys):
     need = per_token * 2 * 4 * 32 / 197e12
     assert reduce(run, "proj.matmul_roofline") == pytest.approx(100 * need / 0.055)
     assert "needs" in capsys.readouterr().out
+
+
+# ---- counts found by name (ISSUE 26): another architecture's by a new file -----
+
+def counted_run():
+    """The fixture trace as a run of the tests' cut configuration, whose
+    counts are the module ``counting/tiny_counts.py`` under ITS paths."""
+    run = make_run(FIXTURE.read_text())
+    run.conf, run.manifest = CUT.config("tiny-cut"), CUT
+    run.end_to_end = {"train_tokens_per_s_chip": 1e6}
+    return run
+
+
+def test_mfu_takes_its_count_from_the_named_module():
+    run = counted_run()
+    mfu = REAL.reducer("mfu")
+    got = mfu(run, count="train_flops_per_token", rate="train_tokens_per_s_chip",
+              counts="tiny_counts")
+    assert got == pytest.approx(100 * (1000 * 64 + 32) * 1e6 / 197e12)
+    spec = CUT.layer_metric("tiny.mfu_pct")
+    assert CUT.reducer(spec["reducer"])(run, **spec["args"]) == pytest.approx(got)
+    # left out, it reads what it read before: harness/counts.py
+    run.conf = make_run(FIXTURE.read_text()).conf
+    want = counts.lora_train_flops_per_token(run.conf, 32)
+    assert mfu(run, count="lora_train_flops_per_token",
+               rate="train_tokens_per_s_chip") == pytest.approx(
+        100 * want * 1e6 / 197e12)
+
+
+def test_scope_roofline_takes_its_count_from_the_named_module(capsys):
+    run = counted_run()
+    got = REAL.reducer("scope_roofline")(
+        run, scopes=["q_proj", "up_proj", "down_proj"],
+        count="proj_flops_per_token", counts="tiny_counts")
+    need = 500 * 64 * 2 * 4 * 32 / 197e12
+    assert got == pytest.approx(100 * need / 0.055)
+    with pytest.raises(AttributeError):     # not a function of scope_counts.py
+        REAL.reducer("scope_roofline")(run, scopes=["q_proj"],
+                                       count="proj_flops_per_token")
+
+
+def test_flash_roofline_takes_flops_and_bytes_from_the_named_module(capsys):
+    # q/k heads of 24 and v heads of 16: the one dQ call (24 ms) of 4
+    # sequences of 32 needs 2 score products and 1 value product
+    run = counted_run()
+    kernels = [k for k in REAL.layer_metric("flash_attention_roofline")["args"]["kernels"]]
+    got = REAL.reducer("flash_roofline")(run, kernels=kernels, counts="tiny_counts")
+    flops = (2 * 24 + 16) * 32 * 32 * 4 * 4
+    nbytes = 2.0 * 4 * 32 * 4 * (2 * 24 + 2 * 16)
+    need = max(flops / 197e12, nbytes / 819e9)
+    assert got == pytest.approx(100 * need / 0.024)
+    assert "memory" in capsys.readouterr().out      # so small it is bytes-bound
+    # left out: harness/counts.py, one head size for q, k and v
+    run.conf = make_run(FIXTURE.read_text()).conf
+    want = max(counts.flash_call_flops(run.conf, 4, 32, "bwd_dq") / 197e12,
+               counts.flash_call_bytes(run.conf, 4, 32, "bwd_dq") / 819e9)
+    assert REAL.reducer("flash_roofline")(run, kernels=kernels) == pytest.approx(
+        100 * want / 0.024)
 
 
 def test_a_share_over_105_percent_prints_no_result():
