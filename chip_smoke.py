@@ -451,12 +451,19 @@ def serve_phase(api: Api, job_id: str, cfg: dict, seed: int) -> tuple:
     t0 = time.monotonic()
     api.post(f"/admin/serve/{job_id}/load", timeout=1000)
     load_s = time.monotonic() - t0
-    session = api.get("/admin/serve")["sessions"][job_id]
-    check(session["transport"] == "process" and session["worker_pids"],
-          f"no serve worker process: {session.get('transport')}")
-    replicas = list(session["replicas"].values())
-    check(len(replicas) == 1, f"{len(replicas)} replicas, expected 1")
-    runtime = replicas[0].get("runtime") or {}
+    deadline = time.monotonic() + 30
+    while True:
+        session = api.get("/admin/serve")["sessions"][job_id]
+        check(session["transport"] == "process" and session["worker_pids"],
+              f"no serve worker process: {session.get('transport')}")
+        replicas = list(session["replicas"].values())
+        check(len(replicas) == 1, f"{len(replicas)} replicas, expected 1")
+        runtime = replicas[0].get("runtime") or {}
+        # the worker's device reaches the session with its first stats beat,
+        # which a loaded host delivers after the load call has returned
+        if runtime or time.monotonic() > deadline:
+            break
+        time.sleep(0.5)
     device = {k: runtime.get(k) for k in ("platform", "kind", "count")}
     check(device["platform"] == cfg["platform"],
           f"the serve worker ran on {device}, not on a {cfg['platform']}")

@@ -392,7 +392,8 @@ def _shape_leaves(model, *args) -> list[tuple[str, Any]]:
 def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     """Abstract param trees spanning every weight family the rule tables
     must cover: dense+LoRA (untied, so lm_head exists), QLoRA int4 scales,
-    MoE experts + router, and the multimodal projector + ViT tower.  All
+    MoE experts + router, latent attention with a selection-biased router,
+    and the multimodal projector + ViT tower.  All
     ``eval_shape`` — no parameter memory is allocated."""
     global _VARIANT_CACHE
     if _VARIANT_CACHE is not None:
@@ -400,7 +401,7 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     import jax.numpy as jnp
 
     from ..models.llama import PRESETS, LlamaForCausalLM
-    from ..models.lora import LoRAConfig
+    from ..models.lora import MLA_TARGETS, LoRAConfig
     from ..models.multimodal import MM_PRESETS, LlavaForCausalLM
 
     tokens = jnp.zeros((1, 8), jnp.int32)
@@ -416,6 +417,12 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     )
     out["tiny-moe-test+qlora"] = _shape_leaves(
         LlamaForCausalLM(cfg_moe), tokens
+    )
+    cfg_mla = PRESETS["tiny-mla-moe-test"].replace(
+        lora=LoRAConfig(rank=4, targets=MLA_TARGETS)
+    )
+    out["tiny-mla-moe-test+lora"] = _shape_leaves(
+        LlamaForCausalLM(cfg_mla), tokens
     )
     mm = MM_PRESETS["tiny-mm-test"].replace(lora=LoRAConfig(rank=4))
     pixels = jnp.zeros(

@@ -136,13 +136,15 @@ def _base_kernel(leaves: dict[str, np.ndarray], layer: int, cfg: LlamaConfig) ->
     return np.asarray(deq, np.float32)
 
 
-def _expert_stack(moe: dict[str, np.ndarray], name: str, layer: int) -> np.ndarray:
-    """(E, in, out) f32 expert kernels for one layer, dequantizing int4
-    expert storage (the MoE-QLoRA path — ``models/moe.py``)."""
-    if name in moe:
-        return np.asarray(moe[name][layer], np.float32)
-    packed = moe[f"{name}_packed"][layer]
-    scales = moe[f"{name}_scales"][layer]
+def _expert_stack(moe: dict, name: str, layer: int) -> np.ndarray:
+    """(E, in, out) f32 expert kernels of projection ``name`` for one layer,
+    dequantizing int4 expert storage (the MoE-QLoRA path —
+    ``models/moe.py``)."""
+    leaves = moe["experts"][name]
+    if "kernel" in leaves:
+        return np.asarray(leaves["kernel"][layer], np.float32)
+    packed = leaves["kernel_packed"][layer]
+    scales = leaves["kernel_scales"][layer]
     return np.stack([
         np.asarray(dequantize_int4(packed[e], scales[e], dtype=np.float32))
         for e in range(packed.shape[0])
@@ -152,6 +154,13 @@ def _expert_stack(moe: dict[str, np.ndarray], name: str, layer: int) -> np.ndarr
 def _hf_layout(cfg: LlamaConfig) -> tuple[str, str]:
     """(architecture, model_type) for the config's semantics; raises on
     combinations no HF architecture encodes."""
+    if (cfg.attention_kind != "gqa" or cfg.first_k_dense
+            or cfg.n_shared_experts or cfg.moe_scoring != "softmax"):
+        raise NotImplementedError(
+            "latent attention, leading dense layers and shared-expert "
+            "sigmoid routing have no transformers export yet (ROADMAP.md B); "
+            "export the PEFT adapter instead"
+        )
     gemma_markers = (cfg.norm_offset, cfg.embed_scale, cfg.mlp_act != "silu")
     if any(gemma_markers):
         # Gemma semantics: HF stores the SAME offset-form norm weights and
@@ -244,12 +253,12 @@ def export_merged_checkpoint(
             moe = blocks["moe"]
             mp = f"{prefix}.block_sparse_moe"
             tensors[f"{mp}.gate.weight"] = np.asarray(
-                moe["router_kernel"][i], np.float32
+                moe["router"]["kernel"][i], np.float32
             ).T
             # stacked (E, in, out) → per-expert HF (out, in); the importer's
             # w1=gate / w2=down / w3=up mapping, inverted
-            for name, hf_w in (("experts_gate", "w1"), ("experts_down", "w2"),
-                               ("experts_up", "w3")):
+            for name, hf_w in (("gate_proj", "w1"), ("down_proj", "w2"),
+                               ("up_proj", "w3")):
                 stack = _expert_stack(moe, name, i)
                 for e in range(stack.shape[0]):
                     tensors[f"{mp}.experts.{e}.{hf_w}.weight"] = stack[e].T

@@ -150,10 +150,13 @@ def _map_llama_tensors(
                 down.append(proj(rest, f"block_sparse_moe.experts.{e}.w2.weight"))
                 up.append(proj(rest, f"block_sparse_moe.experts.{e}.w3.weight"))
             tree["moe"] = {
-                "experts_gate": np.stack(gate),
-                "experts_up": np.stack(up),
-                "experts_down": np.stack(down),
-                "router_kernel": proj(rest, "block_sparse_moe.gate.weight"),
+                "experts": {
+                    "gate_proj": {"kernel": np.stack(gate)},
+                    "up_proj": {"kernel": np.stack(up)},
+                    "down_proj": {"kernel": np.stack(down)},
+                },
+                "router": {
+                    "kernel": proj(rest, "block_sparse_moe.gate.weight")},
             }
         else:
             tree["mlp"] = {

@@ -1,4 +1,8 @@
-"""Llama-family decoder (RMSNorm + RoPE + GQA + SwiGLU) in Flax linen.
+"""Llama-family decoder (RMSNorm + RoPE + GQA + SwiGLU) in Flax linen, with
+the block's parts chosen by the configuration: grouped-query or latent
+attention (``attention_kind``), a dense SwiGLU or an expert layer
+(``n_experts``), and ``first_k_dense`` leading dense layers before the
+scanned stack.  ONE top level: embedding -> layers -> final norm -> head.
 
 TPU-first choices:
   * layers run under ``nn.scan`` (one traced layer, stacked params) so XLA
@@ -126,7 +130,39 @@ class LlamaConfig:
     n_experts: int = 0
     moe_top_k: int = 2
     capacity_factor: float = 1.25
+    #: weight of the Switch load-balancing term in the objective; 0 = the
+    #: model has no auxiliary loss (bias-balanced routing) and none is sown
     router_aux_weight: float = 0.02
+    #: width of a routed expert where it is not ``d_ff`` (fine-grained
+    #: experts beside a wide leading dense layer); 0 = ``d_ff``
+    moe_d_ff: int = 0
+    #: shared experts every token passes, as ONE dense SwiGLU of width
+    #: ``n_shared_experts * moe_d_ff`` beside the routed ones
+    n_shared_experts: int = 0
+    moe_scoring: str = "softmax"       # | "sigmoid"
+    #: "capacity" (static slots, pairs over them dropped) | "dropless"
+    moe_dispatch: str = "capacity"
+    #: frozen per-expert bias on the scores, for selection only
+    moe_select_bias: bool = False
+    moe_routed_scale: float = 1.0
+    #: ``(first, count)`` of the experts an expert layer holds (it routes over
+    #: all of them): one member's share under expert parallelism; None = all
+    experts_held: tuple | None = None
+    #: leading layers that keep a dense MLP in a model whose other layers are
+    #: expert layers; they lie outside the scanned stack, as ``layer_<i>``
+    first_k_dense: int = 0
+    # --- latent attention (MLA): queries and keys/values through low-rank
+    # latents, a rotary part of ``qk_rope_head_dim`` beside a position-free
+    # part of ``qk_nope_head_dim``, the rotary KEY shared by all heads, and
+    # values of their own width.  "gqa" = the Llama attention above
+    attention_kind: str = "gqa"        # | "mla"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    #: rotate adjacent pairs (x[2i], x[2i+1]) instead of the two halves
+    rope_interleave: bool = False
     # QLoRA: frozen projection kernels stored as blockwise int4 (config #3)
     quantize_base: bool = False
     quant_block: int = 64
@@ -191,35 +227,48 @@ class LlamaConfig:
             t["ulysses_inner"] = self.ulysses_inner
         return t
 
-    def _count_with_mlp(self, mlp: int) -> int:
-        d, v, L = self.d_model, self.vocab_size, self.n_layers
+    def _attention_params(self) -> int:
+        d, h = self.d_model, self.n_heads
+        if self.attention_kind == "mla":
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            return (d * self.q_lora_rank + self.q_lora_rank          # q_a, its norm
+                    + self.q_lora_rank * h * qk                      # q_b
+                    + d * (self.kv_lora_rank + self.qk_rope_head_dim)
+                    + self.kv_lora_rank                              # kv_a, its norm
+                    + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)                       # o
         hd = self.head_dim
-        qo = 2 * d * self.n_heads * hd
-        kv = 2 * d * self.n_kv_heads * hd
-        per_layer = qo + kv + mlp + 2 * d
-        return v * d + L * per_layer + d + (0 if self.tie_embeddings else d * v)
+        return 2 * d * h * hd + 2 * d * self.n_kv_heads * hd
+
+    def _count(self, experts_counted: int) -> int:
+        """Stored parameters with ``experts_counted`` routed experts a layer."""
+        d, v, L = self.d_model, self.vocab_size, self.n_layers
+        dense_mlp = 3 * d * self.d_ff
+        per_layer = self._attention_params() + 2 * d
+        if self.n_experts:
+            f = self.moe_d_ff or self.d_ff
+            expert_mlp = (experts_counted * 3 * d * f + d * self.n_experts
+                          + (self.n_experts if self.moe_select_bias else 0)
+                          + 3 * d * f * self.n_shared_experts)
+            mlps = (self.first_k_dense * dense_mlp
+                    + (L - self.first_k_dense) * expert_mlp)
+        else:
+            mlps = L * dense_mlp
+        return (v * d + L * per_layer + mlps + d
+                + (0 if self.tie_embeddings else d * v))
 
     def param_count(self) -> int:
-        """Total stored parameters (MoE: ALL experts)."""
-        d, f = self.d_model, self.d_ff
-        if self.n_experts:
-            mlp = self.n_experts * 3 * d * f + d * self.n_experts
-        else:
-            mlp = 3 * d * f
-        return self._count_with_mlp(mlp)
+        """Total stored parameters (MoE: ALL experts, the shared one, the
+        router; leading dense layers with their dense MLP)."""
+        return self._count(self.n_experts)
 
     def active_param_count(self) -> int:
         """Parameters one token's forward actually touches — for MoE, the
-        router plus ``moe_top_k`` of ``n_experts`` experts; equal to
-        :meth:`param_count` on dense configs.  MFU/FLOP accounting must use
-        this (6·N_active per token): counting idle experts would credit the
-        chip with matmuls it never ran."""
-        d, f = self.d_model, self.d_ff
-        if self.n_experts:
-            mlp = self.moe_top_k * 3 * d * f + d * self.n_experts
-        else:
-            mlp = 3 * d * f
-        return self._count_with_mlp(mlp)
+        router, the shared expert and ``moe_top_k`` of ``n_experts`` routed
+        experts; equal to :meth:`param_count` on dense configs.  MFU/FLOP
+        accounting must use this (6·N_active per token): counting idle
+        experts would credit the chip with matmuls it never ran."""
+        return self._count(self.moe_top_k if self.n_experts else 0)
 
 
 # Architecture presets for the BASELINE.md configs (shapes per the public
@@ -324,6 +373,20 @@ PRESETS: dict[str, LlamaConfig] = {
         vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=128, max_seq_len=128, n_experts=4, moe_top_k=2,
     ),
+    # the fine-grained expert family at toy size: latent attention with
+    # uneven head sizes (q/k 16 + 8, v 16) and interleaved RoPE, one leading
+    # dense layer, then dropless sigmoid top-2 of 8 narrow experts beside a
+    # shared one, balanced by a selection bias (no auxiliary loss)
+    "tiny-mla-moe-test": LlamaConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=4,
+        d_ff=128, max_seq_len=128, rms_eps=1e-6,
+        attention_kind="mla", q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        rope_interleave=True, first_k_dense=1,
+        n_experts=8, moe_top_k=2, moe_d_ff=32, n_shared_experts=1,
+        moe_scoring="sigmoid", moe_dispatch="dropless", moe_select_bias=True,
+        moe_routed_scale=2.5, router_aux_weight=0.0,
+    ),
 }
 
 
@@ -358,9 +421,11 @@ def rope_inv_freqs(cfg: "LlamaConfig") -> jax.Array:
 
 def apply_rope(
     x: jax.Array, positions: jax.Array, theta: float | None = None,
-    *, inv_freqs: jax.Array | None = None,
+    *, inv_freqs: jax.Array | None = None, interleave: bool = False,
 ) -> jax.Array:
-    """Rotary embedding. x: (B, S, H, D), positions: (B, S).
+    """Rotary embedding. x: (B, S, H, D), positions: (B, S).  ``interleave``
+    rotates the adjacent pairs ``(x[2i], x[2i+1])`` (the layout latent-
+    attention checkpoints store) instead of ``(x[i], x[i + D/2])``.
 
     Pass exactly one of ``theta`` (plain schedule) or ``inv_freqs``
     (precomputed, e.g. :func:`rope_inv_freqs` with llama3 scaling) — a
@@ -378,6 +443,12 @@ def apply_rope(
     angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, half)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if interleave:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        out = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).reshape(x.shape)
+        return out.astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -593,17 +664,80 @@ class Attention(nn.Module):
             out.reshape(b, s, -1), deterministic, adapter_ids)
 
 
+class MLAttention(nn.Module):
+    """Latent attention, the un-absorbed form training uses: queries through
+    a normed latent (``q_a_proj`` -> ``q_a_norm`` -> ``q_b_proj``), keys and
+    values through another (``kv_a_proj_with_mqa`` -> ``kv_a_norm`` ->
+    ``kv_b_proj``), each head's q/k = [position-free | rotary] with the ONE
+    rotary key head shared by all heads, values of their own width.  The
+    kernels take q/k of ``qk_nope + qk_rope`` beside v of ``v_head_dim``
+    (``ops/pallas/flash_attention.py``); the rotary key is broadcast into the
+    heads' keys (one contraction over the whole q/k width)."""
+
+    cfg: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids, deterministic=True,
+                 decode=False, page_table=None, adapter_ids=None):
+        cfg = self.cfg
+        if decode:
+            raise NotImplementedError(
+                "latent attention has no decode path yet: serving it needs a "
+                "latent paged cache (ROADMAP.md B); train and evaluate only")
+        b, s, _ = x.shape
+        h = cfg.n_heads
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+        def norm(name):
+            return RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype,
+                           cfg.norm_offset, name=name)
+
+        c_q = _proj(cfg, "q_a_proj", cfg.q_lora_rank)(x, deterministic, adapter_ids)
+        q = _proj(cfg, "q_b_proj", h * (dn + dr))(
+            norm("q_a_norm")(c_q), deterministic, adapter_ids
+        ).reshape(b, s, h, dn + dr)
+        kv_a = _proj(cfg, "kv_a_proj_with_mqa", cfg.kv_lora_rank + dr)(
+            x, deterministic, adapter_ids)
+        c_kv, k_rope = kv_a[..., :cfg.kv_lora_rank], kv_a[..., cfg.kv_lora_rank:]
+        kv = _proj(cfg, "kv_b_proj", h * (dn + dv))(
+            norm("kv_a_norm")(c_kv), deterministic, adapter_ids
+        ).reshape(b, s, h, dn + dv)
+        with jax.named_scope("rope"):
+            q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta,
+                                interleave=cfg.rope_interleave)
+            k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                                interleave=cfg.rope_interleave)
+            q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
+        v = kv[..., dn:]
+        q = checkpoint_name(q, "attn_qkv")
+        k = checkpoint_name(k, "attn_qkv")
+        v = checkpoint_name(v, "attn_qkv")
+        out = causal_attention(
+            q, k, v, impl=cfg.attention_impl, segment_ids=segment_ids,
+            tuning=cfg.kernel_tuning(),
+        )
+        out = checkpoint_name(out, "attn_ctx")
+        out = _proj(cfg, "o_proj", cfg.d_model)(
+            out.reshape(b, s, h * dv), deterministic, adapter_ids)
+        return checkpoint_name(out, "attn_o")
+
+
 class MLP(nn.Module):
     cfg: LlamaConfig
+    #: hidden width where it is not ``cfg.d_ff`` (a shared expert's)
+    d_ff: int = 0
 
     @nn.compact
     def __call__(self, x, deterministic=True, adapter_ids=None):
         cfg = self.cfg
+        d_ff = self.d_ff or cfg.d_ff
         gate = checkpoint_name(
-            _proj(cfg, "gate_proj", cfg.d_ff)(x, deterministic, adapter_ids),
+            _proj(cfg, "gate_proj", d_ff)(x, deterministic, adapter_ids),
             "mlp_gate")
         up = checkpoint_name(
-            _proj(cfg, "up_proj", cfg.d_ff)(x, deterministic, adapter_ids),
+            _proj(cfg, "up_proj", d_ff)(x, deterministic, adapter_ids),
             "mlp_up")
         act = nn.gelu if cfg.mlp_act == "gelu" else nn.silu  # GeGLU | SwiGLU
         out = _proj(cfg, "down_proj", cfg.d_model)(
@@ -613,25 +747,42 @@ class MLP(nn.Module):
 
 class Block(nn.Module):
     cfg: LlamaConfig
+    #: a leading layer of an expert model: keeps the dense MLP
+    dense_mlp: bool = False
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, deterministic=True,
                  decode=False, page_table=None, adapter_ids=None):
         cfg = self.cfg
+        if cfg.attention_kind not in ("gqa", "mla"):
+            raise ValueError(f"unknown attention_kind {cfg.attention_kind!r}")
+        attention = MLAttention if cfg.attention_kind == "mla" else Attention
         h = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="attn_norm")(x)
-        x = x + Attention(cfg, name="attn")(
+        x = x + attention(cfg, name="attn")(
             h, positions, segment_ids, deterministic, decode,
             page_table, adapter_ids)
         h = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="mlp_norm")(x)
-        if cfg.n_experts:
+        if cfg.n_experts and not self.dense_mlp:
             from .moe import MoEMLP
 
+            expert_ff = cfg.moe_d_ff or cfg.d_ff
             mlp_out = MoEMLP(
                 d_model=cfg.d_model,
-                d_ff=cfg.d_ff,
+                d_ff=expert_ff,
                 n_experts=cfg.n_experts,
                 top_k=cfg.moe_top_k,
                 capacity_factor=cfg.capacity_factor,
+                dispatch=cfg.moe_dispatch,
+                scoring=cfg.moe_scoring,
+                select_bias=cfg.moe_select_bias,
+                routed_scale=cfg.moe_routed_scale,
+                experts_held=cfg.experts_held,
+                # parent=None: adopted by the expert layer under the name of
+                # its attribute (moe/shared/...), not by this block
+                shared=(MLP(cfg, d_ff=cfg.n_shared_experts * expert_ff,
+                            parent=None)
+                        if cfg.n_shared_experts else None),
+                aux_loss=cfg.router_aux_weight > 0,
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 quantize_base=cfg.quantize_base,
@@ -761,37 +912,40 @@ class LlamaForCausalLM(nn.Module):
             x = x * jnp.asarray(cfg.d_model ** 0.5, cfg.dtype)
 
         policy = remat_policy_fn(cfg.remat_policy)
+        # args 4/5 = deterministic/decode (0 is self): static bools
+        unrolled_cls = (
+            nn.remat(Block, prevent_cse=False, static_argnums=(4, 5), policy=policy)
+            if cfg.remat and policy is not None
+            else Block
+        )
+        # the leading dense layers of an expert model are another kind of
+        # block: a stack of their own, ``layer_<i>``, before the scanned one
+        n_dense = cfg.first_k_dense if cfg.n_experts else 0
+        n_unrolled = n_dense if cfg.scan_layers else cfg.n_layers
+        for i in range(n_unrolled):
+            x = unrolled_cls(cfg, dense_mlp=i < n_dense, name=f"layer_{i}")(
+                x, positions, segment_ids, deterministic, decode,
+                page_table, adapter_ids)
         if cfg.scan_layers:
             block_cls = _ScanBlock
             if cfg.remat and policy is not None:
                 block_cls = nn.remat(
                     _ScanBlock,
                     prevent_cse=False,
-                    # args 4/5 = deterministic/decode (0 is self): static bools
                     static_argnums=(4, 5),
                     policy=policy,
                 )
             stack = nn.scan(
                 block_cls,
                 variable_axes={"params": 0, "lora": 0, "moe_aux": 0,
-                               "cache": 0, "tenants": 0},
+                               "moe_stats": 0, "cache": 0, "tenants": 0},
                 split_rngs={"params": True, "dropout": True},
                 in_axes=(nn.broadcast, nn.broadcast, nn.broadcast,
                          nn.broadcast, nn.broadcast, nn.broadcast),
-                length=cfg.n_layers,
+                length=cfg.n_layers - n_dense,
             )(cfg, name="blocks")
             x, _ = stack(x, positions, segment_ids, deterministic, decode,
                          page_table, adapter_ids)
-        else:
-            block_cls = (
-                nn.remat(Block, prevent_cse=False, static_argnums=(4, 5), policy=policy)
-                if cfg.remat and policy is not None
-                else Block
-            )
-            for i in range(cfg.n_layers):
-                x = block_cls(cfg, name=f"layer_{i}")(
-                    x, positions, segment_ids, deterministic, decode,
-                    page_table, adapter_ids)
 
         x = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="final_norm")(x)
         if cfg.tie_embeddings:

@@ -25,6 +25,20 @@ DEFAULT_TARGETS = (
     "down_proj",
 )
 
+#: the projections of a latent-attention block (``models/llama.py``
+#: MLAttention) beside the dense / shared-expert MLP's: what PEFT recipes for
+#: that family target (routed experts and the router stay frozen)
+MLA_TARGETS = (
+    "q_a_proj",
+    "q_b_proj",
+    "kv_a_proj_with_mqa",
+    "kv_b_proj",
+    "o_proj",
+    "gate_proj",
+    "up_proj",
+    "down_proj",
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class LoRAConfig:

@@ -288,7 +288,8 @@ class LlavaForCausalLM(nn.Module):
                 )
             stack = nn.scan(
                 block_cls,
-                variable_axes={"params": 0, "lora": 0, "moe_aux": 0, "cache": 0},
+                variable_axes={"params": 0, "lora": 0, "moe_aux": 0,
+                               "moe_stats": 0, "cache": 0},
                 split_rngs={"params": True, "dropout": True},
                 in_axes=(nn.broadcast, nn.broadcast, nn.broadcast, nn.broadcast),
                 length=tcfg.n_layers,

@@ -42,8 +42,9 @@ def xla_causal_attention(
 ) -> jax.Array:
     """Causal (optionally segment-masked) GQA attention.
 
-    Shapes: q (B, S, H, D); k, v (B, S, Hkv, D) with H % Hkv == 0.
-    Returns (B, S, H, D) in q.dtype.
+    Shapes: q (B, S, H, D); k (B, S, Hkv, D); v (B, S, Hkv, Dv) with
+    H % Hkv == 0.  Dv may differ from D (latent attention's 192 / 128); the
+    softmax scale comes from D.  Returns (B, S, H, Dv) in q.dtype.
     """
     b, s, h, d = q.shape
     hkv = k.shape[2]
@@ -60,7 +61,7 @@ def xla_causal_attention(
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
 
     out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
-    return out.reshape(b, s, h, d)
+    return out.reshape(b, s, h, v.shape[-1])
 
 
 def chunked_cache_attention(

@@ -103,12 +103,12 @@ def _block_positions(iq, ik, bq, bk):
 def _fwd_kernel(
     q_ref,      # (1, 1, bq, d)
     k_ref,      # (1, 1, bk, d)
-    v_ref,      # (1, 1, bk, d)
+    v_ref,      # (1, 1, bk, dv)  — dv may differ from d (latent attention)
     qseg_ref,   # (1, 1, bq)
     kseg_ref,   # (1, 1, bk)
-    o_ref,      # (1, 1, bq, d)
+    o_ref,      # (1, 1, bq, dv)
     lse_ref,    # (1, 1, bq, 1)
-    acc_ref,    # VMEM scratch (bq, d) f32
+    acc_ref,    # VMEM scratch (bq, dv) f32
     m_ref,      # VMEM scratch (bq, 1) f32
     l_ref,      # VMEM scratch (bq, 1) f32
     *,
@@ -235,7 +235,7 @@ def _pad_inputs(q, k, v, segment_ids, bq, bk, kv_segment_ids=None):
 def _flash_forward(
     q: jax.Array,           # (B, S, H, D)
     k: jax.Array,           # (B, S, Hkv, D)
-    v: jax.Array,
+    v: jax.Array,           # (B, S, Hkv, Dv): Dv may differ from D
     segment_ids: jax.Array,  # (B, S) int32
     *,
     block_q: int,
@@ -246,9 +246,10 @@ def _flash_forward(
     causal: bool = True,
     kv_segment_ids: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (out (B, S, H, D), lse (B, H, S_pad, 1) f32)."""
+    """Returns (out (B, S, H, Dv), lse (B, H, S_pad, 1) f32).  The softmax
+    scale comes from the q/k head size; V keeps its own width in HBM."""
     b, s, h, d = q.shape
-    hkv = k.shape[2]
+    hkv, d_v = k.shape[2], v.shape[3]
     group = h // hkv
     scale = d ** -0.5
 
@@ -278,20 +279,20 @@ def _flash_forward(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
             pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, 0, iq)),
             pl.BlockSpec((1, 1, bk), lambda ib, ih, iq, ik: (ib, 0, ik)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, bq, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s_pad, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s_pad, d_v), q.dtype),
             jax.ShapeDtypeStruct((b, h, s_pad, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, d_v), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
@@ -313,8 +314,8 @@ def _flash_forward(
 def _bwd_dq_kernel(
     q_ref,      # (1, 1, bq, d)
     k_ref,      # (1, 1, bk, d)
-    v_ref,      # (1, 1, bk, d)
-    do_ref,     # (1, 1, bq, d)
+    v_ref,      # (1, 1, bk, dv)
+    do_ref,     # (1, 1, bq, dv)
     lse_ref,    # (1, 1, bq, 1)
     delta_ref,  # (1, 1, bq, 1)
     qseg_ref,   # (1, 1, bq)
@@ -394,17 +395,17 @@ def _bwd_dq_kernel(
 
 def _bwd_dkv_kernel(
     k_ref,      # (1, 1, bk, d)
-    v_ref,      # (1, 1, bk, d)
+    v_ref,      # (1, 1, bk, dv)
     q_ref,      # (1, 1, bq, d)  — q head = ihkv*group + j // nq
-    do_ref,     # (1, 1, bq, d)
+    do_ref,     # (1, 1, bq, dv)
     lse_ref,    # (1, 1, bq, 1)
     delta_ref,  # (1, 1, bq, 1)
     kseg_ref,   # (1, 1, bk)
     qseg_ref,   # (1, 1, bq)
     dk_ref,     # (1, 1, bk, d)  — one accumulator per KV head (GQA group
-    dv_ref,     # (1, 1, bk, d)     reduced IN kernel, no per-q-head partials)
+    dv_ref,     # (1, 1, bk, dv)    reduced IN kernel, no per-q-head partials)
     dk_acc,     # VMEM scratch (bk, d) f32
-    dv_acc,     # VMEM scratch (bk, d) f32
+    dv_acc,     # VMEM scratch (bk, dv) f32
     *,
     n_q_blocks: int,
     seq_len: int,
@@ -496,7 +497,7 @@ def _flash_backward(
     kv_segment_ids=None,
 ):
     b, s, h, d = q.shape
-    hkv = k.shape[2]
+    hkv, d_v = k.shape[2], v.shape[3]
     group = h // hkv
     scale = d ** -0.5
 
@@ -512,8 +513,8 @@ def _flash_backward(
 
     qt = q_p.transpose(0, 2, 1, 3)      # (B, H, S, D)
     kt = k_p.transpose(0, 2, 1, 3)      # (B, Hkv, S, D)
-    vt = v_p.transpose(0, 2, 1, 3)
-    dot = g_p.transpose(0, 2, 1, 3)     # (B, H, S, D)
+    vt = v_p.transpose(0, 2, 1, 3)      # (B, Hkv, S, Dv)
+    dot = g_p.transpose(0, 2, 1, 3)     # (B, H, S, Dv)
     outt = out_p.transpose(0, 2, 1, 3)
 
     # delta_i = Σ_d dO_i · O_i — O(S·D) precompute, plain XLA
@@ -540,8 +541,8 @@ def _flash_backward(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
-            pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
+            pl.BlockSpec((1, 1, bq, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, 0, iq)),
@@ -568,13 +569,13 @@ def _flash_backward(
         grid=(b, hkv, nk, group * nq),
         in_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
             pl.BlockSpec(
                 (1, 1, bq, d),
                 lambda ib, ih, ik, j: (ib, ih * group + j // nq, j % nq, 0),
             ),
             pl.BlockSpec(
-                (1, 1, bq, d),
+                (1, 1, bq, d_v),
                 lambda ib, ih, ik, j: (ib, ih * group + j // nq, j % nq, 0),
             ),
             pl.BlockSpec(
@@ -590,15 +591,15 @@ def _flash_backward(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, s_pad, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, hkv, s_pad, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, s_pad, d_v), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bk, d), jnp.float32),
-            pltpu.VMEM((bk, d), jnp.float32),
+            pltpu.VMEM((bk, d_v), jnp.float32),
         ],
         compiler_params=_dimension_semantics(
             "parallel", "parallel", "parallel", "arbitrary"

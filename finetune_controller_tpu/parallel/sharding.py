@@ -112,6 +112,20 @@ LLAMA_RULES = PartitionRules(
         (r"embed_tokens/embedding", P(Ax.TENSOR, Ax.FSDP)),
         # lm head kernel: (d_model, vocab)
         (r"lm_head/kernel", P(Ax.FSDP, Ax.TENSOR)),
+        # MoE experts (models/moe.py): stacked (n_experts, in, out) under
+        # experts/<projection>/, experts over EP so expert matmuls are local
+        # and token exchange is all-to-all.  These precede the dense
+        # projection rules, whose patterns the paths also contain.  Int4
+        # scales first (same tiny-block-dim reasoning as the dense
+        # kernel_scales carve-outs): (E, in/block, out) keeps the block dim
+        # whole and shards only experts + the feature dim
+        (r"experts/(gate_proj|up_proj)/kernel_scales", P(Ax.EXPERT, None, Ax.TENSOR)),
+        (r"experts/down_proj/kernel_scales", P(Ax.EXPERT, None, Ax.FSDP)),
+        (r"experts/(gate_proj|up_proj)/kernel", P(Ax.EXPERT, Ax.FSDP, Ax.TENSOR)),
+        (r"experts/down_proj/kernel", P(Ax.EXPERT, Ax.TENSOR, Ax.FSDP)),
+        (r"router/kernel", P(Ax.FSDP, None)),
+        # the selection bias: one number an expert, whole everywhere
+        (r"router/bias", P()),
         # QLoRA int4 scales: (in/block, out) — the block dim is tiny, keep it
         # whole and shard only the feature dim (must precede the kernel rules,
         # which would otherwise also match "kernel_scales")
@@ -120,19 +134,15 @@ LLAMA_RULES = PartitionRules(
         # attention projections (kernel and int4-packed kernel share layout)
         (r"(q_proj|k_proj|v_proj)/kernel", P(Ax.FSDP, Ax.TENSOR)),
         (r"o_proj/kernel", P(Ax.TENSOR, Ax.FSDP)),
+        # latent attention (models/llama.py MLAttention): the down-projections
+        # into the latents feed a norm over the whole latent, so their output
+        # stays whole over TP; the up-projections out of the latents split by
+        # head like q/k/v (o_proj is the rule above)
+        (r"(q_a_proj|kv_a_proj_with_mqa)/kernel", P(Ax.FSDP, None)),
+        (r"(q_b_proj|kv_b_proj)/kernel", P(Ax.FSDP, Ax.TENSOR)),
         # MLP
         (r"(gate_proj|up_proj)/kernel", P(Ax.FSDP, Ax.TENSOR)),
         (r"down_proj/kernel", P(Ax.TENSOR, Ax.FSDP)),
-        # MoE experts (models/moe.py): stacked (n_experts, in, out), experts
-        # over EP so expert matmuls are local and token exchange is all-to-all.
-        # Int4 scales first (same tiny-block-dim reasoning as the dense
-        # kernel_scales carve-outs above): (E, in/block, out) keeps the block
-        # dim whole and shards only experts + the feature dim
-        (r"experts_(gate|up)_scales", P(Ax.EXPERT, None, Ax.TENSOR)),
-        (r"experts_down_scales", P(Ax.EXPERT, None, Ax.FSDP)),
-        (r"experts_(gate|up)", P(Ax.EXPERT, Ax.FSDP, Ax.TENSOR)),
-        (r"experts_down", P(Ax.EXPERT, Ax.TENSOR, Ax.FSDP)),
-        (r"router_kernel", P(Ax.FSDP, None)),
         # multimodal projector (models/multimodal.py): fc1 (d_vision, hidden)
         # column-parallel, fc2 (hidden, d_model) row-parallel
         (r"projector_fc1/kernel", P(Ax.FSDP, Ax.TENSOR)),
@@ -148,6 +158,9 @@ LLAMA_RULES = PartitionRules(
         # B (r, out) over the output dim.  Rank r is tiny — keep it replicated.
         (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/lora_a", P(Ax.FSDP, None)),
         (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/lora_b", P(None, Ax.TENSOR)),
+        (r"(q_a_proj|kv_a_proj_with_mqa|q_b_proj|kv_b_proj)/lora_a", P(Ax.FSDP, None)),
+        (r"(q_a_proj|kv_a_proj_with_mqa)/lora_b", P()),
+        (r"(q_b_proj|kv_b_proj)/lora_b", P(None, Ax.TENSOR)),
         (r"o_proj/lora_a|down_proj/lora_a", P(Ax.TENSOR, None)),
         (r"o_proj/lora_b|down_proj/lora_b", P(None, Ax.FSDP)),
         # norms, scales, biases — replicated

@@ -190,9 +190,9 @@ def _adapt_loaded_params(loaded: Any, target: Any, *, quant_block: int) -> Any:
     out: dict[str, Any] = {}
     loaded = dict(loaded)
     # quantize every kernel the target stores int4: dense projections are
-    # "kernel" -> "kernel_packed"/"kernel_scales"; stacked MoE experts are
-    # "experts_gate" -> "experts_gate_packed"/... (models/moe.py). Leading
-    # axes (scan layers, the expert axis) are vmapped generically.
+    # "kernel" -> "kernel_packed"/"kernel_scales", and so are the stacked MoE
+    # experts' (models/moe.py: experts/<projection>/kernel). Leading axes
+    # (scan layers, the expert axis) are vmapped generically.
     for pk in [k for k in target if k.endswith("_packed")]:
         stem = pk[: -len("_packed")]
         if stem not in loaded:
@@ -345,6 +345,7 @@ class Trainer:
         # drop the init-time sown aux collection: re-feeding it to apply would
         # make flax append to the stale tuple and double-count the MoE aux loss
         variables.pop("moe_aux", None)
+        variables.pop("moe_stats", None)
         if self.cfg.mode == "lora":
             if "lora" not in variables:
                 raise ValueError("mode='lora' but the model has no LoRA params; set lora.rank > 0")
@@ -578,21 +579,29 @@ class Trainer:
         )
         if self._is_multimodal:
             apply_kw["pixels"] = batch.get("pixels")
+        aux_penalty = 0.0
         if self.model_cfg.n_experts:
-            logits, collections = self.model.apply(
-                variables, batch["tokens"], mutable=("moe_aux",), **apply_kw
-            )
-            from ..models.moe import moe_aux_loss
+            from ..models.moe import moe_aux_loss, moe_counters
 
-            aux_penalty = self.model_cfg.router_aux_weight * moe_aux_loss(collections)
+            logits, collections = self.model.apply(
+                variables, batch["tokens"], mutable=("moe_aux", "moe_stats"),
+                **apply_kw
+            )
+            # a model balanced by a selection bias has no auxiliary loss and
+            # sows none: the collection is skipped, not read as zero
+            aux_weight = self.model_cfg.router_aux_weight
+            if aux_weight:
+                aux_penalty = aux_weight * moe_aux_loss(collections)
         else:
             logits = self.model.apply(variables, batch["tokens"], **apply_kw)
-            aux_penalty = 0.0
         loss, metrics = next_token_loss(
             logits, batch["tokens"], batch.get("loss_mask")
         )
         if self.model_cfg.n_experts:
-            metrics = dict(metrics, moe_aux=aux_penalty)
+            metrics = dict(metrics, **jax.lax.stop_gradient(
+                moe_counters(collections)))
+            if aux_weight:
+                metrics["moe_aux"] = aux_penalty
         return loss + aux_penalty, metrics
 
     def _train_step(self, state: TrainState, batch: dict):

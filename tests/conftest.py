@@ -72,3 +72,42 @@ def tiny_job_spec(steps: int = 3):
             total_steps=steps, warmup_steps=1, batch_size=2, seq_len=16, lora_rank=2
         )
     )
+
+
+#: the three tests under ``tests/benchmarks/`` that pin PR 26's two-cell
+#: ``BENCHMARK.json``, which ISSUE 27 appends to, and that only a
+#: ``benchmark`` PR may edit (a PR of another kind changes no file the
+#: benchmark already has): ``node id -> (why, the test that holds what it
+#: held)``.  The next ``benchmark`` PR edits the three and deletes this table
+#: and the hook under it (ROADMAP.md, B1)
+SUPERSEDED = {
+    "tests/benchmarks/test_benchmark_manifest.py::"
+    "test_the_real_manifest_has_its_two_cells_and_no_metric_by_default": (
+        "pins PR 26's two cells; ISSUE 27 adds a third",
+        "tests/benchmarks/test_benchmark_mla_moe.py::"
+        "test_the_real_manifest_has_its_three_cells_and_no_metric_by_default"),
+    "tests/benchmarks/test_benchmark_scopes.py::"
+    "test_manifest_registers_and_loads_every_new_metric": (
+        "pins every PR 24 entry's cells to PR 26's two; ISSUE 27 appends its "
+        "cell to the architecture-neutral ones",
+        "tests/benchmarks/test_benchmark_mla_moe.py::"
+        "test_manifest_registers_and_loads_every_accepted_metric"),
+    "tests/benchmarks/test_benchmark_scopes.py::"
+    "test_the_accepted_entries_stand_first_and_unchanged": (
+        "pins the list's end; ISSUE 27 appends its entries",
+        "tests/benchmarks/test_benchmark_mla_moe.py::"
+        "test_the_accepted_entries_stand_first_and_the_new_ones_last"),
+}
+
+
+def pytest_collection_modifyitems(config, items):
+    """Skip a superseded pin ONLY in a session that also collected the test
+    holding what it held: without its replacement (renamed, deleted, or left
+    out of the run) the pin runs, and fails."""
+    collected = {item.nodeid.split("[")[0] for item in items}
+    for item in items:
+        why, held_by = SUPERSEDED.get(item.nodeid.split("[")[0], (None, None))
+        if held_by in collected:
+            item.add_marker(pytest.mark.skip(
+                reason=f"{why}; held by {held_by} until a benchmark PR "
+                       "edits the file"))

@@ -75,23 +75,28 @@ def _custom_calls(compiled) -> int:
 # flash attention: the training kernels
 # ---------------------------------------------------------------------------
 
-#: (B, S, H, Hkv, D): the TinyLlama training shape chip_smoke.py runs, and a
-#: head-dim-128 GQA shape (Llama-3 / Mistral heads at a batch that fits)
+#: (B, S, H, Hkv, D[, Dv]): the TinyLlama training shape chip_smoke.py runs,
+#: a head-dim-128 GQA shape (Llama-3 / Mistral heads at a batch that fits),
+#: and latent attention's uneven pair (q/k 128 + 64, v 128; 192 is no
+#: multiple of the 128 lanes) at the expert cell's batch
 FLASH_SHAPES = {
     "tinyllama-b8-s2048": (8, 2048, 32, 4, 64),
     "d128-b2-s2048": (2, 2048, 32, 8, 128),
+    "qk192-v128-b2-s4096": (2, 4096, 32, 32, 192, 128),
 }
 
 
 @pytest.mark.parametrize("shape,segments", [
     ("tinyllama-b8-s2048", False), ("tinyllama-b8-s2048", True),
-    ("d128-b2-s2048", False),
-], ids=["tinyllama-plain", "tinyllama-segments", "d128-plain"])
+    ("d128-b2-s2048", False), ("qk192-v128-b2-s4096", False),
+], ids=["tinyllama-plain", "tinyllama-segments", "d128-plain", "qk192-v128"])
 def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
-    b, s, h, hkv, d = FLASH_SHAPES[shape]
+    b, s, h, hkv, d, *rest = FLASH_SHAPES[shape]
     one = SingleDeviceSharding(v5e[0])
     q = jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=one)
-    kv = jax.ShapeDtypeStruct((b, s, hkv, d), BF16, sharding=one)
+    k = jax.ShapeDtypeStruct((b, s, hkv, d), BF16, sharding=one)
+    v = jax.ShapeDtypeStruct((b, s, hkv, rest[0] if rest else d), BF16,
+                             sharding=one)
     seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one)
 
     def loss(q, k, v, seg):
@@ -100,8 +105,37 @@ def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv, seg).compile()
+        q, k, v, seg).compile()
     assert _custom_calls(compiled) == 3  # forward + dQ + dK/dV
+
+
+# ---------------------------------------------------------------------------
+# the expert layer's grouped product (models/moe.py dropless dispatch)
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)], ids=["up", "down"])
+def test_grouped_expert_product_compiles_for_v5e_forward_and_activation_gradient(
+        v5e, monkeypatch, k, n):
+    """65,536 sorted pairs over 256 experts at the published widths, with the
+    tiles ``_gmm_tiling`` picks from each product's own shapes: the Pallas
+    kernel forward and, transposed, for the activation gradient (the frozen
+    experts' weight gradient is never asked for, so its kernel goes)."""
+    from finetune_controller_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe._gmm_tiling(65536, k, n) == (512, min(k, 1024), min(n, 1024))
+    one = SingleDeviceSharding(v5e[0])
+    rows = jax.ShapeDtypeStruct((65536, k), BF16, sharding=one)
+    kernels = jax.ShapeDtypeStruct((256, k, n), BF16, sharding=one)
+    sizes = jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one)
+
+    def loss(rows, kernels, sizes):
+        return jnp.sum(moe._grouped_dot(rows, kernels, sizes).astype(jnp.float32))
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(rows, kernels, sizes).compile()
+    assert _custom_calls(compiled) >= 2
+    assert "ragged-dot" not in compiled.as_text()
 
 
 # ---------------------------------------------------------------------------

@@ -309,7 +309,8 @@ def test_mixtral_int4_experts_merged_export(tmp_path):
     tensors = load_file(str(merged_dir / "model.safetensors"))
     moe = variables["params"]["blocks"]["block"]["moe"]
     want = np.asarray(dequantize_int4(
-        moe["experts_gate_packed"][0][1], moe["experts_gate_scales"][0][1],
+        moe["experts"]["gate_proj"]["kernel_packed"][0][1],
+        moe["experts"]["gate_proj"]["kernel_scales"][0][1],
         dtype=jnp.float32,
     )).T
     got = tensors["model.layers.0.block_sparse_moe.experts.1.w1.weight"]

@@ -300,7 +300,7 @@ def test_qlora_moe_experts_quantize_on_load(tmp_path):
     state = trainer.load_pretrained(state, str(ckpt))
 
     blocks = state.frozen["params"]["blocks"]["block"]
-    gate = blocks["moe"]["experts_gate_packed"]
+    gate = blocks["moe"]["experts"]["gate_proj"]["kernel_packed"]
     assert gate.dtype == jnp.uint8
     # (L, E, in/2, out): expert axis preserved through the vmapped quantize
     assert gate.shape == (
@@ -330,10 +330,10 @@ def test_qlora_moe_experts_quantize_on_load(tmp_path):
 
     deq = dequantize_int4(
         np.asarray(gate[0, 0]),
-        np.asarray(blocks["moe"]["experts_gate_scales"][0, 0]),
+        np.asarray(blocks["moe"]["experts"]["gate_proj"]["kernel_scales"][0, 0]),
         dtype=jnp.float32,
     )
-    orig = f32_params["blocks"]["block"]["moe"]["experts_gate"][0, 0]
+    orig = f32_params["blocks"]["block"]["moe"]["experts"]["gate_proj"]["kernel"][0, 0]
     werr = np.max(np.abs(np.asarray(deq) - np.asarray(orig)))
     assert werr < 0.1 * np.max(np.abs(np.asarray(orig))), werr
     # logits: int4 error compounds through layers — sanity bound only

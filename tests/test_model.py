@@ -345,7 +345,7 @@ def test_moe_permutation_dispatch_matches_dense():
         capacity = max(8, _math.ceil(t / e * 0.5 * k))
         capacity = min(capacity, t)
         xt = x.reshape(t, dd)
-        logits = xt.astype(jnp.float32) @ params["router_kernel"].astype(jnp.float32)
+        logits = xt.astype(jnp.float32) @ params["router"]["kernel"].astype(jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)
         top_w, top_idx = jax.lax.top_k(probs, k)
         top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
@@ -359,10 +359,10 @@ def test_moe_permutation_dispatch_matches_dense():
         dispatch = jnp.einsum("tke,tkc->tec", in_cap, cap_onehot)
         combine = jnp.einsum("tke,tkc,tk->tec", in_cap, cap_onehot, top_w)
         expert_in = jnp.einsum("tec,td->ecd", dispatch, xt)
-        gate = jnp.einsum("ecd,edf->ecf", expert_in, params["experts_gate"])
-        up = jnp.einsum("ecd,edf->ecf", expert_in, params["experts_up"])
+        gate = jnp.einsum("ecd,edf->ecf", expert_in, params["experts"]["gate_proj"]["kernel"])
+        up = jnp.einsum("ecd,edf->ecf", expert_in, params["experts"]["up_proj"]["kernel"])
         h = jax.nn.silu(gate) * up
-        expert_out = jnp.einsum("ecf,efd->ecd", h, params["experts_down"])
+        expert_out = jnp.einsum("ecf,efd->ecd", h, params["experts"]["down_proj"]["kernel"])
         return jnp.einsum("tec,ecd->td", combine, expert_out).reshape(bb, ss, dd)
 
     ref = dense_reference(params, x)
@@ -375,7 +375,7 @@ def test_moe_permutation_dispatch_matches_dense():
 
     t = b * s
     capacity = min(max(8, _math.ceil(t / e * 0.5 * k)), t)
-    logits = x.reshape(t, d) @ params["router_kernel"]
+    logits = x.reshape(t, d) @ params["router"]["kernel"]
     _, top_idx = jax.lax.top_k(jax.nn.softmax(logits, -1), k)
     counts = np.bincount(np.asarray(top_idx).reshape(-1), minlength=e)
     assert counts.max() > capacity

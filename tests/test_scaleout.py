@@ -18,11 +18,17 @@ from finetune_controller_tpu.parallel.ring import ring_attention_sharded, ring_m
 from finetune_controller_tpu.parallel.sharding import LLAMA_RULES
 
 
-def _qkv(b=2, s=64, h=4, hkv=2, d=16, dtype=jnp.float32):
+def _qkv(b=2, s=64, h=4, hkv=2, d=16, dtype=jnp.float32, dv=None):
     q = jax.random.normal(jax.random.PRNGKey(0), (b, s, h, d), dtype)
     k = jax.random.normal(jax.random.PRNGKey(1), (b, s, hkv, d), dtype)
-    v = jax.random.normal(jax.random.PRNGKey(2), (b, s, hkv, d), dtype)
+    v = jax.random.normal(jax.random.PRNGKey(2), (b, s, hkv, dv or d), dtype)
     return q, k, v
+
+
+#: q/k and v head sizes: equal (every dense preset) and latent attention's
+#: uneven pair (q/k = nope + rope wider than v), at toy size
+HEAD_SIZES = pytest.mark.parametrize(
+    "d,dv", [(16, 16), (24, 16)], ids=["equal", "qk24_v16"])
 
 
 # ---------------------------------------------------------------------------
@@ -30,16 +36,18 @@ def _qkv(b=2, s=64, h=4, hkv=2, d=16, dtype=jnp.float32):
 # ---------------------------------------------------------------------------
 
 
-def test_flash_attention_matches_xla():
-    q, k, v = _qkv()
+@HEAD_SIZES
+def test_flash_attention_matches_xla(d, dv):
+    q, k, v = _qkv(d=d, dv=dv)
     seg = (jnp.arange(64)[None, :] // 32).astype(jnp.int32).repeat(2, 0)
     ref = xla_causal_attention(q, k, v, segment_ids=seg)
     out = flash_attention(q, k, v, segment_ids=seg, block_q=16, block_k=16)
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
-def test_flash_attention_grads_match_xla():
-    q, k, v = _qkv(s=32)
+@HEAD_SIZES
+def test_flash_attention_grads_match_xla(d, dv):
+    q, k, v = _qkv(s=32, d=d, dv=dv)
 
     def loss_flash(q, k, v):
         return (flash_attention(q, k, v, block_q=8, block_k=8) ** 2).sum()
@@ -53,10 +61,11 @@ def test_flash_attention_grads_match_xla():
         np.testing.assert_allclose(a, b, atol=1e-4)
 
 
-def test_flash_attention_grads_match_xla_gqa_segments_uneven():
+@HEAD_SIZES
+def test_flash_attention_grads_match_xla_gqa_segments_uneven(d, dv):
     """Pallas backward (dQ + dK/dV kernels) vs XLA autodiff with everything
     turned on at once: GQA group reduction, segment masks, ragged tail block."""
-    q, k, v = _qkv(s=40)
+    q, k, v = _qkv(s=40, d=d, dv=dv)
     seg = (jnp.arange(40)[None, :] // 20).astype(jnp.int32).repeat(2, 0)
 
     def loss_flash(q, k, v):
@@ -258,7 +267,7 @@ def test_moe_params_have_expert_axis_sharding(devices8):
             shardings, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec)
         )[0]
     }
-    gate_specs = [s for p, s in flat.items() if "experts_gate" in p]
+    gate_specs = [s for p, s in flat.items() if "experts/gate_proj" in p]
     assert gate_specs, flat.keys()
     # leading layer-scan axis is None, then experts over 'ep'
     assert all(s[1] == "ep" or s[0] == "ep" for s in gate_specs), gate_specs

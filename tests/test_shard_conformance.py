@@ -363,13 +363,13 @@ def test_shadowed_rule_turns_coverage_red():
     assert anchor in src
     mutated = src.replace(
         anchor,
-        '        (r"router_kernel", P(Ax.FSDP, None)),\n' + anchor,
+        '        (r"router/kernel", P(Ax.FSDP, None)),\n' + anchor,
     )
     result = _heavy_lint(
         ("shard-rule-coverage",), {str(SHARD_PY): mutated}
     )
     assert any(
-        "shadowed" in f.message and "router_kernel" in f.message
+        "shadowed" in f.message and "router/kernel" in f.message
         for f in result.findings
     ), [f.message for f in result.findings]
 
@@ -397,10 +397,10 @@ def test_undefined_axis_in_table_turns_coverage_red():
     """A spec axis the AxisNames table does not define is red even before
     any topology is consulted."""
     src = SHARD_PY.read_text()
-    line = '        (r"router_kernel", P(Ax.FSDP, None)),'
+    line = '        (r"router/kernel", P(Ax.FSDP, None)),'
     assert line in src
     mutated = src.replace(
-        line, '        (r"router_kernel", P("bogus_axis", None)),'
+        line, '        (r"router/kernel", P("bogus_axis", None)),'
     )
     result = _heavy_lint(
         ("shard-rule-coverage",), {str(SHARD_PY): mutated}
