@@ -752,7 +752,8 @@ class Block(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, deterministic=True,
-                 decode=False, page_table=None, adapter_ids=None):
+                 decode=False, page_table=None, adapter_ids=None,
+                 layer=None, stacked_experts=None):
         cfg = self.cfg
         if cfg.attention_kind not in ("gqa", "mla"):
             raise ValueError(f"unknown attention_kind {cfg.attention_kind!r}")
@@ -788,7 +789,7 @@ class Block(nn.Module):
                 quantize_base=cfg.quantize_base,
                 quant_block=cfg.quant_block,
                 name="moe",
-            )(h, deterministic)
+            )(h, deterministic, layer, stacked_experts)
         else:
             mlp_out = MLP(cfg, name="mlp")(h, deterministic, adapter_ids)
         return x + mlp_out
@@ -879,10 +880,11 @@ class _ScanBlock(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, deterministic=True,
-                 decode=False, page_table=None, adapter_ids=None):
+                 decode=False, page_table=None, adapter_ids=None,
+                 layer=None, stacked_experts=None):
         y = Block(self.cfg, name="block")(
             x, positions, segment_ids, deterministic, decode,
-            page_table, adapter_ids
+            page_table, adapter_ids, layer, stacked_experts
         )
         return y, None
 
@@ -935,17 +937,23 @@ class LlamaForCausalLM(nn.Module):
                     static_argnums=(4, 5),
                     policy=policy,
                 )
+            # expert layers that can read their kernels in place in the
+            # stacked leaves get the layer index (scanned) and those leaves
+            # whole (loop-invariant: carried by alias); a model without such
+            # layers passes nothing more and traces the program it always did
+            stacked_experts = self._stacked_experts()
+            in_place = () if stacked_experts is None else (
+                jnp.arange(cfg.n_layers - n_dense), stacked_experts)
             stack = nn.scan(
                 block_cls,
                 variable_axes={"params": 0, "lora": 0, "moe_aux": 0,
                                "moe_stats": 0, "cache": 0, "tenants": 0},
                 split_rngs={"params": True, "dropout": True},
-                in_axes=(nn.broadcast, nn.broadcast, nn.broadcast,
-                         nn.broadcast, nn.broadcast, nn.broadcast),
+                in_axes=(nn.broadcast,) * 6 + ((0, nn.broadcast) if in_place else ()),
                 length=cfg.n_layers - n_dense,
             )(cfg, name="blocks")
             x, _ = stack(x, positions, segment_ids, deterministic, decode,
-                         page_table, adapter_ids)
+                         page_table, adapter_ids, *in_place)
 
         x = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="final_norm")(x)
         if cfg.tie_embeddings:
@@ -958,6 +966,22 @@ class LlamaForCausalLM(nn.Module):
                 param_dtype=cfg.param_dtype,
             )(x)
         return logits.astype(cfg.logits_dtype or jnp.float32)
+
+    def _stacked_experts(self):
+        """The scanned stack's three expert kernels whole, ``[L, E, ., .]``
+        each, read from this module's own ``params`` — or None where the
+        expert layer would not take them (``models/moe.py``: the dropless
+        layer holding all its experts unquantised), where the experts are
+        trained (no adapters: their weight gradient would visit all ``L·E``
+        groups a layer) and while initialising, when no leaf exists yet."""
+        cfg = self.cfg
+        if not (cfg.n_experts and cfg.moe_dispatch == "dropless"
+                and cfg.experts_held is None and not cfg.quantize_base
+                and cfg.lora.rank > 0) or self.is_initializing():
+            return None
+        experts = self.get_variable("params", "blocks")["block"]["moe"]["experts"]
+        return tuple(experts[name]["kernel"]
+                     for name in ("gate_proj", "up_proj", "down_proj"))
 
     def init_variables(self, rng: jax.Array, batch: int = 1, seq: int = 8):
         tokens = jnp.zeros((batch, seq), jnp.int32)

@@ -34,6 +34,22 @@ TPU-native design:
     ``_unsort``): the transpose of a row gather is a scatter-add, which a
     TPU serialises.
 
+- inside a scanned stack the dropless layer's three grouped products read
+  the layer's experts IN PLACE in the stacked leaf ``(L, E, d, f)``
+  (``_grouped_dot(..., layer)``: ``L·E`` groups, all empty but the layer's
+  own).  The Pallas kernel is a custom call and takes its operand whole, so
+  the slice ``nn.scan`` hands the body was copied for it: at 256 experts of
+  2048 x 768 three 805 MB kernels a layer in the forward loop and again in
+  the backward one, 24 copies and 0.059 s of a 0.489 s step over four layers
+  (12 %), and 2.0 GB of temporaries.  Taken where that copy would be made
+  and nothing else is lost: the Pallas kernel runs (one TPU, no mesh), the
+  layer was handed its index and the stacked leaves (``models/llama.py``:
+  the scanned stack of a model with adapters on — trained experts would
+  have a weight gradient visit all ``L·E`` groups a layer), it holds all
+  its experts, and they are stored unquantised in the compute type (a
+  dequantised or cast kernel is a fresh whole array already).  Everywhere
+  else the per-layer slice, as before.  The variable tree is the same
+  either way;
 - ``experts_held = (first, count)``: the layer routes over ALL experts and
   computes the part of the result its own ``count`` experts give (plus the
   shared expert) — what expert parallelism needs of one member, and what a
@@ -42,9 +58,10 @@ TPU-native design:
   a module so its projections carry LoRA like any other);
 - Switch-Transformer load-balancing aux loss, sown into the ``moe_aux``
   collection where the configuration asks for one (the trainer folds it into
-  the objective), and two counters sown into ``moe_stats``:
-  ``load_max_over_mean`` (the fullest expert's pairs over the mean) and
-  ``pairs`` (pairs that reached an expert: ``T·k`` when nothing is dropped).
+  the objective), and three counters sown into ``moe_stats``:
+  ``load_max_over_mean`` (the fullest expert's pairs over the mean),
+  ``pairs`` (pairs that reached an expert: ``T·k`` when nothing is dropped)
+  and ``experts_in_place`` (1.0 where the layer read its experts in place).
 """
 
 from __future__ import annotations
@@ -155,11 +172,22 @@ def _pallas_grouped_dot_ok(rows: int) -> bool:
             or bool(jax.sharding.get_abstract_mesh().manual_axes))
 
 
-def _grouped_dot(lhs, rhs, sizes):
+def _grouped_dot(lhs, rhs, sizes, layer=None):
     """``lhs[rows of group g] @ rhs[g]`` for consecutive groups of ``sizes``
     rows: ``(M, k) x (G, k, n) -> (M, n)``, differentiable in both operands.
     ``sizes`` must cover every group of ``rhs`` and every row of ``lhs``
-    for the Pallas kernel (rows no group covers are left unwritten there)."""
+    for the Pallas kernel (rows no group covers are left unwritten there).
+
+    With ``layer`` (a traced index), ``rhs`` is a scanned stack's whole leaf
+    ``(L, G, k, n)`` and the product is the one with ``rhs[layer]``, read IN
+    PLACE: the leading dimensions merge (a bitcast) and the ``L·G`` groups are
+    empty outside ``[layer·G, (layer+1)·G)``.  An empty group costs the kernel
+    no grid step, and the row tile of group ``g`` reads block ``layer·G + g``."""
+    if layer is not None:
+        n_layers, g = rhs.shape[:2]
+        rhs = rhs.reshape((n_layers * g,) + rhs.shape[2:])
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((n_layers * g,), sizes.dtype), sizes, (layer * g,))
     if _pallas_grouped_dot_ok(lhs.shape[0]):
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
@@ -237,7 +265,12 @@ class MoEMLP(nn.Module):
     quant_block: int = 64
 
     @nn.compact
-    def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
+    def __call__(self, x: jax.Array, deterministic: bool = True,
+                 layer=None, stacked=None) -> jax.Array:
+        """``layer`` and ``stacked``: this layer's index in a scanned stack
+        and the stack's three expert kernels whole, ``(L, E, ., .)`` each
+        (``models/llama.py`` hands them where the experts take no gradient);
+        the layer then reads its experts in place where that saves a copy."""
         if self.dispatch not in ("capacity", "dropless"):
             raise ValueError(f"unknown MoE dispatch {self.dispatch!r}")
         if self.scoring not in ("softmax", "sigmoid"):
@@ -270,7 +303,20 @@ class MoEMLP(nn.Module):
         kernels = _Experts(
             n_held, d, self.d_ff, self.dtype, self.param_dtype,
             self.quantize_base, self.quant_block, name="experts")()
-        if self.dispatch == "dropless":
+        # the Pallas kernel is a custom call and takes its operand whole, so
+        # the slice the scan hands this body would be copied for it; the
+        # compiler's own product fuses that slice and has nothing to save.
+        # Everything held, unquantised and stored in the compute type: else
+        # ``kernels`` is a fresh array already, or a cast of the whole stack
+        in_place = (
+            stacked is not None and self.dispatch == "dropless"
+            and self.experts_held is None and not self.quantize_base
+            and all(w.dtype == jnp.dtype(self.dtype) for w in stacked)
+            and _pallas_grouped_dot_ok(t * k))
+        if in_place:
+            out, pairs = self._dropless(
+                xt, top_idx, top_w, load, stacked, first, layer)
+        elif self.dispatch == "dropless":
             out, pairs = self._dropless(xt, top_idx, top_w, load, kernels, first)
         else:
             out, pairs = self._capacity(xt, top_idx, top_w, onehot, kernels)
@@ -284,15 +330,19 @@ class MoEMLP(nn.Module):
             self.sow("moe_aux", "load_balance", e * jnp.sum(frac_routed * mean_prob))
         self.sow("moe_stats", "load_max_over_mean", load.max() * (e / (t * k)))
         self.sow("moe_stats", "pairs", pairs.astype(jnp.float32))
+        self.sow("moe_stats", "experts_in_place", jnp.float32(in_place))
         return out.reshape(b, s, d).astype(x.dtype)
 
     # ---- dropless: sorted pairs, one grouped product --------------------------
 
-    def _dropless(self, xt, top_idx, top_w, load, kernels, first: int):
+    def _dropless(self, xt, top_idx, top_w, load, kernels, first: int,
+                  layer=None):
+        """``layer``: ``kernels`` are a scanned stack's whole leaves and this
+        layer's experts are read in place there (all of them held)."""
         t, d = xt.shape
         e, k = self.n_experts, self.top_k
         w_gate, w_up, w_down = kernels
-        n_held = w_gate.shape[0]
+        n_held = e if layer is not None else w_gate.shape[0]
         with jax.named_scope("moe_dispatch"):
             # held experts first, in order: their pairs are the leading rows
             # and every other pair falls behind the last group
@@ -305,7 +355,8 @@ class MoEMLP(nn.Module):
         with jax.named_scope("experts"):
             # a share of the experts leaves rows no group covers: the
             # compiler's product writes zeros there, the Pallas one nothing
-            dot = _grouped_dot if n_held == e else jax.lax.ragged_dot
+            dot = (functools.partial(_grouped_dot, layer=layer) if n_held == e
+                   else jax.lax.ragged_dot)
             gate = dot(rows, w_gate, sizes)
             up = dot(rows, w_up, sizes)
             out_rows = dot(nn.silu(gate) * up, w_down, sizes)
@@ -390,17 +441,22 @@ def moe_aux_loss(collections: dict) -> jax.Array:
     return sum(jnp.sum(leaf) for leaf in leaves)
 
 
+#: a counter sown into ``moe_stats`` -> how the layers' readings become the step's
+_COUNTERS = {"load_max_over_mean": jnp.max, "pairs": jnp.min,
+             "experts_in_place": jnp.sum}
+
+
 def moe_counters(collections: dict) -> dict:
-    """The step's two routing counters from the sown ``moe_stats`` (scan
-    stacks them per layer): ``moe_load_max_over_mean`` of the worst layer and
-    ``moe_pairs`` of the layer that computed the fewest (``T·k`` where nothing
-    is dropped).  Empty for a model without expert layers."""
-    worst, fewest = [], []
+    """The step's counters from the sown ``moe_stats`` (scan stacks them per
+    layer): ``moe_load_max_over_mean`` of the worst layer, ``moe_pairs`` of
+    the layer that computed the fewest (``T·k`` where nothing is dropped) and
+    ``moe_experts_in_place``, the number of layers whose grouped products read
+    their experts in place in the scanned stack.  Empty for a model without
+    expert layers."""
+    sown: dict[str, list] = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(
             collections.get("moe_stats", {})):
-        name = "/".join(str(getattr(p, "key", p)) for p in path)
-        (worst if "load_max_over_mean" in name else fewest).append(jnp.ravel(leaf))
-    if not worst:
-        return {}
-    return {"moe_load_max_over_mean": jnp.max(jnp.concatenate(worst)),
-            "moe_pairs": jnp.min(jnp.concatenate(fewest))}
+        name = next(n for p in path if (n := str(getattr(p, "key", p))) in _COUNTERS)
+        sown.setdefault(name, []).append(jnp.ravel(leaf))
+    return {f"moe_{name}": _COUNTERS[name](jnp.concatenate(sown[name]))
+            for name in _COUNTERS if name in sown}

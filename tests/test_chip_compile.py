@@ -138,6 +138,89 @@ def test_grouped_expert_product_compiles_for_v5e_forward_and_activation_gradient
     assert "ragged-dot" not in compiled.as_text()
 
 
+def _written(text: str, shapes) -> list[str]:
+    """The instructions that WRITE a bf16 array of one of ``shapes``: every
+    one whose result has it but a parameter, a tuple's element or a bitcast."""
+    results = tuple(f" = bf16[{','.join(map(str, s))}]" for s in shapes)
+    return [line.strip()[:160] for line in text.splitlines()
+            if any(r in line for r in results)
+            and not re.search(r" (parameter|get-tuple-element|bitcast)\(", line)]
+
+
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)], ids=["up", "down"])
+def test_in_place_expert_product_compiles_for_v5e_and_copies_no_kernel(
+        v5e, monkeypatch, k, n):
+    """The same product reading layer ``l`` of a scanned stack's WHOLE leaf
+    (4 layers x 256 experts: 1,024 groups, all but 256 empty; group and tile
+    ids of 1,151 entries in SMEM), forward and transposed: the kernel takes a
+    bitcast of the leaf, so nothing shaped like the leaf or like one layer of
+    it is written."""
+    from finetune_controller_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    rows = jax.ShapeDtypeStruct((65536, k), BF16, sharding=one)
+    stacked = jax.ShapeDtypeStruct((4, 256, k, n), BF16, sharding=one)
+    sizes = jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+
+    def loss(rows, stacked, sizes, layer):
+        return jnp.sum(
+            moe._grouped_dot(rows, stacked, sizes, layer).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(
+        rows, stacked, sizes, layer).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "ragged-dot" not in text
+    assert not _written(text, [(4, 256, k, n), (1024, k, n), (256, k, n)])
+
+
+@pytest.mark.parametrize("path", ["in_place", "sliced"])
+def test_scanned_expert_step_copies_no_layer_of_expert_kernels(
+        v5e, monkeypatch, path):
+    """A scanned stack of two expert layers at small width, loss and LoRA
+    gradients under full remat, compiled for one v5e: with the experts read
+    in place no instruction writes an array shaped like a layer's expert
+    kernel — in neither loop body, nor anywhere.  ``sliced`` is why the path
+    exists, and that this test sees it: handed the loop's slice, the kernel
+    has its operand copied (three kernels in each of the two loops)."""
+    from finetune_controller_tpu.models import moe
+    from finetune_controller_tpu.models.llama import PRESETS, LlamaForCausalLM
+    from finetune_controller_tpu.models.lora import MLA_TARGETS, LoRAConfig
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if path == "sliced":
+        monkeypatch.setattr(LlamaForCausalLM, "_stacked_experts", lambda self: None)
+    cfg = PRESETS["tiny-mla-moe-test"].replace(
+        d_model=256, moe_d_ff=128, dtype=BF16, moe_select_bias=False,
+        lora=LoRAConfig(rank=4, targets=MLA_TARGETS))
+    model = LlamaForCausalLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    shapes = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32)))
+    # the frozen base stored in the compute type, adapters in float32
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=one),
+        shapes["params"])
+    lora = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        shapes["lora"])
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32, sharding=one)
+
+    def loss(lora, params, tokens):
+        logits, sown = model.apply({"params": params, "lora": lora}, tokens,
+                                   mutable=("moe_stats",))
+        return jnp.mean(logits ** 2), moe.moe_counters(sown)
+
+    text = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        lora, params, tokens).compile().as_text()
+    # three products forward, three recomputed, three activation gradients
+    assert text.count('custom_call_target="tpu_custom_call"') == 9
+    e, d, f = cfg.n_experts, cfg.d_model, cfg.moe_d_ff
+    copies = _written(text, [(e, d, f), (e, f, d)])
+    assert bool(copies) == (path == "sliced"), copies
+
+
 # ---------------------------------------------------------------------------
 # paged attention: the serve kernel
 # ---------------------------------------------------------------------------

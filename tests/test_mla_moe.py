@@ -1,7 +1,9 @@
 """The latent-attention expert model (ISSUE 27) at toy size on the CPU: the
 program's blocks against the plain reference (``benchmarks/reference/
-mla_moe.py``), dropless routing against a per-token loop, and the shares of
-``experts_held`` against the uncut layer."""
+mla_moe.py``), dropless routing against a per-token loop, the shares of
+``experts_held`` against the uncut layer, and the grouped products that read
+a layer's experts in place in the scanned stack (ISSUE 28) against the
+per-layer products."""
 
 import json
 import sys
@@ -22,6 +24,7 @@ from benchmarks.reference.model import head_logits, top_weights  # noqa: E402
 from finetune_controller_tpu.models.llama import (  # noqa: E402
     MLP, PRESETS, LlamaForCausalLM, apply_rope)
 from finetune_controller_tpu.models.lora import MLA_TARGETS, LoRAConfig  # noqa: E402
+from finetune_controller_tpu.models import moe  # noqa: E402
 from finetune_controller_tpu.models.moe import MoEMLP  # noqa: E402
 
 CONF = json.loads(
@@ -279,3 +282,215 @@ def test_trainer_step_reports_the_routing_counters_and_no_auxiliary_loss():
     assert float(metrics["moe_pairs"]) == 2 * 16 * cfg.moe_top_k
     assert float(metrics["moe_load_max_over_mean"]) >= 1.0
     assert "moe_aux" not in metrics and np.isfinite(float(metrics["loss"]))
+
+
+# ---- the grouped products read a layer's experts in place (ISSUE 28) ---------
+
+#: rows of each of E experts: all the rows in uneven groups, and with experts
+#: that no pair chose (the first, one in the middle, the last)
+GROUPS = {"uneven": (5, 1, 9, 2, 7, 3, 4, 1), "empty_experts": (0, 6, 0, 11, 8, 0, 7, 0)}
+
+
+@pytest.mark.parametrize("layer", range(3))
+@pytest.mark.parametrize("groups", list(GROUPS), ids=list(GROUPS))
+def test_in_place_grouped_product_is_the_per_layer_product(groups, layer):
+    """``_grouped_dot(rows, stacked [L, E, k, n], sizes, layer)`` — the merged
+    ``L·E`` groups, empty outside the layer's — is ``_grouped_dot(rows,
+    stacked[layer], sizes)`` bit for bit, forward and activation gradient
+    (``ragged_dot`` takes the same operands the Pallas kernel does)."""
+    sizes = jnp.asarray(GROUPS[groups], jnp.int32)
+    m = int(sizes.sum())
+    stacked = jax.random.normal(jax.random.PRNGKey(11), (3, E, D, F), jnp.float32)
+    rows = jax.random.normal(jax.random.PRNGKey(12), (m, D), jnp.float32)
+    probe = jax.random.normal(jax.random.PRNGKey(13), (m, F), jnp.float32)
+
+    def both(dot):
+        def f(rows):
+            out = dot(rows)
+            return (out * probe).sum(), out
+
+        (_, out), grad = jax.value_and_grad(f, has_aux=True)(rows)
+        return out, grad
+
+    in_place = jax.jit(lambda l: both(
+        lambda r: moe._grouped_dot(r, stacked, sizes, l)))(jnp.int32(layer))
+    sliced = both(lambda r: moe._grouped_dot(r, stacked[layer], sizes))
+    assert float(jnp.abs(sliced[0]).max()) > 0
+    np.testing.assert_array_equal(in_place[0], sliced[0])
+    np.testing.assert_array_equal(in_place[1], sliced[1])
+
+
+def _take_the_in_place_path(monkeypatch):
+    """What one TPU decides, here: the layer is told the Pallas kernel runs,
+    and ``ragged_dot`` stands in for it on the kernel's own operands."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    monkeypatch.setattr(moe, "_pallas_grouped_dot_ok", lambda rows: True)
+    monkeypatch.setattr(
+        megablox, "gmm",
+        lambda lhs, rhs, sizes, dtype, tiling: jax.lax.ragged_dot(lhs, rhs, sizes))
+
+
+def _row_by_row(lhs, rhs, sizes):
+    """A grouped product that multiplies each row by its own group's matrix:
+    the same bits however many empty groups ``rhs`` holds.  (The CPU's
+    ``ragged_dot`` contracts over groups and width together, so its rounding
+    follows the group count from some width on; the Pallas kernel's does
+    not.)"""
+    group = jnp.repeat(jnp.arange(rhs.shape[0]), sizes,
+                       total_repeat_length=lhs.shape[0])
+    return jnp.einsum("mk,mkn->mn", lhs, rhs[group])
+
+
+def _expert_model(**overrides):
+    """Four layers (one dense, three expert layers), adapters on, the frozen
+    base stored in the compute type, every leaf random."""
+    cfg = PRESETS["tiny-mla-moe-test"].replace(**{
+        "n_layers": 4, "dtype": jnp.float32,
+        "lora": LoRAConfig(rank=4, targets=MLA_TARGETS), **overrides})
+    model = LlamaForCausalLM(cfg)
+    variables = model.init({"params": jax.random.PRNGKey(20)},
+                           jnp.zeros((1, 8), jnp.int32))
+    variables = {c: variables[c] for c in ("params", "lora") if c in variables}
+    leaves, tree = jax.tree.flatten(variables)
+    keys = jax.random.split(jax.random.PRNGKey(21), len(leaves))
+    return model, jax.tree.unflatten(tree, [
+        a + 0.05 * jax.random.normal(k, a.shape, a.dtype)
+        if jnp.issubdtype(a.dtype, jnp.floating) else a
+        for a, k in zip(leaves, keys)])
+
+
+def _logits_grads_counters(model, variables, tokens):
+    def f(lora):
+        logits, sown = model.apply({**variables, "lora": lora}, tokens,
+                                   mutable=("moe_stats",))
+        return (logits ** 2).mean(), (logits, moe.moe_counters(sown))
+
+    (_, (logits, counters)), grads = jax.value_and_grad(f, has_aux=True)(
+        variables.get("lora", {}))
+    return logits, grads, counters
+
+
+def test_scanned_expert_model_in_place_is_the_sliced_model(monkeypatch):
+    """Logits and every LoRA gradient of the scanned stack under full remat
+    with the experts read in place equal the sliced path's exactly."""
+    model, variables = _expert_model()
+    tokens = jnp.asarray(_tokens(2, 32))
+    monkeypatch.setattr(jax.lax, "ragged_dot", _row_by_row)
+    sliced = _logits_grads_counters(model, variables, tokens)
+    _take_the_in_place_path(monkeypatch)
+    in_place = _logits_grads_counters(model, variables, tokens)
+    assert float(sliced[2]["moe_experts_in_place"]) == 0
+    assert float(in_place[2]["moe_experts_in_place"]) == 3
+    np.testing.assert_array_equal(in_place[0], sliced[0])
+    grads = _flat(in_place[1])
+    assert grads and all(float(jnp.abs(g).max()) > 0 for g in grads.values())
+    for name, g in _flat(sliced[1]).items():
+        np.testing.assert_array_equal(grads[name], g, err_msg=name)
+
+
+#: what keeps a model on the sliced path even where one TPU would run the
+#: Pallas kernel -> the configuration that has it
+SLICED = {
+    "on_the_cpu": {},
+    "a_share_of_the_experts": {"experts_held": (0, 4)},
+    "capacity_dispatch": {"moe_dispatch": "capacity"},
+    "trained_experts_no_adapters": {"lora": LoRAConfig()},
+    "unrolled_layers": {"scan_layers": False},
+    "quantised_experts": {"quantize_base": True, "quant_block": 16},
+    "stored_wider_than_computed": {"dtype": jnp.bfloat16},
+}
+
+
+@pytest.mark.parametrize("why", ["in_place", *SLICED])
+def test_experts_in_place_counts_the_layers_that_took_it(monkeypatch, why):
+    """``moe_experts_in_place`` is the scanned expert layers (3) where the
+    in-place product is taken and 0 everywhere else."""
+    model, variables = _expert_model(**SLICED.get(why, {}))
+    if why != "on_the_cpu":
+        _take_the_in_place_path(monkeypatch)
+    logits, _, counters = _logits_grads_counters(
+        model, variables, jnp.asarray(_tokens(2, 32)))
+    assert np.isfinite(np.asarray(logits, np.float32)).all()
+    assert float(counters["moe_experts_in_place"]) == (3 if why == "in_place" else 0)
+    assert float(counters["moe_pairs"]) > 0
+
+
+#: the variable tree of ``tiny-mla-moe-test`` with rank-4 adapters as the
+#: parent of ISSUE 28 had it: the benchmark fills the tree by path,
+#: checkpoints and the sharding rules name these paths
+TREE = {'lora/blocks/block/attn/kv_a_proj_with_mqa/lora_a': (2, 64, 4),
+ 'lora/blocks/block/attn/kv_a_proj_with_mqa/lora_b': (2, 4, 40),
+ 'lora/blocks/block/attn/kv_b_proj/lora_a': (2, 32, 4),
+ 'lora/blocks/block/attn/kv_b_proj/lora_b': (2, 4, 128),
+ 'lora/blocks/block/attn/o_proj/lora_a': (2, 64, 4),
+ 'lora/blocks/block/attn/o_proj/lora_b': (2, 4, 64),
+ 'lora/blocks/block/attn/q_a_proj/lora_a': (2, 64, 4),
+ 'lora/blocks/block/attn/q_a_proj/lora_b': (2, 4, 48),
+ 'lora/blocks/block/attn/q_b_proj/lora_a': (2, 48, 4),
+ 'lora/blocks/block/attn/q_b_proj/lora_b': (2, 4, 96),
+ 'lora/blocks/block/moe/shared/down_proj/lora_a': (2, 32, 4),
+ 'lora/blocks/block/moe/shared/down_proj/lora_b': (2, 4, 64),
+ 'lora/blocks/block/moe/shared/gate_proj/lora_a': (2, 64, 4),
+ 'lora/blocks/block/moe/shared/gate_proj/lora_b': (2, 4, 32),
+ 'lora/blocks/block/moe/shared/up_proj/lora_a': (2, 64, 4),
+ 'lora/blocks/block/moe/shared/up_proj/lora_b': (2, 4, 32),
+ 'lora/layer_0/attn/kv_a_proj_with_mqa/lora_a': (64, 4),
+ 'lora/layer_0/attn/kv_a_proj_with_mqa/lora_b': (4, 40),
+ 'lora/layer_0/attn/kv_b_proj/lora_a': (32, 4),
+ 'lora/layer_0/attn/kv_b_proj/lora_b': (4, 128),
+ 'lora/layer_0/attn/o_proj/lora_a': (64, 4),
+ 'lora/layer_0/attn/o_proj/lora_b': (4, 64),
+ 'lora/layer_0/attn/q_a_proj/lora_a': (64, 4),
+ 'lora/layer_0/attn/q_a_proj/lora_b': (4, 48),
+ 'lora/layer_0/attn/q_b_proj/lora_a': (48, 4),
+ 'lora/layer_0/attn/q_b_proj/lora_b': (4, 96),
+ 'lora/layer_0/mlp/down_proj/lora_a': (128, 4),
+ 'lora/layer_0/mlp/down_proj/lora_b': (4, 64),
+ 'lora/layer_0/mlp/gate_proj/lora_a': (64, 4),
+ 'lora/layer_0/mlp/gate_proj/lora_b': (4, 128),
+ 'lora/layer_0/mlp/up_proj/lora_a': (64, 4),
+ 'lora/layer_0/mlp/up_proj/lora_b': (4, 128),
+ 'params/blocks/block/attn/kv_a_norm/scale': (2, 32),
+ 'params/blocks/block/attn/kv_a_proj_with_mqa/kernel': (2, 64, 40),
+ 'params/blocks/block/attn/kv_b_proj/kernel': (2, 32, 128),
+ 'params/blocks/block/attn/o_proj/kernel': (2, 64, 64),
+ 'params/blocks/block/attn/q_a_norm/scale': (2, 48),
+ 'params/blocks/block/attn/q_a_proj/kernel': (2, 64, 48),
+ 'params/blocks/block/attn/q_b_proj/kernel': (2, 48, 96),
+ 'params/blocks/block/attn_norm/scale': (2, 64),
+ 'params/blocks/block/mlp_norm/scale': (2, 64),
+ 'params/blocks/block/moe/experts/down_proj/kernel': (2, 8, 32, 64),
+ 'params/blocks/block/moe/experts/gate_proj/kernel': (2, 8, 64, 32),
+ 'params/blocks/block/moe/experts/up_proj/kernel': (2, 8, 64, 32),
+ 'params/blocks/block/moe/router/bias': (2, 8),
+ 'params/blocks/block/moe/router/kernel': (2, 64, 8),
+ 'params/blocks/block/moe/shared/down_proj/kernel': (2, 32, 64),
+ 'params/blocks/block/moe/shared/gate_proj/kernel': (2, 64, 32),
+ 'params/blocks/block/moe/shared/up_proj/kernel': (2, 64, 32),
+ 'params/embed_tokens/embedding': (256, 64),
+ 'params/final_norm/scale': (64,),
+ 'params/layer_0/attn/kv_a_norm/scale': (32,),
+ 'params/layer_0/attn/kv_a_proj_with_mqa/kernel': (64, 40),
+ 'params/layer_0/attn/kv_b_proj/kernel': (32, 128),
+ 'params/layer_0/attn/o_proj/kernel': (64, 64),
+ 'params/layer_0/attn/q_a_norm/scale': (48,),
+ 'params/layer_0/attn/q_a_proj/kernel': (64, 48),
+ 'params/layer_0/attn/q_b_proj/kernel': (48, 96),
+ 'params/layer_0/attn_norm/scale': (64,),
+ 'params/layer_0/mlp/down_proj/kernel': (128, 64),
+ 'params/layer_0/mlp/gate_proj/kernel': (64, 128),
+ 'params/layer_0/mlp/up_proj/kernel': (64, 128),
+ 'params/layer_0/mlp_norm/scale': (64,),
+ 'params/lm_head/kernel': (64, 256)}
+
+
+def test_the_variable_tree_is_the_one_checkpoints_and_the_benchmark_name():
+    cfg = PRESETS["tiny-mla-moe-test"].replace(
+        lora=LoRAConfig(rank=4, targets=MLA_TARGETS))
+    shapes = jax.eval_shape(lambda: LlamaForCausalLM(cfg).init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32)))
+    tree = {"/".join(str(getattr(k, "key", k)) for k in path): leaf.shape
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                {c: shapes[c] for c in ("params", "lora")})[0]}
+    assert tree == TREE
