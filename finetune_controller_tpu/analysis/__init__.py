@@ -24,13 +24,13 @@ a mechanical CI failure instead of an expensive rediscovery:
   service op/payload tables vs their clients) and metric-doc-drift
   (emitted ``ftc_*`` families vs docs/observability.md's catalog);
 * :mod:`recompile_guard` — the runtime complement: counts distinct jit
-  signatures behind ``TrainConfig.recompile_budget`` / bench env knobs and
+  signatures behind ``TrainConfig.recompile_budget`` and
   warns or raises when a shape-unstable step blows the budget;
 * :mod:`transfer_guard` — runtime complement #2: wraps the trainer step
   and serve decode hot windows in ``jax.transfer_guard`` (plus a
   backend-independent ``jax.device_get`` trap) behind
   ``TrainConfig.transfer_guard`` / ``FTC_TRANSFER_GUARD``, armed by
-  ``bench.py`` so a reintroduced sync aborts the timed window.
+  the benchmark so a reintroduced sync aborts the run.
 
 ``tests/test_lint_clean.py`` gates the repo: zero unsuppressed findings over
 ``finetune_controller_tpu/``.  See ``docs/static_analysis.md``.

@@ -23,8 +23,8 @@ Threaded into the hot paths behind config flags:
 
 * ``TrainConfig.recompile_budget`` (0 = off) wraps the trainer's step/eval
   jits; ``TrainConfig.recompile_action`` picks warn vs raise;
-* ``BENCH_RECOMPILE_BUDGET`` does the same for ``bench.py`` with
-  ``on_excess="raise"`` — a recompiling bench is a measurement bug and must
+* the benchmark (``benchmarks/harness/drivers/train.py``) sets a budget of
+  1 with ``"raise"`` — a recompiling timed run is a measurement bug and must
   fail loudly, not print a slow number.
 """
 
@@ -69,7 +69,7 @@ def signature_of(*args: Any, **kwargs: Any) -> tuple:
     leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
     # the treedef object itself is hashable/eq-comparable; str()-ifying a
     # TrainState-sized treedef every step would be measurable host overhead
-    # inside the very windows bench.py times
+    # inside the very windows the benchmark times
     return (treedef, tuple(_leaf_signature(x) for x in leaves))
 
 

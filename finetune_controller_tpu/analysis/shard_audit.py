@@ -23,9 +23,9 @@ Knobs (docs/static_analysis.md § Shard audit):
   empty default inherits ``FTC_SHARD_AUDIT`` from the env;
 * ``FTC_SHARD_AUDIT`` — same values, read by the serve loader and as the
   trainer fallback; off when unset;
-* ``bench.py`` arms ``raise`` (``BENCH_SHARD_AUDIT``, default on): a
-  mis-sharded timed run ABORTS instead of printing a slow number;
-* ``FTC_FAULT_SHARD=1`` — chaos hand for tests/bench: the auditor itself
+* the benchmark (``benchmarks/harness/drivers/train.py``) arms ``raise``:
+  a mis-sharded timed run ABORTS instead of printing a slow number;
+* ``FTC_FAULT_SHARD=1`` — chaos hand for tests: the auditor itself
   re-``device_put``s one sharded leaf as fully replicated before checking,
   proving the abort path end to end.
 
@@ -106,7 +106,7 @@ class ShardAuditor:
         self.violations = 0
         self._warned: set[str] = set()
         #: chaos hand: re-device_put ONE sharded leaf as replicated before
-        #: checking, so tests/bench prove the abort path with a REAL
+        #: checking, so tests prove the abort path with a REAL
         #: mis-sharded array, not a mocked comparison
         self._fault = (
             inject_fault if inject_fault is not None

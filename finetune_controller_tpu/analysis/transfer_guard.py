@@ -34,11 +34,12 @@ Knobs (docs/static_analysis.md § Transfer guard):
   the empty default inherits ``FTC_TRANSFER_GUARD`` from the env;
 * ``FTC_TRANSFER_GUARD`` — same values, read by the serve engine and as
   the trainer fallback; off when unset;
-* ``bench.py`` arms ``raise`` inside its timed windows (train and
-  ``BENCH_MODE=serve``) behind ``BENCH_TRANSFER_GUARD`` (default on): a
-  silently reintroduced sync ABORTS the bench instead of printing a slow
-  number — the ``recompile_guard`` contract, for transfers;
-* ``FTC_FAULT_TRANSFER=1`` — chaos hand for tests/bench: the guard itself
+* the benchmark (``benchmarks/harness/drivers/train.py``) arms ``raise``
+  for the whole run: a silently reintroduced sync ABORTS the run instead of
+  printing a slow number — the ``recompile_guard`` contract, for transfers;
+  ``tests/test_transfer_guard.py`` holds the trainer step and the serve
+  decode to zero trips under ``raise``;
+* ``FTC_FAULT_TRANSFER=1`` — chaos hand for tests: the guard itself
   injects a ``jax.device_get`` inside the window, proving the abort path.
 
 ``action="warn"`` swaps the disallow levels for jax's ``log`` levels and
@@ -126,7 +127,7 @@ class TransferGuard:
         self._warned: set[str] = set()
         self._calls: dict[str, int] = {}
         #: chaos hand: perform a real jax.device_get INSIDE the window so
-        #: tests/bench prove the abort path end to end
+        #: tests prove the abort path end to end
         self._fault = (
             inject_fault if inject_fault is not None
             else os.environ.get("FTC_FAULT_TRANSFER", "") not in ("", "0")

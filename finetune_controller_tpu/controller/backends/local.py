@@ -456,7 +456,7 @@ class LocalProcessBackend(TrainingBackend):
             if not alive:
                 # every spawned worker died (broken env, import failure) —
                 # an empty pool must not report "ready": claims will cold-
-                # spawn, and a latency bench would otherwise publish a bogus
+                # spawn, and a latency measurement would otherwise read a bogus
                 # warm number
                 logger.warning(
                     "warm-worker pool is empty: all spawned workers exited "

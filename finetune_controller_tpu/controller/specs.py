@@ -138,12 +138,6 @@ class BaseFineTuneJob(BaseModel):
     #: and resize-instead-of-evict fall back to full preemption for it
     #: (docs/preference.md, docs/elasticity.md)
     atomic_gang: ClassVar[bool] = False
-    #: model-config overrides baked into the spec (``LlamaConfig`` field →
-    #: value) — how a family spec pins its measured kernel winners
-    #: (``flash_block_q``/``flash_block_k``/``flash_exp_dtype``/
-    #: ``ring_inner``/``ulysses_inner``) so API-submitted jobs carry them;
-    #: FTC_* env vars remain per-pod operator overrides
-    model_overrides: ClassVar[dict] = {}
 
     # ---- instance-level (validated user input) ----
     training_arguments: TrainingArguments
@@ -165,7 +159,6 @@ class BaseFineTuneJob(BaseModel):
         "promotion_path": str,
         "mesh_policy": dict,
         "pretrained_weights_dir": str,
-        "model_overrides": dict,
         "atomic_gang": bool,
     }
 
@@ -249,7 +242,7 @@ class BaseFineTuneJob(BaseModel):
         model: dict[str, Any] = {"preset": self.model_preset}
         if self.pretrained_weights_dir:
             model["weights_dir"] = self.pretrained_weights_dir
-        overrides = dict(self.model_overrides)
+        overrides: dict[str, Any] = {}
         if self.framework == TrainingFramework.JAX_QLORA:
             # int4 base weights (models/quant.py); adapters still train in LoRA
             overrides["quantize_base"] = True

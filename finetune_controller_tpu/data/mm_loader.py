@@ -39,7 +39,7 @@ class PixelCache:
     dataset just past the cap evict only the least-recently-used entries, so
     most rows keep their decode instead of the whole dataset re-decoding
     every epoch. ``capacity <= 0`` disables caching entirely (every access
-    decodes — what the input-pipeline bench uses to measure raw decode cost).
+    decodes — the raw decode cost).
     """
 
     def __init__(self, capacity: int):

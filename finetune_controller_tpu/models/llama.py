@@ -104,15 +104,6 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     attention_impl: str = "xla"
-    # --- kernel-tuning knobs (round-5: typed-spec surface for the measured
-    # winners so API-submitted jobs carry them; the FTC_FLASH_* /
-    # FTC_RING_INNER / FTC_ULYSSES_INNER env vars remain operator overrides —
-    # ``ops/attention.py`` merges env over these). 0/"" = kernel default.
-    flash_block_q: int = 0
-    flash_block_k: int = 0
-    flash_exp_dtype: str = ""      # "float32" | "bfloat16"
-    ring_inner: str = ""           # "xla" | "flash"
-    ulysses_inner: str = ""        # "xla" | "pallas"
     remat: bool = True
     #: which activations the per-layer remat keeps (see ``remat_policy_fn``):
     #: "full" | "attn" | "mlp" | "wide" | "matmuls" | "none" ("none" disables
@@ -211,22 +202,6 @@ class LlamaConfig:
     def replace(self, **kw) -> "LlamaConfig":
         return dataclasses.replace(self, **kw)
 
-    def kernel_tuning(self) -> dict:
-        """Non-default kernel knobs as the dict ``ops.attention`` consumes
-        (a trace-time constant — values are static ints/strings)."""
-        t: dict = {}
-        if self.flash_block_q:
-            t["block_q"] = self.flash_block_q
-        if self.flash_block_k:
-            t["block_k"] = self.flash_block_k
-        if self.flash_exp_dtype:
-            t["exp_dtype"] = self.flash_exp_dtype
-        if self.ring_inner:
-            t["ring_inner"] = self.ring_inner
-        if self.ulysses_inner:
-            t["ulysses_inner"] = self.ulysses_inner
-        return t
-
     def _attention_params(self) -> int:
         d, h = self.d_model, self.n_heads
         if self.attention_kind == "mla":
@@ -278,9 +253,9 @@ PRESETS: dict[str, LlamaConfig] = {
         vocab_size=256, d_model=64, n_layers=2, n_heads=4, n_kv_heads=2,
         d_ff=128, max_seq_len=128,
     ),
-    # real model families use the measured attention dispatch ("auto": Pallas
-    # flash on TPU past the kernel_bench crossover, XLA otherwise) and the
-    # measured remat policy ("mlp": keep the d_ff-wide activations — on a v5e
+    # real model families leave the attention kernel to the program ("auto":
+    # ops/attention.py::resolve_attention_impl) and take the measured remat
+    # policy ("mlp": keep the d_ff-wide activations — on a v5e
     # chip at bs8/seq2048 this is the largest policy that fits HBM and cuts
     # the TinyLlama step 1.59s -> 1.47s; "wide" OOMs by ~1G)
     "tinyllama-1.1b": LlamaConfig(attention_impl="auto", remat_policy="mlp"),
@@ -334,7 +309,7 @@ PRESETS: dict[str, LlamaConfig] = {
     # the larger proxy int4 expert quantization unlocks (experts are ~95% of
     # a Mixtral-family model's weights): ~10B total / ~3.3B active params,
     # int4 experts ≈ 5G — fits one v5e chip where bf16 would need ~20G.
-    # Run with quantize_base=True (BENCH_MODE=qlora BENCH_PRESET=mixtral-proxy-10b)
+    # Run with quantize_base=True.
     "mixtral-proxy-10b": LlamaConfig(
         vocab_size=32000, d_model=3072, n_layers=16, n_heads=24, n_kv_heads=8,
         d_ff=8192, max_seq_len=8192, n_experts=8, moe_top_k=2,
@@ -522,9 +497,7 @@ class Attention(nn.Module):
         k = checkpoint_name(k, "attn_qkv")
         v = checkpoint_name(v, "attn_qkv")
         out = causal_attention(
-            q, k, v, impl=cfg.attention_impl, segment_ids=segment_ids,
-            tuning=cfg.kernel_tuning(),
-        )
+            q, k, v, impl=cfg.attention_impl, segment_ids=segment_ids)
         out = checkpoint_name(out, "attn_ctx")
         out = _proj(cfg, "o_proj", cfg.d_model)(
             out.reshape(b, s, -1), deterministic, adapter_ids)
@@ -715,9 +688,7 @@ class MLAttention(nn.Module):
         k = checkpoint_name(k, "attn_qkv")
         v = checkpoint_name(v, "attn_qkv")
         out = causal_attention(
-            q, k, v, impl=cfg.attention_impl, segment_ids=segment_ids,
-            tuning=cfg.kernel_tuning(),
-        )
+            q, k, v, impl=cfg.attention_impl, segment_ids=segment_ids)
         out = checkpoint_name(out, "attn_ctx")
         out = _proj(cfg, "o_proj", cfg.d_model)(
             out.reshape(b, s, h * dv), deterministic, adapter_ids)

@@ -160,16 +160,13 @@ def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
 
 def _pallas_grouped_dot_ok(rows: int) -> bool:
     """The Pallas kernel where the compiler takes it: on a TPU, rows a
-    multiple of its smallest row tile, and not under a mesh of several
-    devices (a Mosaic call cannot be partitioned; ``ops/attention.py``
-    makes the same choice for the flash kernels)."""
-    if jax.default_backend() != "tpu" or rows % 128:
-        return False
-    from ..parallel.ring import get_ring_mesh
+    multiple of its smallest row tile, and where a Mosaic call may be issued
+    bare (it cannot be partitioned, and this layer wraps it in no
+    ``shard_map``)."""
+    from ..ops.pallas import bare_mosaic_call_ok
 
-    mesh = get_ring_mesh()
-    return (mesh is None or mesh.size == 1
-            or bool(jax.sharding.get_abstract_mesh().manual_axes))
+    return (jax.default_backend() == "tpu" and rows % 128 == 0
+            and bare_mosaic_call_ok())
 
 
 def _grouped_dot(lhs, rhs, sizes, layer=None):

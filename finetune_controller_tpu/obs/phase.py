@@ -10,8 +10,8 @@ other phases don't claim).  Per-step averages land in the metrics CSV as
 ``ftc_step_phase_ms`` histogram (``obs/prom.py``).
 
 Measurement is host-side ``perf_counter`` bracketing — a handful of calls
-per step, no device syncs added (the ``BENCH_MODE=obs`` gate holds the whole
-tracing layer under 2% of step time).
+per step, no device syncs added (what the profiler costs a step on the
+chip: ``PERF.md`` §6, PR 24).
 """
 
 from __future__ import annotations

@@ -1,8 +1,11 @@
 """Attention implementations with a single dispatch point.
 
-``impl``:
-  * ``"auto"``   — measured dispatch: Pallas flash on TPU at long sequence
-                   (crossover from ``ops.kernel_bench``), XLA otherwise.
+:func:`resolve_attention_impl` is the ONE place a kernel is chosen, from what
+the program can observe (the backend, the row length, the mesh's ``sp``
+axis); :func:`causal_attention` and the trainer call it, nothing re-derives
+it.  ``impl``:
+  * ``"auto"``   — Pallas flash on a TPU at rows of ``PALLAS_MIN_SEQ`` and
+                   longer, XLA otherwise.
   * ``"xla"``    — einsum + masked softmax; XLA fuses this well on TPU and it
                    runs everywhere (CPU tests).  Default.
   * ``"pallas"`` — hand-written TPU flash attention (``ops.pallas``); wins
@@ -11,7 +14,6 @@
                    (``parallel.ring``); requires shard_map.
   * ``"ulysses"`` — all-to-all head-sharded sequence parallelism
                    (``parallel.ulysses``); ``sp`` must divide ``n_kv_heads``.
-                   Local kernel via ``FTC_ULYSSES_INNER`` (xla | pallas).
 
 All paths compute softmax in float32 regardless of input dtype (bf16 inputs,
 f32 accumulation — the MXU-friendly recipe).
@@ -265,57 +267,7 @@ def paged_cache_attention(
     )
 
 
-def _check_block(name: str, raw) -> int:
-    try:
-        val = int(raw)
-    except (TypeError, ValueError):
-        raise ValueError(f"{name}={raw!r}: not an integer") from None
-    if val < 128 or val % 128:
-        raise ValueError(f"{name}={val}: must be a positive multiple of 128")
-    return val
-
-
-def _check_exp_dtype(name: str, raw: str) -> str:
-    if raw not in ("float32", "bfloat16"):
-        raise ValueError(f"{name}={raw!r}: expected float32 or bfloat16")
-    return raw
-
-
-def flash_tuning_kwargs(tuning: dict | None = None) -> dict:
-    """Validated flash-kernel overrides — shared by every flash call site
-    (the plain dispatch and the ring inner), so a tuning sweep moves all of
-    them together.
-
-    Two sources, env over spec: the job's typed config
-    (``LlamaConfig.kernel_tuning()`` — how API-submitted jobs carry the
-    measured winners) seeds the values, and the ``FTC_FLASH_BLOCK_Q``/``K``
-    (positive multiples of 128) / ``FTC_FLASH_EXP_DTYPE``
-    (``float32``/``bfloat16``) env vars remain the operator override
-    (``docs/performance.md``).
-    """
-    import os
-
-    kwargs: dict = {}
-    tuning = tuning or {}
-    for kw in ("block_q", "block_k"):
-        if tuning.get(kw):
-            kwargs[kw] = _check_block(f"kernel_tuning.{kw}", tuning[kw])
-    if tuning.get("exp_dtype"):
-        kwargs["exp_dtype"] = _check_exp_dtype(
-            "kernel_tuning.exp_dtype", tuning["exp_dtype"]
-        )
-    for env_name, kw in (("FTC_FLASH_BLOCK_Q", "block_q"),
-                         ("FTC_FLASH_BLOCK_K", "block_k")):
-        raw = os.environ.get(env_name)
-        if raw:
-            kwargs[kw] = _check_block(env_name, raw)
-    raw = os.environ.get("FTC_FLASH_EXP_DTYPE")
-    if raw:
-        kwargs["exp_dtype"] = _check_exp_dtype("FTC_FLASH_EXP_DTYPE", raw)
-    return kwargs
-
-
-def _flash_attention_on_mesh(q, k, v, segment_ids, kwargs: dict) -> jax.Array:
+def _flash_attention_on_mesh(q, k, v, segment_ids) -> jax.Array:
     """The Pallas flash kernel, one call per device.
 
     The chip's compiler cannot partition a Mosaic kernel, so under a mesh of
@@ -327,25 +279,22 @@ def _flash_attention_on_mesh(q, k, v, segment_ids, kwargs: dict) -> jax.Array:
     and the KV head count (a shard then keeps whole GQA groups); otherwise
     they stay whole on every ``tp`` member, which repeats the attention
     ``tp`` times over but is still correct.  The other axes (``sp``/``ep``/
-    ``pp``) see replicated operands.  A single-device mesh, no mesh, and a
-    caller that is already inside a ``shard_map`` body (the pipeline stages)
-    get the bare kernel call.
+    ``pp``) see replicated operands.  Where ``bare_mosaic_call_ok`` (no mesh,
+    a single-device mesh, a caller already inside a ``shard_map`` body such
+    as a pipeline stage) the kernel is called bare.
     """
-    from ..parallel.ring import get_ring_mesh
+    from .pallas import bare_mosaic_call_ok
     from .pallas.flash_attention import flash_attention
 
-    mesh = get_ring_mesh()
-    if (
-        mesh is None
-        or mesh.size == 1
-        or jax.sharding.get_abstract_mesh().manual_axes
-    ):
-        return flash_attention(q, k, v, segment_ids=segment_ids, **kwargs)
+    if bare_mosaic_call_ok():
+        return flash_attention(q, k, v, segment_ids=segment_ids)
 
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.mesh import AxisNames
+    from ..parallel.ring import get_ring_mesh
 
+    mesh = get_ring_mesh()
     tp = mesh.shape.get(AxisNames.TENSOR, 1)
     heads = (
         AxisNames.TENSOR
@@ -358,7 +307,7 @@ def _flash_attention_on_mesh(q, k, v, segment_ids, kwargs: dict) -> jax.Array:
         in_specs += (P(AxisNames.BATCH_AXES, None),)
 
     def local(q, k, v, segment_ids=None):
-        return flash_attention(q, k, v, segment_ids=segment_ids, **kwargs)
+        return flash_attention(q, k, v, segment_ids=segment_ids)
 
     return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec,
@@ -368,6 +317,48 @@ def _flash_attention_on_mesh(q, k, v, segment_ids, kwargs: dict) -> jax.Array:
     )(*operands)
 
 
+#: shortest row at which ``"auto"`` takes the flash kernels on a TPU.  From a
+#: 2026-07-31 timing of the gradient path on a v5e at 8 rows, 32/4 heads of 64
+#: (XLA ahead at 512, Pallas at 1024 and further ahead at 2048); all three
+#: ledger cells (2,048 / 4,096 / 8,192 tokens a row) sit above it.
+PALLAS_MIN_SEQ = 1024
+
+ATTENTION_IMPLS = ("auto", "xla", "pallas", "ring", "ulysses")
+
+
+def resolve_attention_impl(
+    impl: str, seq_len: int, *, mesh=None, backend: str | None = None
+) -> str:
+    """The implementation ``impl`` means for rows of ``seq_len`` tokens:
+    one of ``"xla"``, ``"pallas"``, ``"ring"``, ``"ulysses"``.
+
+    ``mesh`` defaults to the one the trainer installed
+    (``parallel.ring.ring_mesh``).  With an ``sp`` axis of more than one
+    device the sequence is sharded, so attention must go through a
+    sequence-parallel path or XLA would all-gather S every layer: anything
+    else becomes ``"ring"``.  Without one, ``"ring"`` and ``"ulysses"`` are
+    plain attention.  ``"auto"`` is the flash kernels on a TPU
+    (``backend``, default ``jax.default_backend()``) from ``PALLAS_MIN_SEQ``.
+    """
+    if impl not in ATTENTION_IMPLS:
+        raise ValueError(
+            f"unknown attention impl: {impl!r} (expected one of {ATTENTION_IMPLS})"
+        )
+    if mesh is None:
+        from ..parallel.ring import get_ring_mesh
+
+        mesh = get_ring_mesh()
+    sp = 1 if mesh is None else mesh.shape.get("sp", 1)
+    if impl in ("ring", "ulysses"):
+        return impl if sp > 1 else "xla"
+    if sp > 1:
+        return "ring"
+    if impl == "auto":
+        on_tpu = (backend or jax.default_backend()) == "tpu"
+        return "pallas" if on_tpu and seq_len >= PALLAS_MIN_SEQ else "xla"
+    return impl
+
+
 def causal_attention(
     q: jax.Array,
     k: jax.Array,
@@ -375,45 +366,18 @@ def causal_attention(
     *,
     impl: str = "xla",
     segment_ids: jax.Array | None = None,
-    tuning: dict | None = None,
 ) -> jax.Array:
-    """``tuning`` is the job's typed kernel config
-    (``LlamaConfig.kernel_tuning()``); env vars override it per knob."""
-    import os
-
-    tuning = tuning or {}
-    if impl == "auto":
-        # measured dispatch gate (ops/kernel_bench.py): Pallas flash on TPU
-        # at long sequence, XLA otherwise
-        from .kernel_bench import preferred_impl
-
-        impl = preferred_impl(q.shape[1])
+    """Causal GQA attention by the implementation
+    :func:`resolve_attention_impl` gives for ``impl`` and this row length."""
+    impl = resolve_attention_impl(impl, q.shape[1])
     if impl == "xla":
         return xla_causal_attention(q, k, v, segment_ids=segment_ids)
     if impl == "pallas":
-        return _flash_attention_on_mesh(
-            q, k, v, segment_ids, flash_tuning_kwargs(tuning)
-        )
-    if impl in ("ring", "ulysses"):
-        from ..parallel.ring import get_ring_mesh, ring_attention_sharded
+        return _flash_attention_on_mesh(q, k, v, segment_ids)
+    if impl == "ring":
+        from ..parallel.ring import ring_attention_sharded
 
-        mesh = get_ring_mesh()
-        if mesh is None or mesh.shape.get("sp", 1) == 1:
-            # no sp axis active: plain attention is both correct and faster
-            return xla_causal_attention(q, k, v, segment_ids=segment_ids)
-        if impl == "ring":
-            return ring_attention_sharded(
-                q, k, v, segment_ids=segment_ids, mesh=mesh, tuning=tuning
-            )
-        from ..parallel.ulysses import ulysses_attention_sharded
+        return ring_attention_sharded(q, k, v, segment_ids=segment_ids)
+    from ..parallel.ulysses import ulysses_attention_sharded
 
-        inner = (
-            os.environ.get("FTC_ULYSSES_INNER", "").strip().lower()
-            or tuning.get("ulysses_inner")
-            or "xla"
-        )
-        return ulysses_attention_sharded(
-            q, k, v, segment_ids=segment_ids, mesh=mesh, impl=inner,
-            tuning=tuning,
-        )
-    raise ValueError(f"unknown attention impl: {impl!r}")
+    return ulysses_attention_sharded(q, k, v, segment_ids=segment_ids)

@@ -28,7 +28,7 @@ tails — genuinely accumulate l == 0 and emit zeros with zero gradients.
 
 Runs in interpreter mode off-TPU so CPU CI exercises the same kernel logic
 (SURVEY.md §4 test strategy). Dispatch between this kernel and the XLA path
-is measured, not assumed — see ``ops/kernel_bench.py``.
+is ``ops/attention.py::resolve_attention_impl``'s.
 """
 
 from __future__ import annotations
@@ -44,13 +44,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
-# Default block size, measured on v5e (tpu_session.jsonl, 2026-07-31): the
-# seq-8192 grad-path A/B ran 64.5 ms at block 512 vs 49.1 ms at block 1024
-# with f32 exp held fixed (−24% — fewer grid steps, same VMEM residency).
-# The full TinyLlama seq-2048 train step gained +8% end-to-end from block
-# 1024 and bf16 exp TOGETHER (no block-only train-step measurement exists).
-# Capped to the sequence length at call time, so short-sequence callers are
-# unaffected.
+# Default block size.  All three ledger cells run it with no override
+# (``mistral-7b-qlora.train-sft-2k`` / ``-8k`` at head size 128,
+# ``joyai-llm-flash-lora.train-sft-4k`` at 192/128; PERF.md §5 has a call's
+# time in each).  It came from a 2026-07-31 timing on a v5e at head size 64
+# (block 1024 ahead of 512 on the seq-8192 gradient path), older than the
+# cells.  Capped to the sequence length at call time, so short-sequence
+# callers are unaffected.
 DEFAULT_BLOCK = 1024
 
 
@@ -63,8 +63,8 @@ def _resolve_tuning(
     path — p is about to be rounded to bf16 for the MXU anyway
     (``p.astype(v.dtype)``), so computing exp in bf16 after the f32
     max-subtract adds <0.4% relative error to an already-bf16-rounded
-    quantity and measured −10% on the seq-8192 grad path (tpu_session.jsonl
-    kernel A/B: bf16-b1024 44.3 ms vs f32-b1024 49.1 ms). Full-precision
+    quantity; the three ledger cells (bf16 compute) all take this path and
+    their plain reference holds it to ``correct``'s limits. Full-precision
     inputs keep the f32 exp — the numerics oracle is untouched.
     """
     if block_q is None:
@@ -736,13 +736,13 @@ def flash_attention(
 ) -> jax.Array:
     """Causal GQA flash attention. Shapes as ``ops.attention.causal_attention``.
 
-    Unset tuning knobs resolve to the measured v5e winners (1024-token
-    blocks; exp dtype follows the input dtype — ``_resolve_tuning``). The
-    earlier 512 default came from a kernel-only sweep where 512→1024 was
-    flat at seq 2048; the 2026-07-31 session measured block 1024 −24% on
-    the seq-8192 grad path (f32 exp held fixed) and the combined winner
-    (block 1024 + bf16 exp) +8% on the full seq-2048 train step, so these
-    are the defaults (blocks are capped to S at call time)."""
+    Unset ``block_q``/``block_k``/``exp_dtype`` resolve to the defaults
+    (1024-token blocks; exp dtype follows the input dtype —
+    ``_resolve_tuning``, the ONE place a default lives).  The program
+    passes none of the three: they are here for tests, which run small
+    blocks through the kernels, and for whoever next changes the rule in
+    ``_resolve_tuning`` from what it can observe (head size, row length).
+    Blocks are capped to S at call time."""
     out, _ = flash_attention_with_lse(
         q, k, v, segment_ids=segment_ids, block_q=block_q, block_k=block_k,
         interpret=interpret, exp_dtype=exp_dtype,

@@ -34,8 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 def _timed_chain(fn, x, *, warmup: int = 2, iters: int = 5) -> float:
     """Per-call seconds with a data-dependency chain + host fetch.
 
-    Same discipline as ``ops.kernel_bench._time_chained`` (and for the same
-    measured reason): independent repeated calls through an async or caching
+    Independent repeated calls through an async or caching
     remote-TPU runtime can appear nearly free even under
     ``block_until_ready`` — and this tool's whole job is telling an operator
     the truth about a slice. Every collective here maps a sharded array to a

@@ -144,7 +144,6 @@ def _ring_attention_local_flash(
     *,
     axis_name: str,
     have_segments: bool = True,
-    tuning: dict | None = None,
 ) -> jax.Array:
     """Ring attention with the PALLAS flash kernel as the per-step inner.
 
@@ -163,17 +162,14 @@ def _ring_attention_local_flash(
     The lse cotangent is differentiable end-to-end (the kernel's
     ``custom_vjp`` folds it into the backward's delta term).
     """
-    from ..ops.attention import flash_tuning_kwargs
-    from ..ops.pallas.flash_attention import flash_attention_with_lse
+    # blocks and exp dtype are the kernel's defaults (_resolve_tuning), which
+    # also caps blocks to the per-hop length
+    from ..ops.pallas.flash_attention import flash_attention_with_lse as flash
 
     n = axis_size(axis_name)
     i = jax.lax.axis_index(axis_name)
     b, s_local, h, d = q.shape
 
-    # spec kernel_tuning seeded, FTC_FLASH_* env overriding; unset knobs
-    # resolve to the measured defaults inside the kernel (_resolve_tuning),
-    # which also caps blocks to the per-hop length
-    flash = partial(flash_attention_with_lse, **flash_tuning_kwargs(tuning))
     # segmentless corpora must not pay the per-interior-block segment-mask
     # VPU pass — the kernel compiles it out when given no segment ids
     qseg = segment_ids if have_segments else None
@@ -243,8 +239,7 @@ def ring_attention_sharded(
     segment_ids: jax.Array | None = None,
     mesh: Mesh | None = None,
     axis_name: str = AxisNames.SEQ,
-    inner: str | None = None,
-    tuning: dict | None = None,
+    inner: str = "xla",
 ) -> jax.Array:
     """Causal GQA attention with S sharded over ``axis_name``.
 
@@ -254,12 +249,9 @@ def ring_attention_sharded(
 
     ``inner`` picks the per-hop block kernel: ``"xla"`` (einsum + masked
     softmax — materialises the (S/n)² score block per hop) or ``"flash"``
-    (Pallas streaming kernel + logsumexp merge). Default from
-    ``FTC_RING_INNER`` (``xla`` until the flash inner is measured on a real
-    multi-chip slice).
+    (Pallas streaming kernel + logsumexp merge; reached only from tests
+    until it has compiled for a chip and a cell measures it — ROADMAP.md C8).
     """
-    import os
-
     mesh = mesh or _ring_mesh
     if mesh is None:
         raise ValueError("ring attention needs a mesh (use ring_mesh(...) or pass mesh=)")
@@ -270,18 +262,10 @@ def ring_attention_sharded(
     have_segments = segment_ids is not None
     if segment_ids is None:
         segment_ids = jnp.zeros(q.shape[:2], jnp.int32)
-    if inner is None:
-        # env over spec over default — same precedence as the flash knobs
-        inner = (
-            os.environ.get("FTC_RING_INNER", "").strip().lower()
-            or (tuning or {}).get("ring_inner")
-            or "xla"
-        )
     if inner not in ("xla", "flash"):
         raise ValueError(f"unknown ring inner {inner!r}: expected xla or flash")
     local = (
-        partial(_ring_attention_local_flash, have_segments=have_segments,
-                tuning=tuning)
+        partial(_ring_attention_local_flash, have_segments=have_segments)
         if inner == "flash"
         else _ring_attention_local
     )

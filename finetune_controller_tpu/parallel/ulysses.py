@@ -50,9 +50,9 @@ def _ulysses_local(
     axis_name: str,
     have_segments: bool,
     impl: str,
-    tuning: dict | None = None,
 ) -> jax.Array:
-    from ..ops.attention import causal_attention
+    from ..ops.attention import xla_causal_attention
+    from ..ops.pallas.flash_attention import flash_attention
 
     # seq-shard -> head-shard: split the head axis across sp, gather the
     # sequence axis (tiled all-to-all = the Ulysses/DeepSpeed layout swap)
@@ -68,9 +68,10 @@ def _ulysses_local(
         if have_segments else None
     )
 
-    out_h = causal_attention(
-        q_h, k_h, v_h, impl=impl, segment_ids=seg, tuning=tuning
-    )
+    # a shard_map body: the kernels are called directly (the Mosaic call
+    # bare), past the dispatch that would see the sp axis and choose "ring"
+    attend = flash_attention if impl == "pallas" else xla_causal_attention
+    out_h = attend(q_h, k_h, v_h, segment_ids=seg)
 
     # head-shard -> seq-shard: the inverse all-to-all
     return jax.lax.all_to_all(
@@ -87,7 +88,6 @@ def ulysses_attention_sharded(
     mesh: Mesh | None = None,
     axis_name: str = AxisNames.SEQ,
     impl: str = "xla",
-    tuning: dict | None = None,
 ) -> jax.Array:
     """Causal GQA attention, S sharded over ``axis_name`` via head all-to-all.
 
@@ -128,7 +128,7 @@ def ulysses_attention_sharded(
     seg_spec = P(AxisNames.BATCH_AXES, axis_name)
     fn = shard_map(
         partial(_ulysses_local, axis_name=axis_name,
-                have_segments=have_segments, impl=impl, tuning=tuning),
+                have_segments=have_segments, impl=impl),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec),
         out_specs=qkv_spec,

@@ -4,9 +4,9 @@ The installed JAX honours ``JAX_PLATFORMS`` by itself, so no platform
 resolution lives here: a process told ``JAX_PLATFORMS=tpu`` fails at backend
 start-up when it finds no chip, and one told ``cpu`` never looks for one.
 What the entry points (``train/cli.py``, ``train/warm_worker.py``,
-``transport/worker.py``, ``bench.py``, ``ops/kernel_bench.py``,
-``tests/conftest.py``) do share is where compiled programs are cached and how
-a process says which device it landed on.
+``transport/worker.py``, ``benchmarks/run.py``, ``tests/conftest.py``) do
+share is where compiled programs are cached and how a process says which
+device it landed on.
 """
 
 from __future__ import annotations
@@ -35,7 +35,7 @@ def enable_compile_cache() -> str:
     With ``JAX_COMPILATION_CACHE_DIR`` set, JAX has already read it and no
     other directory is set in code; without it the cache goes to the fixed
     path in the checkout, so every process of one checkout (trainer, serve
-    worker, bench, tests and the subprocesses they spawn) shares one cache.
+    worker, benchmark, tests and the subprocesses they spawn) shares one cache.
     Programs that took under half a second to compile are cached too unless
     ``JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS`` says otherwise — the test
     suite is made of such programs.
@@ -62,11 +62,3 @@ def device_report() -> dict:
         "kind": devices[0].device_kind,
         "count": len(devices),
     }
-
-
-def env_flag(name: str, default: bool = False) -> bool:
-    """Parse a boolean env var: '', '0', 'false', 'no', 'off' are false."""
-    raw = os.environ.get(name)
-    if raw is None:
-        return default
-    return raw.strip().lower() not in ("", "0", "false", "no", "off")

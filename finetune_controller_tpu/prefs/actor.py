@@ -15,7 +15,8 @@ Per round the actor:
    take ``variables`` as an argument, so a reload costs zero recompiles —
    the whole loop stays inside the engine's existing compile budget (the
    armed :class:`~..analysis.recompile_guard.RecompileGuard` raises
-   otherwise, and the BENCH_MODE=dpo smoke asserts it);
+   otherwise; ``tests/test_prefs.py::test_actor_reloads_committed_checkpoint``
+   asserts it);
 2. :meth:`generate_pairs` — batch-decode TWO sampled candidates per prompt
    through :class:`~..serve.engine.BatchEngine` (continuous batching: both
    candidates of all prompts share the decode lanes), score them with the

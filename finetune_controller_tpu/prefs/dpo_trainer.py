@@ -86,7 +86,7 @@ class DPOTrainer(Trainer):
                 # background prefetch thread would interleave the serve
                 # engine's decode steps with the learner's jitted steps and
                 # read checkpoints concurrently with the blocking save —
-                # enforce here so every caller (cli, bench, harnesses) is
+                # enforce here so every caller (cli, harnesses) is
                 # covered
                 logger.info("rlhf task: forcing prefetch=0 (actor runs inline)")
                 train_cfg.prefetch = 0

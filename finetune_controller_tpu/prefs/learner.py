@@ -41,8 +41,8 @@ logger = logging.getLogger(__name__)
 @dataclasses.dataclass
 class RolloutConfig:
     """Knobs of the actor/learner loop (job-spec arguments; the ``FTC_RLHF_*``
-    env vars in ``examples/ftc.env.example`` are per-pod operator overrides,
-    the ``FTC_FLASH_*`` convention)."""
+    env vars in ``examples/ftc.env.example`` are per-pod operator
+    overrides)."""
 
     pairs_per_round: int = 16
     buffer_capacity: int = 256

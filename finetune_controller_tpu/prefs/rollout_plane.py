@@ -272,7 +272,7 @@ class RolloutService:
             "actor_pairs_generated": self.actor.pairs_generated,
             "actor_rounds": self.actor.rounds,
             # cumulative decode counters: windowed deltas give the decode
-            # throughput over any interval (the BENCH_MODE=dpo overlap leg)
+            # throughput over any interval
             "actor_tokens_generated": self.actor.tokens_generated,
             "actor_generate_seconds": round(self.actor.generate_seconds, 6),
         }

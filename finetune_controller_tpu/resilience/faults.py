@@ -17,9 +17,9 @@ happy-path test.  This module makes failures *reproducible inputs*:
   — a chosen fleet replica is killed (its decode step raises
   :class:`ReplicaKilled`) or wedged (its decode step stops making progress
   while holding lanes) when that replica's engine reaches a chosen decode
-  step.  Armed through ``FTC_FAULT_SERVE_*``; the serve-chaos tests and
-  ``BENCH_MODE=serve`` share this one injection path
-  (docs/serving.md §Fleet).
+  step.  Armed through ``FTC_FAULT_SERVE_*``; the serve-chaos tests
+  (``tests/test_serve_fleet.py``, ``tests/test_transport.py``) go through
+  this one injection path (docs/serving.md §Fleet).
 
 Nothing here imports controller or serve modules; the trainer arms
 ``StepFault`` in pods that carry no controller extras, and the serve fleet

@@ -21,8 +21,8 @@ Modules:
   graceful drain (docs/serving.md §Fleet);
 - :mod:`.sim` — a seeded, clock-injected cluster simulator so fairness /
   starvation / preemption / progress-loss properties are provable in fast
-  deterministic tests (and ``BENCH_MODE=sched`` comparisons against the
-  FIFO and evict-only baselines).
+  deterministic tests, among them the comparisons against the FIFO and
+  evict-only baselines (``tests/test_sched.py``, ``tests/test_resize.py``).
 """
 
 from .backfill import backfill_capacity

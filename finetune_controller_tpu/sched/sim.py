@@ -16,8 +16,7 @@ on a virtual clock:
   (>= 2 tenants with arrived-but-unfinished demand) so Jain's fairness
   index is computed on entitlement-normalised allocations;
 - every event is totally ordered (time, then a tie-break counter), so a
-  seeded trace replays bit-identically — the property tests and
-  ``BENCH_MODE=sched`` both lean on this.
+  seeded trace replays bit-identically — the property tests lean on this.
 """
 
 from __future__ import annotations
@@ -403,7 +402,7 @@ class ClusterSim:
 
 
 # ---------------------------------------------------------------------------
-# Canonical trace + catalog for tests and BENCH_MODE=sched
+# Canonical trace + catalog for the tests
 # ---------------------------------------------------------------------------
 
 
@@ -472,7 +471,8 @@ def elastic_trace(
     again until ALL of its chips are simultaneously free, so its
     anti-starvation reservation idles every chip that frees before the last
     arrival drains; under resize it degrades onto the leftovers and grows
-    back.  ``BENCH_MODE=sched`` gates resize-vs-evict progress loss here.
+    back.  ``tests/test_resize.py::test_sim_resize_beats_evict_on_progress_lost``
+    holds resize to less progress lost than eviction here.
     """
     rng = random.Random(seed)
     jobs: list[SimJob] = [

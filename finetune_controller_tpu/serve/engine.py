@@ -334,7 +334,7 @@ class BatchEngine:
         self.tokens_by_tenant: dict[str, int] = {}
         self._prefix_warned = False
         # runtime transfer guard on the decode hot window
-        # (FTC_TRANSFER_GUARD=raise|warn; armed by BENCH_MODE=serve):
+        # (FTC_TRANSFER_GUARD=raise|warn; armed in tests/test_transfer_guard.py):
         # every per-step host->device argument is device_put EXPLICITLY
         # before the guarded dispatch, so a steady-state decode step that
         # moves anything else across the boundary aborts loudly
@@ -1196,7 +1196,7 @@ class BatchEngine:
         return finished
 
     def run(self, requests: list[GenRequest]) -> dict[str, GenResult]:
-        """Synchronous convenience driver (tests/bench): admit everything —
+        """Synchronous convenience driver (tests): admit everything —
         overflow waits for a lane or for pool pages — and step until the
         batch drains."""
         results: dict[str, GenResult] = {}

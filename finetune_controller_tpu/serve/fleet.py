@@ -25,8 +25,8 @@ checkpoints are immutable artifacts, so replicas are cattle
   (the router prefers the newest generation), and only then drains the old
   generation — no stop-the-world swap;
 * the seeded chaos hand (``resilience/faults.py::ServeFault``) can kill or
-  wedge a chosen replica at a chosen decode step, the injection path the
-  serve-chaos tests and ``BENCH_MODE=serve`` share;
+  wedge a chosen replica at a chosen decode step, the injection path of
+  the serve-chaos tests (``tests/test_serve_fleet.py``);
 * **transport** (docs/serving.md §Cross-process transport): with a
   ``transport`` attached (``serve_transport=process``), every replica is a
   separate WORKER PROCESS — its own JAX runtime behind an RPC socket — and

@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.llama import LlamaConfig, LlamaForCausalLM
 from ..obs.trace import annotate
+from ..ops.attention import resolve_attention_impl
 from ..parallel.mesh import MeshSpec
 from ..parallel.sharding import (
     LLAMA_RULES,
@@ -134,8 +135,8 @@ class TrainConfig:
     #: an implicit host->device copy of a stray numpy leaf, a leftover
     #: ``jax.device_get`` — fails loudly instead of silently serializing
     #: every step.  "raise" | "warn" | "off"; the empty default inherits
-    #: ``FTC_TRANSFER_GUARD`` from the env (off when unset).  bench.py
-    #: arms "raise" inside its timed windows.
+    #: ``FTC_TRANSFER_GUARD`` from the env (off when unset).  The benchmark
+    #: (``benchmarks/harness/drivers/train.py``) arms "raise".
     transfer_guard: str = ""
     #: shard audit (``analysis/shard_audit.py``): at checkpoint/restore
     #: boundaries, assert every live state leaf's ``.sharding`` still equals
@@ -144,7 +145,7 @@ class TrainConfig:
     #: (every later step then pays a GSPMD reshard that profiles as "slow",
     #: never as an error).  "raise" | "warn" | "off"; the empty default
     #: inherits ``FTC_SHARD_AUDIT`` from the env (off when unset).
-    #: bench.py arms "raise" so a mis-sharded timed run aborts.
+    #: The benchmark arms "raise" so a mis-sharded timed run aborts.
     shard_audit: str = ""
     #: liveness heartbeat cadence (``resilience/heartbeat.py``): rank 0
     #: writes ``heartbeat.json`` (step + wall clock) into the artifacts dir
@@ -154,8 +155,7 @@ class TrainConfig:
     #: observability (docs/observability.md): rank 0 records lifecycle
     #: events (``events.jsonl``), spans (``trace/trainer.jsonl``), and the
     #: step-phase split (``phase_*_ms`` CSV columns).  ``FTC_TRACE=0`` in the
-    #: env is the operator kill switch; overhead is gated <2% of step time
-    #: by ``BENCH_MODE=obs``.
+    #: env is the operator kill switch.
     trace: bool = True
 
 
@@ -246,13 +246,12 @@ class Trainer:
     ):
         self.cfg = train_cfg
         self.mesh = mesh if mesh is not None else MeshSpec(fsdp=1).build(jax.devices()[:1])
-        if (
-            self.mesh.shape.get("sp", 1) > 1
-            and model_cfg.attention_impl not in ("ring", "ulysses")
-        ):
-            # an active sp axis means the sequence is sharded: attention must
-            # go through an SP-aware path (ring or ulysses) or XLA would
-            # all-gather S every layer
+        #: what the step's attention runs as, for the ``train-started`` event
+        self.attention_impl = resolve_attention_impl(
+            model_cfg.attention_impl, train_cfg.seq_len, mesh=self.mesh)
+        if self.attention_impl == "ring" and model_cfg.attention_impl != "ring":
+            # the sp axis shards the sequence: the model carries the choice,
+            # so a trace outside the step's installed mesh makes it too
             logger.info("sp=%d mesh axis active: attention_impl -> ring",
                         self.mesh.shape["sp"])
             model_cfg = model_cfg.replace(attention_impl="ring")
@@ -1165,7 +1164,7 @@ class Trainer:
         # events (events.jsonl) + spans (trace/trainer.jsonl) through the
         # artifact channel, and the phase clock splits every logging window
         # into input/compute/checkpoint/sync/eval.  FTC_TRACE=0 is the
-        # operator kill switch; BENCH_MODE=obs gates the overhead <2%.
+        # operator kill switch.
         from ..obs.events import EventLogWriter
         from ..obs.phase import PhaseClock
         from ..obs.trace import SpanRecorder
@@ -1363,8 +1362,7 @@ class Trainer:
         # delivers profile_request.json through the artifact channel and the
         # loop picks it up within one poll window — a live job profiles
         # without restarting.  The stat() is throttled to the preemption-sync
-        # cadence: per-step filesystem polling is exactly the kind of cost
-        # the BENCH_MODE=obs <2% gate exists to keep out of the step loop.
+        # cadence: per-step filesystem polling has no place in the step loop.
         profile_req_path = os.path.join(artifacts_dir, "profile_request.json")
         profile_poll = self._preempt_sync_every
         try:
@@ -1593,16 +1591,12 @@ class Trainer:
         resolves to, and the bytes the freshly-initialised state holds on
         each local device.  The control plane (and ``chip_smoke.py``) stays
         off JAX and learns the device from this."""
-        from ..ops.kernel_bench import preferred_impl
         from ..platform import device_report
 
-        impl = getattr(self.model_cfg, "attention_impl", None)
-        if impl == "auto":
-            impl = preferred_impl(self.cfg.seq_len)
         return {
             **device_report(),
             "mesh": {k: v for k, v in self.mesh.shape.items() if v > 1},
-            "attention_impl": impl,
+            "attention_impl": self.attention_impl,
             "device_state_bytes": self._device_bytes("bytes_in_use"),
         }
 

@@ -27,7 +27,7 @@ from typing import Any, Callable
 
 def tiny_test(preset: str = "tiny-test", seed: int = 0,
               lora_rank: int = 0) -> tuple[Any, dict]:
-    """Deterministic tiny model (tests + transport bench): same ``seed`` ⇒
+    """Deterministic tiny model (tests): same ``seed`` ⇒
     bit-identical weights in every process on the same backend."""
     import jax
     import jax.numpy as jnp

@@ -6,8 +6,8 @@ on 400 KB of real English, 200-step controller-submitted LoRA SFT), prints
 the record, and writes it to ``FIDELITY.json`` at the repo root — the raw
 evidence behind BASELINE.md's fidelity row.
 
-On a real TPU the record is also appended to ``tpu_session.jsonl`` (the
-committed measurement log) with ``step: "fidelity"``.
+With ``--session-log FILE`` a TPU run also appends the record to that file
+with ``step: "fidelity"``.
 """
 
 from __future__ import annotations
@@ -33,8 +33,8 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--sft-steps", type=int, default=200)
     p.add_argument("--corpus-bytes", type=int, default=400_000)
     p.add_argument("--max-new-tokens", type=int, default=48)
-    p.add_argument("--session-log", default=str(REPO / "tpu_session.jsonl"),
-                   help="where the TPU-run record is appended")
+    p.add_argument("--session-log", default=None,
+                   help="a file the TPU-run record is appended to")
     args = p.parse_args(argv)
 
     import jax
@@ -57,7 +57,7 @@ def main(argv: list[str] | None = None) -> int:
     print(json.dumps(record, indent=2))
     (REPO / "FIDELITY.json").write_text(json.dumps(record, indent=2) + "\n")
 
-    if device.platform == "tpu":
+    if device.platform == "tpu" and args.session_log:
         session_rec = {
             "ts": round(time.time(), 1),
             "step": "fidelity",

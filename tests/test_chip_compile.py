@@ -285,11 +285,14 @@ def test_paged_kernel_is_refused_past_its_vmem_limit(v5e, monkeypatch):
 @pytest.mark.parametrize(
     "mesh_axes", [{"fsdp": 4}, {"fsdp": 2, "tp": 2}], ids=["fsdp4", "fsdp2-tp2"])
 def test_sharded_flash_compiles_for_four_chips_without_gathering(
-        v5e, mesh_axes):
+        v5e, mesh_axes, monkeypatch):
     """The Mosaic kernel cannot be partitioned by the compiler; under a mesh
     of several devices ``_flash_attention_on_mesh`` wraps it in shard_map —
     batch over dp/fsdp, heads over tp — so each chip runs it on its own
     shard: the kernels are in the program and no q/k/v all-gather is."""
+    # the dispatch passes the kernel no argument: it compiles for the chip
+    # (not the interpreter) where the backend says it is on one
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     b, s, h, hkv, d = FLASH_SHAPES["tinyllama-b8-s2048"]
     mesh = MeshSpec(**mesh_axes).build(v5e)
     heads = "tp" if mesh_axes.get("tp", 1) > 1 else None
@@ -298,7 +301,7 @@ def test_sharded_flash_compiles_for_four_chips_without_gathering(
     kv = jax.ShapeDtypeStruct((b, s, hkv, d), BF16, sharding=sharding)
 
     def loss(q, k, v):
-        out = _flash_attention_on_mesh(q, k, v, None, {"interpret": False})
+        out = _flash_attention_on_mesh(q, k, v, None)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     with mesh, ring_mesh(mesh):

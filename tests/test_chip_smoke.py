@@ -223,7 +223,7 @@ def test_full_mode_runs_the_published_tinyllama_config(smoke):
     from finetune_controller_tpu.controller.devices import default_catalog
     from finetune_controller_tpu.controller.examples import BUILTIN_JOB_SPECS
     from finetune_controller_tpu.models.llama import PRESETS
-    from finetune_controller_tpu.ops.kernel_bench import preferred_impl
+    from finetune_controller_tpu.ops.attention import resolve_attention_impl
 
     cfg = smoke.mode_config(tiny=False, seed=0)
     (spec_cls,) = [c for c in BUILTIN_JOB_SPECS
@@ -236,8 +236,9 @@ def test_full_mode_runs_the_published_tinyllama_config(smoke):
     assert (args.batch_size, args.seq_len, args.lora_rank,
             args.frozen_dtype) == (8, 2048, 8, "bfloat16")
     assert args.checkpoint_every == args.total_steps == 24
-    assert preferred_impl(args.seq_len, backend="tpu") == \
-        cfg["attention_impl"] == "pallas"
+    assert resolve_attention_impl(
+        model.attention_impl, args.seq_len, backend="tpu"
+    ) == cfg["attention_impl"] == "pallas"
     flavor = default_catalog().get(cfg["device"])
     assert flavor.runtime == "tpu" and flavor.total_chips == 1
     assert default_catalog().quota_for(cfg["device"]) == 1

@@ -68,6 +68,18 @@ def test_bad_spec_rejected(tmp_path):
         assert "bogus_field" in str(e)
 
 
+def test_unknown_model_field_refused(tmp_path):
+    """``model.overrides`` is outside input: a field ``LlamaConfig`` does not
+    have (here a kernel knob that is gone) is refused by name."""
+    import pytest
+
+    spec = _spec(tmp_path)
+    spec["model"]["overrides"] = {"flash_block_q": 256}
+    with pytest.raises(TypeError, match="flash_block_q"):
+        cli.run_job(spec)
+    assert not (tmp_path / "artifacts" / "done.txt").exists()
+
+
 def test_eval_loop_writes_heldout_metrics(tmp_path):
     """eval_every drives a held-out evaluation: eval columns ride on the
     train log rows at the eval cadence (dense rows — ragged cells would

@@ -344,6 +344,33 @@ def test_paged_admission_backpressure_and_recovery(tiny_model):
     assert res["small"].generated == _baseline(model, variables, [5, 2], 8)
 
 
+def test_paged_lanes_per_byte_at_a_fixed_budget(tiny_model):
+    """The capacity argument for paging: with exactly the KV bytes a
+    2-lane unpaged cache reserves, the paged engine runs at least twice as
+    many short requests at once — they stop paying full-length
+    reservations."""
+    model, variables = tiny_model
+    unpaged_lanes = 2
+    probe = _paged_engine(model, variables)
+    pages_per_lane = -(-probe.config.cache_len // probe.config.page_tokens)
+    budget_pages = unpaged_lanes * pages_per_lane
+    eng = _paged_engine(model, variables, slots=4 * unpaged_lanes,
+                        pool_pages=budget_pages + 1)   # + the scratch page
+    prompts = [[3 + i, 5, 8 + i][: 2 + i % 2] for i in range(4 * unpaged_lanes)]
+    pending = [GenRequest(request_id=f"s{i}", tokens=p, max_new_tokens=4)
+               for i, p in enumerate(prompts)]
+    done, max_active = {}, 0
+    while pending or eng.active_requests:
+        while pending and eng.free_slots and eng.can_admit(pending[0]):
+            eng.admit(pending.pop(0))
+        max_active = max(max_active, eng.active_requests)
+        for r in eng.step():
+            done[r.request_id] = r
+    assert max_active >= 2 * unpaged_lanes, max_active
+    for i, p in enumerate(prompts):
+        assert done[f"s{i}"].generated == _baseline(model, variables, p, 4)
+
+
 def test_paged_pool_too_small_refused(tiny_model):
     model, variables = tiny_model
     with pytest.raises(ValueError, match="pool too small"):
@@ -591,6 +618,52 @@ def test_tier_capacity_beyond_device_budget(tiny_model):
                 "tier_host_bytes", "demotions_total", "restores_total"):
         assert key in st, key
     assert st["tier_host_pages_used"] == hp.used_count
+
+
+def test_tier_admits_more_lanes_at_a_fixed_pool(tiny_model, monkeypatch):
+    """Three shared prefixes, four lanes each, admitted until the pool is
+    exhausted, with a device prefix budget of half an entry.  Without the
+    host tier the cache refuses every insert and each lane reserves its
+    full span; with it the entries live on the host, a group's first lane
+    restores the prefix and its followers share those pages — at least 1.5x
+    the lanes from the same pool, the same tokens, and no transfer inside
+    the guarded decode window."""
+    monkeypatch.setenv("FTC_TRANSFER_GUARD", "raise")
+    model, variables = tiny_model
+    buckets, page_tokens, new_tokens = (8, 32), 8, 8
+    prefixes = [[(11 * j + 5 * i) % 190 + 1 for i in range(max(buckets) - 1)]
+                for j in range(3)]
+    lane_pages = -(-(max(buckets) + new_tokens - 1) // page_tokens)
+
+    def reqs(tag, tails):
+        return [GenRequest(request_id=f"{tag}-p{j}t{t}", tokens=pre + [t],
+                           max_new_tokens=new_tokens)
+                for j, pre in enumerate(prefixes) for t in tails]
+
+    lanes, outs = {}, {}
+    for which, host_bytes in (("tiered", 1 << 16), ("untiered", 0)):
+        eng = _tiered_engine(model, variables, device_budget_pages=1,
+                             slots=12, pool_pages=8 * lane_pages,
+                             prompt_buckets=buckets, host_pool_bytes=host_bytes)
+        eng.run(reqs("seed", [200]))   # entries born on the host, or refused
+        admitted = 0
+        for req in reqs("wave", [201, 202, 203, 204]):
+            try:
+                eng.admit(req)
+            except PoolExhausted:
+                break
+            admitted += 1
+        results = {}
+        while eng.active_requests:
+            for r in eng.step():
+                results[r.request_id] = r
+        lanes[which], outs[which] = admitted, results
+        assert eng._transfer_guard.trips == 0
+    assert lanes["tiered"] >= 1.5 * lanes["untiered"], lanes
+    shared = set(outs["tiered"]) & set(outs["untiered"])
+    assert shared
+    for rid in shared:
+        assert outs["tiered"][rid].generated == outs["untiered"][rid].generated
 
 
 def test_tier_mid_flight_demotion_is_invisible(tiny_model):

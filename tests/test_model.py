@@ -295,7 +295,7 @@ def test_seq_len_beyond_preset_max_warns(caplog):
 def test_active_param_count_accounting():
     """MFU accounting: dense configs are unchanged; MoE counts the router
     plus top-k experts only — idle experts must not earn FLOP credit
-    (bench.py uses 6 * active_param_count per token)."""
+    (6 * active_param_count per token is the full-training figure)."""
     dense = PRESETS["tinyllama-1.1b"]
     assert dense.active_param_count() == dense.param_count()
 

@@ -253,3 +253,29 @@ def test_prefix_stats_and_disabled_engine(tiny_model):
     off.run([GenRequest(request_id="z", tokens=PROMPTS[0], max_new_tokens=2)])
     assert off.prefix_hits_total == 0 and off.prefix_misses_total == 0
     assert off.prefix_cache_bytes == 0 and off.prefix_cache_entries == 0
+
+
+def test_shared_system_prompt_saves_most_prefill_tokens(tiny_model):
+    """The workload the cache exists for: requests that share a long system
+    prompt and differ in a short tail.  Once the prefix is cached, more than
+    half of all prompt tokens are never prefilled again, with greedy output
+    unchanged and the compile budget held."""
+    model, variables = tiny_model
+    system = [(7 * i + 3) % 200 + 1 for i in range(24)]
+    prompts = [system + [210 + i, 220 + i] for i in range(6)]
+    eng = _engine(model, variables, prompt_buckets=(8, 32))
+
+    def reqs(tag):
+        return [
+            GenRequest(request_id=f"{tag}{i}", tokens=p, max_new_tokens=4)
+            for i, p in enumerate(prompts)
+        ]
+
+    eng.run(reqs("w"))  # seeds the cache
+    saved0 = eng.prefill_tokens_saved_total
+    out = eng.run(reqs("m"))
+    saved = eng.prefill_tokens_saved_total - saved0
+    assert saved > 0.5 * sum(len(p) for p in prompts), saved
+    for i, p in enumerate(prompts):
+        assert out[f"m{i}"].generated == _baseline(model, variables, p, 4)
+    assert eng.compilations <= 2 * len(eng.config.prompt_buckets) + 1

@@ -148,6 +148,27 @@ def test_dpo_trainer_margin_increases_and_ref_grad_free():
     jax.tree.map(np.testing.assert_array_equal, frozen_before, frozen_after)
 
 
+def test_dpo_heldout_accuracy_after_training():
+    """Eighty steps on the seeded synthetic preference set rank held-out
+    pairs (a disjoint seed region, read through the job's own ``evaluate``)
+    correctly at least seven times in ten, with the reward margin finite and
+    higher in the last quarter of the run than in the first."""
+    trainer, cfg = _tiny_dpo_trainer(
+        batch_size=8, seq_len=32, total_steps=80, eval_steps=8,
+        recompile_budget=4, recompile_action="raise")
+    state = trainer.init_state()
+    batches = synthetic_preference_batches(8, 32, cfg.vocab_size, seed=0)
+    margins = []
+    for _ in range(80):
+        state, metrics = trainer.step(state, next(batches))
+        margins.append(float(metrics["reward_margin"]))
+    assert np.all(np.isfinite(margins))
+    assert np.mean(margins[-20:]) > np.mean(margins[:20])
+    held_out = synthetic_preference_batches(8, 32, cfg.vocab_size, seed=100_003)
+    accuracy = float(trainer.evaluate(state, held_out)["eval_dpo_accuracy"])
+    assert accuracy >= 0.7, accuracy
+
+
 def test_dpo_trainer_restrictions():
     cfg = PRESETS["tiny-test"].replace(lora=LoRAConfig(rank=4))
     with pytest.raises(ValueError, match="mode='lora'"):

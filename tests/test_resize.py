@@ -334,7 +334,7 @@ def _run_leg(trace, *, resize, grow_delay_s=5.0):
 
 
 def test_sim_resize_beats_evict_on_progress_lost():
-    """The BENCH_MODE=sched gate, pinned: on the capacity-reclaim trace,
+    """On the capacity-reclaim trace,
     resize strictly beats full eviction on chip-seconds of progress lost,
     with Jain fairness no worse and small-job p95 wait within two exit
     graces of the evict leg."""

@@ -90,8 +90,8 @@ def test_flash_attention_uneven_blocks():
 
 
 def test_flash_tuning_defaults_resolution():
-    """Unset knobs resolve to the measured TPU winners (block 1024; exp
-    dtype following the input dtype — tpu_session.jsonl kernel A/B)."""
+    """Unset knobs resolve to the defaults the three ledger cells run
+    (block 1024; exp dtype following the input dtype)."""
     from finetune_controller_tpu.ops.pallas.flash_attention import (
         DEFAULT_BLOCK,
         _resolve_tuning,
@@ -108,68 +108,102 @@ def test_flash_tuning_defaults_resolution():
     assert _resolve_tuning(q_bf16, 256, 128, "float32") == (256, 128, "float32")
 
 
-def test_flash_tuning_spec_and_env_precedence(monkeypatch):
-    """Round-5: the job's typed kernel config (LlamaConfig.kernel_tuning())
-    seeds the flash knobs; FTC_* env vars override per knob."""
-    from finetune_controller_tpu.models.llama import LlamaConfig
-    from finetune_controller_tpu.ops.attention import flash_tuning_kwargs
+@pytest.mark.parametrize("impl,seq_len,backend,sp,want", [
+    ("auto", 4096, "cpu", 1, "xla"),
+    ("auto", 512, "tpu", 1, "xla"),
+    ("auto", 1024, "tpu", 1, "pallas"),
+    ("auto", 2048, "tpu", 1, "pallas"),   # mistral-7b-qlora.train-sft-2k
+    ("auto", 4096, "tpu", 1, "pallas"),   # joyai-llm-flash-lora.train-sft-4k
+    ("auto", 8192, "tpu", 1, "pallas"),   # mistral-7b-qlora.train-sft-8k
+    ("xla", 8192, "tpu", 1, "xla"),
+    ("pallas", 16, "cpu", 1, "pallas"),
+    ("auto", 8192, "tpu", 2, "ring"),
+    ("xla", 64, "cpu", 2, "ring"),
+    ("pallas", 64, "cpu", 2, "ring"),
+    ("ring", 64, "cpu", 2, "ring"),
+    ("ulysses", 64, "cpu", 2, "ulysses"),
+    ("ring", 64, "cpu", 1, "xla"),
+    ("ulysses", 64, "tpu", 1, "xla"),
+])
+def test_resolve_attention_impl(devices8, impl, seq_len, backend, sp, want):
+    """The one rule: ``auto`` is the flash kernels on a TPU from
+    ``PALLAS_MIN_SEQ``; an ``sp`` axis sends every choice that is not
+    sequence-parallel to ``ring``; without one ``ring``/``ulysses`` are plain
+    attention; an explicit kernel is kept."""
+    from finetune_controller_tpu.ops.attention import resolve_attention_impl
 
-    for var in ("FTC_FLASH_BLOCK_Q", "FTC_FLASH_BLOCK_K",
-                "FTC_FLASH_EXP_DTYPE"):
-        monkeypatch.delenv(var, raising=False)
-
-    cfg = LlamaConfig(
-        flash_block_q=256, flash_block_k=512, flash_exp_dtype="bfloat16",
-        ulysses_inner="pallas", ring_inner="flash",
-    )
-    tuning = cfg.kernel_tuning()
-    assert tuning == {
-        "block_q": 256, "block_k": 512, "exp_dtype": "bfloat16",
-        "ring_inner": "flash", "ulysses_inner": "pallas",
-    }
-    assert flash_tuning_kwargs(tuning) == {
-        "block_q": 256, "block_k": 512, "exp_dtype": "bfloat16"
-    }
-    # env overrides spec, knob by knob
-    monkeypatch.setenv("FTC_FLASH_BLOCK_Q", "1024")
-    monkeypatch.setenv("FTC_FLASH_EXP_DTYPE", "float32")
-    assert flash_tuning_kwargs(tuning) == {
-        "block_q": 1024, "block_k": 512, "exp_dtype": "float32"
-    }
-    # defaults stay empty; invalid spec values fail loudly
-    assert LlamaConfig().kernel_tuning() == {}
-    import pytest
-
-    with pytest.raises(ValueError, match="multiple of 128"):
-        flash_tuning_kwargs({"block_q": 100})
-    with pytest.raises(ValueError, match="float32 or bfloat16"):
-        flash_tuning_kwargs({"exp_dtype": "fp8"})
+    mesh = MeshSpec(dp=1, fsdp=1, sp=sp).build(devices8[:sp])
+    assert resolve_attention_impl(
+        impl, seq_len, mesh=mesh, backend=backend) == want
+    # the mesh the trainer installs is the default
+    with ring_mesh(mesh):
+        assert resolve_attention_impl(impl, seq_len, backend=backend) == want
 
 
-def test_kernel_tuning_flows_from_job_spec():
-    """model_overrides on a job spec land in the resolved LlamaConfig — the
-    API path for shipping measured kernel winners (round-3 weak #5)."""
-    from finetune_controller_tpu.controller.examples import (
-        LoRASFTArguments,
-        TinyTestLoRA,
-    )
-    from finetune_controller_tpu.train.cli import build_model_config
+def test_resolve_attention_impl_refuses_unknown_name():
+    from finetune_controller_tpu.ops.attention import resolve_attention_impl
 
-    class TunedTiny(TinyTestLoRA):
-        model_name = "tiny-tuned-lora"
-        model_overrides = {"flash_block_q": 256, "ulysses_inner": "pallas"}
+    with pytest.raises(ValueError, match="vulkan"):
+        resolve_attention_impl("vulkan", 2048)
+    q, k, v = _qkv(s=16)
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        causal_attention(q, k, v, impl="vulkan")
 
-    spec = TunedTiny(
-        training_arguments=LoRASFTArguments()
-    ).build_trainer_spec("j1", "/tmp/a")
-    assert spec["model"]["overrides"] == {
-        "flash_block_q": 256, "ulysses_inner": "pallas"
-    }
-    cfg = build_model_config(spec)
-    assert cfg.flash_block_q == 256 and cfg.ulysses_inner == "pallas"
-    assert cfg.kernel_tuning() == {
-        "block_q": 256, "ulysses_inner": "pallas"
-    }
+
+@pytest.mark.parametrize("where,want", [
+    ("no_mesh", True),
+    ("one_device_mesh", True),
+    ("four_devices", False),
+    ("inside_shard_map", True),
+])
+def test_bare_mosaic_call_predicate_is_shared(devices8, monkeypatch, where, want):
+    """One predicate says where a Mosaic call may be issued bare; the flash
+    dispatch and the grouped expert product both follow it."""
+    from jax.sharding import PartitionSpec as P
+
+    from finetune_controller_tpu.models import moe
+    from finetune_controller_tpu.ops import attention as attn_mod
+    from finetune_controller_tpu.ops.pallas import bare_mosaic_call_ok
+
+    # the grouped product's other conditions held true: a TPU, rows % 128 == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wrapped = []
+    real_shard_map = jax.shard_map
+    monkeypatch.setattr(
+        attn_mod.jax, "shard_map",
+        lambda *a, **kw: wrapped.append(1) or real_shard_map(*a, **kw))
+    q, k, v = _qkv(b=4, s=16)
+
+    def probe():
+        before = len(wrapped)
+        jax.eval_shape(
+            lambda q, k, v: attn_mod._flash_attention_on_mesh(q, k, v, None),
+            q, k, v)
+        flash_bare = len(wrapped) == before
+        return bare_mosaic_call_ok(), flash_bare, moe._pallas_grouped_dot_ok(256)
+
+    if where == "no_mesh":
+        got = probe()
+    else:
+        n = 1 if where == "one_device_mesh" else 4
+        mesh = MeshSpec(dp=1, fsdp=n).build(devices8[:n])
+        with ring_mesh(mesh):
+            if where == "inside_shard_map":
+                seen = []
+
+                def body(x):
+                    seen.append(probe())
+                    return x
+
+                real_shard_map(
+                    body, mesh=mesh, in_specs=P("fsdp"), out_specs=P("fsdp"),
+                    check_vma=False,   # a pallas_call declares no vma
+                )(jnp.zeros((4,)))
+                (got,) = seen
+            else:
+                got = probe()
+    assert got == (want, want, want)
+    assert not moe._pallas_grouped_dot_ok(100)   # its own row-multiple rule
 
 
 def test_flash_attention_bf16_default_exp_matches_xla():

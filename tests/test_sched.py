@@ -344,7 +344,7 @@ def test_sim_is_deterministic():
 
 
 def test_sim_fairshare_beats_fifo_on_canonical_trace():
-    """The acceptance numbers (also BENCH_MODE=sched): vs FIFO on the same
+    """The acceptance numbers: vs FIFO on the same
     seeded trace, fair-share eliminates head-of-line blocking for small
     jobs, improves the Jain index past 0.8 at steady state, and reports
     preempt->readmit latency."""

@@ -1,5 +1,6 @@
 import jax
 import numpy as np
+import pytest
 
 from finetune_controller_tpu.data import synthetic_batches
 from finetune_controller_tpu.models import PRESETS, LoRAConfig
@@ -173,3 +174,38 @@ def test_grad_accumulation_matches_unsplit_step(devices8):
     with pytest.raises(ValueError, match="batch sharding"):
         Trainer(cfg, TrainConfig(mode="lora", batch_size=8, grad_accum_steps=8),
                 mesh=mesh)
+
+
+@pytest.mark.parametrize("impl,sp,want", [
+    ("auto", 1, "xla"),        # the rule's own answer off the TPU
+    ("pallas", 1, "pallas"),   # an explicit kernel is kept
+    ("xla", 2, "ring"),        # an sp axis shards the sequence
+])
+def test_runtime_attrs_reports_the_traced_impl(
+        devices8, monkeypatch, impl, sp, want):
+    """The ``train-started`` event's ``attention_impl`` is the chooser's
+    answer, and the step traces exactly that: every resolution
+    ``causal_attention`` makes while the step is traced gives it."""
+    from finetune_controller_tpu.ops import attention
+
+    traced = []
+    resolve = attention.resolve_attention_impl
+
+    def spy(*args, **kwargs):
+        traced.append(resolve(*args, **kwargs))
+        return traced[-1]
+
+    # the trainer holds the function itself, so the spy sees only the
+    # model's calls
+    monkeypatch.setattr(attention, "resolve_attention_impl", spy)
+    model_cfg = _tiny_cfg().replace(attention_impl=impl)
+    train_cfg = TrainConfig(total_steps=1, batch_size=4, seq_len=16)
+    mesh = MeshSpec(dp=1, fsdp=1, sp=sp).build(devices8[:sp])
+    trainer = Trainer(model_cfg, train_cfg, mesh=mesh)
+    state = trainer.init_state()
+    traced.clear()
+    batch = next(synthetic_batches(4, 16, model_cfg.vocab_size, task="increment"))
+    _, metrics = trainer.step(state, batch)
+    assert np.isfinite(float(metrics["loss"]))
+    assert traced and set(traced) == {want}
+    assert trainer._runtime_attrs()["attention_impl"] == want

@@ -7,7 +7,7 @@ call per label is compile-exempt).  Integration layer: the trainer's
 jitted step and the serve engine's decode window run CLEAN under
 ``raise`` (zero trips on the default paths), and the ``FTC_FAULT_TRANSFER``
 chaos hand — a real ``jax.device_get`` injected INSIDE the window — aborts
-both, which is exactly the bench.py abort contract for timed windows.
+both, which is exactly the abort contract of the benchmark's timed windows.
 """
 
 import numpy as np
