@@ -11,8 +11,18 @@ Kernel structure (the canonical Mosaic pipeline shape): grid =
 iterated sequentially per core — online-softmax state lives in VMEM scratch
 across inner iterations and Mosaic double-buffers the inner operand's block
 DMAs behind the MXU work. GQA is handled in the index map (q head h reads kv
-head h // group_size), so no K/V duplication ever happens. Causally-skipped
-blocks still DMA (static grid) but skip all compute via ``pl.when``.
+head h // group_size), so no K/V duplication ever happens.
+
+Nothing is done above the causal diagonal.  A block the frontier excludes
+skips all compute via ``pl.when`` AND fetches nothing: the index maps of the
+operands the inner axis sweeps are clamped to the nearest block the frontier
+admits (``_kv_block_index``, ``_q_block_index``), so an excluded step names the
+block already resident and Mosaic issues no copy.  A square block the diagonal
+crosses is computed in row (dK/dV: key) bands of ``DIAG_TILE`` — each against
+what lies at or under its own diagonal tile, which alone builds the position
+mask — so ``c(c+1)/2`` of its ``c²`` sub-tiles are computed
+(``causal_work_over_need`` is the count).  What is left out contributed an
+exact 0.0 to every sum and never raised a row's maximum.
 
 Differentiation is a full Pallas path under ``jax.custom_vjp``:
 
@@ -34,6 +44,7 @@ is ``ops/attention.py::resolve_attention_impl``'s.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 
 import jax
@@ -52,6 +63,74 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 # cells.  Capped to the sequence length at call time, so short-sequence
 # callers are unaffected.
 DEFAULT_BLOCK = 1024
+
+# Width of the sub-tiles a square diagonal block is computed in, capped to
+# the block: 4 a side of a 1024 block, 10 of its 16 computed.  From one v5e
+# timing of the three kernels at the three cells' shapes (PR 31, chip call 2:
+# forward + dQ + dK/dV of a call, ms, at 512 / 256 / 128 — 33.90 / 33.82 /
+# 36.04 at 2 x 8192 d128, 10.82 / 10.73 / 12.95 at 8 x 2048 d128, 14.27 /
+# 14.14 / 15.21 at 2 x 4096 q/k 192 v 128; whole blocks 40.29, 13.57, 17.16).
+# The forward alone prefers 512 by 2-4 %, dQ 256 by as much; one width serves.
+DIAG_TILE = 256
+
+#: the whole block, as a row or key range of it
+ALL = ...
+
+
+def _padded_len(s: int, bq: int, bk: int) -> int:
+    """``s`` rounded up to a common multiple of both blocks."""
+    return math.lcm(bq, bk) * pl.cdiv(s, math.lcm(bq, bk))
+
+
+def _diag_tiles(bq: int, bk: int, causal: bool) -> int:
+    """Sub-tiles a side of a block the causal diagonal crosses; 1 = the block
+    is computed whole under its mask (no diagonal, a block that is not square
+    or no wider than the sub-tile)."""
+    t = min(DIAG_TILE, bq)
+    if causal and bq == bk and bq > t and bq % t == 0:
+        return bq // t
+    return 1
+
+
+def _kv_block_index(iq, ik, bq: int, bk: int, causal: bool):
+    """The K/V (and key segment id) block step ``(iq, ik)`` of the forward and
+    dQ grids names: its own where the causal frontier admits it, else the
+    last admitted one — already resident, so nothing is copied."""
+    if not causal:
+        return ik
+    return jnp.minimum(ik, ((iq + 1) * bq - 1) // bk)
+
+
+def _q_block_index(ik, j, nq: int, bq: int, bk: int, causal: bool):
+    """The q-side (Q, dO, lse, delta, query segment id) block inner step ``j``
+    of the dK/dV grid names for key block ``ik``: its own, ``j % nq``, where
+    the frontier admits it, else the first admitted one — the block the next
+    computing step wants."""
+    iq = j % nq
+    if not causal:
+        return iq
+    return jnp.maximum(iq, (ik * bk) // bq)
+
+
+def causal_work_over_need(
+    seq: int, block_q: int | None = None, block_k: int | None = None
+) -> float:
+    """Score area the causal kernels compute ÷ the causal triangle's, S²/2
+    (what ``benchmarks/harness/counts.py`` charges a call): every admitted
+    block whole, a sub-tiled diagonal block by the sub-tiles at or under its
+    diagonal.  Static, like the mechanism: a function of the row length and
+    the blocks alone."""
+    bq = min(block_q or DEFAULT_BLOCK, seq)
+    bk = min(block_k or DEFAULT_BLOCK, seq)
+    s_pad = _padded_len(seq, bq, bk)
+    c = _diag_tiles(bq, bk, True)
+    area = 0
+    for iq in range(s_pad // bq):
+        for ik in range(s_pad // bk):
+            if ik * bk <= (iq + 1) * bq - 1:
+                diagonal = c > 1 and iq == ik
+                area += (c * (c + 1) // 2) * (bq // c) ** 2 if diagonal else bq * bk
+    return area / (seq * seq / 2)
 
 
 def _resolve_tuning(
@@ -80,9 +159,10 @@ def _dimension_semantics(*sem):
     return pltpu.CompilerParams(dimension_semantics=sem)
 
 
-def _segment_mask(qseg_ref, kseg_ref):
-    """(bq, bk) same-segment mask from the (1, 1, b*) segment-id refs."""
-    return qseg_ref[0, 0][:, None] == kseg_ref[0, 0][None, :]
+def _segment_mask(qseg_ref, kseg_ref, rows=ALL, keys=ALL):
+    """Same-segment mask of the block's ``rows`` x ``keys`` from the
+    (1, 1, b*) segment-id refs."""
+    return qseg_ref[0, 0, rows][:, None] == kseg_ref[0, 0, keys][None, :]
 
 
 def _block_positions(iq, ik, bq, bk):
@@ -93,6 +173,55 @@ def _block_positions(iq, ik, bq, bk):
     q_pos = iq * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     k_pos = ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     return q_pos, k_pos
+
+
+def _diagonal_tiles(tiles, block, first, seq_len, segment_refs, key_bands=False):
+    """The sub-tiles of a square diagonal block at or under its diagonal, as
+    ``(rows, keys, mask)`` with ``mask`` None where every pair is valid.
+
+    The block (first query = first key = ``first``) is cut in ``tiles`` bands
+    of rows — of keys with ``key_bands``, the dK/dV kernel's accumulators —
+    and each band meets its own diagonal tile plus, in ONE tile, all that
+    lies left of it (``key_bands``: under it).  Only a tile ON the diagonal
+    builds the position mask, and since its rows and keys start together the
+    compare is position-free: one mask serves all of them.  The skip is by
+    position alone; what is computed still gets the padded tail's mask (keys
+    beyond ``seq_len``; ``key_bands``: queries, as in the whole-block paths)
+    and the segment mask."""
+    t = block // tiles
+    on_diagonal = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+                   >= jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
+    padded = 0 if key_bands else 1  # axis of the score tile that may be padding
+    for i in range(tiles):
+        band = slice(i * t, (i + 1) * t)
+        rest = slice((i + 1) * t, block) if key_bands else slice(0, i * t)
+        for off_band, mask in ((band, on_diagonal), (rest, None)):
+            rows, keys = (off_band, band) if key_bands else (band, off_band)
+            shape = (rows.stop - rows.start, keys.stop - keys.start)
+            if not all(shape):
+                continue
+            valid = []
+            if seq_len % block:
+                pos = first + (rows, keys)[padded].start + (
+                    jax.lax.broadcasted_iota(jnp.int32, shape, padded))
+                valid.append(pos < seq_len)
+            if segment_refs is not None:
+                valid.append(_segment_mask(*segment_refs, rows, keys))
+            for other in valid:
+                mask = other if mask is None else mask & other
+            yield rows, keys, mask
+
+
+def _reduce_rows(parts, combine, reduce):
+    """``reduce`` every row over the key ranges ``parts`` (same rows; widths
+    multiples of the narrowest) with ONE reduction across lanes: the parts
+    are folded chunk by chunk elementwise first, in float32."""
+    if len(parts) == 1:
+        return reduce(parts[0])
+    width = min(part.shape[1] for part in parts)
+    chunks = [part[:, at:at + width].astype(jnp.float32)
+              for part in parts for at in range(0, part.shape[1], width)]
+    return reduce(functools.reduce(combine, chunks))
 
 
 # ---------------------------------------------------------------------------
@@ -117,6 +246,7 @@ def _fwd_kernel(
     use_segments: bool,
     exp_dtype: str = "float32",
     causal: bool = True,
+    diag_tiles: int = 1,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -146,12 +276,17 @@ def _fwd_kernel(
         needed = ik * bk < seq_len
         interior = (ik + 1) * bk <= seq_len
 
-    def _online_update(s, mask):
-        """Shared online-softmax update; ``mask`` None = fully valid block."""
-        if mask is not None:
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    def _online_update(parts, rows=ALL):
+        """ONE online-softmax update of the block's ``rows`` (the state is per
+        row, so a row band updates its own slice) with ``parts``: the scores
+        ``s`` of key ranges ``keys``, each under its ``mask`` (None = every
+        pair valid)."""
+        parts = [(s if mask is None else jnp.where(mask, s, NEG_INF), mask, keys)
+                 for s, mask, keys in parts]
+        m_prev = m_ref[rows]
+        m_new = jnp.maximum(m_prev, _reduce_rows(
+            [s for s, _, _ in parts], jnp.maximum,
+            lambda s: jnp.max(s, axis=-1, keepdims=True)))
         # zero p under the mask explicitly: for a fully-masked row m_new is
         # still NEG_INF and exp(s - m_new) would be exp(0) = 1 per lane,
         # accumulating l = block count instead of 0.
@@ -160,48 +295,68 @@ def _fwd_kernel(
         # (safe: arguments are <= 0, so the bf16 range is never stressed;
         # precision is ~3 decimal digits on a probability-like quantity).
         # f32 stays the default until the chip A/B proves a win.
-        diff = s - m_new
-        p = jnp.exp(diff if edt == jnp.float32 else diff.astype(edt))
-        if mask is not None:
-            p = jnp.where(mask, p, jnp.zeros((), p.dtype))
+        ps = []
+        for s, mask, _ in parts:
+            diff = s - m_new
+            p = jnp.exp(diff if edt == jnp.float32 else diff.astype(edt))
+            if mask is not None:
+                p = jnp.where(mask, p, jnp.zeros((), p.dtype))
+            ps.append(p)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(
-            p, axis=-1, keepdims=True, dtype=jnp.float32
-        )
+        l_ref[rows] = l_ref[rows] * alpha + _reduce_rows(
+            ps, jnp.add,
+            lambda p: jnp.sum(p, axis=-1, keepdims=True, dtype=jnp.float32))
         # p rounds to the value dtype for the MXU (the FlashAttention-2
         # recipe); accumulation stays f32 in VMEM scratch
-        v = v_ref[0, 0]
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
+        vs = [v_ref[0, 0, keys] for _, _, keys in parts]
+        acc = acc_ref[rows] * alpha
+        for p, v in zip(ps, vs):
+            acc = acc + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        acc_ref[rows] = acc
+        m_ref[rows] = m_new
 
-    def _scores():
+    def _scores(rows=ALL, keys=ALL):
         # matmul inputs stay in their storage dtype (bf16 in production) with
         # f32 MXU accumulation; the scale folds in AFTER the dot, in f32
         return jax.lax.dot_general(
-            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            q_ref[0, 0, rows], k_ref[0, 0, keys], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # (bq, bk) f32
+        ) * scale  # (rows, keys) f32
 
-    @pl.when(needed & ~interior)
-    def _compute_masked():
-        s = _scores()
-        q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-        mask = k_pos < seq_len  # tail block: beyond-S lanes are padding
-        if causal:
-            mask &= q_pos >= k_pos
-        if use_segments:
-            mask &= _segment_mask(qseg_ref, kseg_ref)
-        _online_update(s, mask)
+    segment_refs = (qseg_ref, kseg_ref) if use_segments else None
+    if diag_tiles > 1:
+        # square blocks: a needed block left of the diagonal holds real keys
+        # only, so the one block that is not interior is the diagonal one
+        @pl.when(iq == ik)
+        def _compute_diagonal():
+            # a row band's tiles in ONE update: what an update costs a row
+            # (reductions across lanes, rescaling) is paid once a band
+            tiles = _diagonal_tiles(diag_tiles, bq, iq * bq, seq_len, segment_refs)
+            for rows, band in itertools.groupby(tiles, key=lambda tile: tile[0]):
+                _online_update([(_scores(rows, keys), mask, keys)
+                                for _, keys, mask in band], rows)
+    else:
+        @pl.when(needed & ~interior)
+        def _compute_masked():
+            s = _scores()
+            q_pos, k_pos = _block_positions(iq, ik, bq, bk)
+            mask = k_pos < seq_len  # tail block: beyond-S lanes are padding
+            if causal:
+                mask &= q_pos >= k_pos
+            if use_segments:
+                mask &= _segment_mask(qseg_ref, kseg_ref)
+            _online_update([(s, mask, ALL)])
 
     @pl.when(needed & interior)
     def _compute_interior():
-        _online_update(
+        _online_update([(
             _scores(),
             _segment_mask(qseg_ref, kseg_ref) if use_segments else None,
-        )
+            ALL,
+        )])
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -221,7 +376,7 @@ def _pad_inputs(q, k, v, segment_ids, bq, bk, kv_segment_ids=None):
     s = q.shape[1]
     if kv_segment_ids is None:
         kv_segment_ids = segment_ids
-    s_pad = math.lcm(bq, bk) * pl.cdiv(s, math.lcm(bq, bk))
+    s_pad = _padded_len(s, bq, bk)
     if s_pad != s:
         pad = [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]
         q = jnp.pad(q, pad)
@@ -271,17 +426,19 @@ def _flash_forward(
     nq = pl.cdiv(s_pad, bq)
     nk = pl.cdiv(s_pad, bk)
 
+    kv = functools.partial(_kv_block_index, bq=bq, bk=bk, causal=causal)
+
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, seq_len=s, scale=scale,
                           use_segments=use_segments, exp_dtype=exp_dtype,
-                          causal=causal),
+                          causal=causal, diag_tiles=_diag_tiles(bq, bk, causal)),
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, kv(iq, ik), 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, iq, ik: (ib, ih // group, kv(iq, ik), 0)),
             pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, 0, iq)),
-            pl.BlockSpec((1, 1, bk), lambda ib, ih, iq, ik: (ib, 0, ik)),
+            pl.BlockSpec((1, 1, bk), lambda ib, ih, iq, ik: (ib, 0, kv(iq, ik))),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -328,6 +485,7 @@ def _bwd_dq_kernel(
     use_segments: bool,
     exp_dtype: str = "float32",
     causal: bool = True,
+    diag_tiles: int = 1,
 ):
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -347,15 +505,15 @@ def _bwd_dq_kernel(
         needed = ik * bk < seq_len
         interior = (ik + 1) * bk <= seq_len
 
-    def _update(mask):
+    def _update(mask, rows=ALL, keys=ALL):
         # storage-dtype (bf16) matmul inputs + f32 accumulation — see the
         # forward kernel's note; the scale folds in after the s dot
-        q = q_ref[0, 0]                                        # (bq, d)
-        k = k_ref[0, 0]
-        v = v_ref[0, 0]
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                                    # (bq, 1)
-        delta = delta_ref[0, 0]
+        q = q_ref[0, 0, rows]                                  # (rows, d)
+        k = k_ref[0, 0, keys]
+        v = v_ref[0, 0, keys]
+        do = do_ref[0, 0, rows]
+        lse = lse_ref[0, 0, rows]                              # (rows, 1)
+        delta = delta_ref[0, 0, rows]
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -369,20 +527,29 @@ def _bwd_dq_kernel(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
         ds = p * (dp - delta)
-        dq_acc[...] += jax.lax.dot_general(
+        dq_acc[rows] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(needed & ~interior)
-    def _compute_masked():
-        q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-        mask = k_pos < seq_len
-        if causal:
-            mask &= q_pos >= k_pos
-        if use_segments:
-            mask &= _segment_mask(qseg_ref, kseg_ref)
-        _update(mask)
+    segment_refs = (qseg_ref, kseg_ref) if use_segments else None
+    if diag_tiles > 1:
+        # as in the forward kernel: the one non-interior block, in row bands
+        @pl.when(iq == ik)
+        def _compute_diagonal():
+            for rows, keys, mask in _diagonal_tiles(
+                    diag_tiles, bq, iq * bq, seq_len, segment_refs):
+                _update(mask, rows, keys)
+    else:
+        @pl.when(needed & ~interior)
+        def _compute_masked():
+            q_pos, k_pos = _block_positions(iq, ik, bq, bk)
+            mask = k_pos < seq_len
+            if causal:
+                mask &= q_pos >= k_pos
+            if use_segments:
+                mask &= _segment_mask(qseg_ref, kseg_ref)
+            _update(mask)
 
     @pl.when(needed & interior)
     def _compute_interior():
@@ -413,6 +580,7 @@ def _bwd_dkv_kernel(
     use_segments: bool,
     exp_dtype: str = "float32",
     causal: bool = True,
+    diag_tiles: int = 1,
 ):
     ik, j = pl.program_id(2), pl.program_id(3)
     n_inner = pl.num_programs(3)   # = group * n_q_blocks
@@ -436,16 +604,16 @@ def _bwd_dkv_kernel(
         needed = iq * bq < seq_len
         interior = (iq + 1) * bq <= seq_len
 
-    def _update(mask):
+    def _update(mask, rows=ALL, keys=ALL):
         # storage-dtype (bf16) matmul inputs + f32 accumulation — see the
         # forward kernel's note; the scale folds in after the s dot and at
         # the dK finalize (it used to ride on a pre-scaled f32 q)
-        k = k_ref[0, 0]                                        # (bk, d)
-        v = v_ref[0, 0]
-        q = q_ref[0, 0]                                        # (bq, d)
-        do = do_ref[0, 0]
-        lse = lse_ref[0, 0]                                    # (bq, 1)
-        delta = delta_ref[0, 0]
+        k = k_ref[0, 0, keys]                                  # (keys, d)
+        v = v_ref[0, 0, keys]
+        q = q_ref[0, 0, rows]                                  # (rows, d)
+        do = do_ref[0, 0, rows]
+        lse = lse_ref[0, 0, rows]                              # (rows, 1)
+        delta = delta_ref[0, 0, rows]
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -456,7 +624,7 @@ def _bwd_dkv_kernel(
             p = jnp.where(mask, p, jnp.zeros((), p.dtype))
 
         # dV += pᵀ · dO
-        dv_acc[...] += jax.lax.dot_general(
+        dv_acc[keys] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
@@ -465,20 +633,37 @@ def _bwd_dkv_kernel(
         )
         ds = p * (dp - delta)
         # dK += scale · dsᵀ · q (scale applied once, at finalize)
-        dk_acc[...] += jax.lax.dot_general(
+        dk_acc[keys] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
 
-    @pl.when(needed & ~interior)
-    def _compute_masked():
-        q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-        mask = q_pos < seq_len
-        if causal:
-            mask &= q_pos >= k_pos
-        if use_segments:
-            mask &= _segment_mask(qseg_ref, kseg_ref)
-        _update(mask)
+    segment_refs = (qseg_ref, kseg_ref) if use_segments else None
+    masked = needed & ~interior
+    tail = seq_len % bq != 0
+    if diag_tiles > 1:
+        # the diagonal block in key bands (the accumulators are per key),
+        # each against the queries at or under its own diagonal tile
+        masked &= iq != ik
+
+        @pl.when(iq == ik)
+        def _compute_diagonal():
+            for rows, keys, mask in _diagonal_tiles(
+                    diag_tiles, bk, ik * bk, seq_len, segment_refs, key_bands=True):
+                _update(mask, rows, keys)
+
+    # with sub-tiled diagonals only a tail leaves whole blocks to mask: the
+    # last q block's padded queries, against every key block left of it
+    if diag_tiles == 1 or tail:
+        @pl.when(masked)
+        def _compute_masked():
+            q_pos, k_pos = _block_positions(iq, ik, bq, bk)
+            mask = q_pos < seq_len
+            if causal:
+                mask &= q_pos >= k_pos
+            if use_segments:
+                mask &= _segment_mask(qseg_ref, kseg_ref)
+            _update(mask)
 
     @pl.when(needed & interior)
     def _compute_interior():
@@ -533,20 +718,25 @@ def _flash_backward(
     nq = pl.cdiv(s_pad, bq)
     nk = pl.cdiv(s_pad, bk)
 
+    diag_tiles = _diag_tiles(bq, bk, causal)
+
+    kv = functools.partial(_kv_block_index, bq=bq, bk=bk, causal=causal)
+    qb = functools.partial(_q_block_index, nq=nq, bq=bq, bk=bk, causal=causal)
+
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, seq_len=s, scale=scale,
                           use_segments=use_segments, exp_dtype=exp_dtype,
-                          causal=causal),
+                          causal=causal, diag_tiles=diag_tiles),
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
-            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, iq, ik: (ib, ih // group, ik, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, kv(iq, ik), 0)),
+            pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, iq, ik: (ib, ih // group, kv(iq, ik), 0)),
             pl.BlockSpec((1, 1, bq, d_v), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bq), lambda ib, ih, iq, ik: (ib, 0, iq)),
-            pl.BlockSpec((1, 1, bk), lambda ib, ih, iq, ik: (ib, 0, ik)),
+            pl.BlockSpec((1, 1, bk), lambda ib, ih, iq, ik: (ib, 0, kv(iq, ik))),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s_pad, d), q.dtype),
@@ -565,6 +755,7 @@ def _flash_backward(
         functools.partial(
             _bwd_dkv_kernel, n_q_blocks=nq, seq_len=s, scale=scale,
             use_segments=use_segments, exp_dtype=exp_dtype, causal=causal,
+            diag_tiles=diag_tiles,
         ),
         grid=(b, hkv, nk, group * nq),
         in_specs=[
@@ -572,22 +763,22 @@ def _flash_backward(
             pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
             pl.BlockSpec(
                 (1, 1, bq, d),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, j % nq, 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
             ),
             pl.BlockSpec(
                 (1, 1, bq, d_v),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, j % nq, 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
             ),
             pl.BlockSpec(
                 (1, 1, bq, 1),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, j % nq, 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
             ),
             pl.BlockSpec(
                 (1, 1, bq, 1),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, j % nq, 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
             ),
             pl.BlockSpec((1, 1, bk), lambda ib, ih, ik, j: (ib, 0, ik)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, ik, j: (ib, 0, j % nq)),
+            pl.BlockSpec((1, 1, bq), lambda ib, ih, ik, j: (ib, 0, qb(ik, j))),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, j: (ib, ih, ik, 0)),

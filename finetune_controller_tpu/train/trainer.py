@@ -1588,17 +1588,25 @@ class Trainer:
     def _runtime_attrs(self) -> dict:
         """Where this run landed, for the ``train-started`` event: the device
         as JAX reports it, the mesh, which attention implementation the step
-        resolves to, and the bytes the freshly-initialised state holds on
-        each local device.  The control plane (and ``chip_smoke.py``) stays
+        resolves to (with the flash kernels, how much score area they compute
+        over what the causal triangle needs), and the bytes the
+        freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
         off JAX and learns the device from this."""
         from ..platform import device_report
 
-        return {
+        attrs = {
             **device_report(),
             "mesh": {k: v for k, v in self.mesh.shape.items() if v > 1},
             "attention_impl": self.attention_impl,
             "device_state_bytes": self._device_bytes("bytes_in_use"),
         }
+        if self.attention_impl == "pallas":
+            from ..ops.pallas.flash_attention import causal_work_over_need
+
+            # score area the flash kernels compute over the causal triangle's
+            attrs["flash_causal_work_over_need"] = causal_work_over_need(
+                self.cfg.seq_len)
+        return attrs
 
     def _device_bytes(self, stat: str) -> list[int] | None:
         """``memory_stats()[stat]`` of every local device, or None where the

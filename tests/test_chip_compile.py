@@ -77,11 +77,15 @@ def _custom_calls(compiled) -> int:
 
 #: (B, S, H, Hkv, D[, Dv]): the TinyLlama training shape chip_smoke.py runs,
 #: a head-dim-128 GQA shape (Llama-3 / Mistral heads at a batch that fits),
-#: and latent attention's uneven pair (q/k 128 + 64, v 128; 192 is no
-#: multiple of the 128 lanes) at the expert cell's batch
+#: the two Mistral cells' calls (8 x 2048 and 2 x 8192), and latent
+#: attention's uneven pair (q/k 128 + 64, v 128; 192 is no multiple of the
+#: 128 lanes) at the expert cell's batch.  Every one runs 1024-wide blocks
+#: whose diagonal ones are computed in sub-tiles.
 FLASH_SHAPES = {
     "tinyllama-b8-s2048": (8, 2048, 32, 4, 64),
     "d128-b2-s2048": (2, 2048, 32, 8, 128),
+    "d128-b8-s2048": (8, 2048, 32, 8, 128),
+    "d128-b2-s8192": (2, 8192, 32, 8, 128),
     "qk192-v128-b2-s4096": (2, 4096, 32, 32, 192, 128),
 }
 
@@ -89,7 +93,9 @@ FLASH_SHAPES = {
 @pytest.mark.parametrize("shape,segments", [
     ("tinyllama-b8-s2048", False), ("tinyllama-b8-s2048", True),
     ("d128-b2-s2048", False), ("qk192-v128-b2-s4096", False),
-], ids=["tinyllama-plain", "tinyllama-segments", "d128-plain", "qk192-v128"])
+    ("d128-b8-s2048", False), ("d128-b2-s8192", False), ("d128-b2-s8192", True),
+], ids=["tinyllama-plain", "tinyllama-segments", "d128-plain", "qk192-v128",
+        "mistral-2k-plain", "mistral-8k-plain", "mistral-8k-segments"])
 def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
     b, s, h, hkv, d, *rest = FLASH_SHAPES[shape]
     one = SingleDeviceSharding(v5e[0])

@@ -89,6 +89,197 @@ def test_flash_attention_uneven_blocks():
     np.testing.assert_allclose(out, ref, atol=1e-5)
 
 
+def _masked_reference(q, k, v, seg, kv_seg):
+    """Plain causal attention under query / key segment ids that may differ:
+    a row no key answers gives zeros (the kernels' masked-row rule)."""
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    qr = q.reshape(b, s, hkv, h // hkv, d) * d ** -0.5
+    scores = jnp.einsum("bskgd,btkd->bkgst", qr, k).astype(jnp.float32)
+    pos = jnp.arange(s)
+    mask = (pos[:, None] >= pos[None, :]) & (seg[:, :, None] == kv_seg[:, None, :])
+    mask = mask[:, None, None]
+    scores = jnp.where(mask, scores, -1e30)
+    p = jnp.exp(scores - scores.max(-1, keepdims=True)) * mask
+    p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
+    return jnp.einsum("bkgst,btkd->bskgd", p, v).reshape(b, s, h, v.shape[-1])
+
+
+def _segments(s, *bounds, b=2):
+    """(b, s) segment ids 0, 1, … changing at each of ``bounds``."""
+    ids = sum((jnp.arange(s) >= at).astype(jnp.int32) for at in bounds)
+    return jnp.broadcast_to(ids, (b, s))
+
+
+#: name -> (shape of _qkv, blocks, segment boundaries, sub-tiles a side of a
+#: diagonal block at DIAG_TILE = 8; 1 = today's whole-block masked path)
+DIAGONAL_CASES = {
+    # block 4 x the sub-tile, three blocks a side, one kv head for four q heads
+    "subtiled-gqa4": (dict(s=96, h=4, hkv=1), (32, 32), (), 4),
+    # latent attention's heads: the 192-wide contraction stays whole
+    "subtiled-qk192-v128": (dict(s=64, h=2, hkv=2, d=192, dv=128), (32, 32), (), 4),
+    # 80 = 2.5 blocks: the last diagonal block holds 16 real and 16 padded rows
+    "subtiled-padded-tail": (dict(s=80), (32, 32), (), 4),
+    # a boundary at 44: inside the second diagonal block, inside a sub-tile
+    "subtiled-two-segments": (dict(s=64), (32, 32), (44,), 4),
+    "subtiled-three-segments": (dict(s=96), (32, 32), (12, 50), 4),
+    "subtiled-tail-and-segments": (dict(s=80, h=4, hkv=1), (32, 32), (27, 70), 4),
+    "blocks-differ-falls-back": (dict(s=64), (32, 16), (40,), 1),
+    "block-no-wider-than-tile-falls-back": (dict(s=40), (8, 8), (20,), 1),
+}
+
+
+@pytest.mark.parametrize("case", [*DIAGONAL_CASES, "subtiled-fully-masked-rows"])
+def test_flash_diagonal_blocks_match_xla(monkeypatch, case):
+    """Output and q/k/v gradients against the XLA path where a diagonal block
+    is computed as the sub-tiles under its diagonal (and where it must not
+    be): the skip is by position alone, tail and segment masks still apply."""
+    from finetune_controller_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "DIAG_TILE", 8)
+    masked_rows = case == "subtiled-fully-masked-rows"
+    shape, (bq, bk), bounds, tiles = (
+        (dict(s=64), (32, 32), (), 4) if masked_rows else DIAGONAL_CASES[case])
+    q, k, v = _qkv(**shape)
+    assert fa._diag_tiles(bq, bk, True) == tiles
+    if masked_rows:
+        # queries 36..43 (inside a diagonal block) are of a segment no key has
+        seg = jnp.where((jnp.arange(64) >= 36) & (jnp.arange(64) < 44), 7, 0)
+        seg = jnp.broadcast_to(seg.astype(jnp.int32), (2, 64))
+        kv_seg = jnp.zeros((2, 64), jnp.int32)
+
+        def run(q, k, v):
+            return fa.flash_attention_with_lse(
+                q, k, v, segment_ids=seg, kv_segment_ids=kv_seg,
+                block_q=bq, block_k=bk)[0]
+
+        def ref(q, k, v):
+            return _masked_reference(q, k, v, seg, kv_seg)
+    else:
+        seg = _segments(q.shape[1], *bounds) if bounds else None
+
+        def run(q, k, v):
+            return flash_attention(q, k, v, segment_ids=seg, block_q=bq, block_k=bk)
+
+        def ref(q, k, v):
+            return xla_causal_attention(q, k, v, segment_ids=seg)
+
+    weight = jax.random.normal(jax.random.PRNGKey(3), ref(q, k, v).shape)
+    out, grads = jax.value_and_grad(
+        lambda *a: (run(*a) * weight).sum(), argnums=(0, 1, 2))(q, k, v)
+    want, want_grads = jax.value_and_grad(
+        lambda *a: (ref(*a) * weight).sum(), argnums=(0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(run(q, k, v), ref(q, k, v), atol=2e-5)
+    np.testing.assert_allclose(out, want, rtol=1e-5)
+    for got, exp in zip(grads, want_grads):
+        np.testing.assert_allclose(got, exp, atol=1e-4)
+    if masked_rows:
+        assert not np.asarray(run(q, k, v))[:, 36:44].any()
+        assert not np.asarray(grads[0])[:, 36:44].any()
+
+
+@pytest.mark.parametrize("seq", [2048, 4096, 8192])
+def test_flash_excluded_steps_name_a_resident_block(seq):
+    """The clamped index maps at the cells' grids (block 1024): a step the
+    causal frontier admits names its own block; an excluded step names the
+    block of its computing neighbour — the last admitted one before it in
+    the forward / dQ sweep over keys, the first admitted one after it in
+    the dK/dV sweep over queries — so no copy is issued for it."""
+    from finetune_controller_tpu.ops.pallas.flash_attention import (
+        DEFAULT_BLOCK as B,
+        _kv_block_index,
+        _q_block_index,
+    )
+
+    n, group = seq // B, 4
+    excluded = 0
+    for iq in range(n):
+        for ik in range(n):
+            named = int(_kv_block_index(iq, ik, B, B, True))
+            if ik <= iq:
+                assert named == ik
+            else:
+                excluded += 1
+                assert named == int(_kv_block_index(iq, ik - 1, B, B, True)) == iq
+            assert _kv_block_index(iq, ik, B, B, False) == ik
+    assert excluded == n * (n - 1) // 2  # 1 of 4, 6 of 16, 28 of 64
+    for ik in range(n):
+        for j in reversed(range(group * n)):
+            named = int(_q_block_index(ik, j, n, B, B, True))
+            if j % n >= ik:
+                assert named == j % n
+            else:
+                assert named == int(_q_block_index(ik, j + 1, n, B, B, True)) == ik
+            assert _q_block_index(ik, j, n, B, B, False) == j % n
+
+
+def test_flash_index_maps_with_blocks_that_differ():
+    """Frontier arithmetic for bq != bk: the clamp is the kernels' own
+    ``needed`` — every admitted step keeps its block, every other names an
+    admitted one."""
+    from finetune_controller_tpu.ops.pallas.flash_attention import (
+        _kv_block_index,
+        _q_block_index,
+    )
+
+    for bq, bk in [(32, 16), (16, 32)]:
+        nq, nk = 96 // bq, 96 // bk
+        for iq in range(nq):
+            for ik in range(nk):
+                needed = ik * bk <= (iq + 1) * bq - 1
+                named = int(_kv_block_index(iq, ik, bq, bk, True))
+                assert named == ik if needed else (
+                    named < ik and named * bk <= (iq + 1) * bq - 1)
+                named = int(_q_block_index(ik, iq, nq, bq, bk, True))
+                assert named == iq if needed else (
+                    named > iq and (named + 1) * bq - 1 >= ik * bk)
+
+
+def test_flash_causal_work_over_need():
+    """The static counter: whole 1024-wide blocks compute n(n+1)/2 of the
+    triangle's n²/2; with 256-wide sub-tiles 10 of a diagonal block's 16."""
+    from finetune_controller_tpu.ops.pallas import flash_attention as fa
+
+    assert fa.DEFAULT_BLOCK // min(fa.DIAG_TILE, fa.DEFAULT_BLOCK) == 4
+    got = [fa.causal_work_over_need(s) for s in (2048, 4096, 8192)]
+    assert got == [1.125, 1.0625, 1.03125]
+    # blocks that fall back are computed whole
+    assert fa.causal_work_over_need(2048, 1024, 512) == 1.5
+    assert fa.causal_work_over_need(4096, 256, 256) == 17 / 16
+
+
+def test_flash_whole_block_work_is_the_parents(monkeypatch):
+    """With no sub-tile narrower than the block: n(n+1)/2 blocks for n²/2."""
+    from finetune_controller_tpu.ops.pallas import flash_attention as fa
+
+    monkeypatch.setattr(fa, "DIAG_TILE", fa.DEFAULT_BLOCK)
+    got = [fa.causal_work_over_need(s) for s in (2048, 4096, 8192)]
+    assert got == [1.5, 1.25, 1.125]
+
+
+@pytest.mark.parametrize("call,dots", [
+    ("noncausal-1024", 4),       # masked + interior, whole blocks
+    ("causal-small-block", 4),   # falls back to the same two paths
+    ("causal-1024", 16),         # 4 diagonal + 3 left updates, + interior
+])
+def test_flash_forward_kernel_paths_by_products(call, dots):
+    """``causal=False`` (the ring / Ulysses off-diagonal hops) and blocks no
+    wider than the sub-tile trace the parent's forward kernel — two products
+    in each of its two paths — and only a sub-tiled causal call holds the
+    band loop's."""
+    from finetune_controller_tpu.ops.pallas.flash_attention import (
+        flash_attention_with_lse,
+    )
+
+    causal = call != "noncausal-1024"
+    s, block = (64, 16) if call == "causal-small-block" else (2048, None)
+    q = jax.ShapeDtypeStruct((1, s, 2, 16), jnp.float32)
+    text = str(jax.make_jaxpr(lambda q, k, v: flash_attention_with_lse(
+        q, k, v, causal=causal, block_q=block, block_k=block,
+        interpret=True))(q, q, q))
+    assert text.count("dot_general") == dots
+
+
 def test_flash_tuning_defaults_resolution():
     """Unset knobs resolve to the defaults the three ledger cells run
     (block 1024; exp dtype following the input dtype)."""

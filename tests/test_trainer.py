@@ -185,8 +185,12 @@ def test_runtime_attrs_reports_the_traced_impl(
         devices8, monkeypatch, impl, sp, want):
     """The ``train-started`` event's ``attention_impl`` is the chooser's
     answer, and the step traces exactly that: every resolution
-    ``causal_attention`` makes while the step is traced gives it."""
+    ``causal_attention`` makes while the step is traced gives it.  With the
+    flash kernels it also carries ``flash_causal_work_over_need``."""
     from finetune_controller_tpu.ops import attention
+    from finetune_controller_tpu.ops.pallas.flash_attention import (
+        causal_work_over_need,
+    )
 
     traced = []
     resolve = attention.resolve_attention_impl
@@ -208,4 +212,10 @@ def test_runtime_attrs_reports_the_traced_impl(
     _, metrics = trainer.step(state, batch)
     assert np.isfinite(float(metrics["loss"]))
     assert traced and set(traced) == {want}
-    assert trainer._runtime_attrs()["attention_impl"] == want
+    attrs = trainer._runtime_attrs()
+    assert attrs["attention_impl"] == want
+    # the flash kernels' static counter rides along exactly when they run
+    if want == "pallas":
+        assert attrs["flash_causal_work_over_need"] == causal_work_over_need(16)
+    else:
+        assert "flash_causal_work_over_need" not in attrs
