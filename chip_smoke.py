@@ -114,6 +114,84 @@ print(json.dumps({"device": device_report(), "dtype": dtype_name,
 """
 
 
+#: max |kernel - ragged_dot| the grouped-product parity child allows, as a
+#: share of the reference's largest magnitude: two roundings of bf16 at the
+#: top of the outputs' range (both accumulate in float32; the tiles change
+#: the order)
+GROUPED_TOL = 2 ** -6
+
+#: runs in a child BEFORE the server starts (nothing holds the chip yet):
+#: ``models/moe.py::_grouped_dot`` at the tiles its rule picks — on a TPU the
+#: Pallas megablox
+#: kernel — against ``jax.lax.ragged_dot``, values and activation gradient,
+#: the weights ARGUMENTS of the jit (closed over they would be constants of
+#: the program: gigabytes of host memory), on group sizes even, skewed, with
+#: empty groups and with rows no group covers.  A tile that compiles and
+#: then faults dies here in a minute.
+GROUPED_PARITY_SNIPPET = r"""
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from finetune_controller_tpu.platform import device_report, enable_compile_cache
+from finetune_controller_tpu.models import moe
+
+enable_compile_cache()
+shapes = json.loads(sys.argv[1])
+on_tpu = jax.default_backend() == "tpu"
+
+
+def loads(rng, m, g):
+    even = np.bincount(rng.integers(0, g, m), minlength=g)
+    p = np.exp(1.2 * rng.standard_normal(g))
+    skewed = np.bincount(rng.choice(g, m, p=p / p.sum()), minlength=g)
+    p[rng.permutation(g)[: max(1, g // 5)]] = 0
+    empty = np.bincount(rng.choice(g, m, p=p / p.sum()), minlength=g)
+    uncovered = np.bincount(rng.integers(0, g, m - m // 3), minlength=g)
+    return {"even": even, "skewed": skewed, "empty_groups": empty,
+            "rows_no_group_covers": uncovered}
+
+
+def both(dot):
+    # the product and its activation gradient under a fixed cotangent
+    def f(rows, kernels, sizes, layer, cot):
+        out, vjp = jax.vjp(lambda r: dot(r, kernels, sizes, layer), rows)
+        return out, vjp(cot)[0]
+    return jax.jit(f)
+
+
+kernel = both(moe._grouped_dot)
+oracle = both(lambda r, w, s, l: jax.lax.ragged_dot(r, w[l], s))
+cases = []
+for n_case, (m, g, k, n, layers) in enumerate(shapes):
+    if on_tpu and not moe._pallas_grouped_dot_ok(m):
+        raise SystemExit(f"the Pallas kernel would not run at {shapes[n_case]}")
+    ks = jax.random.split(jax.random.PRNGKey(n_case), 3)
+    rows = jax.random.normal(ks[0], (m, k), jnp.bfloat16)
+    kernels = jax.random.normal(ks[1], (layers, g, k, n), jnp.bfloat16) * k ** -0.5
+    cot = jax.random.normal(ks[2], (m, n), jnp.bfloat16)
+    layer = jnp.int32(layers - 1)
+    for name, sizes in loads(np.random.default_rng(n_case), m, g).items():
+        sizes = jnp.asarray(sizes, jnp.int32)
+        covered = (jnp.arange(m) < sizes.sum())[:, None]
+        got = kernel(rows, kernels, sizes, layer, cot)
+        want = oracle(rows, kernels, sizes, layer, cot)
+        errs, finite = [], True
+        for a, b in zip(got, want):
+            # rows behind the last group: the Pallas kernel writes nothing
+            a = jnp.where(covered, a, 0).astype(jnp.float32)
+            b = jnp.where(covered, b, 0).astype(jnp.float32)
+            errs.append(float(jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))))
+            finite = finite and bool(jnp.all(jnp.isfinite(a)))
+        tile = moe.gmm_row_tile(m, g)
+        cases.append({"shape": shapes[n_case], "sizes": name,
+                      "tiles": [list(moe._GmmTiling(g)(m, k, n)),
+                                list(moe._GmmTiling(g)(m, n, k))],
+                      "work_over_need": float(moe.gmm_work_over_need(sizes, tile)),
+                      "value_err": errs[0], "grad_err": errs[1], "finite": finite})
+    del rows, kernels, cot, got, want, a, b   # the next stack needs the room
+print(json.dumps({"device": device_report(), "compiled": on_tpu, "cases": cases}))
+"""
+
+
 class SmokeFailure(Exception):
     """A phase did not do what it had to; the run ends non-zero."""
 
@@ -154,6 +232,9 @@ def mode_config(tiny: bool, seed: int) -> dict:
             "attention_impl": "xla",
             # interpret-mode parity at small shapes (b, s, h, hkv, d, t, mp)
             "paged_shapes": [[2, 1, 4, 2, 16, 8, 5], [1, 12, 4, 2, 16, 8, 5]],
+            # the compiler's own grouped product against itself: control flow
+            # only (rows, groups, k, n, layers of the stack)
+            "grouped_shapes": [[256, 8, 64, 32, 2]],
         }
     return {
         "platform": "tpu", "model_name": "tinyllama-1.1b-lora",
@@ -170,6 +251,15 @@ def mode_config(tiny: bool, seed: int) -> dict:
             [8, 1, 32, 4, 64, 16, 40], [1, 32, 32, 4, 64, 16, 40],
             [1, 128, 32, 4, 64, 16, 40], [1, 512, 32, 4, 64, 16, 40],
             [8, 1, 32, 8, 128, 16, 40], [1, 512, 32, 8, 128, 16, 40],
+        ],
+        # the grouped expert products of the two expert configurations (up
+        # and down: the activation gradient of one has the other's shapes),
+        # read in place in a stack of 4 layers / 2 (3.2 GB / 0.8 GB a leaf),
+        # and a decode step's 32 lanes x 8 over 256 experts
+        "grouped_shapes": [
+            [65536, 256, 2048, 768, 4], [65536, 256, 768, 2048, 4],
+            [16384, 16, 6144, 2048, 2], [16384, 16, 2048, 6144, 2],
+            [256, 256, 2048, 768, 1],
         ],
     }
 
@@ -554,22 +644,29 @@ def stop_server(proc: subprocess.Popen, run_id: str, log: Path) -> None:
     check(not left, f"processes survived the server's shutdown: {left}")
 
 
-def paged_parity_phase(run_id: str, cfg: dict) -> dict:
-    t0 = time.monotonic()
-    dtype = "bfloat16"
+def parity_child(run_id: str, cfg: dict, what: str, snippet: str, *args) -> dict:
+    """Run a parity snippet in a child that takes the chip, and return the
+    JSON record of its last line; its device must be the mode's."""
     out = subprocess.run(
-        [sys.executable, "-c", PAGED_PARITY_SNIPPET,
-         json.dumps(cfg["paged_shapes"]), dtype],
+        [sys.executable, "-c", snippet, *args],
         env=child_env(run_id, cfg["platform"]), cwd=str(REPO),
         capture_output=True, text=True, timeout=900,
     )
     check(out.returncode == 0,
-          f"paged parity child exited {out.returncode}:\n{out.stderr[-3000:]}")
+          f"{what} parity child exited {out.returncode}:\n{out.stderr[-3000:]}")
     rec = json.loads(out.stdout.strip().splitlines()[-1])
     check(rec["device"]["platform"] == cfg["platform"],
-          f"paged parity ran on {rec['device']}")
-    worst = max(c["max_err"] for c in rec["cases"])
+          f"{what} parity ran on {rec['device']}")
     check(all(c["finite"] for c in rec["cases"]), f"non-finite output: {rec}")
+    return rec
+
+
+def paged_parity_phase(run_id: str, cfg: dict) -> dict:
+    t0 = time.monotonic()
+    dtype = "bfloat16"
+    rec = parity_child(run_id, cfg, "paged", PAGED_PARITY_SNIPPET,
+                       json.dumps(cfg["paged_shapes"]), dtype)
+    worst = max(c["max_err"] for c in rec["cases"])
     check(worst <= PAGED_TOL[dtype],
           f"paged kernel off the gather path by {worst} > {PAGED_TOL[dtype]}:"
           f" {rec['cases']}")
@@ -580,8 +677,28 @@ def paged_parity_phase(run_id: str, cfg: dict) -> dict:
     return rec["device"]
 
 
+def grouped_parity_phase(run_id: str, cfg: dict) -> dict:
+    t0 = time.monotonic()
+    rec = parity_child(run_id, cfg, "grouped", GROUPED_PARITY_SNIPPET,
+                       json.dumps(cfg["grouped_shapes"]))
+    worst = max(max(c["value_err"], c["grad_err"]) for c in rec["cases"])
+    check(worst <= GROUPED_TOL,
+          f"grouped product off ragged_dot by {worst} > {GROUPED_TOL} of the "
+          f"largest magnitude: {rec['cases']}")
+    say("grouped-parity", time.monotonic() - t0, compiled=rec["compiled"],
+        tolerance=GROUPED_TOL, worst_err=worst,
+        tiles_by_shape={"x".join(map(str, c["shape"])): c["tiles"]
+                        for c in rec["cases"]},
+        work_over_need={"x".join(map(str, c["shape"])) + ":" + c["sizes"]:
+                        round(c["work_over_need"], 3) for c in rec["cases"]})
+    return rec["device"]
+
+
 def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     _, entries0 = cache_state()
+    # first, while nothing holds the chip: a tile that faults ends the run
+    # here, in a minute
+    grouped_device = grouped_parity_phase(run_id, cfg)
     t0 = time.monotonic()
     server, api, log = start_server(work, run_id, cfg["platform"])
     say("server", time.monotonic() - t0, pid=server.pid, log=str(log))
@@ -608,9 +725,10 @@ def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     stop_server(server, run_id, log)
     say("shutdown", time.monotonic() - t1, survivors=[])
     parity_device = paged_parity_phase(run_id, cfg)
-    check(train_device == serve_device == parity_device,
+    check(train_device == serve_device == parity_device == grouped_device,
           f"children disagree on the device: trainer {train_device}, "
-          f"serve worker {serve_device}, parity child {parity_device}")
+          f"serve worker {serve_device}, parity children {parity_device}, "
+          f"{grouped_device}")
     cache_dir, entries1 = cache_state()
     say("compile-cache", 0.0, dir=cache_dir,
         entries_before=entries0, entries_after=entries1)

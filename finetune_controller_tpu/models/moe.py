@@ -25,11 +25,19 @@ TPU-native design:
     zero) — a trade that only suits few wide experts;
   * ``dropless``: the ``T·k`` pairs sorted by expert, one grouped matrix
     product over the experts held (static total rows, dynamic group sizes:
-    ``_grouped_dot`` — on one TPU the Pallas megablox kernel, measured 1.6x
-    (forward) and 2.3x (activation gradient) faster at 65,536 rows x 256
-    experts of 2048 x 768 than the compiler's own lowering of
-    ``jax.lax.ragged_dot``, which is the path everywhere else), un-sorted and
-    combined with float32 weights.  No pair is dropped at any imbalance.
+    ``_grouped_dot``), un-sorted and combined with float32 weights.  No pair
+    is dropped at any imbalance.  On one TPU the product is the Pallas
+    megablox kernel, everywhere else the compiler's own lowering of
+    ``jax.lax.ragged_dot``, which the kernel beat 1.6x (forward) and 2.3x
+    (activation gradient) at 65,536 rows x 256 experts of 2048 x 768 with
+    row tiles of 512.  Its tiles follow the groups it is given
+    (``_GmmTiling``): the kernel visits a row tile once for every group with
+    a row in it and multiplies the whole tile each time, so the row tile is
+    the largest no larger than an even group's rows (``gmm_row_tile``: 256
+    there, 2.0 x the rows needed where 512 computed 2.99 x), and under 512
+    rows the contraction is not tiled, so a group's weights are fetched once
+    — a call 2.4-2.7 ms where it took 3.4.  ``gmm_work_over_need`` is the
+    step's counter of it.
     Both permutations are gathers in BOTH passes (``_rows_of_tokens``,
     ``_unsort``): the transpose of a row gather is a scatter-add, which a
     TPU serialises.
@@ -61,16 +69,19 @@ TPU-native design:
   a module so its projections carry LoRA like any other);
 - Switch-Transformer load-balancing aux loss, sown into the ``moe_aux``
   collection where the configuration asks for one (the trainer folds it into
-  the objective), and four counters sown into ``moe_stats``:
+  the objective), and five counters sown into ``moe_stats``:
   ``load_max_over_mean`` (the fullest expert's pairs over the mean),
   ``pairs`` (pairs that reached an expert the layer holds: ``T·k`` when it
   holds them all and nothing is dropped), ``experts_in_place`` (1.0 where
-  the layer read its experts in place) and ``pairs_over_bound`` (a held
-  share's pairs beyond its first pass's rows: computed in later passes).
+  the layer read its experts in place), ``pairs_over_bound`` (a held
+  share's pairs beyond its first pass's rows: computed in later passes) and,
+  where the Pallas kernel runs, ``gmm_work_over_need`` (rows a grouped
+  product multiplies over rows that have a group, at its row tile).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Any
@@ -155,12 +166,80 @@ def _largest_tile(dim: int, tiles=(1024, 768, 512, 384, 256, 128)) -> int:
     return next((t for t in tiles if dim % t == 0), 128)
 
 
-def _gmm_tiling(m: int, k: int, n: int) -> tuple[int, int, int]:
-    """Tiles of the megablox kernel from the shapes of THIS product (the
-    activation-gradient product swaps ``k`` and ``n``): rows 512 where they
-    divide, the widest tile of 1024 or under that divides each width — (512,
-    1024, 768) and (512, 768, 1024) at 2048 x 768 experts, 8 MB of VMEM."""
-    return (_largest_tile(m, (512, 256, 128)), _largest_tile(k), _largest_tile(n))
+#: what the tiles of one grouped product may take of the 16 MB of VMEM a
+#: Pallas call is given: the compiler's own stack needs the rest
+_GMM_VMEM_BYTES = 12 * 2 ** 20
+
+
+def _gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """VMEM of the megablox kernel at these tiles: the row, weight and result
+    tiles of ``itemsize`` bytes an element double-buffered, and the float32
+    accumulator."""
+    return 2 * itemsize * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def gmm_row_tile(rows: int, groups: int) -> int:
+    """Row tile of the grouped product: the largest of 512, 256, 128 that
+    divides ``rows`` and is no larger than the rows an even load gives one of
+    the ``groups``.  The kernel visits a row tile once for EVERY group with a
+    row in it, each visit is a whole tile's product, and the other groups'
+    rows are masked away at the store: it multiplies ``rows + (groups' - 1) ·
+    tile`` rows (``groups'``: the non-empty ones) — 2.99 x the need at 512 and
+    256 rows a group, 2.0 x at 256.  A tile smaller than a group's rows
+    masks fewer rows still (1.5 x at 128) but pays a visit's fixed cost
+    (~1.4 us on a v5e: zeroing the accumulator, the masked store) more often:
+    3-6 % of a call gained at even load at 2048 x 768, nothing under a
+    skewed load, a loss at half those widths (``PERF.md`` section 6)."""
+    return next((t for t in (512, 256, 128)
+                 if rows % t == 0 and t * groups <= rows), 128)
+
+
+@dataclasses.dataclass(frozen=True)
+class _GmmTiling:
+    """Tiles of the megablox kernel from the shapes of THIS product — the
+    kernel asks once for the forward product and once, ``k`` and ``n``
+    swapped, for the activation gradient — and the ``groups`` its rows are
+    spread over (a layer's OWN experts, also where they are read in place
+    among a stack's ``L·G``), of ``itemsize`` bytes an element.  Hashable: a
+    static argument of the kernel's ``jit``."""
+
+    groups: int
+    itemsize: int = 2
+
+    def __call__(self, m: int, k: int, n: int) -> tuple[int, int, int]:
+        tm = gmm_row_tile(m, self.groups)
+        return (tm, *_gmm_widths(tm, k, n, self.itemsize))
+
+
+def _gmm_widths(tm: int, k: int, n: int, itemsize: int) -> tuple[int, int]:
+    """Contraction and width tiles beside a row tile of ``tm``.  Under 512
+    rows HBM paces a visit (at 256 x 768 its product is 192 FLOPs a byte
+    fetched, where the chip does 240 in the time it moves one), and what a
+    visit fetches is what counts: the contraction whole and as much of the
+    width as VMEM holds, so that a visit addresses the blocks the one before
+    it did — a group's weights are fetched once, not once a row tile it
+    spans, and a row tile's rows once, not once a group in it.  On the chip
+    at 65,536 rows over 256 experts of 2048 x 768: 2.44 / 2.71 ms a call
+    where the tiled contraction took 3.03 / 2.95 at the same row tile, and
+    3.86 at 128 rows, slower than 512's 3.49.  At 512 rows the product
+    outlasts its fetches (307 FLOPs a byte) and the tiles stay the widest of
+    1024 or under that divide, as the chip has run them since PR 27 (whole
+    contractions would buy 4-7 % of a call there: ``PERF.md`` section 6)."""
+    if tm < 512 and k % 128 == 0 and n % 128 == 0:
+        for tn in (n, *(t for t in (1024, 768, 512, 384, 256, 128) if n % t == 0)):
+            if _gmm_vmem_bytes(tm, k, tn, itemsize) <= _GMM_VMEM_BYTES:
+                return k, tn
+    return _largest_tile(k), _largest_tile(n)
+
+
+def gmm_work_over_need(sizes, tile: int):
+    """Rows the kernel multiplies over rows that have a group, at row tile
+    ``tile`` and group ``sizes`` (consecutive rows from row 0): (row tile,
+    group) pairs with a row in common x ``tile`` / ``sum(sizes)``."""
+    ends = jnp.cumsum(sizes)
+    first = (ends - sizes) // tile
+    visits = jnp.where(sizes > 0, -(-ends // tile) - first, 0).sum()
+    return (visits * tile).astype(jnp.float32) / jnp.maximum(ends[-1], 1)
 
 
 def _pallas_grouped_dot_ok(rows: int) -> bool:
@@ -185,15 +264,17 @@ def _grouped_dot(lhs, rhs, sizes, layer=None):
     PLACE: the leading dimensions merge (a bitcast) and the ``L·G`` groups are
     empty outside ``[layer·G, (layer+1)·G)``.  An empty group costs the kernel
     no grid step, and the row tile of group ``g`` reads block ``layer·G + g``."""
+    g = rhs.shape[-3]
     if layer is not None:
-        n_layers, g = rhs.shape[:2]
+        n_layers = rhs.shape[0]
         rhs = rhs.reshape((n_layers * g,) + rhs.shape[2:])
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((n_layers * g,), sizes.dtype), sizes, (layer * g,))
     if _pallas_grouped_dot_ok(lhs.shape[0]):
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
-        return megablox.gmm(lhs, rhs, sizes, lhs.dtype, _gmm_tiling)
+        return megablox.gmm(
+            lhs, rhs, sizes, lhs.dtype, _GmmTiling(g, lhs.dtype.itemsize))
     return jax.lax.ragged_dot(lhs, rhs, sizes)
 
 
@@ -247,6 +328,22 @@ def held_row_bound(pairs: int, n_held: int, n_experts: int) -> int:
     pass's memory, not the pairs computed."""
     even = -(-pairs * n_held // n_experts)
     return min(pairs, -(-2 * even // 512) * 512)
+
+
+def _dropless_rows(pairs: int, n_held: int, n_experts: int) -> int:
+    """Rows one call of a dropless layer's grouped products covers: every
+    pair, or a pass of a held share's."""
+    return (pairs if n_held == n_experts
+            else held_row_bound(pairs, n_held, n_experts))
+
+
+def dropless_row_tile(pairs: int, n_held: int, n_experts: int) -> int | None:
+    """The row tile a dropless layer's grouped products run at with ``pairs``
+    routed pairs a call and ``n_held`` of ``n_experts`` experts held, or None
+    where the Pallas kernel does not run.  Static: the ``train-started``
+    event carries it."""
+    rows = _dropless_rows(pairs, n_held, n_experts)
+    return gmm_row_tile(rows, n_held) if _pallas_grouped_dot_ok(rows) else None
 
 
 def _sum_of_pairs(rows, row_of_pair, weight=None):
@@ -303,6 +400,14 @@ def _held_combine_bwd(res, g):
 _held_combine.defvjp(_held_combine_fwd, _held_combine_bwd)
 
 
+def _sizes_in_rows(sizes, start, bound: int):
+    """The part of every group that lies in the rows ``[start, start +
+    bound)`` of the pairs sorted by group."""
+    ends = jnp.cumsum(sizes)
+    return (jnp.clip(ends - start, 0, bound)
+            - jnp.clip(ends - sizes - start, 0, bound))
+
+
 def _held_pass(x, top_w, kernels, layer, order, sizes, start, bound: int):
     """What the held experts give the rows ``[start, start + bound)`` of the
     pairs sorted with the held experts' first, back at their tokens: ``(T,
@@ -313,11 +418,8 @@ def _held_pass(x, top_w, kernels, layer, order, sizes, start, bound: int):
     w_gate, w_up, w_down = kernels
     dot = functools.partial(_grouped_dot, layer=layer)
     with jax.named_scope("moe_dispatch"):
-        ends = jnp.cumsum(sizes)
-        mine = ends[-1]
-        # the part of every group that lies in these rows
-        sizes = (jnp.clip(ends - start, 0, bound)
-                 - jnp.clip(ends - sizes - start, 0, bound))
+        mine = sizes.sum()
+        sizes = _sizes_in_rows(sizes, start, bound)
         pair_of_row = jax.lax.dynamic_slice(order, (start,), (bound,))
         row = jnp.arange(bound, dtype=jnp.int32)
         live = row < mine - start
@@ -462,8 +564,7 @@ class MoEMLP(nn.Module):
             stacked is not None and self.dispatch == "dropless"
             and not self.quantize_base
             and all(w.dtype == jnp.dtype(self.dtype) for w in stacked)
-            and _pallas_grouped_dot_ok(
-                held_row_bound(t * k, n_held, e) if held else t * k))
+            and _pallas_grouped_dot_ok(_dropless_rows(t * k, n_held, e)))
         if held:
             out, pairs = self._dropless_held(
                 xt, top_idx, top_w, load, stacked if in_place else kernels,
@@ -506,6 +607,7 @@ class MoEMLP(nn.Module):
             gate = dot(rows, w_gate, sizes)
             up = dot(rows, w_up, sizes)
             out_rows = dot(nn.silu(gate) * up, w_down, sizes)
+        self._sow_gmm_work(sizes, t * k)
         with jax.named_scope("moe_combine"):
             pair_out = _unsort(out_rows, order, inverse).reshape(t, k, d)
             out = jnp.einsum("tk,tkd->td", top_w,
@@ -547,7 +649,18 @@ class MoEMLP(nn.Module):
                 out, x, top_w, kernels, layer, order, sizes, bound)
         self.sow("moe_stats", "pairs_over_bound",
                  jnp.maximum(mine - bound, 0).astype(jnp.float32))
+        self._sow_gmm_work(_sizes_in_rows(sizes, 0, bound), t * k)
         return out, mine
+
+    def _sow_gmm_work(self, sizes, pairs: int) -> None:
+        """``gmm_work_over_need`` of one call of the Pallas kernel on groups
+        of ``sizes`` rows (a held share's: of its first pass) with ``pairs``
+        routed pairs; nothing where the compiler's own product runs, which
+        has no tile."""
+        tile = dropless_row_tile(pairs, sizes.shape[0], self.n_experts)
+        if tile:
+            self.sow("moe_stats", "gmm_work_over_need",
+                     gmm_work_over_need(sizes, tile))
 
     # ---- capacity: static slots per expert, pairs over them dropped -----------
 
@@ -622,16 +735,18 @@ def moe_aux_loss(collections: dict) -> jax.Array:
 
 #: a counter sown into ``moe_stats`` -> how the layers' readings become the step's
 _COUNTERS = {"load_max_over_mean": jnp.max, "pairs": jnp.min,
-             "experts_in_place": jnp.sum, "pairs_over_bound": jnp.max}
+             "experts_in_place": jnp.sum, "pairs_over_bound": jnp.max,
+             "gmm_work_over_need": jnp.max}
 
 
 def moe_counters(collections: dict) -> dict:
     """The step's counters from the sown ``moe_stats`` (scan stacks them per
     layer): ``moe_load_max_over_mean`` of the worst layer, ``moe_pairs`` of
-    the layer that computed the fewest (``T·k`` where nothing is dropped) and
+    the layer that computed the fewest (``T·k`` where nothing is dropped),
     ``moe_experts_in_place``, the number of layers whose grouped products read
-    their experts in place in the scanned stack.  Empty for a model without
-    expert layers."""
+    their experts in place in the scanned stack, and of the worst layer
+    ``moe_pairs_over_bound`` and ``moe_gmm_work_over_need``.  Empty for a
+    model without expert layers."""
     sown: dict[str, list] = {}
     for path, leaf in jax.tree_util.tree_leaves_with_path(
             collections.get("moe_stats", {})):

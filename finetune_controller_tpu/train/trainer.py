@@ -1595,7 +1595,8 @@ class Trainer:
         """Where this run landed, for the ``train-started`` event: the device
         as JAX reports it, the mesh, which attention implementation the step
         resolves to (with the flash kernels, how much score area they compute
-        over what the causal triangle needs), and the bytes the
+        over what the causal triangle needs), the row tile of a dropless
+        expert model's grouped products where the Pallas kernel runs, and the bytes the
         freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
         off JAX and learns the device from this."""
         from ..platform import device_report
@@ -1614,6 +1615,21 @@ class Trainer:
             # score area the flash kernels compute over the causal triangle's
             attrs["flash_causal_work_over_need"] = causal_work_over_need(
                 self.cfg.seq_len, head_widths=cfg.head_widths)
+        if cfg.n_experts and cfg.moe_dispatch == "dropless":
+            from ..models.moe import dropless_row_tile
+            from ..parallel.ring import ring_mesh
+
+            # the grouped expert products' row tile (``moe_gmm_work_over_need``
+            # among the step's counters is what it costs), where the Pallas
+            # kernel runs: asked as the step's trace asks, under its mesh
+            tokens = (self.cfg.batch_size // self.cfg.grad_accum_steps
+                      * self.cfg.seq_len)
+            with ring_mesh(self.mesh):
+                tile = dropless_row_tile(
+                    tokens * cfg.moe_top_k,
+                    (cfg.experts_held or (0, cfg.n_experts))[1], cfg.n_experts)
+            if tile:
+                attrs["moe_gmm_row_tile"] = tile
         kinds = cfg.indexer_kinds()
         if kinds:
             attrs["dsa_full_layers"] = kinds.count("full")

@@ -120,30 +120,6 @@ def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)], ids=["up", "down"])
-def test_grouped_expert_product_compiles_for_v5e_forward_and_activation_gradient(
-        v5e, monkeypatch, k, n):
-    """65,536 sorted pairs over 256 experts at the published widths, with the
-    tiles ``_gmm_tiling`` picks from each product's own shapes: the Pallas
-    kernel forward and, transposed, for the activation gradient (the frozen
-    experts' weight gradient is never asked for, so its kernel goes)."""
-    from finetune_controller_tpu.models import moe
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert moe._gmm_tiling(65536, k, n) == (512, min(k, 1024), min(n, 1024))
-    one = SingleDeviceSharding(v5e[0])
-    rows = jax.ShapeDtypeStruct((65536, k), BF16, sharding=one)
-    kernels = jax.ShapeDtypeStruct((256, k, n), BF16, sharding=one)
-    sizes = jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one)
-
-    def loss(rows, kernels, sizes):
-        return jnp.sum(moe._grouped_dot(rows, kernels, sizes).astype(jnp.float32))
-
-    compiled = jax.jit(jax.value_and_grad(loss)).lower(rows, kernels, sizes).compile()
-    assert _custom_calls(compiled) >= 2
-    assert "ragged-dot" not in compiled.as_text()
-
-
 def _written(text: str, shapes) -> list[str]:
     """The instructions that WRITE a bf16 array of one of ``shapes``: every
     one whose result has it but a parameter, a tuple's element or a bitcast."""
@@ -153,32 +129,65 @@ def _written(text: str, shapes) -> list[str]:
             and not re.search(r" (parameter|get-tuple-element|bitcast)\(", line)]
 
 
-@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)], ids=["up", "down"])
-def test_in_place_expert_product_compiles_for_v5e_and_copies_no_kernel(
-        v5e, monkeypatch, k, n):
-    """The same product reading layer ``l`` of a scanned stack's WHOLE leaf
-    (4 layers x 256 experts: 1,024 groups, all but 256 empty; group and tile
-    ids of 1,151 entries in SMEM), forward and transposed: the kernel takes a
-    bitcast of the leaf, so nothing shaped like the leaf or like one layer of
-    it is written."""
+#: the two expert configurations' grouped products: rows a call, the groups
+#: they are spread over, the experts' widths (d_model, d_ff), the layers of
+#: the scanned stack
+EXPERT_SHAPES = {
+    # 8,192 tokens x 8 over all 256 experts: 256 rows a group
+    "joyai_4k": (65536, 256, 2048, 768, 4),
+    # a pass of ``held_row_bound`` rows over the 16 experts held: 1,024 a group
+    "glm_16k_share": (16384, 16, 6144, 2048, 4),
+}
+#: (rows, contraction, width) the rule gives ``(up, down)`` and, the same two
+#: swapped, their activation gradients
+EXPERT_TILES = {
+    "joyai_4k": ((256, 2048, 768), (256, 768, 2048)),
+    "glm_16k_share": ((512, 1024, 1024), (512, 1024, 1024)),
+}
+
+
+@pytest.mark.parametrize("where", ["plain", "in_place"])
+@pytest.mark.parametrize("product", ["up", "down"])
+@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
+def test_grouped_expert_product_compiles_for_v5e_forward_and_activation_gradient(
+        v5e, monkeypatch, shape, product, where):
+    """Both expert configurations' products at the published widths, with the
+    tiles the rule picks from each product's own shapes and the groups its
+    rows are spread over (``_GmmTiling``): the Pallas kernel forward and,
+    transposed, for the activation gradient (the frozen experts' weight
+    gradient is never asked for, so its kernel goes) — handed a layer's
+    experts, and reading them in place in the scanned stack's whole leaf,
+    where the kernel sees ``L·G`` groups (all but ``G`` empty; at 4 x 256 the
+    group and tile ids are 1,279 entries in SMEM) and the rule still ``G``:
+    there the kernel takes a bitcast of the leaf, so nothing shaped like the
+    leaf or like one layer of it is written."""
     from finetune_controller_tpu.models import moe
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    m, g, d, f, layers = EXPERT_SHAPES[shape]
+    k, n = (d, f) if product == "up" else (f, d)
+    forward, swapped = EXPERT_TILES[shape][::1 if product == "up" else -1]
+    assert moe._GmmTiling(g)(m, k, n) == forward
+    assert moe._GmmTiling(g)(m, n, k) == swapped
     one = SingleDeviceSharding(v5e[0])
-    rows = jax.ShapeDtypeStruct((65536, k), BF16, sharding=one)
-    stacked = jax.ShapeDtypeStruct((4, 256, k, n), BF16, sharding=one)
-    sizes = jax.ShapeDtypeStruct((256,), jnp.int32, sharding=one)
-    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    rows = jax.ShapeDtypeStruct((m, k), BF16, sharding=one)
+    sizes = jax.ShapeDtypeStruct((g,), jnp.int32, sharding=one)
+    if where == "plain":
+        args = (rows, jax.ShapeDtypeStruct((g, k, n), BF16, sharding=one), sizes)
+    else:
+        args = (rows, jax.ShapeDtypeStruct((layers, g, k, n), BF16, sharding=one),
+                sizes, jax.ShapeDtypeStruct((), jnp.int32, sharding=one))
 
-    def loss(rows, stacked, sizes, layer):
+    def loss(rows, kernels, sizes, layer=None):
         return jnp.sum(
-            moe._grouped_dot(rows, stacked, sizes, layer).astype(jnp.float32))
+            moe._grouped_dot(rows, kernels, sizes, layer).astype(jnp.float32))
 
-    text = jax.jit(jax.value_and_grad(loss)).lower(
-        rows, stacked, sizes, layer).compile().as_text()
+    text = jax.jit(jax.value_and_grad(loss)).lower(*args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 2
     assert "ragged-dot" not in text
-    assert not _written(text, [(4, 256, k, n), (1024, k, n), (256, k, n)])
+    if where == "in_place":
+        assert not _written(
+            text, [(layers, g, k, n), (layers * g, k, n), (g, k, n)])
 
 
 @pytest.mark.parametrize("path", ["in_place", "sliced"])

@@ -25,9 +25,9 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 SMOKE = REPO / "chip_smoke.py"
 
-PHASES = ["server", "submit", "train", "promote", "serve-load",
-          "serve-generate", "shutdown", "paged-parity", "compile-cache",
-          "total"]
+PHASES = ["grouped-parity", "server", "submit", "train", "promote",
+          "serve-load", "serve-generate", "shutdown", "paged-parity",
+          "compile-cache", "total"]
 
 
 def _env(**overrides) -> dict:
@@ -98,6 +98,13 @@ def test_tiny_rehearsal_runs_every_phase_and_is_not_a_chip_pass():
     assert detail["shutdown"]["survivors"] == []
     assert detail["paged-parity"]["worst_max_err"] <= \
         detail["paged-parity"]["tolerance"]
+    # the compiler's own grouped product against itself here: every kind of
+    # group sizes ran, and nothing was compiled for a chip
+    grouped = detail["grouped-parity"]
+    assert grouped["compiled"] is False
+    assert grouped["worst_err"] <= grouped["tolerance"]
+    assert {key.split(":")[1] for key in grouped["work_over_need"]} == {
+        "even", "skewed", "empty_groups", "rows_no_group_covers"}
     # the last line is a rehearsal record: no "ok" anywhere on stdout
     last = json.loads(lines[-1])
     assert last == {"rehearsal": "tiny", "passed": True,
@@ -125,7 +132,8 @@ def test_a_failed_phase_exits_nonzero_and_prints_no_result(tmp_path):
     assert "FAILED: POST /jobs -> HTTP 400" in out.stderr
     assert "unknown device 'cpu-test'" in out.stderr
     phases = [l for l in out.stdout.splitlines() if l.startswith("phase ")]
-    assert [p.split(":")[0] for p in phases] == ["phase server"]
+    assert [p.split(":")[0] for p in phases] == [
+        "phase grouped-parity", "phase server"]
     assert _result_lines(out.stdout) == []
     # and nothing it started is left behind
     leftovers = subprocess.run(
@@ -242,6 +250,20 @@ def test_full_mode_runs_the_published_tinyllama_config(smoke):
     flavor = default_catalog().get(cfg["device"])
     assert flavor.runtime == "tpu" and flavor.total_chips == 1
     assert default_catalog().quota_for(cfg["device"]) == 1
+
+
+def test_full_mode_checks_the_grouped_product_at_both_expert_cells_shapes(smoke):
+    """(rows, groups, k, n, layers of the stack): the JoyAI cell's 65,536
+    pairs over 256 experts of 2048 x 768 and the 16k cell's pass of 16,384
+    rows over 16 held experts of 6144 x 2048, up and down — the activation
+    gradient of one has the other's shapes — and a decode step's 256 rows."""
+    from finetune_controller_tpu.models import moe
+
+    shapes = smoke.mode_config(tiny=False, seed=0)["grouped_shapes"]
+    assert [65536, 256, 2048, 768, 4] in shapes and [65536, 256, 768, 2048, 4] in shapes
+    assert [16384, 16, 6144, 2048, 2] in shapes and [16384, 16, 2048, 6144, 2] in shapes
+    assert {moe.gmm_row_tile(m, g) for m, g, *_ in shapes} == {512, 256, 128}
+    assert all(m % 128 == 0 for m, *_ in shapes)     # the Pallas kernel's rows
 
 
 # ---------------------------------------------------------------------------

@@ -353,6 +353,19 @@ def test_trainer_step_reports_the_routing_counters_and_no_auxiliary_loss():
     assert "moe_aux" not in metrics and np.isfinite(float(metrics["loss"]))
 
 
+def test_train_started_carries_the_row_tile_where_the_kernel_runs(monkeypatch):
+    from finetune_controller_tpu.train.trainer import TrainConfig, Trainer
+
+    cfg = PRESETS["tiny-mla-moe-test"].replace(
+        lora=LoRAConfig(rank=4, targets=MLA_TARGETS))
+    trainer = Trainer(cfg, TrainConfig(
+        mode="lora", total_steps=2, batch_size=4, seq_len=256, grad_accum_steps=2))
+    assert "moe_gmm_row_tile" not in trainer._runtime_attrs()      # the CPU
+    monkeypatch.setattr(moe, "_pallas_grouped_dot_ok", lambda rows: True)
+    # a microbatch of 2 x 256 tokens x top-2 over 8 experts: 128 rows a group
+    assert trainer._runtime_attrs()["moe_gmm_row_tile"] == 128
+
+
 # ---- the grouped products read a layer's experts in place (ISSUE 28) ---------
 
 #: rows of each of E experts: all the rows in uneven groups, and with experts
@@ -488,6 +501,146 @@ def test_experts_in_place_counts_the_layers_that_took_it(monkeypatch, why):
     assert np.isfinite(np.asarray(logits, np.float32)).all()
     assert float(counters["moe_experts_in_place"]) == (3 if why in IN_PLACE else 0)
     assert float(counters["moe_pairs"]) > 0
+
+
+# ---- the grouped product's tiles follow the groups it is given (ISSUE 35) -----
+
+
+@pytest.mark.parametrize("rows, groups, tile", [
+    (65536, 256, 256),    # the JoyAI cell: 8,192 tokens x 8 over 256 experts
+    (16384, 16, 512),     # the 16k cell's held share: 1,024 rows a group
+    (65536, 128, 512), (65536, 512, 128),
+    (256, 256, 128),      # a decode step's 32 lanes x 8: the smallest tile
+    (1536, 3, 512), (768, 2, 256), (640, 2, 128),   # only tiles that divide
+])
+def test_row_tile_is_the_largest_no_larger_than_a_groups_rows(rows, groups, tile):
+    assert moe.gmm_row_tile(rows, groups) == tile
+    assert rows % tile == 0
+
+
+#: (k, n) of every grouped product the two expert configurations run, and
+#: of a few-wide-experts model
+WIDTHS = [(2048, 768), (768, 2048), (6144, 2048), (2048, 6144),
+          (4096, 14336), (14336, 4096), (64, 32)]
+
+
+@pytest.mark.parametrize("k, n", WIDTHS)
+def test_every_tile_the_rule_returns_divides_and_fits_vmem(k, n):
+    """Whatever the rows and groups: tiles the kernel's grid can walk (the
+    row tile divides the rows; 128-multiples that divide a width, or cover a
+    narrow one) inside the VMEM the rule allows itself, under the call's
+    16 MB."""
+    assert moe._GMM_VMEM_BYTES < 16 * 2 ** 20
+    for rows in (128, 256, 4096, 16384, 65536):
+        for groups in (1, 8, 16, 256, 1024):
+            for itemsize in (2, 4):          # bf16 and float32 experts
+                tm, tk, tn = moe._GmmTiling(groups, itemsize)(rows, k, n)
+                assert tm == moe.gmm_row_tile(rows, groups)
+                assert all(t % 128 == 0 for t in (tm, tk, tn))
+                for tile, dim in ((tk, k), (tn, n)):
+                    assert dim % tile == 0 or dim < 128
+                # (float32 experts at the widest tiles of 1024 are the
+                # parent's, over the budget and not this rule's)
+                if itemsize == 2 or (tk, tn) != (
+                        moe._largest_tile(k), moe._largest_tile(n)):
+                    assert (moe._gmm_vmem_bytes(tm, tk, tn, itemsize)
+                            <= moe._GMM_VMEM_BYTES)
+
+
+def _tile_visits(sizes, tile):
+    """(row tile, group) pairs with a row in common, counted row by row."""
+    group_of_row = np.repeat(np.arange(len(sizes)), sizes)
+    return len({(row // tile, g) for row, g in enumerate(group_of_row)})
+
+
+#: group sizes over 1,024 rows: even, skewed, with empty groups (the first,
+#: runs in the middle, the last), aligned to the tile, and with rows behind
+#: the last group that no group covers (a held share's pass)
+SIZES = {
+    "even": (128,) * 8,
+    "even_unaligned": (120, 136, 130, 126, 127, 129, 140, 116),
+    "skewed": (700, 3, 1, 200, 60, 50, 9, 1),
+    "empty_groups": (0, 300, 0, 0, 500, 224, 0, 0),
+    "one_group": (0, 0, 1024, 0),
+    "rows_no_group_covers": (100, 0, 250, 30),
+    "no_rows": (0, 0, 0, 0),
+}
+
+
+@pytest.mark.parametrize("tile", [128, 256, 512])
+@pytest.mark.parametrize("sizes", list(SIZES), ids=list(SIZES))
+def test_gmm_work_over_need_is_the_tile_visits_counted_by_hand(sizes, tile):
+    sizes = np.asarray(SIZES[sizes], np.int32)
+    got = float(jax.jit(moe.gmm_work_over_need, static_argnums=1)(
+        jnp.asarray(sizes), tile))
+    want = _tile_visits(sizes, tile) * tile / max(int(sizes.sum()), 1)
+    assert got == pytest.approx(want, rel=1e-6)
+    # the issue's reckoning: rows + (non-empty groups - 1) x tile at the most
+    if sizes.sum() == 1024:
+        assert got <= (1024 + (np.count_nonzero(sizes) - 1) * tile) / 1024
+
+
+def test_even_load_at_the_cells_shapes_reads_what_the_issue_reckoned():
+    """65,536 rows over 256 groups of 256, every group off the tiles' grid by
+    a row: 2.99 x the need at 512 rows a tile, 2.0 at 256, 1.5 at 128."""
+    sizes = np.full((256,), 256, np.int32)
+    sizes[0], sizes[-1] = 255, 257
+    for tile, want in ((512, 383 * 512), (256, 511 * 256), (128, 767 * 128)):
+        got = float(moe.gmm_work_over_need(jnp.asarray(sizes), tile))
+        assert got == pytest.approx(want / 65536, rel=1e-6)
+
+
+@pytest.mark.parametrize("layer", [None, 1], ids=["plain", "in_place"])
+def test_the_tiling_sees_the_layers_own_groups(monkeypatch, layer):
+    """Under ``layer=`` the kernel gets ``L·G`` groups; the rule is told
+    ``G``, and the tiling is hashable (a static argument of the kernel's
+    ``jit``)."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    seen = []
+    monkeypatch.setattr(moe, "_pallas_grouped_dot_ok", lambda rows: True)
+    monkeypatch.setattr(
+        megablox, "gmm", lambda lhs, rhs, sizes, dtype, tiling: (
+            seen.append((rhs.shape[0], tiling)),
+            jax.lax.ragged_dot(lhs, rhs, sizes))[1])
+    rows = jnp.ones((1024, 64))
+    kernels = jnp.ones((3, 8, 64, 32))
+    sizes = jnp.full((8,), 128, jnp.int32)
+    if layer is None:
+        moe._grouped_dot(rows, kernels[0], sizes)
+    else:
+        moe._grouped_dot(rows, kernels, sizes, jnp.int32(layer))
+    (groups, tiling), = seen
+    assert groups == (8 if layer is None else 24)
+    want = moe._GmmTiling(8, rows.dtype.itemsize)
+    assert tiling == want and hash(tiling) == hash(want)
+    assert tiling(1024, 64, 32)[0] == 128
+
+
+@pytest.mark.parametrize("held", [None, (0, 4)], ids=["all_experts", "a_share"])
+def test_gmm_work_over_need_is_a_counter_where_the_kernel_runs(monkeypatch, held):
+    """Sown by every dropless layer that runs the Pallas kernel, the worst
+    layer's reading among the step's counters; absent where the compiler's
+    own product runs (it has no tile)."""
+    model, variables = _expert_model(experts_held=held)
+    tokens = jnp.asarray(_tokens(2, 32))
+    assert "moe_gmm_work_over_need" not in _logits_grads_counters(
+        model, variables, tokens)[2]
+    _take_the_in_place_path(monkeypatch)
+    counters = _logits_grads_counters(model, variables, tokens)[2]
+    # whole tiles of 128 rows for a few rows a group: far over the need
+    assert 1.0 < float(counters["moe_gmm_work_over_need"]) < float("inf")
+
+
+@pytest.mark.parametrize("tokens, held, tile", [
+    (8192, None, 256), (16384, (0, 16), 512), (32, None, 128)],
+    ids=["joyai_4k", "glm_16k_share", "decode_lanes"])
+def test_dropless_row_tile_is_static_and_none_without_the_kernel(
+        monkeypatch, tokens, held, tile):
+    n_held = held[1] if held else 256
+    assert moe.dropless_row_tile(tokens * 8, n_held, 256) is None    # the CPU
+    monkeypatch.setattr(moe, "_pallas_grouped_dot_ok", lambda rows: True)
+    assert moe.dropless_row_tile(tokens * 8, n_held, 256) == tile
 
 
 #: the variable tree of ``tiny-mla-moe-test`` with rank-4 adapters as the

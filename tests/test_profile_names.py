@@ -62,10 +62,15 @@ def op_names():
     return sorted(set(re.findall(r'op_name="([^"]*)"', text)))
 
 
-FORWARD = r"/jvp\(LlamaForCausalLM\)/while/body/closed_call/"
-RECOMPUTE = (r"/transpose\(jvp\(LlamaForCausalLM\)\)/while/body/closed_call/"
-             r"checkpoint/rematted_computation/")
-BACKWARD = r"/transpose\(jvp\(LlamaForCausalLM\)\)/while/body/closed_call/checkpoint/"
+#: one iteration of the scanned stack.  The stack is built in a method the
+#: model calls on itself (``_scanned_blocks``), and flax names such a call
+#: in the stack; a program served from a compile cache written before the
+#: method existed has the stack without it (the cache's key leaves names out)
+LAYER = r"(?:LlamaForCausalLM\._scanned_blocks/)?while/body/closed_call/"
+FORWARD = r"/jvp\(LlamaForCausalLM\)/" + LAYER
+RECOMPUTE = (r"/transpose\(jvp\(LlamaForCausalLM\)\)/" + LAYER
+             + r"checkpoint/rematted_computation/")
+BACKWARD = r"/transpose\(jvp\(LlamaForCausalLM\)\)/" + LAYER + "checkpoint/"
 PASSES = {"forward": FORWARD, "recompute": RECOMPUTE, "backward": BACKWARD}
 PROJECTIONS = ["attn/q_proj", "attn/k_proj", "attn/v_proj", "attn/o_proj",
                "mlp/gate_proj", "mlp/up_proj", "mlp/down_proj"]
