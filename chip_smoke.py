@@ -191,6 +191,65 @@ for n_case, (m, g, k, n, layers) in enumerate(shapes):
 print(json.dumps({"device": device_report(), "compiled": on_tpu, "cases": cases}))
 """
 
+#: largest error of the chunked scan, as a share of the largest magnitude of
+#: the token-by-token recurrence's result: bf16 operands, float32 sums
+SSD_TOL = 2 ** -6
+
+#: the state-space mixer's chunked scan (``models/ssm.py::ssd_chunked``: bf16
+#: products, float32 decays and states) against the recurrence itself, a token
+#: at a time in float32 (``benchmarks/reference/falcon_h1.py::recurrence``) —
+#: values and the gradient of the inputs under a fixed cotangent, at the
+#: family's own ranges (``A`` in [1, 16], step sizes log-uniform in [0.001,
+#: 0.1]: decays near 1, so the carry between chunks is most of the result),
+#: rows (rows, heads, head size, groups, state size, chunk)
+SSD_PARITY_SNIPPET = r"""
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from finetune_controller_tpu.platform import device_report, enable_compile_cache
+from finetune_controller_tpu.models import ssm
+from benchmarks.reference.falcon_h1 import recurrence
+
+enable_compile_cache()
+cases = []
+for n_case, (s, h, p, g, n, chunk) in enumerate(json.loads(sys.argv[1])):
+    rng = np.random.default_rng(n_case)
+    x, b, c, cot = (jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+                    for shape in ((1, s, h, p), (1, s, g, n), (1, s, g, n),
+                                  (1, s, h, p)))
+    dt = jnp.asarray(np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (1, s, h))),
+                     jnp.float32)
+    a = -jnp.asarray(rng.uniform(1.0, 16.0, (h,)), jnp.float32)
+    d = jnp.ones((h,), jnp.float32)
+
+    def chunked(x, dt, b, c):
+        return ssm.ssd_chunked(x, dt, a, b, c, d, chunk=chunk)
+
+    def by_token(x, dt, b, c):
+        x32 = x.astype(jnp.float32).reshape(1, s, g, h // g, p)
+        dtg = dt.reshape(1, s, g, h // g)
+        with jax.default_matmul_precision("highest"):
+            y = recurrence(x32 * dtg[..., None], dtg * a.reshape(g, h // g),
+                           b.astype(jnp.float32), c.astype(jnp.float32))
+        return (y + x32).reshape(1, s, h, p)
+
+    def both(f):
+        def run(x, dt, b, c, cot):
+            out, vjp = jax.vjp(f, x, dt, b, c)
+            return (out, *vjp(cot.astype(out.dtype)))
+        return jax.jit(run)
+
+    got, want = both(chunked)(x, dt, b, c, cot), both(by_token)(x, dt, b, c, cot)
+    errs = [float(jnp.max(jnp.abs(u.astype(jnp.float32) - v.astype(jnp.float32)))
+                  / jnp.max(jnp.abs(v.astype(jnp.float32))))
+            for u, v in zip(got, want)]
+    cases.append({"shape": [s, h, p, g, n, chunk], "value_err": errs[0],
+                  "grad_err": max(errs[1:]), "chunks": -(-s // chunk),
+                  "finite": all(bool(jnp.all(jnp.isfinite(u.astype(jnp.float32))))
+                                for u in got)})
+print(json.dumps({"device": device_report(),
+                  "compiled": jax.default_backend() == "tpu", "cases": cases}))
+"""
+
 
 class SmokeFailure(Exception):
     """A phase did not do what it had to; the run ends non-zero."""
@@ -235,6 +294,8 @@ def mode_config(tiny: bool, seed: int) -> dict:
             # the compiler's own grouped product against itself: control flow
             # only (rows, groups, k, n, layers of the stack)
             "grouped_shapes": [[256, 8, 64, 32, 2]],
+            # the chunked scan against the recurrence: five and a half chunks
+            "ssd_shapes": [[44, 4, 8, 2, 6, 8]],
         }
     return {
         "platform": "tpu", "model_name": "tinyllama-1.1b-lora",
@@ -261,6 +322,9 @@ def mode_config(tiny: bool, seed: int) -> dict:
             [16384, 16, 6144, 2048, 2], [16384, 16, 2048, 6144, 2],
             [256, 256, 2048, 768, 1],
         ],
+        # one block's scan of the hybrid configuration at its published
+        # widths: 1,024 rows, eight chunks of 128, 32 heads of 128 x 256 states
+        "ssd_shapes": [[1024, 32, 128, 2, 256, 128]],
     }
 
 
@@ -694,11 +758,28 @@ def grouped_parity_phase(run_id: str, cfg: dict) -> dict:
     return rec["device"]
 
 
+def ssd_parity_phase(run_id: str, cfg: dict) -> dict:
+    t0 = time.monotonic()
+    rec = parity_child(run_id, cfg, "ssd", SSD_PARITY_SNIPPET,
+                       json.dumps(cfg["ssd_shapes"]))
+    worst = max(max(c["value_err"], c["grad_err"]) for c in rec["cases"])
+    check(worst <= SSD_TOL,
+          f"chunked scan off the token-by-token recurrence by {worst} > "
+          f"{SSD_TOL} of the largest magnitude: {rec['cases']}")
+    say("ssd-parity", time.monotonic() - t0, compiled=rec["compiled"],
+        tolerance=SSD_TOL, worst_err=worst,
+        errs_by_shape={"x".join(map(str, c["shape"])):
+                       {"value": c["value_err"], "grad": c["grad_err"],
+                        "chunks": c["chunks"]} for c in rec["cases"]})
+    return rec["device"]
+
+
 def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     _, entries0 = cache_state()
     # first, while nothing holds the chip: a tile that faults ends the run
     # here, in a minute
     grouped_device = grouped_parity_phase(run_id, cfg)
+    ssd_device = ssd_parity_phase(run_id, cfg)
     t0 = time.monotonic()
     server, api, log = start_server(work, run_id, cfg["platform"])
     say("server", time.monotonic() - t0, pid=server.pid, log=str(log))
@@ -725,10 +806,11 @@ def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     stop_server(server, run_id, log)
     say("shutdown", time.monotonic() - t1, survivors=[])
     parity_device = paged_parity_phase(run_id, cfg)
-    check(train_device == serve_device == parity_device == grouped_device,
+    check(train_device == serve_device == parity_device == grouped_device
+          == ssd_device,
           f"children disagree on the device: trainer {train_device}, "
           f"serve worker {serve_device}, parity children {parity_device}, "
-          f"{grouped_device}")
+          f"{grouped_device}, {ssd_device}")
     cache_dir, entries1 = cache_state()
     say("compile-cache", 0.0, dir=cache_dir,
         entries_before=entries0, entries_after=entries1)

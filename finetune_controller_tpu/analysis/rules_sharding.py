@@ -393,7 +393,8 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     """Abstract param trees spanning every weight family the rule tables
     must cover: dense+LoRA (untied, so lm_head exists), QLoRA int4 scales,
     MoE experts + router, latent attention with a selection-biased router,
-    and the multimodal projector + ViT tower.  All
+    a state-space mixer beside attention, and the multimodal projector + ViT
+    tower.  All
     ``eval_shape`` — no parameter memory is allocated."""
     global _VARIANT_CACHE
     if _VARIANT_CACHE is not None:
@@ -401,7 +402,7 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     import jax.numpy as jnp
 
     from ..models.llama import PRESETS, LlamaForCausalLM
-    from ..models.lora import MLA_TARGETS, LoRAConfig
+    from ..models.lora import HYBRID_TARGETS, MLA_TARGETS, LoRAConfig
     from ..models.multimodal import MM_PRESETS, LlavaForCausalLM
 
     tokens = jnp.zeros((1, 8), jnp.int32)
@@ -423,6 +424,12 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     )
     out["tiny-mla-moe-test+lora"] = _shape_leaves(
         LlamaForCausalLM(cfg_mla), tokens
+    )
+    cfg_ssm = PRESETS["tiny-falcon-h1-test"].replace(
+        lora=LoRAConfig(rank=4, targets=HYBRID_TARGETS)
+    )
+    out["tiny-falcon-h1-test+lora"] = _shape_leaves(
+        LlamaForCausalLM(cfg_ssm), tokens
     )
     mm = MM_PRESETS["tiny-mm-test"].replace(lora=LoRAConfig(rank=4))
     pixels = jnp.zeros(

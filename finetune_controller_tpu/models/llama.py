@@ -186,6 +186,31 @@ class LlamaConfig:
     #: bias terms on the q/k/v projections (Qwen-2 family; o_proj and the
     #: MLP stay bias-free there, matching the HF architecture)
     attention_qkv_bias: bool = False
+    # --- a state-space mixer BESIDE attention (``models/ssm.py``): one norm
+    # feeds both, their scaled outputs are summed into one residual add.
+    # ``ssm_n_heads`` 0 = attention alone.  Heads of ``ssm_head_dim`` over a
+    # ``ssm_head_dim x ssm_d_state`` state each, B and C in ``ssm_n_groups``
+    # groups, a depthwise convolution of ``ssm_d_conv`` rows, the recurrence
+    # in chunks of ``ssm_chunk`` rows
+    ssm_n_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_d_state: int = 0
+    ssm_n_groups: int = 1
+    ssm_d_conv: int = 4
+    ssm_chunk: int = 128
+    # --- muP multipliers (the hybrid family fixes one on every branch;
+    # 1.0 = none, and no operation is traced for it)
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    #: on the segments z | x | B | C | dt of the mixer's input projection
+    ssm_multipliers: tuple[float, ...] = (1.0,) * 5
+    #: on the MLP's gate (before the activation) and on its output
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)
     # --- serving-only knobs (inert at 0; never set by training specs) ------
     #: paged KV cache (docs/serving.md §Paged KV): sequence positions per
     #: page. When > 0 together with ``kv_pool_pages``, the decode-path cache
@@ -205,6 +230,11 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.head_dim_override or self.d_model // self.n_heads
+
+    @property
+    def ssm_d_inner(self) -> int:
+        """Channels of the state-space mixer; 0 for a model without one."""
+        return self.ssm_n_heads * self.ssm_head_dim
 
     @property
     def head_widths(self) -> tuple[int, int]:
@@ -255,11 +285,21 @@ class LlamaConfig:
         hd = self.head_dim
         return 2 * d * h * hd + 2 * d * self.n_kv_heads * hd
 
+    def _mixer_params(self) -> int:
+        """One state-space mixer's: both projections, the convolution and its
+        bias, ``A_log``, ``D``, ``dt_bias`` and the gated norm's scale."""
+        if not self.ssm_d_inner:
+            return 0
+        inner, heads = self.ssm_d_inner, self.ssm_n_heads
+        channels = inner + 2 * self.ssm_n_groups * self.ssm_d_state
+        return (self.d_model * (inner + channels + heads) + inner * self.d_model
+                + (self.ssm_d_conv + 1) * channels + 3 * heads + inner)
+
     def _count(self, experts_counted: int) -> int:
         """Stored parameters with ``experts_counted`` routed experts a layer."""
         d, v, L = self.d_model, self.vocab_size, self.n_layers
         dense_mlp = 3 * d * self.d_ff
-        per_layer = self._attention_params() + 2 * d
+        per_layer = self._attention_params() + self._mixer_params() + 2 * d
         if self.n_experts:
             f = self.moe_d_ff or self.d_ff
             expert_mlp = (experts_counted * 3 * d * f + d * self.n_experts
@@ -404,6 +444,23 @@ PRESETS: dict[str, LlamaConfig] = {
         moe_scoring="sigmoid", moe_dispatch="dropless", moe_select_bias=True,
         moe_routed_scale=2.5, router_aux_weight=0.0,
     ),
+    # the hybrid state-space family at toy size: a Mamba-2 mixer (4 heads of
+    # 16 over a 16 x 8 state, B and C in 2 groups, chunks of 8) beside
+    # attention under one norm, heads of 16 that do not make up d_model, a
+    # query group of 3, and a muP multiplier on every branch
+    "tiny-falcon-h1-test": LlamaConfig(
+        vocab_size=256, d_model=64, n_layers=2, n_heads=6, n_kv_heads=2,
+        d_ff=128, max_seq_len=128, head_dim_override=16, rope_theta=1e11,
+        ssm_n_heads=4, ssm_head_dim=16, ssm_d_state=8,
+        ssm_n_groups=2, ssm_d_conv=4, ssm_chunk=8,
+        embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+        attention_in_multiplier=1.0, attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+    ),
 }
 
 
@@ -473,6 +530,15 @@ def apply_rope(
     return out.astype(x.dtype)
 
 
+def times(x: jax.Array, by: float) -> jax.Array:
+    """``x * by`` for a muP multiplier: the product in float32, rounded once
+    (a multiplier rounded to bf16 first is off by up to 0.26 %, the same way
+    on every element); nothing is traced where ``by`` is 1."""
+    if by == 1.0:
+        return x
+    return (x.astype(jnp.float32) * by).astype(x.dtype)
+
+
 class RMSNorm(nn.Module):
     eps: float = 1e-5
     dtype: Any = jnp.bfloat16
@@ -525,6 +591,7 @@ class Attention(nn.Module):
         q = _proj(cfg, "q_proj", cfg.n_heads * hd)(x, deterministic, adapter_ids)
         k = _proj(cfg, "k_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
         v = _proj(cfg, "v_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
+        k = times(k, cfg.key_multiplier)
         with jax.named_scope("rope"):
             inv_freqs = rope_inv_freqs(cfg)
             q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), positions,
@@ -966,15 +1033,17 @@ class MLP(nn.Module):
     def __call__(self, x, deterministic=True, adapter_ids=None):
         cfg = self.cfg
         d_ff = self.d_ff or cfg.d_ff
+        gate_by, down_by = cfg.mlp_multipliers
         gate = checkpoint_name(
-            _proj(cfg, "gate_proj", d_ff)(x, deterministic, adapter_ids),
+            times(_proj(cfg, "gate_proj", d_ff)(x, deterministic, adapter_ids),
+                  gate_by),
             "mlp_gate")
         up = checkpoint_name(
             _proj(cfg, "up_proj", d_ff)(x, deterministic, adapter_ids),
             "mlp_up")
         act = nn.gelu if cfg.mlp_act == "gelu" else nn.silu  # GeGLU | SwiGLU
-        out = _proj(cfg, "down_proj", cfg.d_model)(
-            act(gate) * up, deterministic, adapter_ids)
+        out = times(_proj(cfg, "down_proj", cfg.d_model)(
+            act(gate) * up, deterministic, adapter_ids), down_by)
         return checkpoint_name(out, "mlp_down")
 
 
@@ -996,7 +1065,10 @@ class Block(nn.Module):
             raise ValueError(f"unknown attention_kind {cfg.attention_kind!r}")
         attention = MLAttention if cfg.attention_kind == "mla" else Attention
         h = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="attn_norm")(x)
-        if self.indexer is None:
+        if cfg.ssm_d_inner:
+            x = x + self._attention_beside_mixer(
+                h, positions, segment_ids, deterministic, decode, adapter_ids)
+        elif self.indexer is None:
             x = x + attention(cfg, name="attn")(
                 h, positions, segment_ids, deterministic, decode,
                 page_table, adapter_ids)
@@ -1038,6 +1110,28 @@ class Block(nn.Module):
         x = x + mlp_out
         return x if self.indexer is None else (x, selection)
 
+    def _attention_beside_mixer(self, h, positions, segment_ids, deterministic,
+                                decode, adapter_ids):
+        """The hybrid block's first half: the ONE normed input ``h`` feeds
+        grouped-query attention and the state-space mixer side by side, and
+        their outputs, each times its multiplier, are summed — one norm, two
+        mixers, one residual add (not one after the other)."""
+        from .ssm import Mamba2Mixer
+
+        cfg = self.cfg
+        if cfg.attention_kind != "gqa" or self.indexer is not None:
+            raise ValueError("a state-space mixer stands beside grouped-query "
+                             "attention only")
+        # the mixer first: it is the one that refuses ``decode``, before
+        # attention makes a cache
+        mixer_out = Mamba2Mixer(cfg, name="mamba")(
+            h, segment_ids, deterministic, decode, adapter_ids)
+        attn_out = Attention(cfg, name="attn")(
+            times(h, cfg.attention_in_multiplier), positions, segment_ids,
+            deterministic, decode, None, adapter_ids)
+        return (times(attn_out, cfg.attention_out_multiplier)
+                + times(mixer_out, cfg.ssm_out_multiplier))
+
 
 def stacked_block_variables(variables: dict) -> dict:
     """Extract the layer-stacked block variables (leading layer axis) from a
@@ -1057,6 +1151,12 @@ def make_block_stage_fn(cfg: LlamaConfig):
         raise NotImplementedError(
             "a model with an indexer has no pipeline path: a stage's first "
             "shared layers need the selection of the stage before")
+    if cfg.ssm_d_inner:
+        raise NotImplementedError(
+            "a model with a state-space mixer has no pipeline path: "
+            "pipelined_causal_lm_logits knows neither the embedding's nor the "
+            "head's multiplier, and no stage test holds the mixer "
+            "(ROADMAP.md B13)")
     block = Block(cfg)
 
     def one_layer(layer_vars, h, positions, segment_ids):
@@ -1164,7 +1264,7 @@ class LlamaForCausalLM(nn.Module):
             param_dtype=cfg.param_dtype,
             name="embed_tokens",
         )
-        x = embed(tokens)
+        x = times(embed(tokens), cfg.embedding_multiplier)
         if cfg.embed_scale:
             # Gemma scales embedding outputs by sqrt(d_model); the cast
             # matches transformers (the scale rounds through the compute
@@ -1223,7 +1323,8 @@ class LlamaForCausalLM(nn.Module):
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
             )(x)
-        return logits.astype(cfg.logits_dtype or jnp.float32)
+        return times(logits.astype(cfg.logits_dtype or jnp.float32),
+                     cfg.lm_head_multiplier)
 
     def _scanned_blocks(self, x, selection, kinds, policy, *args):
         """The scanned stack; ``kinds``: its layers' of a model with an

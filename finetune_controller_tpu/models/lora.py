@@ -39,6 +39,21 @@ MLA_TARGETS = (
     "down_proj",
 )
 
+#: the projections of a hybrid block (``models/llama.py`` Block with
+#: ``models/ssm.py`` Mamba2Mixer beside attention): attention's four, the
+#: mixer's two and the MLP's three
+HYBRID_TARGETS = (
+    "q_proj",
+    "k_proj",
+    "v_proj",
+    "o_proj",
+    "in_proj",
+    "out_proj",
+    "gate_proj",
+    "up_proj",
+    "down_proj",
+)
+
 
 @dataclasses.dataclass(frozen=True)
 class LoRAConfig:

@@ -129,11 +129,19 @@ LLAMA_RULES = PartitionRules(
         # QLoRA int4 scales: (in/block, out) — the block dim is tiny, keep it
         # whole and shard only the feature dim (must precede the kernel rules,
         # which would otherwise also match "kernel_scales")
-        (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/kernel_scales", P(None, Ax.TENSOR)),
-        (r"(o_proj|down_proj)/kernel_scales", P(None, Ax.FSDP)),
+        (r"(q_proj|k_proj|v_proj|gate_proj|up_proj|in_proj)/kernel_scales", P(None, Ax.TENSOR)),
+        (r"(o_proj|down_proj|out_proj)/kernel_scales", P(None, Ax.FSDP)),
         # attention projections (kernel and int4-packed kernel share layout)
         (r"(q_proj|k_proj|v_proj)/kernel", P(Ax.FSDP, Ax.TENSOR)),
         (r"o_proj/kernel", P(Ax.TENSOR, Ax.FSDP)),
+        # the state-space mixer (models/ssm.py): its input projection splits
+        # by output feature and its output projection by input feature, as
+        # attention's do (heads over TP); the convolution, the per-head
+        # A_log / D / dt_bias and the gated norm's scale are 30 k numbers a
+        # layer, whole everywhere DELIBERATELY
+        (r"in_proj/kernel", P(Ax.FSDP, Ax.TENSOR)),
+        (r"out_proj/kernel", P(Ax.TENSOR, Ax.FSDP)),
+        (r"mamba/(conv1d|A_log|D|dt_bias|norm)/", P()),
         # latent attention (models/llama.py MLAttention): the down-projections
         # into the latents feed a norm over the whole latent, so their output
         # stays whole over TP; the up-projections out of the latents split by
@@ -161,13 +169,13 @@ LLAMA_RULES = PartitionRules(
         (r"vision_tower/", P()),
         # LoRA adapters: A (in, r) sharded like the frozen kernel's input dim;
         # B (r, out) over the output dim.  Rank r is tiny — keep it replicated.
-        (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/lora_a", P(Ax.FSDP, None)),
-        (r"(q_proj|k_proj|v_proj|gate_proj|up_proj)/lora_b", P(None, Ax.TENSOR)),
+        (r"(q_proj|k_proj|v_proj|gate_proj|up_proj|in_proj)/lora_a", P(Ax.FSDP, None)),
+        (r"(q_proj|k_proj|v_proj|gate_proj|up_proj|in_proj)/lora_b", P(None, Ax.TENSOR)),
         (r"(q_a_proj|kv_a_proj_with_mqa|q_b_proj|kv_b_proj)/lora_a", P(Ax.FSDP, None)),
         (r"(q_a_proj|kv_a_proj_with_mqa)/lora_b", P()),
         (r"(q_b_proj|kv_b_proj)/lora_b", P(None, Ax.TENSOR)),
-        (r"o_proj/lora_a|down_proj/lora_a", P(Ax.TENSOR, None)),
-        (r"o_proj/lora_b|down_proj/lora_b", P(None, Ax.FSDP)),
+        (r"(o_proj|down_proj|out_proj)/lora_a", P(Ax.TENSOR, None)),
+        (r"(o_proj|down_proj|out_proj)/lora_b", P(None, Ax.FSDP)),
         # norms, scales, biases — replicated
         (r".*", P()),
     ]

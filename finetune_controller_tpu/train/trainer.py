@@ -280,6 +280,13 @@ class Trainer:
             self.model = LlamaForCausalLM(model_cfg)
 
         self._pp = self.mesh.shape.get("pp", 1)
+        if getattr(model_cfg, "ssm_d_inner", 0) and (
+                self._pp > 1 or self.mesh.shape.get("sp", 1) > 1):
+            raise ValueError(
+                "a model with a state-space mixer trains with sp = pp = 1: a "
+                "scan over a split sequence needs a state hand-off between "
+                "members, and the pipeline's stage body does not hold the "
+                "mixer (ROADMAP.md B13)")
         if self._pp > 1:
             from ..parallel.pipeline import validate_pp_mesh
 
@@ -1596,8 +1603,9 @@ class Trainer:
         as JAX reports it, the mesh, which attention implementation the step
         resolves to (with the flash kernels, how much score area they compute
         over what the causal triangle needs), the row tile of a dropless
-        expert model's grouped products where the Pallas kernel runs, and the bytes the
-        freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
+        expert model's grouped products where the Pallas kernel runs, a hybrid
+        model's state-space mixers (layers, chunks a row, state bytes a row),
+        and the bytes the freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
         off JAX and learns the device from this."""
         from ..platform import device_report
 
@@ -1634,6 +1642,13 @@ class Trainer:
         if kinds:
             attrs["dsa_full_layers"] = kinds.count("full")
             attrs["dsa_shared_layers"] = kinds.count("shared")
+        if cfg.ssm_d_inner:
+            # the state-space mixers: how many, the chain of chunk states a
+            # row's scan walks in each, and the float32 state a row carries
+            attrs["ssm_layers"] = cfg.n_layers
+            attrs["ssm_chunks_per_row"] = -(-self.cfg.seq_len // cfg.ssm_chunk)
+            attrs["ssm_state_bytes_per_row"] = (
+                4 * cfg.ssm_n_heads * cfg.ssm_head_dim * cfg.ssm_d_state)
         return attrs
 
     def _device_bytes(self, stat: str) -> list[int] | None:

@@ -76,10 +76,11 @@ def tiny_job_spec(steps: int = 3):
 
 #: the tests under ``tests/benchmarks/`` that pin a ``BENCHMARK.json`` a later
 #: ``model_config`` PR appended to — three of PR 26's two cells (ISSUE 27),
-#: five of PR 27's three (ISSUE 32) — and that only a ``benchmark`` PR may
+#: five of PR 27's three (ISSUE 32), four of PR 32's four (ISSUE 36) — and that
+#: only a ``benchmark`` PR may
 #: edit (a PR of another kind changes no file the benchmark already has):
 #: ``node id -> (why, the test that holds what it held)``.  The next
-#: ``benchmark`` PR edits the eight and deletes this table and the hook under
+#: ``benchmark`` PR edits the twelve and deletes this table and the hook under
 #: it (ROADMAP.md, B1)
 SUPERSEDED = {
     "tests/benchmarks/test_benchmark_manifest.py::"
@@ -121,6 +122,26 @@ SUPERSEDED = {
         ("test_the_superseded_pins_are_three_and_each_has_its_replacement_here",
          "pins this table's length at three",
          "test_the_superseded_pins_are_eight_and_each_has_its_replacement"),
+    )},
+    # ... and the four of ``test_benchmark_mla_dsa_moe.py`` that pin PR 32's
+    # four-cell manifest, to which ISSUE 36 appends a fifth cell, four
+    # per-layer entries and its cell's name in thirteen accepted ones
+    **{"tests/benchmarks/test_benchmark_mla_dsa_moe.py::" + pin: (
+        why, "tests/benchmarks/test_benchmark_falcon_h1.py::" + held_by)
+       for pin, why, held_by in (
+        ("test_the_real_manifest_has_its_four_cells_and_no_metric_by_default",
+         "pins PR 32's four cells; ISSUE 36 adds a fifth",
+         "test_the_real_manifest_has_its_five_cells_and_no_metric_by_default"),
+        ("test_manifest_registers_and_loads_every_accepted_metric",
+         "pins every entry's cells to PR 32's four; ISSUE 36 appends its cell "
+         "to the neutral ones, the dense flash roofline and the loop's plumbing",
+         "test_manifest_registers_and_loads_every_accepted_metric"),
+        ("test_the_accepted_entries_stand_first_and_the_new_ones_last",
+         "pins the lists' ends; ISSUE 36 appends its entries",
+         "test_the_accepted_entries_stand_first_and_the_new_ones_last"),
+        ("test_the_superseded_pins_are_eight_and_each_has_its_replacement",
+         "pins this table's length at eight",
+         "test_the_superseded_pins_are_twelve_and_each_has_its_replacement"),
     )},
 }
 

@@ -79,14 +79,17 @@ def _custom_calls(compiled) -> int:
 #: a head-dim-128 GQA shape (Llama-3 / Mistral heads at a batch that fits),
 #: the two Mistral cells' calls (8 x 2048 and 2 x 8192), and latent
 #: attention's uneven pair (q/k 128 + 64, v 128; 192 is no multiple of the
-#: 128 lanes) at the expert cell's batch.  Every one runs 1024-wide blocks
-#: whose diagonal ones are computed in sub-tiles.
+#: 128 lanes) at the expert cell's batch, and the hybrid cell's call (one row
+#: of 8,192, 20 query heads over 4 key/value heads: a group of 5, where every
+#: other shape's is 1, 4 or 8).  Every one runs 1024-wide blocks whose
+#: diagonal ones are computed in sub-tiles.
 FLASH_SHAPES = {
     "tinyllama-b8-s2048": (8, 2048, 32, 4, 64),
     "d128-b2-s2048": (2, 2048, 32, 8, 128),
     "d128-b8-s2048": (8, 2048, 32, 8, 128),
     "d128-b2-s8192": (2, 8192, 32, 8, 128),
     "qk192-v128-b2-s4096": (2, 4096, 32, 32, 192, 128),
+    "d128-g5-b1-s8192": (1, 8192, 20, 4, 128),
 }
 
 
@@ -94,8 +97,10 @@ FLASH_SHAPES = {
     ("tinyllama-b8-s2048", False), ("tinyllama-b8-s2048", True),
     ("d128-b2-s2048", False), ("qk192-v128-b2-s4096", False),
     ("d128-b8-s2048", False), ("d128-b2-s8192", False), ("d128-b2-s8192", True),
+    ("d128-g5-b1-s8192", False), ("d128-g5-b1-s8192", True),
 ], ids=["tinyllama-plain", "tinyllama-segments", "d128-plain", "qk192-v128",
-        "mistral-2k-plain", "mistral-8k-plain", "mistral-8k-segments"])
+        "mistral-2k-plain", "mistral-8k-plain", "mistral-8k-segments",
+        "hybrid-8k-plain", "hybrid-8k-segments"])
 def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
     b, s, h, hkv, d, *rest = FLASH_SHAPES[shape]
     one = SingleDeviceSharding(v5e[0])
@@ -113,6 +118,63 @@ def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
         q, k, v, seg).compile()
     assert _custom_calls(compiled) == 3  # forward + dQ + dK/dV
+
+
+# ---------------------------------------------------------------------------
+# the state-space mixer (models/ssm.py): plain jnp the compiler must take
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["plain", "segments"])
+def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(v5e, segments):
+    """One block's mixer of the hybrid configuration — 32 heads of 128 over
+    128 x 256 states, chunks of 128, one row of 8,192 — with adapters on both
+    projections: value and gradients (the scan's transpose among them)
+    compile for the chip, the carry across the 64 chunks is a loop of the
+    program in BOTH passes, no Mosaic kernel is involved, and what the layer's
+    backward pass holds stays under the 6 GB the step has for a block."""
+    from finetune_controller_tpu.models.llama import LlamaConfig
+    from finetune_controller_tpu.models.lora import HYBRID_TARGETS, LoRAConfig
+    from finetune_controller_tpu.models.ssm import Mamba2Mixer
+
+    cfg = LlamaConfig(
+        d_model=5120, dtype=BF16, ssm_n_heads=32,
+        ssm_head_dim=128, ssm_d_state=256, ssm_n_groups=2, ssm_d_conv=4,
+        ssm_chunk=128, ssm_in_multiplier=0.25,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        lora=LoRAConfig(rank=16, targets=HYBRID_TARGETS))
+    mixer = Mamba2Mixer(cfg)
+    one = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(
+                s.shape, BF16 if s.dtype == jnp.float32 else s.dtype,
+                sharding=one), tree)
+
+    variables = jax.eval_shape(lambda: mixer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, 5120), BF16)))
+    assert sorted(variables["params"]) == [
+        "A_log", "D", "conv1d", "dt_bias", "in_proj", "norm", "out_proj"]
+    params = on_chip(variables["params"])        # the frozen base, as stored
+    lora = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one), variables["lora"])
+    u = jax.ShapeDtypeStruct((1, 8192, 5120), BF16, sharding=one)
+    seg = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one)
+
+    def loss(u, lora, params, seg):
+        y = mixer.apply({"params": params, "lora": lora}, u,
+                        seg if segments else None)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        u, lora, params, seg).compile()
+    text = compiled.as_text()
+    assert _custom_calls(compiled) == 0
+    loops = re.findall(r"\bwhile\(", text)
+    assert len(loops) >= 2, "the carry's scan and its transpose are loops"
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,7 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 SMOKE = REPO / "chip_smoke.py"
 
-PHASES = ["grouped-parity", "server", "submit", "train", "promote",
+PHASES = ["grouped-parity", "ssd-parity", "server", "submit", "train", "promote",
           "serve-load", "serve-generate", "shutdown", "paged-parity",
           "compile-cache", "total"]
 
@@ -105,6 +105,12 @@ def test_tiny_rehearsal_runs_every_phase_and_is_not_a_chip_pass():
     assert grouped["worst_err"] <= grouped["tolerance"]
     assert {key.split(":")[1] for key in grouped["work_over_need"]} == {
         "even", "skewed", "empty_groups", "rows_no_group_covers"}
+    # the chunked scan against the token-by-token recurrence, float32 here:
+    # five and a half chunks, value and gradients
+    ssd = detail["ssd-parity"]
+    assert ssd["compiled"] is False
+    assert ssd["worst_err"] <= ssd["tolerance"]
+    assert ssd["errs_by_shape"]["44x4x8x2x6x8"]["chunks"] == 6
     # the last line is a rehearsal record: no "ok" anywhere on stdout
     last = json.loads(lines[-1])
     assert last == {"rehearsal": "tiny", "passed": True,
@@ -133,7 +139,7 @@ def test_a_failed_phase_exits_nonzero_and_prints_no_result(tmp_path):
     assert "unknown device 'cpu-test'" in out.stderr
     phases = [l for l in out.stdout.splitlines() if l.startswith("phase ")]
     assert [p.split(":")[0] for p in phases] == [
-        "phase grouped-parity", "phase server"]
+        "phase grouped-parity", "phase ssd-parity", "phase server"]
     assert _result_lines(out.stdout) == []
     # and nothing it started is left behind
     leftovers = subprocess.run(
@@ -264,6 +270,22 @@ def test_full_mode_checks_the_grouped_product_at_both_expert_cells_shapes(smoke)
     assert [16384, 16, 6144, 2048, 2] in shapes and [16384, 16, 2048, 6144, 2] in shapes
     assert {moe.gmm_row_tile(m, g) for m, g, *_ in shapes} == {512, 256, 128}
     assert all(m % 128 == 0 for m, *_ in shapes)     # the Pallas kernel's rows
+
+
+def test_full_mode_checks_the_chunked_scan_at_the_hybrid_cells_widths(smoke):
+    """(rows, heads, head size, groups, state size, chunk): one block's scan of
+    the hybrid configuration as published — 32 heads of 128 over 128 x 256
+    states, B and C in 2 groups, chunks of 128 — on 1,024 rows, eight chunks,
+    within a tolerance that bf16 products allow and a dropped carry does not."""
+    import json as _json
+
+    conf = _json.loads((REPO / "benchmarks/configs/falcon-h1-34b-lora.json").read_text())
+    (shape,) = smoke.mode_config(tiny=False, seed=0)["ssd_shapes"]
+    assert shape == [1024, conf["mamba_n_heads"], conf["mamba_d_head"],
+                     conf["mamba_n_groups"], conf["mamba_d_state"],
+                     conf["mamba_chunk_size"]]
+    assert shape[0] // shape[-1] == 8 and smoke.SSD_TOL == 2 ** -6
+    assert "recurrence" in smoke.SSD_PARITY_SNIPPET and "ssd_chunked" in smoke.SSD_PARITY_SNIPPET
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,445 @@
+"""The state-space mixer (``models/ssm.py``) and the hybrid block that runs it
+beside attention (``models/llama.py``): the chunked scan against the
+token-by-token recurrence at the family's own decays, packed documents against
+the same documents alone, the block's shape, every muP multiplier, and what the
+model refuses."""
+
+import sys
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+from benchmarks.reference.falcon_h1 import recurrence  # noqa: E402
+from finetune_controller_tpu.models import llama, ssm  # noqa: E402
+from finetune_controller_tpu.models.llama import PRESETS, LlamaForCausalLM  # noqa: E402
+from finetune_controller_tpu.models.lora import HYBRID_TARGETS, LoRAConfig  # noqa: E402
+from finetune_controller_tpu.train.losses import next_token_loss  # noqa: E402
+
+TINY = PRESETS["tiny-falcon-h1-test"].replace(
+    dtype=jnp.float32, lora=LoRAConfig(rank=4, targets=HYBRID_TARGETS))
+MULTIPLIERS = ("embedding_multiplier", "lm_head_multiplier",
+               "attention_in_multiplier", "attention_out_multiplier",
+               "key_multiplier", "ssm_in_multiplier", "ssm_out_multiplier",
+               *(f"ssm_multipliers[{i}]" for i in range(5)),
+               "mlp_multipliers[0]", "mlp_multipliers[1]")
+
+
+def _scan_inputs(seq, seed=0, bsz=2, h=4, p=8, g=2, n=6):
+    """Inputs at the family's initialisation: ``A`` from [1, 16], step sizes
+    log-uniform in [0.001, 0.1] — a row decays by 0.2 to 0.999, so a state
+    crosses MANY chunks of 8."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(bsz, seq, h, p)).astype(np.float32)
+    b = rng.normal(size=(bsz, seq, g, n)).astype(np.float32)
+    c = rng.normal(size=(bsz, seq, g, n)).astype(np.float32)
+    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (bsz, seq, h))).astype(np.float32)
+    a = -rng.uniform(1.0, 16.0, (h,)).astype(np.float32)
+    d = rng.normal(size=(h,)).astype(np.float32)
+    return tuple(jnp.asarray(t) for t in (x, dt, a, b, c, d))
+
+
+def _token_by_token(x, dt, a, b, c, d, runs=None):
+    """``ssd_chunked``'s result by the recurrence itself (the reference's)."""
+    bsz, s, h, p = x.shape
+    g = b.shape[2]
+    xg = x.reshape(bsz, s, g, h // g, p)
+    dtg = dt.reshape(bsz, s, g, h // g)
+    log_decay = dtg * a.reshape(g, h // g)
+    if runs is not None:
+        starts = jnp.pad(runs[:, 1:] != runs[:, :-1], ((0, 0), (1, 0)))
+        log_decay = jnp.where(starts[..., None, None], -jnp.inf, log_decay)
+    y = recurrence(xg * dtg[..., None], log_decay, b, c, block=5)
+    return (y + xg * d.reshape(g, h // g, 1)).reshape(bsz, s, h, p)
+
+
+@pytest.mark.parametrize("seq", [64, 53, 8, 3], ids=lambda s: f"rows{s}")
+def test_chunked_scan_is_the_recurrence_forward_and_gradients(seq):
+    """At decays near 1 the carry between chunks of 8 IS the result: the
+    chunked form equals the token-by-token recurrence, values and gradients
+    of every input, whole chunks or a ragged last one."""
+    args = _scan_inputs(seq)
+
+    def loss(fn, *a):
+        y = fn(*a)
+        return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum(), y
+
+    (_, got), g_got = jax.value_and_grad(
+        lambda *a: loss(lambda *t: ssm.ssd_chunked(*t, chunk=8), *a),
+        argnums=(0, 1, 2, 3, 4, 5), has_aux=True)(*args)
+    (_, want), g_want = jax.value_and_grad(
+        lambda *a: loss(_token_by_token, *a),
+        argnums=(0, 1, 2, 3, 4, 5), has_aux=True)(*args)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    for a, b in zip(g_got, g_want):
+        np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3 * float(jnp.abs(b).max()))
+
+
+def test_the_carry_between_chunks_weighs_in_this_test():
+    """The same inputs with every chunk's entering state dropped (each chunk
+    alone) give another result by far: the test above holds the carry."""
+    x, dt, a, b, c, d = _scan_inputs(64)
+    d = jnp.zeros_like(d)           # the skip is no part of the state
+    whole = ssm.ssd_chunked(x, dt, a, b, c, d, chunk=8)
+    alone = jnp.concatenate([
+        ssm.ssd_chunked(*(t[:, i:i + 8] for t in (x, dt)), a,
+                        *(t[:, i:i + 8] for t in (b, c)), d, chunk=8)
+        for i in range(0, 64, 8)], axis=1)
+    gap = jnp.abs(whole - alone)[:, 8:].mean() / jnp.abs(whole).mean()
+    assert gap > 0.2, float(gap)
+
+
+@pytest.mark.parametrize("chunk", [4, 8, 16, 128])
+def test_the_chunk_size_does_not_change_the_result(chunk):
+    args = _scan_inputs(40, seed=1)
+    np.testing.assert_allclose(ssm.ssd_chunked(*args, chunk=chunk),
+                               ssm.ssd_chunked(*args, chunk=40),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_large_steps_never_overflow():
+    """A pair ``s > t`` has a positive exponent, here up to 8 * 50 * 16: it is
+    excluded before ``exp``, so values and gradients stay finite."""
+    x, dt, a, b, c, d = _scan_inputs(32, seed=2)
+    dt = dt * 500.0
+
+    def total(x, dt):
+        return ssm.ssd_chunked(x, dt, a, b, c, d, chunk=8).sum()
+
+    value, grads = jax.value_and_grad(total, argnums=(0, 1))(x, dt)
+    assert np.isfinite(value) and all(np.isfinite(g).all() for g in grads)
+
+
+RUNS = np.asarray([[0] * 5 + [1] * 14 + [2] * 9 + [3] * 12,
+                   [0] * 24 + [1] * 1 + [2] * 15])
+
+
+def test_packed_scan_restarts_at_a_document_boundary():
+    """Documents packed into rows of 40 (boundaries inside chunks, on a chunk's
+    edge, a document of one row, one that spans three chunks) = the
+    token-by-token recurrence with its state zeroed at each boundary = every
+    document scanned alone."""
+    x, dt, a, b, c, d = _scan_inputs(40, seed=3)
+    runs = jnp.asarray(RUNS)
+    got = ssm.ssd_chunked(x, dt, a, b, c, d, runs, chunk=8)
+    np.testing.assert_allclose(got, _token_by_token(x, dt, a, b, c, d, runs),
+                               rtol=2e-4, atol=2e-4)
+    for row in range(2):
+        for doc in np.unique(RUNS[row]):
+            at = np.flatnonzero(RUNS[row] == doc)
+            alone = ssm.ssd_chunked(
+                *(t[row:row + 1, at] for t in (x, dt)), a,
+                *(t[row:row + 1, at] for t in (b, c)), d, chunk=8)
+            np.testing.assert_allclose(got[row:row + 1, at], alone,
+                                       rtol=2e-4, atol=2e-4)
+
+
+def test_document_runs_count_changes_of_id_whatever_the_ids():
+    ids = jnp.asarray([[7, 7, 3, 3, 3, 7, 0, 0], [1, 1, 1, 1, 1, 1, 1, 2]])
+    np.testing.assert_array_equal(
+        ssm.document_runs(ids), [[0, 0, 1, 1, 1, 2, 3, 3], [0, 0, 0, 0, 0, 0, 0, 1]])
+
+
+def test_convolution_is_causal_and_sees_zeros_before_a_document():
+    rng = np.random.default_rng(4)
+    x = jnp.asarray(rng.normal(size=(1, 9, 3)).astype(np.float32))
+    w = jnp.asarray(rng.normal(size=(4, 3)).astype(np.float32))
+    bias = jnp.asarray(rng.normal(size=(3,)).astype(np.float32))
+    want = np.stack([
+        bias + sum(w[3 - j] * x[0, t - j] for j in range(4) if t - j >= 0)
+        for t in range(9)])
+    np.testing.assert_allclose(ssm.causal_conv(x, w, bias)[0], want, rtol=1e-5,
+                               atol=1e-6)
+    runs = jnp.asarray([[0, 0, 0, 0, 0, 1, 1, 1, 1]])
+    packed = ssm.causal_conv(x, w, bias, runs)
+    np.testing.assert_allclose(packed[:, :5], ssm.causal_conv(x[:, :5], w, bias),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(packed[:, 5:], ssm.causal_conv(x[:, 5:], w, bias),
+                               rtol=1e-5, atol=1e-6)
+
+
+# ---- the model -------------------------------------------------------------------
+
+
+def _variables(cfg=TINY, seed=0, seq=24):
+    model = LlamaForCausalLM(cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(seed + 1), (2, seq), 0,
+                                cfg.vocab_size)
+    variables = model.init({"params": jax.random.PRNGKey(seed)}, tokens)
+    # adapters of a job in mid-training: a zero B would hide A's gradient
+    lora = jax.tree_util.tree_map_with_path(
+        lambda p, a: 0.05 * jax.random.normal(
+            jax.random.PRNGKey(hash(str(p)) % 2**31), a.shape, a.dtype),
+        variables["lora"])
+    return model, {"params": variables["params"], "lora": lora}, tokens
+
+
+def _loss(cfg, variables, tokens, **kw):
+    logits = LlamaForCausalLM(cfg).apply(variables, tokens, **kw)
+    return next_token_loss(logits, tokens, None)[0]
+
+
+def test_packed_documents_are_the_same_documents_alone():
+    """Three documents packed into one row with ``segment_ids`` give each the
+    logits it gets alone (attention, the convolution and the scan all restart;
+    positions restart with the document)."""
+    model, variables, _ = _variables()
+    lengths = (7, 12, 5)
+    docs = [jax.random.randint(jax.random.PRNGKey(10 + i), (1, n), 0, 256)
+            for i, n in enumerate(lengths)]
+    packed = jnp.concatenate(docs, axis=1)
+    seg = jnp.concatenate([jnp.full((1, n), i + 1) for i, n in enumerate(lengths)], 1)
+    pos = jnp.concatenate([jnp.arange(n)[None] for n in lengths], axis=1)
+    got = model.apply(variables, packed, positions=pos, segment_ids=seg)
+    at = 0
+    for doc in docs:
+        alone = model.apply(variables, doc)
+        np.testing.assert_allclose(got[:, at:at + doc.shape[1]], alone,
+                                   rtol=2e-4, atol=2e-5)
+        at += doc.shape[1]
+    # and without the ids the documents DO leak into each other
+    leaked = model.apply(variables, packed, positions=pos)
+    assert float(jnp.abs(leaked - got)[:, lengths[0]:].max()) > 1e-4
+
+
+def test_block_is_attention_plus_mixer_under_one_norm_not_in_sequence():
+    """The block's first half, rebuilt from its parts on the SAME normed
+    input: ``h + attn(u) * a_out + mixer(u) * s_out``; feeding the mixer the
+    attention's result instead (one after the other) gives another block."""
+    cfg = TINY.replace(scan_layers=False, n_layers=1, remat=False)
+    model, variables, tokens = _variables(cfg)
+    block = {c: variables[c]["layer_0"] for c in ("params", "lora")}
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(24), (2, 24))
+    got = llama.Block(cfg).apply(block, x, pos, None)
+
+    def part(name, module, *args):
+        return module.apply({c: block[c][name] for c in ("params", "lora")
+                             if name in block[c]}, *args)
+
+    u = part("attn_norm", llama.RMSNorm(cfg.rms_eps, cfg.dtype), x)
+    attn = part("attn", llama.Attention(cfg), u * cfg.attention_in_multiplier,
+                pos, None) * cfg.attention_out_multiplier
+    mixer = part("mamba", ssm.Mamba2Mixer(cfg), u) * cfg.ssm_out_multiplier
+    h = x + attn + mixer
+    want = h + part("mlp", llama.MLP(cfg),
+                    part("mlp_norm", llama.RMSNorm(cfg.rms_eps, cfg.dtype), h))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(attn).mean()) > 0 and float(jnp.abs(mixer).mean()) > 0
+    in_sequence = x + attn
+    in_sequence = in_sequence + part(
+        "mamba", ssm.Mamba2Mixer(cfg),
+        part("attn_norm", llama.RMSNorm(cfg.rms_eps, cfg.dtype), in_sequence)
+    ) * cfg.ssm_out_multiplier
+    assert float(jnp.abs(in_sequence - h).max()) > 1e-4
+
+
+def _moved(cfg, name):
+    if "[" in name:
+        field, at = name[:-1].split("[")
+        values = list(getattr(cfg, field))
+        values[int(at)] *= 1.5
+        return cfg.replace(**{field: tuple(values)})
+    return cfg.replace(**{name: getattr(cfg, name) * 1.5})
+
+
+#: every multiplier of order one and no two alike (at the published values
+#: some move a tiny model's float32 loss by less than its rounding)
+LIVE = TINY.replace(
+    embedding_multiplier=1.3, lm_head_multiplier=0.9, attention_in_multiplier=1.2,
+    attention_out_multiplier=0.8, key_multiplier=1.4, ssm_in_multiplier=0.7,
+    ssm_out_multiplier=1.1, ssm_multipliers=(0.6, 1.5, 0.75, 1.25, 0.85),
+    mlp_multipliers=(1.35, 0.65))
+
+
+@pytest.mark.parametrize("name", MULTIPLIERS)
+def test_every_multiplier_is_live(name):
+    """Each of the fourteen muP scalars, moved alone, changes the loss: none
+    is dropped on the way from the configuration to the block."""
+    _, variables, tokens = _variables()
+    base = float(_loss(LIVE, variables, tokens))
+    moved = float(_loss(_moved(LIVE, name), variables, tokens))
+    assert abs(moved - base) > 1e-5 * abs(base), (name, base, moved)
+
+
+def test_multipliers_of_one_trace_no_operation():
+    """A model without multipliers traces the program it always did: the
+    accepted cells' steps keep their operations."""
+    plain = PRESETS["tiny-test"].replace(dtype=jnp.float32)
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    model = LlamaForCausalLM(plain)
+    variables = model.init({"params": jax.random.PRNGKey(0)}, tokens)
+    ops = str(jax.make_jaxpr(lambda v: model.apply(v, tokens))(variables))
+    ones = plain.replace(mlp_multipliers=(1.0, 1.0), key_multiplier=1.0,
+                         embedding_multiplier=1.0, lm_head_multiplier=1.0)
+    assert ops == str(jax.make_jaxpr(
+        lambda v: LlamaForCausalLM(ones).apply(v, tokens))(variables))
+    scaled = plain.replace(mlp_multipliers=(0.5, 1.0))
+    assert ops.count(" mul ") < str(jax.make_jaxpr(
+        lambda v: LlamaForCausalLM(scaled).apply(v, tokens))(variables)).count(" mul ")
+
+
+@pytest.mark.parametrize("policy", ["full", "mlp", "none"])
+def test_scanned_remat_stack_computes_the_unrolled_models_gradients(policy):
+    """The mixer inside the scanned stack under a remat policy (its scan runs
+    forward, again in the recompute, and its transpose on the way back) gives
+    the unrolled, un-rematerialised model's loss and adapter gradients."""
+    cfg = TINY.replace(remat_policy=policy)
+    _, variables, tokens = _variables(cfg)
+    seg = jnp.asarray(np.repeat([[1] * 10 + [2] * 14], 2, axis=0))
+
+    def grads(c, v):
+        return jax.value_and_grad(
+            lambda lora: _loss(c, {"params": v["params"], "lora": lora}, tokens,
+                               segment_ids=seg))(v["lora"])
+
+    loss, got = grads(cfg, variables)
+    flat = cfg.replace(scan_layers=False, remat=False)
+    unrolled = {c: {**{k: v for k, v in variables[c].items() if k != "blocks"},
+                    **{f"layer_{i}": jax.tree.map(lambda a: a[i],
+                                                  variables[c]["blocks"]["block"])
+                       for i in range(cfg.n_layers)}}
+                for c in ("params", "lora")}
+    want_loss, want = grads(flat, unrolled)
+    np.testing.assert_allclose(loss, want_loss, rtol=1e-6)
+    for i in range(cfg.n_layers):
+        for a, b in zip(
+                jax.tree.leaves(jax.tree.map(lambda t: t[i], got["blocks"]["block"])),
+                jax.tree.leaves(want[f"layer_{i}"])):
+            np.testing.assert_allclose(a, b, rtol=2e-4, atol=1e-9)
+
+
+def test_every_mixer_leaf_is_frozen_but_both_projections_carry_adapters():
+    _, variables, _ = _variables()
+    mamba = variables["params"]["blocks"]["block"]["mamba"]
+    assert {k: sorted(v) for k, v in mamba.items()} == {
+        "A_log": ["bias"], "D": ["scale"], "dt_bias": ["bias"],
+        "conv1d": ["bias", "kernel"], "in_proj": ["kernel"], "norm": ["scale"],
+        "out_proj": ["kernel"]}
+    assert mamba["in_proj"]["kernel"].shape == (2, 64, 64 + 64 + 2 * 2 * 8 + 4)
+    assert mamba["conv1d"]["kernel"].shape == (2, 4, 64 + 2 * 2 * 8)
+    assert sorted(variables["lora"]["blocks"]["block"]["mamba"]) == [
+        "in_proj", "out_proj"]
+    # the family's initialisation: A in [1, 16], step sizes in [0.001, 0.1]
+    fresh = LlamaForCausalLM(TINY).init(
+        {"params": jax.random.PRNGKey(3)}, jnp.zeros((1, 8), jnp.int32))
+    fresh = fresh["params"]["blocks"]["block"]["mamba"]
+    a = np.exp(np.asarray(fresh["A_log"]["bias"]))
+    step = np.log1p(np.exp(np.asarray(fresh["dt_bias"]["bias"])))
+    assert (a >= 1).all() and (a <= 16).all()
+    assert (step >= 1e-3 * 0.999).all() and (step <= 1e-1 * 1.001).all()
+
+
+def test_param_counts_know_the_mixer():
+    _, variables, _ = _variables()
+    held = sum(a.size for a in jax.tree.leaves(variables["params"]))
+    assert TINY.param_count() == TINY.active_param_count() == held
+    # the published widths, one layer: ISSUE 36's arithmetic
+    big = llama.LlamaConfig(
+        vocab_size=32640, d_model=5120, n_layers=9, n_heads=20, n_kv_heads=4,
+        d_ff=21504, head_dim_override=128, ssm_n_heads=32,
+        ssm_head_dim=128, ssm_d_state=256, ssm_n_groups=2)
+    assert big._attention_params() == 31_457_280
+    assert big._mixer_params() == 47_349_760 + 20_971_520 + 29_792 == 68_351_072
+    assert big.param_count() == 9 * (31_457_280 + 68_351_072 + 330_301_440
+                                     + 2 * 5120) + 2 * 32640 * 5120 + 5120
+    assert big.param_count() == pytest.approx(4.205e9, rel=2e-4)
+
+
+def test_decode_raises():
+    model, variables, tokens = _variables()
+    with pytest.raises(NotImplementedError, match="decode"):
+        model.apply(variables, tokens, decode=True, mutable=["cache"])
+    with pytest.raises(NotImplementedError, match="decode"):
+        ssm.Mamba2Mixer(TINY).apply(
+            {c: variables[c]["blocks"]["block"]["mamba"] for c in variables},
+            jnp.zeros((1, 4, 64)), decode=True)
+
+
+def test_mixer_stands_beside_grouped_query_attention_only():
+    mla = PRESETS["tiny-mla-moe-test"].replace(
+        ssm_n_heads=4, ssm_head_dim=16, ssm_d_state=8)
+    with pytest.raises(ValueError, match="grouped-query"):
+        LlamaForCausalLM(mla).init({"params": jax.random.PRNGKey(0)},
+                                   jnp.zeros((1, 8), jnp.int32))
+    with pytest.raises(ValueError, match="groups do not divide"):
+        LlamaForCausalLM(TINY.replace(ssm_n_heads=3)).init(
+            {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32))
+
+
+def test_pipeline_stage_refuses_a_mixer():
+    with pytest.raises(NotImplementedError, match="pipeline"):
+        llama.make_block_stage_fn(TINY)
+
+
+@pytest.mark.parametrize("axis", ["sp", "pp"])
+def test_trainer_refuses_a_split_sequence_and_a_pipeline(axis, devices8):
+    from finetune_controller_tpu.parallel.mesh import MeshSpec
+    from finetune_controller_tpu.train.trainer import TrainConfig, Trainer
+
+    mesh = MeshSpec(**{axis: 2}).build(devices8[:2])
+    with pytest.raises(ValueError, match="sp = pp = 1"):
+        Trainer(TINY, TrainConfig(mode="lora", batch_size=2, seq_len=16,
+                                  total_steps=2), mesh=mesh)
+
+
+def test_mixer_refuses_a_sequence_split_over_sp(devices8):
+    from finetune_controller_tpu.parallel.mesh import MeshSpec
+    from finetune_controller_tpu.parallel.ring import ring_mesh
+
+    model, variables, tokens = _variables()
+    with ring_mesh(MeshSpec(sp=2).build(devices8[:2])):
+        with pytest.raises(NotImplementedError, match="sequence-parallel"):
+            model.apply(variables, tokens)
+
+
+def test_trainer_steps_under_tensor_parallelism_as_on_one_device(devices8):
+    """The mixer's partition rules (projections over ``tp`` and ``fsdp``, the
+    small leaves whole): two steps on a 2 x 2 mesh give one device's losses,
+    and ``train-started`` carries the mixer's three counters."""
+    from finetune_controller_tpu.parallel.mesh import MeshSpec
+    from finetune_controller_tpu.train.trainer import TrainConfig, Trainer
+
+    def run(mesh):
+        trainer = Trainer(TINY, TrainConfig(
+            mode="lora", batch_size=4, seq_len=24, total_steps=4,
+            learning_rate=0.01, warmup_steps=0, frozen_dtype="bfloat16",
+            log_every=10**9, checkpoint_every=10**9), mesh=mesh)
+        state = trainer.init_state()
+        rng = np.random.default_rng(0)
+        losses = []
+        for _ in range(2):
+            tokens = rng.integers(0, 256, (4, 24)).astype(np.int32)
+            state, m = trainer.step(state, trainer._shard_batch(
+                {"tokens": tokens, "loss_mask": np.ones((4, 24), np.float32),
+                 "segment_ids": np.repeat([[1] * 9 + [2] * 15], 4, 0).astype(np.int32)}))
+            losses.append(float(m["loss"]))
+        return trainer, state, losses
+
+    one, state, want = run(MeshSpec(fsdp=1).build(devices8[:1]))
+    attrs = one._runtime_attrs()
+    assert (attrs["ssm_layers"], attrs["ssm_chunks_per_row"],
+            attrs["ssm_state_bytes_per_row"]) == (2, 3, 4 * 4 * 16 * 8)
+    mamba = state.frozen["params"]["blocks"]["block"]["mamba"]
+    assert {mamba[k][leaf].dtype for k, leaf in (
+        ("A_log", "bias"), ("D", "scale"), ("dt_bias", "bias"))} == {
+            jnp.dtype(jnp.bfloat16)}
+    _, _, got = run(MeshSpec(fsdp=2, tp=2).build(devices8[:4]))
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    assert got[1] != got[0]
+
+
+def test_merged_export_refuses_a_mixer_before_writing(tmp_path):
+    """No transformers layout is written for the hybrid block yet: the
+    exporter refuses (the trainer then ships the adapter alone) instead of
+    writing a Llama checkpoint that silently lacks the mixer."""
+    from finetune_controller_tpu.models.hf_export import export_merged_checkpoint
+
+    with pytest.raises(NotImplementedError, match="state-space mixer"):
+        export_merged_checkpoint(TINY, {"params": {}}, tmp_path / "nope")
+    assert not (tmp_path / "nope").exists()
