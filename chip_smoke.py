@@ -251,6 +251,53 @@ print(json.dumps({"device": device_report(),
 """
 
 
+#: the joined LoRA product against the layer's old expression: the forms
+#: differ by where they round (apart: base, delta, sum; joined: once), by
+#: two bf16 ulps of the largest magnitude at most
+LORA_TOL = 2 ** -6
+
+LORA_PARITY_SNIPPET = r"""
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from finetune_controller_tpu.platform import device_report, enable_compile_cache
+from finetune_controller_tpu.models.lora import joined_product
+from finetune_controller_tpu.models.quant import dequantize_int4, quantize_int4
+
+enable_compile_cache()
+cases = []
+for n_case, (rows, n_in, n_out, rank, block, scale) in enumerate(json.loads(sys.argv[1])):
+    rng = np.random.default_rng(n_case)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    packed, scales = quantize_int4(draw(n_in, n_out) * n_in ** -0.5, block)
+    x, cot = draw(rows, n_in).astype(jnp.bfloat16), draw(rows, n_out).astype(jnp.bfloat16)
+    a, b = draw(n_in, rank) * 0.02, draw(rank, n_out) * 0.02
+
+    def joined(x, a, b):
+        return joined_product(x, dequantize_int4(packed, scales), a, b, scale)
+
+    def apart(x, a, b):
+        y = x @ dequantize_int4(packed, scales)
+        return y + (x @ a.astype(x.dtype)) @ b.astype(x.dtype) * scale
+
+    def both(f):
+        def run(x, a, b, cot):
+            out, vjp = jax.vjp(f, x, a, b)
+            return (out, *vjp(cot))
+        return jax.jit(run)
+
+    got, want = both(joined)(x, a, b, cot), both(apart)(x, a, b, cot)
+    errs = [float(jnp.max(jnp.abs(u.astype(jnp.float32) - v.astype(jnp.float32)))
+                  / jnp.max(jnp.abs(v.astype(jnp.float32))))
+            for u, v in zip(got, want)]
+    cases.append({"shape": [rows, n_in, n_out, rank], "value_err": errs[0],
+                  "grad_err": max(errs[1:]),
+                  "finite": all(bool(jnp.all(jnp.isfinite(u.astype(jnp.float32))))
+                                for u in got)})
+print(json.dumps({"device": device_report(),
+                  "compiled": jax.default_backend() == "tpu", "cases": cases}))
+"""
+
+
 class SmokeFailure(Exception):
     """A phase did not do what it had to; the run ends non-zero."""
 
@@ -296,6 +343,9 @@ def mode_config(tiny: bool, seed: int) -> dict:
             "grouped_shapes": [[256, 8, 64, 32, 2]],
             # the chunked scan against the recurrence: five and a half chunks
             "ssd_shapes": [[44, 4, 8, 2, 6, 8]],
+            # the joined LoRA product against the layer's old expression
+            # (rows, in, out, rank, quantisation block, scale)
+            "lora_shapes": [[48, 64, 96, 4, 16, 2.0]],
         }
     return {
         "platform": "tpu", "model_name": "tinyllama-1.1b-lora",
@@ -325,6 +375,9 @@ def mode_config(tiny: bool, seed: int) -> dict:
         # one block's scan of the hybrid configuration at its published
         # widths: 1,024 rows, eight chunks of 128, 32 heads of 128 x 256 states
         "ssd_shapes": [[1024, 32, 128, 2, 256, 128]],
+        # one Mistral-width projection (gate / up) over an int4 base, 2,048
+        # rows, rank 16: the adapter inside the base product's contraction
+        "lora_shapes": [[2048, 4096, 14336, 16, 64, 2.0]],
     }
 
 
@@ -774,12 +827,29 @@ def ssd_parity_phase(run_id: str, cfg: dict) -> dict:
     return rec["device"]
 
 
+def lora_parity_phase(run_id: str, cfg: dict) -> dict:
+    t0 = time.monotonic()
+    rec = parity_child(run_id, cfg, "lora", LORA_PARITY_SNIPPET,
+                       json.dumps(cfg["lora_shapes"]))
+    worst = max(max(c["value_err"], c["grad_err"]) for c in rec["cases"])
+    check(worst <= LORA_TOL,
+          f"joined LoRA product off the layer's old expression by {worst} > "
+          f"{LORA_TOL} of the largest magnitude: {rec['cases']}")
+    say("lora-parity", time.monotonic() - t0, compiled=rec["compiled"],
+        tolerance=LORA_TOL, worst_err=worst,
+        errs_by_shape={"x".join(map(str, c["shape"])):
+                       {"value": c["value_err"], "grad": c["grad_err"]}
+                       for c in rec["cases"]})
+    return rec["device"]
+
+
 def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     _, entries0 = cache_state()
     # first, while nothing holds the chip: a tile that faults ends the run
     # here, in a minute
     grouped_device = grouped_parity_phase(run_id, cfg)
     ssd_device = ssd_parity_phase(run_id, cfg)
+    lora_device = lora_parity_phase(run_id, cfg)
     t0 = time.monotonic()
     server, api, log = start_server(work, run_id, cfg["platform"])
     say("server", time.monotonic() - t0, pid=server.pid, log=str(log))
@@ -807,10 +877,10 @@ def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     say("shutdown", time.monotonic() - t1, survivors=[])
     parity_device = paged_parity_phase(run_id, cfg)
     check(train_device == serve_device == parity_device == grouped_device
-          == ssd_device,
+          == ssd_device == lora_device,
           f"children disagree on the device: trainer {train_device}, "
           f"serve worker {serve_device}, parity children {parity_device}, "
-          f"{grouped_device}, {ssd_device}")
+          f"{grouped_device}, {ssd_device}, {lora_device}")
     cache_dir, entries1 = cache_state()
     say("compile-cache", 0.0, dir=cache_dir,
         entries_before=entries0, entries_after=entries1)

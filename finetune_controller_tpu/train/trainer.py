@@ -430,7 +430,7 @@ class Trainer:
 
     def _build(self) -> None:
         rng = jax.random.PRNGKey(self.cfg.seed)
-        shapes = jax.eval_shape(self.raw_init, rng)
+        shapes = self._state_shapes = jax.eval_shape(self.raw_init, rng)
         self._state_shardings = sharding_for_tree(shapes, self.mesh, self.rules)
         self._batch_sharding = batch_sharding(self.mesh)
         from ..parallel.mesh import AxisNames as Ax
@@ -1605,7 +1605,8 @@ class Trainer:
         over what the causal triangle needs), the row tile of a dropless
         expert model's grouped products where the Pallas kernel runs, a hybrid
         model's state-space mixers (layers, chunks a row, state bytes a row),
-        and the bytes the freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
+        which adapted projections carry their adapter inside the base
+        product, and the bytes the freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
         off JAX and learns the device from this."""
         from ..platform import device_report
 
@@ -1638,6 +1639,8 @@ class Trainer:
                     (cfg.experts_held or (0, cfg.n_experts))[1], cfg.n_experts)
             if tile:
                 attrs["moe_gmm_row_tile"] = tile
+        if cfg.lora.rank > 0 and self._pp == 1 and not self._is_multimodal:
+            attrs["lora_joined_projections"] = self._lora_joined_projections()
         kinds = cfg.indexer_kinds()
         if kinds:
             attrs["dsa_full_layers"] = kinds.count("full")
@@ -1650,6 +1653,33 @@ class Trainer:
             attrs["ssm_state_bytes_per_row"] = (
                 4 * cfg.ssm_n_heads * cfg.ssm_head_dim * cfg.ssm_d_state)
         return attrs
+
+    def _lora_joined_projections(self) -> dict:
+        """How many of the decoder's adapted projections carry their adapter
+        inside the base product, of how many, and which keep the two apart:
+        ``models/lora.py::joins_base_product`` asked as the step's trace asks
+        it — at each adapter's own widths, a microbatch's tokens, under the
+        step's mesh."""
+        from ..models import lora
+        from ..parallel.ring import ring_mesh
+
+        with ring_mesh(self.mesh):
+            devices, sharded = lora.mesh_splits()
+        rows = (self.cfg.batch_size // self.cfg.grad_accum_steps
+                * self.cfg.seq_len // devices)
+        joined = {}
+        for path, leaf in jax.tree_util.tree_leaves_with_path(
+                self._state_shapes.trainable):
+            *module, name = (k.key for k in path)
+            if name == "lora_a":    # [(layers,) in, r]
+                joined["/".join(module)] = not self._use_dropout and (
+                    lora.joins_base_product(
+                        rows, leaf.shape[-2], leaf.shape[-1], sharded=sharded))
+        return {
+            "joined": sum(joined.values()),
+            "of": len(joined),
+            "apart": sorted(p for p, j in joined.items() if not j),
+        }
 
     def _device_bytes(self, stat: str) -> list[int] | None:
         """``memory_stats()[stat]`` of every local device, or None where the

@@ -25,7 +25,7 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 SMOKE = REPO / "chip_smoke.py"
 
-PHASES = ["grouped-parity", "ssd-parity", "server", "submit", "train", "promote",
+PHASES = ["grouped-parity", "ssd-parity", "lora-parity", "server", "submit", "train", "promote",
           "serve-load", "serve-generate", "shutdown", "paged-parity",
           "compile-cache", "total"]
 
@@ -111,6 +111,12 @@ def test_tiny_rehearsal_runs_every_phase_and_is_not_a_chip_pass():
     assert ssd["compiled"] is False
     assert ssd["worst_err"] <= ssd["tolerance"]
     assert ssd["errs_by_shape"]["44x4x8x2x6x8"]["chunks"] == 6
+    # the joined LoRA product against the layer's old expression, bf16 over
+    # an int4 base: value and gradients
+    joined = detail["lora-parity"]
+    assert joined["compiled"] is False
+    assert joined["worst_err"] <= joined["tolerance"]
+    assert set(joined["errs_by_shape"]) == {"48x64x96x4"}
     # the last line is a rehearsal record: no "ok" anywhere on stdout
     last = json.loads(lines[-1])
     assert last == {"rehearsal": "tiny", "passed": True,
@@ -139,7 +145,8 @@ def test_a_failed_phase_exits_nonzero_and_prints_no_result(tmp_path):
     assert "unknown device 'cpu-test'" in out.stderr
     phases = [l for l in out.stdout.splitlines() if l.startswith("phase ")]
     assert [p.split(":")[0] for p in phases] == [
-        "phase grouped-parity", "phase ssd-parity", "phase server"]
+        "phase grouped-parity", "phase ssd-parity", "phase lora-parity",
+        "phase server"]
     assert _result_lines(out.stdout) == []
     # and nothing it started is left behind
     leftovers = subprocess.run(
@@ -286,6 +293,21 @@ def test_full_mode_checks_the_chunked_scan_at_the_hybrid_cells_widths(smoke):
                      conf["mamba_chunk_size"]]
     assert shape[0] // shape[-1] == 8 and smoke.SSD_TOL == 2 ** -6
     assert "recurrence" in smoke.SSD_PARITY_SNIPPET and "ssd_chunked" in smoke.SSD_PARITY_SNIPPET
+
+
+def test_full_mode_checks_the_joined_product_at_a_mistral_projections_width(smoke):
+    """(rows, in, out, rank, quantisation block, scale): the gate / up
+    projection of the Mistral configuration as published over its int4 base,
+    2,048 rows at the cell's rank — the joined form, value and gradients, against
+    the expression the layer had, within two bf16 ulps of the largest value."""
+    import json as _json
+
+    conf = _json.loads((REPO / "benchmarks/configs/mistral-7b-qlora.json").read_text())
+    (shape,) = smoke.mode_config(tiny=False, seed=0)["lora_shapes"]
+    assert shape[:5] == [2048, conf["hidden_size"], conf["intermediate_size"],
+                         conf["run"]["lora_rank"], conf["run"]["quant_block"]]
+    assert shape[5] != 1.0 and smoke.LORA_TOL == 2 ** -6
+    assert "joined_product" in smoke.LORA_PARITY_SNIPPET
 
 
 # ---------------------------------------------------------------------------

@@ -366,6 +366,45 @@ def test_train_started_carries_the_row_tile_where_the_kernel_runs(monkeypatch):
     assert trainer._runtime_attrs()["moe_gmm_row_tile"] == 128
 
 
+def test_train_started_says_which_projections_carry_their_adapter():
+    """``lora_joined_projections`` asks the rule at each adapter's widths and a
+    microbatch's rows; what it says is what the trace does: as many
+    ``joined_product`` calls as it counts in the model traced on that shape
+    (a scanned stack's block is traced once, and counted once)."""
+    from finetune_controller_tpu.train.trainer import TrainConfig, Trainer
+
+    cfg = PRESETS["tiny-mla-moe-test"].replace(
+        lora=LoRAConfig(rank=4, targets=MLA_TARGETS))
+
+    def reported_and_traced(seq_len):
+        trainer = Trainer(cfg, TrainConfig(
+            mode="lora", total_steps=2, batch_size=4, seq_len=seq_len,
+            grad_accum_steps=2))
+        shapes = jax.eval_shape(trainer.raw_init, jax.random.PRNGKey(0))
+        traced = str(jax.make_jaxpr(lambda v, t: trainer.model.apply(
+            v, t, mutable=True))(
+            trainer._assemble(shapes.frozen, shapes.trainable),
+            jax.ShapeDtypeStruct((2, seq_len), jnp.int32)))
+        return (trainer._runtime_attrs()["lora_joined_projections"],
+                traced.count("joined_product"))
+
+    # 112 rows a microbatch (>= 1.75 * (in + 4) up to 60 columns): the
+    # projections of 32 and 48 columns join, those of the model's 64 and the
+    # dense layer's 128 do not
+    got, traced = reported_and_traced(56)
+    assert (got["joined"], got["of"]) == (5, 16) and traced == 5
+    assert {p.rsplit("/", 1)[1] for p in got["apart"]} == {
+        "q_a_proj", "kv_a_proj_with_mqa", "o_proj", "gate_proj", "up_proj",
+        "down_proj"}
+    assert "blocks/block/moe/shared/down_proj" not in got["apart"]
+    got, traced = reported_and_traced(16)       # 32 rows: too few for any
+    assert got["joined"] == traced == 0 and len(got["apart"]) == got["of"] == 16
+    # a full fine-tune adapts nothing
+    full = Trainer(cfg.replace(lora=LoRAConfig()), TrainConfig(
+        mode="full", total_steps=2, batch_size=4, seq_len=64))
+    assert "lora_joined_projections" not in full._runtime_attrs()
+
+
 # ---- the grouped products read a layer's experts in place (ISSUE 28) ---------
 
 #: rows of each of E experts: all the rows in uneven groups, and with experts

@@ -42,8 +42,7 @@ def tiny_trainer(grad_accum_steps: int = 1, **train_kw) -> Trainer:
 
 # ---- the device's work: the compiled step's metadata ---------------------------
 
-@pytest.fixture(scope="module")
-def op_names():
+def _step_op_names():
     """``op_name`` of every instruction of the tiny train step compiled for
     the CPU, with two microbatches so that ``grad_accum`` is there.  The
     persistent cache is off: its key leaves metadata out, so a hit could hand
@@ -60,6 +59,31 @@ def op_names():
     finally:
         jax.config.update("jax_enable_compilation_cache", was)
     return sorted(set(re.findall(r'op_name="([^"]*)"', text)))
+
+
+@pytest.fixture(scope="module")
+def op_names():
+    """The step as the rule leaves it: a microbatch of 16 rows is too few for
+    any projection to take the joined form (``models/lora.py``)."""
+    from finetune_controller_tpu.models import lora
+
+    names = _step_op_names()
+    assert not any("joined_product" in n or "custom_vjp" in n for n in names)
+    assert lora.joins_base_product(16, 32, 2) is False
+    return names
+
+
+@pytest.fixture(scope="module")
+def op_names_joined():
+    """The same step with every adapted projection in the joined form."""
+    from finetune_controller_tpu.models import lora
+
+    rule = lora.joins_base_product
+    lora.joins_base_product = lambda *a, **k: True
+    try:
+        return _step_op_names()
+    finally:
+        lora.joins_base_product = rule
 
 
 #: one iteration of the scanned stack.  The stack is built in a method the
@@ -90,6 +114,19 @@ NOT_REPLAYED = {("mlp/down_proj/base_matmul", "recompute")}
 def test_layer_scope_is_in_the_step_metadata_in_each_pass(op_names, scope, which):
     rx = re.compile(PASSES[which] + "blocks/block/" + scope + "/")
     assert any(rx.search(n) for n in op_names), (scope, which)
+
+
+@pytest.mark.parametrize("scope,which", [
+    (f"{p}/{s}", w) for p in PROJECTIONS for s in ("base_matmul", "lora_delta")
+    for w in ("forward", "recompute", "backward")
+    if (f"{p}/{s}", w) not in NOT_REPLAYED])
+def test_joined_projection_keeps_both_scopes_in_each_pass(
+        op_names_joined, scope, which):
+    """The joined product sits under ``base_matmul`` and what only the
+    adapter needs under ``lora_delta``, in the hand-written backward rule
+    too: the metrics that read the two names find them in every pass."""
+    rx = re.compile(PASSES[which] + "blocks/block/" + scope + "/")
+    assert any(rx.search(n) for n in op_names_joined), (scope, which)
 
 
 @pytest.mark.parametrize("which", ["forward", "recompute"])
