@@ -17,4 +17,10 @@ Two planes:
   lazily constructed and injectable.
 """
 
+# first, so that every import after this line is on the start-up log's clock
+# (obs/trace.py::StartupLog — stdlib only, like all of ``obs``)
+from .obs import trace as _trace
+
+_trace.STARTUP.open()
+
 __version__ = "0.1.0"

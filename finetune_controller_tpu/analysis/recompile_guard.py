@@ -15,7 +15,8 @@ recompile); any other non-array leaf can only reach jit as a static
 argument, where its value IS part of the cache key.  A new signature means
 a new trace/compile.  Past ``budget`` distinct signatures the guard warns once
 (``on_excess="warn"``) or raises :class:`RecompileBudgetExceeded`
-(``on_excess="raise"``).  Where the wrapped fn exposes jit's own
+(``on_excess="raise"``), quoting the program's name and what its last compile
+cost as the process's start-up log heard it (``obs/trace.py::StartupLog``).  Where the wrapped fn exposes jit's own
 ``_cache_size()`` the guard cross-checks it, so signatures the fingerprint
 cannot see (e.g. closure captures) still surface.
 
@@ -71,6 +72,24 @@ def signature_of(*args: Any, **kwargs: Any) -> tuple:
     # TrainState-sized treedef every step would be measurable host overhead
     # inside the very windows the benchmark times
     return (treedef, tuple(_leaf_signature(x) for x in leaves))
+
+
+def _compiled_so_far(fn: Any) -> str:
+    """What the start-up log (obs/trace.py::StartupLog) heard of ``fn``'s
+    program so far — which step recompiles, and what a compile of it costs."""
+    from ..obs import trace
+
+    name = f"jit({getattr(fn, '__name__', '')})"
+    heard = trace.STARTUP.programs.get(name)
+    if heard is None:
+        return ""
+    cost = heard["trace_s"] + heard["lower_s"] + heard["backend_s"]
+    return (
+        f" {name} has compiled {heard['count']} time(s) in this process, "
+        f"the last in {cost:.2f} s (trace {heard['trace_s']:.2f}, lower "
+        f"{heard['lower_s']:.2f}, backend {heard['backend_s']:.2f}, cache "
+        f"{heard['cache']}): another signature pays that again."
+    )
 
 
 class RecompileGuard:
@@ -134,6 +153,7 @@ class RecompileGuard:
             "signature changing per call usually means a shape or a static "
             "Python value varies per step — pad to a fixed shape or hoist "
             "the varying value into an array argument."
+            + _compiled_so_far(fn)
         )
         if self.on_excess == "raise":
             raise RecompileBudgetExceeded(detail)

@@ -27,6 +27,12 @@ A third clock is the PROFILER's: :func:`annotate` puts a host span into the
 open ``jax.profiler`` session, beside the device's operations, and into
 nothing else — that session's file is its only store
 (docs/observability.md §Names in a profile).
+
+The minute BEFORE ``fit`` is the start-up log's (:class:`StartupLog`, one
+for the process: ``STARTUP``): imports, the backend's start, ``Trainer()``,
+every program compiled or loaded, the first step — kept in memory in the
+same span shape until the first ``Trainer.step`` returns, and written to
+disk by the ``SpanRecorder`` that adopts it (docs/observability.md §Start-up).
 """
 
 from __future__ import annotations
@@ -35,9 +41,11 @@ import contextlib
 import json
 import logging
 import os
+import sys
 import threading
 import time
 import uuid
+import weakref
 from typing import Any
 
 logger = logging.getLogger(__name__)
@@ -106,6 +114,16 @@ class SpanRecorder:
 
     Stdlib-only (runs inside pods).  Thread-safe: the async-checkpoint
     thread and the fit loop may both finish spans.
+
+    Given the process's ``startup`` log it ADOPTS it: the spans the log has
+    finished are written here at once, those it finishes later as they
+    finish (the first step's, the root's when the log closes; after that a
+    late ``compile`` or a second trainer's ``trainer.build``), each with
+    this recorder's trace id, service and attempt, and hung — where it has
+    no parent — under the first parentless span this recorder starts
+    (``fit``), which then starts where the log's root did.  A log that had
+    closed before (a process that stepped without a recorder) is not
+    adopted: its spans are another job's minute.
     """
 
     def __init__(
@@ -116,6 +134,7 @@ class SpanRecorder:
         service: str = "trainer",
         attempt: int = 0,
         enabled: bool = True,
+        startup: "StartupLog | None" = None,
         _clock_ns=time.time_ns,
     ):
         self.dir = os.path.join(artifacts_dir, TRACE_DIRNAME)
@@ -129,6 +148,10 @@ class SpanRecorder:
         #: span_id -> the open profiler annotation of a started span
         self._open: dict[str, Any] = {}
         self.write_failures = 0
+        #: the first parentless span started: what adopted spans hang under
+        self._root: dict[str, Any] | None = None
+        #: where the adopted log's root starts (None: nothing adopted)
+        self._since_ns = startup.adopt(self) if startup is not None else None
 
     def start(self, name: str, *, parent: dict | None = None,
               **attrs: Any) -> dict[str, Any]:
@@ -138,6 +161,10 @@ class SpanRecorder:
             parent_span_id=parent["span_id"] if parent else None,
             service=self.service, attempt=self.attempt or None, **attrs,
         )
+        if parent is None and self._root is None:
+            self._root = span
+            if self._since_ns is not None:
+                span["start_ns"] = min(span["start_ns"], self._since_ns)
         # the same span on the profiler's clock (whether or not the JSONL log
         # is enabled: a profile window is armed independently of FTC_TRACE)
         ann = annotate(name)
@@ -174,6 +201,19 @@ class SpanRecorder:
         self._write(span)
         return span
 
+    def adopt(self, span: dict[str, Any]) -> None:
+        """Write a span the start-up log finished as one of this recorder's:
+        its ids and interval kept, the trace id, service and attempt filled
+        in, a parentless one hung under this recorder's first span."""
+        parent = span["parent_span_id"]
+        if parent is None and self._root is not None:
+            parent = self._root["span_id"]
+        attrs = {"service": self.service, **span["attributes"]}
+        if self.attempt:
+            attrs["attempt"] = self.attempt
+        self._write({**span, "trace_id": self.trace_id,
+                     "parent_span_id": parent, "attributes": attrs})
+
     def _write(self, span: dict[str, Any]) -> None:
         if not self.enabled:
             return
@@ -207,6 +247,454 @@ class SpanRecorder:
     def span(self, name: str, *, parent: dict | None = None, **attrs: Any):
         """``with recorder.span("checkpoint", step=40): ...``"""
         return self._SpanCtx(self, self.start(name, parent=parent, **attrs))
+
+
+# ---------------------------------------------------------------------------
+# The start-up log
+# ---------------------------------------------------------------------------
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_EVENTS = "/jax/compilation_cache/"
+
+
+def _process_start_ns() -> int | None:
+    """When this process started, on ``time.time_ns``'s clock: its start time
+    in ``/proc/self/stat`` (ticks since boot) against ``CLOCK_BOOTTIME``.
+    None where either cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            # the command's name may hold blanks: count from its closing ")"
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age_s = (time.clock_gettime(time.CLOCK_BOOTTIME)
+                 - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.time_ns() - int(age_s * 1e9) if age_s >= 0 else None
+
+
+class _TimedLoader:
+    """Stands for a module's real loader while the module is made: times
+    ``create_module`` (an extension's ``dlopen``) and ``exec_module`` (the
+    module's body) on the log's import clock, and hands the module back to
+    the real loader before the body runs, so nothing keeps this object."""
+
+    def __init__(self, loader: Any, log: "StartupLog"):
+        self._loader, self._log = loader, log
+
+    def __getattr__(self, name: str) -> Any:
+        return getattr(self._loader, name)
+
+    def create_module(self, spec: Any) -> Any:
+        create = getattr(self._loader, "create_module", None)
+        if create is None:
+            return None
+        frame = self._log._import_enter(spec.name)
+        try:
+            return create(spec)
+        finally:
+            self._log._import_exit(frame)
+
+    def exec_module(self, module: Any) -> None:
+        spec = getattr(module, "__spec__", None)
+        if spec is not None and spec.loader is self:
+            spec.loader = self._loader
+        if getattr(module, "__loader__", None) is self:
+            module.__loader__ = self._loader
+        frame = self._log._import_enter(module.__name__)
+        try:
+            self._loader.exec_module(module)
+        finally:
+            self._log._import_exit(frame)
+        if module.__name__ == "jax":
+            self._log._listen()
+
+
+class _ImportObserver:
+    """A meta-path finder that finds nothing itself: it asks the finders
+    behind it, times their search, and wraps the loader they name."""
+
+    def __init__(self, log: "StartupLog"):
+        self._log = log
+
+    def find_spec(self, name: str, path: Any = None, target: Any = None) -> Any:
+        frame = self._log._import_enter(name)
+        try:
+            spec = None
+            finders = list(sys.meta_path)
+            # those BEHIND it only: two logs' observers (a test's before the
+            # process's) then ask each other once, not for ever
+            with contextlib.suppress(ValueError):
+                finders = finders[finders.index(self) + 1:]
+            for finder in finders:
+                find = getattr(finder, "find_spec", None)
+                spec = find(name, path, target) if find is not None else None
+                if spec is not None:
+                    break
+        finally:
+            self._log._import_exit(frame)
+        if spec is not None and hasattr(spec.loader, "exec_module"):
+            spec.loader = _TimedLoader(spec.loader, self._log)
+        return spec
+
+
+class StartupLog:
+    """What the process did before its first training step: one tree of spans
+    under a root ``startup``, kept in memory in :func:`make_span`'s shape.
+
+    * **imports** are a counter, not a span (the package imports lazily, all
+      through ``Trainer()`` and the first trace): while the log is open an
+      observer on ``sys.meta_path`` times every module found, created and
+      executed, EXCLUSIVE of the imports it triggers, by top-level package —
+      the root carries ``import_s`` and ``import_by_package``, every other
+      span the ``import_s`` that fell inside it;
+    * ``span(name)`` is a piece of the program's own start-up
+      (``startup.backend``, ``trainer.build`` and its children,
+      ``trainer.first_step``), on the profiler's clock too;
+    * ``compile`` is one program compiled or loaded, put together from what
+      ``jax.monitoring`` says of it: ``fun_name``, ``trace_s``, ``lower_s``,
+      ``backend_s``, ``cache`` (``hit`` | ``miss`` | ``off``), ``cache_load_s``
+      on a hit, ``cache_written`` where a miss was stored, ``step`` for a
+      trainer's step program.  Programs under ``SMALL_COMPILE_S`` are folded
+      into one ``compile.small`` with their count.
+
+    ``close()`` — the first ``Trainer.step`` to return calls it, or a serve
+    load — ends the root and takes the observer off the import machinery.
+    The compile listener stays (it is called when something traces or
+    compiles, never on a cached step's path): a later ``compile`` goes to the
+    recorder that adopted the log (``SpanRecorder(startup=...)``), if one is
+    alive, and ``programs`` keeps each program's last compile by name for
+    ``analysis/recompile_guard.py`` to quote.
+
+    The process has one, ``STARTUP``, opened by the package's ``__init__``;
+    code reaches it as ``trace.STARTUP`` so that a test can put its own there.
+    """
+
+    SMALL_COMPILE_S = 0.010
+
+    def __init__(self, *, from_process_start: bool = True,
+                 _clock_ns=time.time_ns):
+        self._clock_ns = _clock_ns
+        start = _process_start_ns() if from_process_start else None
+        self.root = make_span(
+            "startup", "", start_ns=_clock_ns() if start is None else start,
+            anchor="package_import" if start is None else "process")
+        #: tested by ``Trainer.step`` on every call
+        self.closed = False
+        #: the finished spans, the root last; frozen once closed
+        self.spans: list[dict[str, Any]] = []
+        #: ``fun_name`` -> its last compile's attributes and ``count``
+        self.programs: dict[str, dict[str, Any]] = {}
+        self._step_programs: set[str] = set()
+        self._lock = threading.RLock()
+        self._local = threading.local()
+        self._import_s = 0.0
+        self._by_package: dict[str, float] = {}
+        self._compile_s = 0.0
+        self._small: dict[str, Any] | None = None
+        self._observer = _ImportObserver(self)
+        self._listening = False
+        self._sink: weakref.ref | None = None
+
+    # ---- opening and closing --------------------------------------------------
+
+    def open(self) -> "StartupLog":
+        """Put the observer first on ``sys.meta_path``; hear JAX's compile
+        events from the moment ``jax`` is imported (now, if it is)."""
+        sys.meta_path.insert(0, self._observer)
+        if sys.modules.get("jax") is not None:
+            self._listen()
+        return self
+
+    def close(self) -> None:
+        with self._lock:
+            if self.closed:
+                return
+            self.closed = True
+            self._unobserve()
+            if self._small is not None:
+                self._finished(self._small)
+            self.root["end_ns"] = self._clock_ns()
+            self.root["attributes"].update(
+                import_s=round(self._import_s, 6),
+                import_by_package=self.import_by_package(),
+                compile_s=round(self._compile_s, 6))
+            self._finished(self.root)
+
+    def shutdown(self) -> None:
+        """Take the observer and the listeners away (a test's own log)."""
+        self._unobserve()
+        with self._lock:
+            was, self._listening = self._listening, False
+        if was:
+            import jax.monitoring as monitoring
+
+            monitoring.unregister_scalar_listener(self._on_scalar)
+            monitoring.unregister_event_duration_listener(self._on_duration)
+            monitoring.unregister_event_listener(self._on_event)
+
+    def _unobserve(self) -> None:
+        with contextlib.suppress(ValueError):
+            sys.meta_path.remove(self._observer)
+
+    def _listen(self) -> None:
+        import jax.monitoring as monitoring
+
+        with self._lock:
+            was, self._listening = self._listening, True
+        if not was:
+            monitoring.register_scalar_listener(self._on_scalar)
+            monitoring.register_event_duration_secs_listener(self._on_duration)
+            monitoring.register_event_listener(self._on_event)
+
+    # ---- where finished spans go ---------------------------------------------
+
+    def adopt(self, recorder: "SpanRecorder") -> int | None:
+        """Make ``recorder`` the one that writes this log's spans.  An open
+        log hands over what it has finished and returns its root's start; a
+        closed one hands over nothing, and sends only what it hears later."""
+        with self._lock:
+            self._sink = weakref.ref(recorder)
+            if self.closed:
+                return None
+            for span in self.spans:
+                recorder.adopt(span)
+            return self.root["start_ns"]
+
+    def _finished(self, span: dict[str, Any]) -> None:
+        """Keep a finished span (while the log is open; the root and the
+        folded programs come last) and hand it to the adopting recorder."""
+        with self._lock:
+            if not self.closed or span is self.root or span is self._small:
+                self.spans.append(span)
+            sink = self._sink() if self._sink is not None else None
+            if sink is not None:
+                sink.adopt(span)
+
+    def _fold(self, span: dict[str, Any]) -> None:
+        """A program too small for a span of its own, into ``compile.small``
+        (while the log is open; afterwards it is counted nowhere)."""
+        with self._lock:
+            if self.closed:
+                return
+            if self._small is None:
+                self._small = make_span(
+                    "compile.small", "", start_ns=span["start_ns"],
+                    parent_span_id=self.root["span_id"], step=False, count=0,
+                    trace_s=0.0, lower_s=0.0, backend_s=0.0, import_s=0.0)
+            total, part = self._small["attributes"], span["attributes"]
+            total["count"] += 1
+            for key in ("trace_s", "lower_s", "backend_s", "import_s"):
+                total[key] = round(total[key] + part[key], 6)
+            self._small["end_ns"] = span["end_ns"]
+
+    # ---- the program's own spans ---------------------------------------------
+
+    def _stack(self) -> list[dict[str, Any]]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def _parent(self) -> tuple[bool, dict[str, Any] | None]:
+        """Whether a span that starts now has anywhere to go, and what it
+        hangs under: the innermost open span of this thread, else the root
+        while the log is open, else nothing (the recorder's own first span)."""
+        stack = self._stack()
+        with self._lock:
+            live = not self.closed or (
+                self._sink is not None and self._sink() is not None)
+            parent = stack[-1] if stack else None if self.closed else self.root
+            return live, parent
+
+    def _counters(self) -> tuple[float, float]:
+        """The import and compile seconds counted so far."""
+        with self._lock:
+            return self._import_s, self._compile_s
+
+    @contextlib.contextmanager
+    def span(self, name: str, **attrs: Any):
+        """``with log.span("trainer.build", mode="lora") as span: ...`` — the
+        span's dict, to add attributes to.  After the log has closed the span
+        goes to the adopting recorder alone, and without one nowhere."""
+        live, parent = self._parent()
+        if not live:
+            yield make_span(name, "", start_ns=0)
+            return
+        span = make_span(
+            name, "", start_ns=self._clock_ns(),
+            parent_span_id=parent["span_id"] if parent else None, **attrs)
+        import_s, compile_s = self._counters()
+        stack = self._stack()
+        stack.append(span)
+        try:
+            # on the profiler's clock too — unless that would import JAX
+            with annotate(name) if sys.modules.get("jax") is not None \
+                    else contextlib.nullcontext():
+                yield span
+        except BaseException:
+            span["status"] = "error"
+            raise
+        finally:
+            stack.pop()
+            span["end_ns"] = self._clock_ns()
+            import_end, compile_end = self._counters()
+            span["attributes"].update(
+                import_s=round(import_end - import_s, 6),
+                compile_s=round(compile_end - compile_s, 6))
+            self._finished(span)
+
+    def seconds(self, name: str) -> float | None:
+        """The summed seconds of the finished spans called ``name``."""
+        with self._lock:
+            found = [s for s in self.spans if s["name"] == name]
+        if not found:
+            return None
+        return sum(s["end_ns"] - s["start_ns"] for s in found) / 1e9
+
+    def summary(self) -> dict[str, float]:
+        """``startup_s`` of the ``train-started`` event: the seconds of
+        imports so far, of the backend's start and of ``Trainer()``."""
+        out = {"import": round(self._counters()[0], 3)}
+        for key, name in (("backend", "startup.backend"),
+                          ("trainer_build", "trainer.build")):
+            seconds = self.seconds(name)
+            if seconds is not None:
+                out[key] = round(seconds, 3)
+        return out
+
+    # ---- imports ---------------------------------------------------------------
+
+    def _import_enter(self, module: str) -> list:
+        try:
+            frames = self._local.imports
+        except AttributeError:
+            frames = self._local.imports = []
+        # [package, started, seconds of the imports this one triggered]
+        frame = [module.partition(".")[0], time.perf_counter(), 0.0]
+        frames.append(frame)
+        return frame
+
+    def _import_exit(self, frame: list) -> None:
+        seconds = time.perf_counter() - frame[1]
+        frames = self._local.imports
+        frames.pop()
+        if frames:
+            frames[-1][2] += seconds
+        own = seconds - frame[2]
+        with self._lock:
+            self._import_s += own
+            self._by_package[frame[0]] = self._by_package.get(frame[0], 0.0) + own
+
+    def import_by_package(self) -> dict[str, float]:
+        """Exclusive import seconds by top-level package, largest first:
+        every package over 50 ms, and as many more as put nine tenths of
+        ``import_s`` on a name; the rest under ``(other)``."""
+        with self._lock:
+            total = self._import_s
+            ranked = sorted(self._by_package.items(), key=lambda kv: -kv[1])
+        out, named = {}, 0.0
+        for package, seconds in ranked:
+            if seconds < 0.05 and named >= 0.9 * total:
+                break
+            out[package] = round(seconds, 6)
+            named += seconds
+        if len(out) < len(ranked):
+            out["(other)"] = round(total - named, 6)
+        return out
+
+    # ---- programs compiled or loaded ----------------------------------------
+
+    def step_program(self, python_name: str) -> None:
+        """``jax.jit`` of the function called ``python_name`` is one of a
+        trainer's step programs: its compiles carry ``step: true``."""
+        with self._lock:
+            self._step_programs.add(f"jit({python_name})")
+
+    def step_compiles(self) -> list[dict[str, Any]]:
+        """Each step program's last compile, with how often it compiled."""
+        with self._lock:
+            return [p for p in self.programs.values() if p["step"]]
+
+    def _pending(self) -> dict[str, Any]:
+        try:
+            return self._local.pending
+        except AttributeError:
+            # tracing: ``import_s`` as each open trace began; traced: the
+            # finished outermost traces no program has claimed, by function
+            self._local.pending = {"tracing": [], "traced": {}}
+            return self._local.pending
+
+    def _on_scalar(self, event: str, value: float, **kw: Any) -> None:
+        if event == _TRACE_EVENT:   # a trace begins (``log_elapsed_time``)
+            self._pending()["tracing"].append(self._counters()[0])
+
+    def _on_duration(self, event: str, seconds: float, **kw: Any) -> None:
+        if event == _TRACE_EVENT:
+            p, now = self._pending(), self._counters()[0]
+            import_s = now - (p["tracing"].pop() if p["tracing"] else now)
+            if not p["tracing"]:    # outermost: the traces inside it are its
+                p["traced"][kw.get("fun_name", "")] = (seconds, import_s)
+        elif event == _LOWER_EVENT:
+            p = self._pending()
+            module = kw.get("fun_name", "")
+            # ``jit(f)`` lowers what the trace of ``f`` gave; a trace nothing
+            # lowered (``jax.eval_shape``; one a lowering rule made) goes
+            p["trace"] = p["traced"].get(
+                module[module.find("(") + 1:module.rfind(")")])
+            p["traced"].clear()
+            p["lower_s"] = seconds
+        elif event == _BACKEND_EVENT:
+            self._compiled(kw.get("fun_name", ""), seconds, self._pending())
+        elif event == _CACHE_EVENTS + "cache_retrieval_time_sec":
+            self._pending()["cache_load_s"] = round(seconds, 6)
+
+    def _on_event(self, event: str, **kw: Any) -> None:
+        if not event.startswith(_CACHE_EVENTS):
+            return
+        what = event[len(_CACHE_EVENTS):]
+        if what == "compile_requests_use_cache":
+            self._pending().setdefault("cache", "miss")
+        elif what == "cache_hits":
+            self._pending()["cache"] = "hit"
+        elif what == "cache_misses":    # recorded where the entry is written
+            self._pending()["cache_written"] = True
+
+    def _compiled(self, fun_name: str, backend_s: float, p: dict) -> None:
+        trace_s, import_s = p.pop("trace", None) or (0.0, 0.0)
+        lower_s = p.pop("lower_s", 0.0)
+        seconds = trace_s + lower_s + backend_s
+        _, parent = self._parent()
+        with self._lock:
+            step = fun_name in self._step_programs
+        # its parts need not touch (a program lowered in one span, compiled
+        # in the next): the span ends where the backend did and is as long
+        # as its parts, but never starts before the span it lies in
+        end_ns = self._clock_ns()
+        span = make_span(
+            "compile", "",
+            start_ns=max(end_ns - int(seconds * 1e9),
+                         parent["start_ns"] if parent else 0),
+            end_ns=end_ns, parent_span_id=parent["span_id"] if parent else None,
+            fun_name=fun_name, step=step, trace_s=round(trace_s, 6),
+            lower_s=round(lower_s, 6), backend_s=round(backend_s, 6),
+            cache=p.pop("cache", "off"), cache_load_s=p.pop("cache_load_s", None),
+            cache_written=p.pop("cache_written", None),
+            import_s=round(import_s, 6))
+        with self._lock:
+            self._compile_s += max(seconds - import_s, 0.0)
+            count = self.programs.get(fun_name, {}).get("count", 0) + 1
+            self.programs[fun_name] = {**span["attributes"], "count": count}
+        if seconds < self.SMALL_COMPILE_S and not step:
+            self._fold(span)
+        else:
+            self._finished(span)
+
+
+#: the process's start-up log (``finetune_controller_tpu/__init__.py`` opens it)
+STARTUP = StartupLog()
 
 
 def parse_span_lines(raw: bytes | str) -> list[dict[str, Any]]:
@@ -354,13 +842,27 @@ def build_trace(
         if trace_id:
             grafted["trace_id"] = trace_id
         pid = grafted.get("parent_span_id")
+        attempt = by_attempt.get(
+            grafted.get("attributes", {}).get("attempt")) or root
         if pid is None or pid not in trainer_ids:
             # no recorded parent, or the parent never landed — a kill loses
             # the spans still open (the crash-safe JSONL holds FINISHED
             # spans only), so a killed job's children would dangle off the
             # lost fit span: graft under the attempt/root instead
-            parent = by_attempt.get(grafted.get("attributes", {}).get("attempt"))
-            grafted["parent_span_id"] = (parent or root)["span_id"]
+            grafted["parent_span_id"] = attempt["span_id"]
+        begins = attempt["start_ns"]
+        if grafted["start_ns"] < begins:
+            # the trainer's process is older than the attempt as the
+            # controller saw it — its start-up spans run from process start,
+            # the monitor hears ``running`` a tick later, a warm worker
+            # predates the job: cut to the attempt, and say by how much
+            grafted["attributes"] = {
+                **grafted.get("attributes", {}),
+                "before_attempt_s": round(
+                    (begins - grafted["start_ns"]) / 1e9, 3)}
+            grafted["start_ns"] = begins
+            if grafted.get("end_ns") is not None:
+                grafted["end_ns"] = max(grafted["end_ns"], begins)
         spans.append(grafted)
 
     return {

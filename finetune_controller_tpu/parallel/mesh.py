@@ -24,6 +24,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
+from .. import platform
+
 logger = logging.getLogger(__name__)
 
 
@@ -154,7 +156,7 @@ def build_mesh(
     devices: Sequence[jax.Device] | None = None,
     slice_of: Sequence[int] | None = None,
 ) -> Mesh:
-    devices = list(devices if devices is not None else jax.devices())
+    devices = list(devices if devices is not None else platform.devices())
     fixed = [spec.dp, spec.fsdp, spec.ep, spec.pp, spec.sp, spec.tp]
     if -1 not in fixed and math.prod(fixed) < len(devices):
         # A fully-specified mesh smaller than the host's device count is
@@ -177,5 +179,5 @@ def build_mesh(
 
 
 def single_device_mesh(device: jax.Device | None = None) -> Mesh:
-    devices = [device] if device is not None else jax.devices()[:1]
+    devices = [device] if device is not None else platform.devices()[:1]
     return build_mesh(MeshSpec(fsdp=1), devices)

@@ -12,6 +12,7 @@ device it landed on.
 from __future__ import annotations
 
 import os
+import sys
 from pathlib import Path
 
 #: the checkout (or install root) this package was imported from
@@ -50,15 +51,34 @@ def enable_compile_cache() -> str:
     return path
 
 
+def devices() -> list:
+    """``jax.devices()``.  The program's first call of it while its start-up
+    log is open is the log's ``startup.backend`` span: the backend's start,
+    or — ``already_up`` — nothing, where the host process had started it."""
+    import jax
+
+    from .obs import trace
+
+    log = trace.STARTUP
+    if log.closed or log.seconds("startup.backend") is not None:
+        return jax.devices()
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    with log.span("startup.backend") as span:
+        up = bool(bridge and bridge.backends_are_initialized())
+        found = jax.devices()
+        span["attributes"].update(
+            platform=found[0].platform, kind=found[0].device_kind,
+            count=len(found), already_up=up)
+    return found
+
+
 def device_report() -> dict:
     """The device this process runs on, as JAX reports it — what a parent
     that must stay off JAX (the API server, ``chip_smoke.py``) reads back
     from its trainer and serve-worker children.  Starts the backend."""
-    import jax
-
-    devices = jax.devices()
+    found = devices()
     return {
-        "platform": devices[0].platform,
-        "kind": devices[0].device_kind,
-        "count": len(devices),
+        "platform": found[0].platform,
+        "kind": found[0].device_kind,
+        "count": len(found),
     }

@@ -259,6 +259,10 @@ def load_serving_model(
         "weights_dir": pretrained or None,
     }
     logger.info("serving model ready: %s", meta)
+    # a process that serves never steps: its start-up ends with its load
+    from ..obs import trace
+
+    trace.STARTUP.close()
     return model, variables, meta
 
 

@@ -27,7 +27,9 @@ from flax import struct
 from flax.core import FrozenDict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import platform
 from ..models.llama import LlamaConfig, LlamaForCausalLM
+from ..obs import trace as obs_trace
 from ..obs.trace import annotate
 from ..ops.attention import resolve_attention_impl
 from ..parallel.mesh import MeshSpec
@@ -244,8 +246,21 @@ class Trainer:
         mesh: Mesh | None = None,
         rules: PartitionRules = LLAMA_RULES,
     ):
+        # on the process's start-up log (obs/trace.py::StartupLog) while that
+        # is open; later to the fit's span recorder, or nowhere
+        with obs_trace.STARTUP.span(
+                "trainer.build", mode=train_cfg.mode) as span:
+            self._construct(model_cfg, train_cfg, mesh, rules)
+            cfg = getattr(self.model_cfg, "text", self.model_cfg)
+            span["attributes"].update(
+                n_layers=cfg.n_layers, scan_layers=cfg.scan_layers,
+                mesh={k: v for k, v in self.mesh.shape.items() if v > 1})
+
+    def _construct(self, model_cfg, train_cfg, mesh, rules) -> None:
+        log = obs_trace.STARTUP
         self.cfg = train_cfg
-        self.mesh = mesh if mesh is not None else MeshSpec(fsdp=1).build(jax.devices()[:1])
+        self.mesh = mesh if mesh is not None else MeshSpec(fsdp=1).build(
+            platform.devices()[:1])
         #: what the step's attention runs as, for the ``train-started`` event
         self.attention_impl = resolve_attention_impl(
             model_cfg.attention_impl, train_cfg.seq_len, mesh=self.mesh)
@@ -316,14 +331,15 @@ class Trainer:
                     f"microbatch size {micro} (batch_size/grad_accum_steps) "
                     f"not divisible over the {batch_shards}-way batch sharding"
                 )
-        self.tx, self.sched = build_optimizer(
-            learning_rate=train_cfg.learning_rate,
-            warmup_steps=train_cfg.warmup_steps,
-            total_steps=train_cfg.total_steps,
-            schedule=train_cfg.schedule,
-            weight_decay=train_cfg.weight_decay,
-            clip_norm=train_cfg.clip_norm,
-        )
+        with log.span("trainer.build.optimizer"):
+            self.tx, self.sched = build_optimizer(
+                learning_rate=train_cfg.learning_rate,
+                warmup_steps=train_cfg.warmup_steps,
+                total_steps=train_cfg.total_steps,
+                schedule=train_cfg.schedule,
+                weight_decay=train_cfg.weight_decay,
+                clip_norm=train_cfg.clip_norm,
+            )
         self._state_shardings = None
         self._init_jit = None
         #: calls of step() so far: the profiler's step number
@@ -429,17 +445,27 @@ class Trainer:
         return jax.tree_util.tree_map_with_path(cast, frozen)
 
     def _build(self) -> None:
-        rng = jax.random.PRNGKey(self.cfg.seed)
-        shapes = self._state_shapes = jax.eval_shape(self.raw_init, rng)
-        self._state_shardings = sharding_for_tree(shapes, self.mesh, self.rules)
-        self._batch_sharding = batch_sharding(self.mesh)
-        from ..parallel.mesh import AxisNames as Ax
+        log = obs_trace.STARTUP
+        with log.span("trainer.build.rng"):
+            # the first operation this process runs on the device
+            rng = jax.random.PRNGKey(self.cfg.seed)
+        with log.span("trainer.build.abstract_state", fun_name="raw_init"):
+            shapes = self._state_shapes = jax.eval_shape(self.raw_init, rng)
+        with log.span("trainer.build.shardings"):
+            self._state_shardings = sharding_for_tree(
+                shapes, self.mesh, self.rules)
+            self._batch_sharding = batch_sharding(self.mesh)
+            from ..parallel.mesh import AxisNames as Ax
 
-        self._pixel_sharding = NamedSharding(self.mesh, P(Ax.BATCH_AXES))
+            self._pixel_sharding = NamedSharding(self.mesh, P(Ax.BATCH_AXES))
         self._init_jit = jax.jit(self.raw_init, out_shardings=self._state_shardings)
         # jitted steps are cached per batch structure (multimodal batches add
         # a rank-4 pixels leaf whose sharding differs from token arrays)
         self._step_jits: dict[tuple[str, ...], Any] = {}
+        with log.span("trainer.build.guards"):
+            self._build_guards()
+
+    def _build_guards(self) -> None:
         self._recompile_guard = None
         if self.cfg.recompile_budget > 0:
             from ..analysis.recompile_guard import RecompileGuard
@@ -528,6 +554,7 @@ class Trainer:
                 out_shardings=(self._state_shardings, None),
                 donate_argnums=donate,
             )
+            obs_trace.STARTUP.step_program(self._train_step.__name__)
             if self._recompile_guard is not None:
                 fn = self._recompile_guard.wrap(fn, label=f"step:{','.join(key)}")
             if self._transfer_guard is not None:
@@ -715,6 +742,7 @@ class Trainer:
                 in_shardings=(self._state_shardings, batch_sh),
                 out_shardings=None,
             )
+            obs_trace.STARTUP.step_program(self._eval_step.__name__)
             if self._recompile_guard is not None:
                 fn = self._recompile_guard.wrap(fn, label=f"eval:{','.join(key)}")
             self._step_jits[key] = fn
@@ -786,6 +814,20 @@ class Trainer:
             return self._init_jit(jax.random.PRNGKey(self.cfg.seed))
 
     def step(self, state: TrainState, batch: dict) -> tuple[TrainState, dict]:
+        log = obs_trace.STARTUP
+        if log.closed:
+            return self._step(state, batch)
+        # the process's first step: shard the batch, trace, lower, compile or
+        # load, enqueue — and its return ends the start-up log
+        try:
+            with log.span("trainer.first_step") as span:
+                out = self._step(state, batch)
+                span["attributes"]["step_programs"] = len(self._step_jits)
+                return out
+        finally:
+            log.close()
+
+    def _step(self, state: TrainState, batch: dict) -> tuple[TrainState, dict]:
         from ..parallel.ring import ring_mesh
 
         # numbered on the host: reading state.step would wait for the device
@@ -1203,8 +1245,11 @@ class Trainer:
             artifacts_dir, trace_id=trace_id, attempt=obs_attempt,
             enabled=obs_on,
         )
+        # the recorder adopts the process's start-up log: what ran before
+        # fit() — imports, the backend, Trainer() — lands under ``fit``
         spans = SpanRecorder(
-            artifacts_dir, trace_id, attempt=obs_attempt, enabled=obs_on
+            artifacts_dir, trace_id, attempt=obs_attempt, enabled=obs_on,
+            startup=obs_trace.STARTUP,
         )
         phases = PhaseClock()
         fit_span = spans.start("fit", total_steps=self.cfg.total_steps)
@@ -1288,6 +1333,7 @@ class Trainer:
         events_log.emit(
             "train-started", step=start_step,
             resumed_from=start_step if start_step else None,
+            startup_s=obs_trace.STARTUP.summary(),
             **self._runtime_attrs(),
         )
         # chaos hook (resilience/faults.py): a seeded kill-at-step armed via
@@ -1595,6 +1641,7 @@ class Trainer:
                     events_log.emit(
                         "train-finished", step=self.cfg.total_steps,
                         device_peak_bytes=self._device_bytes("peak_bytes_in_use"),
+                        step_compiles=obs_trace.STARTUP.step_compiles() or None,
                     )
         return state
 

@@ -31,12 +31,11 @@ def main() -> int:
     # spawn env — the pool keys workers by it, so this matches the job's.
     # On a TPU flavor this process HOLDS the chip from here on: no job that
     # does not claim this worker can start on it (docs/operator_guide.md).
-    import jax
+    from .. import platform
+    from ..obs import trace
 
-    from ..platform import enable_compile_cache
-
-    enable_compile_cache()
-    jax.devices()  # force backend init now, not at first trace
+    platform.enable_compile_cache()
+    platform.devices()  # force backend init now, not at first trace
 
     # pre-import the whole training stack (flax/optax/orbax/models/data) —
     # JAX alone is under half the interpreter's import bill
@@ -49,7 +48,10 @@ def main() -> int:
         with open(ready, "w") as f:
             f.write("ready\n")
 
-    line = sys.stdin.readline()
+    # the pool may hold this process for minutes: on the start-up log the
+    # wait is its own span, not an unexplained hole before ``trainer.build``
+    with trace.STARTUP.span("startup.warm_wait"):
+        line = sys.stdin.readline()
     # the sentinel's job is done once a request (or shutdown) arrives; the
     # worker owns its removal — the claim path's unlink is best-effort and
     # misses workers claimed before the file existed

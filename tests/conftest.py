@@ -76,11 +76,12 @@ def tiny_job_spec(steps: int = 3):
 
 #: the tests under ``tests/benchmarks/`` that pin a ``BENCHMARK.json`` a later
 #: ``model_config`` PR appended to — three of PR 26's two cells (ISSUE 27),
-#: five of PR 27's three (ISSUE 32), four of PR 32's four (ISSUE 36) — and that
+#: five of PR 27's three (ISSUE 32), four of PR 32's four (ISSUE 36), four of
+#: PR 36's 34 per-layer entries (ISSUE 38) — and that
 #: only a ``benchmark`` PR may
 #: edit (a PR of another kind changes no file the benchmark already has):
 #: ``node id -> (why, the test that holds what it held)``.  The next
-#: ``benchmark`` PR edits the twelve and deletes this table and the hook under
+#: ``benchmark`` PR edits the sixteen and deletes this table and the hook under
 #: it (ROADMAP.md, B1)
 SUPERSEDED = {
     "tests/benchmarks/test_benchmark_manifest.py::"
@@ -143,6 +144,29 @@ SUPERSEDED = {
          "pins this table's length at eight",
          "test_the_superseded_pins_are_twelve_and_each_has_its_replacement"),
     )},
+    # ... and the three of ``test_benchmark_falcon_h1.py`` that pin PR 36's 34
+    # per-layer entries, to which ISSUE 38 appends the five under ``setup_s``
+    # (every cell reports them: each cell's count of metrics grows by five)
+    **{"tests/benchmarks/test_benchmark_falcon_h1.py::" + pin: (
+        why, "tests/benchmarks/test_benchmark_startup.py::" + held_by)
+       for pin, why, held_by in (
+        ("test_the_real_manifest_has_its_five_cells_and_no_metric_by_default",
+         "pins each cell's count of per-layer metrics; ISSUE 38 adds five to "
+         "every cell",
+         "test_the_real_manifest_has_its_five_cells_and_five_more_metrics_in_each"),
+        ("test_the_accepted_entries_stand_first_and_the_new_ones_last",
+         "pins the list's end; ISSUE 38 appends its entries",
+         "test_the_accepted_entries_stand_first_and_the_start_up_ones_last"),
+        ("test_the_superseded_pins_are_twelve_and_each_has_its_replacement",
+         "pins this table's length at twelve",
+         "test_the_superseded_pins_are_sixteen_and_each_has_its_replacement"),
+    )},
+    "tests/benchmarks/test_benchmark_mla_dsa_moe.py::"
+    "test_cells_report_the_neutral_metrics_and_their_own_and_no_count_that_overstates": (
+        "pins the two expert cells' sets of per-layer metrics; ISSUE 38 adds "
+        "its five to every cell",
+        "tests/benchmarks/test_benchmark_startup.py::"
+        "test_expert_cells_report_the_neutral_metrics_their_own_and_the_start_up_five"),
 }
 
 

@@ -7,7 +7,9 @@ Layers covered:
   ``ftc_build_info`` / ``ftc_uptime_seconds``;
 * ``phase``  — the trainer's step-phase clock (residual compute, reset);
 * ``trace``  — span recorder crash-safety, trace assembly from the event
-  timeline, the gap-free/nesting validator;
+  timeline, the gap-free/nesting validator; the start-up log (imports by
+  package, ``Trainer()``, every program compiled or loaded, the first step),
+  its adoption by the fit's recorder;
 * ``events`` — the trainer-side event log and the torn-line-tolerant parser;
 * statestore — ``append_job_event`` idempotency on BOTH engines;
 * trainer    — fit-loop integration (events/spans/phase columns on, all
@@ -28,6 +30,7 @@ import asyncio
 import json
 import math
 import os
+import sys
 import time
 
 import pytest
@@ -690,6 +693,370 @@ def test_fit_profile_kill_switch(tmp_path, monkeypatch):
     trainer.fit(batches, str(tmp_path), resume=False)
     assert (tmp_path / "profile_request.json").exists()
     assert not (tmp_path / "profile").exists()
+
+
+# ---------------------------------------------------------------------------
+# trace: the start-up log (imports, backend, Trainer(), compiles, first step)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def startup_log(monkeypatch):
+    """A start-up log of the test's own where the process keeps its:
+    a tier-1 worker's closed long ago, at its first step."""
+    from finetune_controller_tpu.obs import trace
+
+    log = trace.StartupLog(from_process_start=False).open()
+    monkeypatch.setattr(trace, "STARTUP", log)
+    yield log
+    log.shutdown()
+
+
+def _slow_packages(tmp_path, monkeypatch):
+    """Two importable packages that sleep: ``ftcslowa`` 60 ms of its own and
+    imports ``ftcslowb``, 30 ms."""
+    for name, body in (
+            ("ftcslowa", "import time\ntime.sleep(0.06)\nimport ftcslowb\n"),
+            ("ftcslowb", "import time\ntime.sleep(0.03)\n")):
+        (tmp_path / name).mkdir()
+        (tmp_path / name / "__init__.py").write_text(body)
+        monkeypatch.delitem(sys.modules, name, raising=False)
+    monkeypatch.syspath_prepend(str(tmp_path))
+
+
+def test_startup_log_spans_nest_and_the_root_covers_them(startup_log):
+    log = startup_log
+    with log.span("trainer.build", mode="lora") as outer:
+        with log.span("trainer.build.rng") as inner:
+            pass
+        outer["attributes"]["n_layers"] = 2
+    with pytest.raises(RuntimeError):
+        with log.span("trainer.first_step"):
+            raise RuntimeError("boom")
+    assert not log.closed and log.root["end_ns"] is None
+    log.close()
+    log.close()     # the second close is nothing
+    by_name = {s["name"]: s for s in log.spans}
+    assert list(by_name) == ["trainer.build.rng", "trainer.build",
+                             "trainer.first_step", "startup"]
+    root = by_name["startup"]
+    assert root is log.root and root["attributes"]["anchor"] == "package_import"
+    assert by_name["trainer.build"]["parent_span_id"] == root["span_id"]
+    assert inner["parent_span_id"] == outer["span_id"]
+    assert by_name["trainer.first_step"]["status"] == "error"
+    assert by_name["trainer.build"]["attributes"]["n_layers"] == 2
+    for s in log.spans:
+        assert root["start_ns"] <= s["start_ns"] <= s["end_ns"] <= root["end_ns"]
+        assert {"import_s", "compile_s"} <= set(s["attributes"])
+    assert validate_trace(log.spans) == []
+    # closed, with no recorder: a later span goes nowhere, and nothing raises
+    with log.span("trainer.build") as late:
+        late["attributes"]["n_layers"] = 2
+    assert len(log.spans) == 4
+
+
+def test_startup_log_anchors_the_root_at_process_start():
+    from finetune_controller_tpu.obs import trace
+
+    log = trace.StartupLog()
+    if log.root["attributes"]["anchor"] == "process":   # /proc and CLOCK_BOOTTIME
+        assert log.root["start_ns"] < trace.STARTUP.root["start_ns"] + 10**9
+        assert time.time_ns() - log.root["start_ns"] < 3600 * 10**9
+    else:
+        assert log.root["attributes"]["anchor"] == "package_import"
+    # the process's own was opened by the package's import
+    assert trace.STARTUP.root["name"] == "startup"
+
+
+def test_the_programs_first_devices_call_is_the_backend_span(startup_log):
+    from finetune_controller_tpu import platform
+
+    found = platform.devices()
+    assert platform.devices() == found      # the second call: no second span
+    assert platform.device_report()["count"] == len(found)
+    backend = [s for s in startup_log.spans if s["name"] == "startup.backend"]
+    assert len(backend) == 1
+    attrs = backend[0]["attributes"]
+    assert (attrs["platform"], attrs["count"]) == ("cpu", len(found))
+    assert attrs["kind"] == found[0].device_kind
+    assert attrs["already_up"] in (True, False)
+    assert backend[0]["parent_span_id"] == startup_log.root["span_id"]
+    startup_log.close()
+    assert platform.devices() == found      # closed: JAX's own call, no span
+    assert startup_log.summary()["backend"] >= 0
+
+
+def test_startup_log_times_imports_exclusive_by_package(
+        startup_log, tmp_path, monkeypatch):
+    log = startup_log
+    _slow_packages(tmp_path, monkeypatch)
+    with log.span("trainer.build") as build:
+        import ftcslowa  # noqa: F401
+    with log.span("trainer.build.guards") as idle:
+        pass
+    log.close()
+    root = log.root["attributes"]
+    by_package = root["import_by_package"]
+    # a package's own modules, not what they import from another: parts add up
+    assert 0.06 <= by_package["ftcslowa"] < 0.09
+    assert 0.03 <= by_package["ftcslowb"] < 0.06
+    assert sum(by_package.values()) == pytest.approx(root["import_s"], abs=1e-4)
+    named = sum(v for k, v in by_package.items() if k != "(other)")
+    assert named >= 0.9 * root["import_s"]
+    assert all(k in by_package for k, v in log._by_package.items() if v > 0.05)
+    # a span's import_s is the imports that ran inside it
+    assert build["attributes"]["import_s"] == pytest.approx(
+        by_package["ftcslowa"] + by_package["ftcslowb"], abs=5e-3)
+    assert idle["attributes"]["import_s"] == 0.0
+    # the modules were handed back to their real loaders
+    for name in ("ftcslowa", "ftcslowb"):
+        module = sys.modules[name]
+        assert type(module.__spec__.loader).__name__ == "SourceFileLoader"
+        assert module.__loader__ is module.__spec__.loader
+
+
+def test_first_step_closes_the_log_and_frees_the_import_machinery(startup_log):
+    from finetune_controller_tpu.data import synthetic_batches
+
+    log = startup_log
+    assert log._observer in sys.meta_path
+    trainer, model_cfg = _tiny_trainer()
+    state = trainer.init_state()
+    batches = synthetic_batches(2, 16, model_cfg.vocab_size, task="increment")
+    assert not log.closed
+    state, _ = trainer.step(state, next(batches))
+    assert log.closed and log._observer not in sys.meta_path
+    n = len(log.spans)
+    trainer.step(state, next(batches))      # the cached path: nothing recorded
+    assert len(log.spans) == n and log.spans[-1] is log.root
+    by_name = {s["name"]: s for s in log.spans}
+    assert {"startup", "trainer.build", "trainer.build.rng",
+            "trainer.build.abstract_state", "trainer.build.shardings",
+            "trainer.build.optimizer", "trainer.build.guards",
+            "trainer.first_step", "compile"} <= set(by_name)
+    build = by_name["trainer.build"]["attributes"]
+    assert (build["n_layers"], build["scan_layers"], build["mode"]) == (
+        model_cfg.n_layers, model_cfg.scan_layers, "lora")
+    assert by_name["trainer.first_step"]["attributes"]["step_programs"] == 1
+    step = [s for s in log.spans if s["name"] == "compile"
+            and s["attributes"]["step"]]
+    assert [s["attributes"]["fun_name"] for s in step] == ["jit(_train_step)"]
+    assert step[0]["parent_span_id"] == by_name["trainer.first_step"]["span_id"]
+    attrs = step[0]["attributes"]
+    assert attrs["trace_s"] > 0 and attrs["lower_s"] > 0 and attrs["backend_s"] > 0
+    assert attrs["cache"] in ("hit", "miss")
+    assert log.step_compiles() == [log.programs["jit(_train_step)"]]
+    # every program has a name; the small ones share one span and are counted
+    assert all(s["attributes"]["fun_name"] for s in log.spans
+               if s["name"] == "compile")
+    assert all(s["attributes"]["count"] >= 1 for s in log.spans
+               if s["name"] == "compile.small")
+    assert validate_trace(log.spans) == []
+
+
+def _assembled(tmp_path, log):
+    """The job's trace as ``GET /jobs/{id}/trace`` assembles it: the
+    controller saw ``running`` just before the log's root starts."""
+    t0 = log.root["start_ns"] / 1e9
+    events = [
+        make_event("submitted", ts=t0 - 2, key="submitted:1"),
+        make_event("running", ts=t0 - 1, key="running:a1", attempt=1),
+        make_event("succeeded", ts=time.time() + 1, key="succeeded:a1"),
+    ]
+    spans = parse_span_lines(
+        (tmp_path / "trace" / "trainer.jsonl").read_text())
+    return build_trace(
+        _job_doc(events, end_time=time.time() + 1, trace_id="f" * 32), spans)
+
+
+def test_fit_adopts_the_startup_log_under_its_fit_span(
+        startup_log, tmp_path, monkeypatch):
+    """A tiny-preset local job: ``startup`` (imports by package),
+    ``trainer.build``, the step's ``compile`` and ``trainer.first_step`` under
+    attempt-1 -> fit in the assembled trace, gap-free from the log's start."""
+    from finetune_controller_tpu.data import synthetic_batches
+
+    log = startup_log
+    monkeypatch.setenv("FTC_TRACE_ID", "f" * 32)
+    monkeypatch.setenv("FTC_ATTEMPT", "1")
+    monkeypatch.delenv("FTC_TRACE", raising=False)
+    trainer, model_cfg = _tiny_trainer()
+    batches = synthetic_batches(2, 16, model_cfg.vocab_size, task="increment")
+    trainer.fit(batches, str(tmp_path), resume=False)
+    assert log.closed
+
+    trace = _assembled(tmp_path, log)
+    assert trace["problems"] == []
+    by_name = {s["name"]: s for s in trace["spans"]}
+    by_id = {s["span_id"]: s for s in trace["spans"]}
+
+    def path(name):
+        out, s = [], by_name[name]
+        while s["parent_span_id"] is not None:
+            s = by_id[s["parent_span_id"]]
+            out.append(s["name"])
+        return out
+
+    assert path("startup") == ["fit", "attempt-1", "job"]
+    assert path("trainer.build") == ["startup", "fit", "attempt-1", "job"]
+    assert path("trainer.first_step") == ["startup", "fit", "attempt-1", "job"]
+    assert path("init") == ["fit", "attempt-1", "job"]
+    step = next(s for s in trace["spans"] if s["name"] == "compile"
+                and s["attributes"]["step"])
+    assert by_id[step["parent_span_id"]]["name"] == "trainer.first_step"
+    start = by_name["startup"]
+    assert start["attributes"]["import_s"] >= 0
+    assert isinstance(start["attributes"]["import_by_package"], dict)
+    # fit starts where the log did: no gap between the attempt and the program
+    assert by_name["fit"]["start_ns"] == start["start_ns"] == log.root["start_ns"]
+    # every adopted span is the job's: trace id, service and attempt filled in
+    adopted = [s for s in trace["spans"] if s["name"].startswith(
+        ("startup", "trainer.", "compile"))]
+    assert all(s["trace_id"] == "f" * 32 and s["attributes"]["attempt"] == 1
+               and s["attributes"]["service"] == "trainer" for s in adopted)
+    # ... and the events say what start-up cost and what the step's compile was
+    events = {e["event"]: e["attrs"] for e in parse_event_lines(
+        (tmp_path / "events.jsonl").read_text())}
+    assert set(events["train-started"]["startup_s"]) == {
+        "import", "backend", "trainer_build"}
+    assert by_name["startup.backend"]["attributes"]["platform"] == "cpu"
+    finished = events["train-finished"]["step_compiles"]
+    assert [p["fun_name"] for p in finished] == ["jit(_train_step)"]
+    assert finished[0]["count"] == 1 and finished[0]["backend_s"] > 0
+
+    # a second fit in the process: the log is closed, nothing is adopted
+    # again, and its trainer's own build goes to the live recorder
+    second = tmp_path / "second"
+    trainer2, _ = _tiny_trainer()
+    trainer2.fit(batches, str(second), resume=False)
+    names = [s["name"] for s in parse_span_lines(
+        (second / "trace" / "trainer.jsonl").read_text())]
+    assert "startup" not in names and "trainer.first_step" not in names
+    assert {"fit", "init"} <= set(names)
+
+
+def test_second_trainer_under_a_live_recorder_writes_its_build_there(
+        startup_log, tmp_path):
+    log = startup_log
+    log.close()
+    rec = SpanRecorder(str(tmp_path), "t" * 32, attempt=3, startup=log)
+    fit = rec.start("fit")
+    _tiny_trainer()
+    rec.finish(fit)
+    spans = parse_span_lines((tmp_path / "trace" / "trainer.jsonl").read_text())
+    by_name = {s["name"]: s for s in spans}
+    assert by_name["trainer.build"]["parent_span_id"] == fit["span_id"]
+    assert by_name["trainer.build.rng"]["parent_span_id"] \
+        == by_name["trainer.build"]["span_id"]
+    assert by_name["trainer.build"]["attributes"]["attempt"] == 3
+    assert validate_trace(spans) == []
+    assert [s["name"] for s in log.spans] == ["startup"]    # frozen
+    del rec     # the recorder gone, the next build goes nowhere
+    _tiny_trainer()
+    assert len(parse_span_lines(
+        (tmp_path / "trace" / "trainer.jsonl").read_text())) == len(spans)
+
+
+def test_fit_with_trace_off_writes_nothing_and_keeps_the_log(
+        startup_log, tmp_path, monkeypatch):
+    """``TrainConfig.trace=False`` (the benchmark's) and ``FTC_TRACE=0`` stop
+    the JSONL flush, not the in-memory log a benchmark reads in-process."""
+    from finetune_controller_tpu.data import synthetic_batches
+
+    log = startup_log
+    monkeypatch.setenv("FTC_TRACE_ID", "f" * 32)
+    trainer, model_cfg = _tiny_trainer(trace=False)
+    batches = synthetic_batches(2, 16, model_cfg.vocab_size, task="increment")
+    trainer.fit(batches, str(tmp_path), resume=False)
+    assert not (tmp_path / "trace").exists()
+    assert not (tmp_path / "events.jsonl").exists()
+    assert log.closed and log.spans[-1] is log.root
+    assert {"trainer.build", "trainer.first_step", "compile"} <= {
+        s["name"] for s in log.spans}
+    assert log.root["attributes"]["import_s"] >= 0
+
+
+def test_build_trace_cuts_spans_older_than_their_attempt_to_it():
+    """A warm worker's process predates the job, and the monitor hears
+    ``running`` a tick after a cold one began: what the trainer did before
+    the attempt as the controller saw it is cut to the attempt's start."""
+    t0 = 50.0
+    events = [
+        make_event("submitted", ts=t0, key="submitted:1"),
+        make_event("running", ts=t0 + 2, key="running:a1", attempt=1),
+        make_event("succeeded", ts=t0 + 30, key="succeeded:a1"),
+    ]
+
+    def span(name, sid, parent, start, end):
+        return {"name": name, "trace_id": "x", "span_id": sid * 16,
+                "parent_span_id": parent and parent * 16,
+                "start_ns": int((t0 + start) * 1e9),
+                "end_ns": int((t0 + end) * 1e9), "status": "ok",
+                "attributes": {"attempt": 1}}
+
+    trace = build_trace(_job_doc(events, end_time=t0 + 30), [
+        span("fit", "f", None, -100, 29),
+        span("startup", "s", "f", -100, 12),
+        span("startup.backend", "b", "s", -99, -90),    # all of it before
+        span("trainer.build", "t", "s", 1, 8),          # straddles
+        span("init", "i", "f", 12, 13),                 # after: untouched
+    ])
+    assert trace["problems"] == []
+    by_name = {s["name"]: s for s in trace["spans"]}
+    begins = by_name["attempt-1"]["start_ns"]
+    assert by_name["fit"]["start_ns"] == by_name["startup"]["start_ns"] == begins
+    assert by_name["fit"]["attributes"]["before_attempt_s"] == 102.0
+    backend = by_name["startup.backend"]
+    assert backend["start_ns"] == backend["end_ns"] == begins
+    assert backend["attributes"]["before_attempt_s"] == 101.0
+    assert by_name["trainer.build"]["attributes"]["before_attempt_s"] == 1.0
+    assert by_name["trainer.build"]["end_ns"] == int((t0 + 8) * 1e9)
+    assert "before_attempt_s" not in by_name["init"]["attributes"]
+
+
+_CACHE_PROBE = """
+import sys
+import jax, jax.numpy as jnp
+from finetune_controller_tpu.obs import trace
+
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
+def cache_probe(x):
+    return jnp.tanh(x @ x.T).sum()
+
+jax.jit(cache_probe)(jnp.ones((64, 64))).block_until_ready()
+import json
+print(json.dumps(trace.STARTUP.programs["jit(cache_probe)"]))
+"""
+
+
+def test_compile_span_says_miss_then_hit_over_two_processes(tmp_path):
+    """One temporary cache directory, two processes: the first compiles the
+    program and writes it, the second loads it — each says so by name."""
+    import subprocess
+
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "cache"),
+           "PYTHONPATH": os.pathsep.join(
+               [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
+               + os.environ.get("PYTHONPATH", "").split(os.pathsep))}
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+    heard = []
+    for _ in range(2):
+        r = subprocess.run([sys.executable, "-c", _CACHE_PROBE], env=env,
+                           capture_output=True, text=True, timeout=300)
+        assert r.returncode == 0, r.stderr[-2000:]
+        heard.append(json.loads(r.stdout.strip().splitlines()[-1]))
+    first, second = heard
+    assert first["fun_name"] == second["fun_name"] == "jit(cache_probe)"
+    assert (first["cache"], first.get("cache_written")) == ("miss", True)
+    assert "cache_load_s" not in first
+    assert second["cache"] == "hit" and second["cache_load_s"] > 0
+    assert second["backend_s"] >= second["cache_load_s"]
+    for attrs in heard:
+        assert attrs["step"] is False and attrs["count"] == 1
+        assert attrs["trace_s"] > 0 and attrs["lower_s"] > 0
 
 
 # ---------------------------------------------------------------------------

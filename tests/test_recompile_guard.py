@@ -109,6 +109,38 @@ def test_trainer_threads_guard_behind_config_flag(devices8):
         trainer.step(state, short)
 
 
+def test_a_forced_second_signature_names_the_program_and_its_seconds():
+    """The process's start-up log hears every compile by name, long after it
+    has closed: the guard quotes what the step's program cost the last time,
+    which is what the refused signature would have paid again."""
+    import re
+
+    from finetune_controller_tpu.obs import trace
+
+    def forced_second_signature(x):
+        return jnp.tanh(x).sum()
+
+    guard = RecompileGuard(1, on_excess="raise")
+    f = guard.wrap(jax.jit(forced_second_signature), label="step")
+    f(jnp.zeros((4,)))
+    heard = trace.STARTUP.programs["jit(forced_second_signature)"]
+    assert heard["count"] == 1 and heard["cache"] in ("hit", "miss", "off")
+    with pytest.raises(RecompileBudgetExceeded) as e:
+        f(jnp.zeros((5,)))
+    said = str(e.value)
+    assert "jit(forced_second_signature) has compiled 1 time(s)" in said
+    cost = heard["trace_s"] + heard["lower_s"] + heard["backend_s"]
+    assert f"the last in {cost:.2f} s" in said
+    assert re.search(r"trace \d+\.\d\d, lower \d+\.\d\d, backend \d+\.\d\d, "
+                     r"cache (hit|miss|off)", said)
+    # a function the log never heard of is quoted as before: no name, no cost
+    quiet = RecompileGuard(1, on_excess="raise")
+    with pytest.raises(RecompileBudgetExceeded) as e:
+        quiet.check("a", ("sig", 1))
+        quiet.check("a", ("sig", 2))
+    assert "has compiled" not in str(e.value)
+
+
 def test_trainer_guard_off_by_default(devices8):
     from finetune_controller_tpu.models import PRESETS, LoRAConfig
     from finetune_controller_tpu.parallel import MeshSpec

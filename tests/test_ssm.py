@@ -5,6 +5,7 @@ the same documents alone, the block's shape, every muP multiplier, and what the
 model refuses."""
 
 import sys
+import zlib
 from pathlib import Path
 
 import jax
@@ -174,7 +175,9 @@ def _variables(cfg=TINY, seed=0, seq=24):
     # adapters of a job in mid-training: a zero B would hide A's gradient
     lora = jax.tree_util.tree_map_with_path(
         lambda p, a: 0.05 * jax.random.normal(
-            jax.random.PRNGKey(hash(str(p)) % 2**31), a.shape, a.dtype),
+            # crc32, not hash(): a str's hash changes with every process, and
+            # one draw in many left a multiplier's loss within rounding
+            jax.random.PRNGKey(zlib.crc32(str(p).encode())), a.shape, a.dtype),
         variables["lora"])
     return model, {"params": variables["params"], "lora": lora}, tokens
 
