@@ -13,10 +13,13 @@ import logging
 import os
 import re
 import threading
-from typing import Any
+import time
+from typing import TYPE_CHECKING, Any, Callable
 
 import jax
-import orbax.checkpoint as ocp
+
+if TYPE_CHECKING:
+    import orbax.checkpoint as ocp
 
 logger = logging.getLogger(__name__)
 
@@ -70,18 +73,44 @@ class CheckpointManager:
         os.makedirs(self.directory, exist_ok=True)
         self._sweep_stale_tmp()
         self._ckptr_obj: ocp.StandardCheckpointer | None = None
+        #: what the first ``_ckptr`` paid to import the checkpoint library,
+        #: and the thread that paid it (None until a save or restore asks)
+        self.backend_import_s: float | None = None
+        self.backend_import_thread: str | None = None
         self._pending: threading.Thread | None = None
         self._pending_error: list[BaseException] = []
 
     @property
     def _ckptr(self) -> ocp.StandardCheckpointer:
-        """Orbax's checkpointer, built on the first save or restore: its
-        constructor starts the JAX backend, and a manager that only lists
-        steps — the API server staging a promoted checkpoint for its worker
-        processes — must not take the chip."""
+        """Orbax's checkpointer, imported and built on the first save or
+        restore, by the thread that makes it (a save's writer thread, so the
+        import runs beside the training steps): the library's import scans
+        every installed distribution twice (``google.cloud.logging``, ~12 s
+        on a chip's host) and its constructor starts the JAX backend, and a
+        manager that only lists steps — the API server staging a promoted
+        checkpoint for its worker processes, a fresh job's ``latest_step()``
+        — must neither pay the scans nor take the chip.  Nothing on a saving
+        caller's thread may touch this before the writer does (``wait()``
+        reads ``_ckptr_obj``)."""
         if self._ckptr_obj is None:
+            t0 = time.perf_counter()
+            import orbax.checkpoint as ocp
+
+            self.backend_import_s = time.perf_counter() - t0
+            self.backend_import_thread = threading.current_thread().name
             self._ckptr_obj = ocp.StandardCheckpointer()
         return self._ckptr_obj
+
+    def take_backend_import(self) -> dict[str, Any]:
+        """The library's import as span or event attributes, reported ONCE:
+        its seconds and the thread that paid them to the first caller after
+        the import, 0.0 and no thread before it and ever after."""
+        seconds, self.backend_import_s = self.backend_import_s, None
+        thread, self.backend_import_thread = self.backend_import_thread, None
+        return {
+            "backend_import_s": round(seconds or 0.0, 4),
+            "backend_import_thread": thread,
+        }
 
     def _sweep_stale_tmp(self) -> None:
         """Remove uncommitted ``step_N.tmp`` staging dirs left by a crash.
@@ -143,7 +172,13 @@ class CheckpointManager:
         steps = self.all_steps()
         return steps[-1] if steps else None
 
-    def _save_sync(self, path: str, tree: Any, manifest: dict | None) -> None:
+    def _save_sync(
+        self,
+        path: str,
+        tree: Any,
+        manifest: dict | None,
+        on_commit: Callable[[], None] | None,
+    ) -> None:
         try:
             if jax.process_count() > 1:
                 # Orbax's save is itself a cross-process collective
@@ -158,6 +193,8 @@ class CheckpointManager:
                 self._ckptr.wait_until_finished()
                 if manifest is not None:
                     self._write_manifest(path, manifest)
+            if on_commit is not None:
+                on_commit()
         except BaseException as exc:  # noqa: BLE001 — re-raised from wait()
             logger.exception("background checkpoint save to %s failed", path)
             # ftc: ignore[shared-mutable-without-lock] -- single in-flight writer thread (save() waits before starting another); list.append is GIL-atomic and drained only after join() in wait()
@@ -170,7 +207,10 @@ class CheckpointManager:
         force: bool = False,
         blocking: bool = False,
         manifest: dict | None = None,
+        on_commit: Callable[[], None] | None = None,
     ) -> None:
+        """``on_commit`` is called by the writer thread once this save is on
+        disk (never for a save that failed, or that found its step there)."""
         self.wait()  # one in-flight save at a time (raises on a prior failure)
         path = self._path(step)
         if os.path.exists(path):
@@ -184,7 +224,8 @@ class CheckpointManager:
         # the whole point is overlapping serialization + IO with training
         self._gc()
         self._pending = threading.Thread(
-            target=self._save_sync, args=(path, tree, manifest), daemon=False
+            target=self._save_sync, args=(path, tree, manifest, on_commit),
+            name="checkpoint-writer", daemon=False,
         )
         self._pending.start()
         if blocking:

@@ -1245,6 +1245,17 @@ class Trainer:
             artifacts_dir, trace_id=trace_id, attempt=obs_attempt,
             enabled=obs_on,
         )
+
+        def checkpoint_committed(step: int, blocking: bool) -> None:
+            # the WRITER thread's to call, once its save is on disk: a job's
+            # first save imports the checkpoint library on that thread
+            # (``CheckpointManager._ckptr``), and what that cost rides the
+            # first event (0.0 on every later one)
+            events_log.emit(
+                "checkpoint-committed", step=step, blocking=blocking or None,
+                **ckpt.take_backend_import(),
+            )
+
         # the recorder adopts the process's start-up log: what ran before
         # fit() — imports, the backend, Trainer() — lands under ``fit``
         spans = SpanRecorder(
@@ -1313,7 +1324,8 @@ class Trainer:
                 )
                 self._audit_state_sharding(state, "restore")
                 start_step = int(host["step"])
-                spans.finish(restore_span, step=start_step)
+                spans.finish(restore_span, step=start_step,
+                             **ckpt.take_backend_import())
                 logger.info("resumed from checkpoint step %d", start_step)
 
         # liveness heartbeat (resilience/heartbeat.py): rank 0 proves forward
@@ -1589,19 +1601,19 @@ class Trainer:
                         # interpreter crash on fast CPU test runs.  Every
                         # committed checkpoint carries its topology manifest
                         # (train/elastic.py) so ANY later mesh can restore it.
-                        ckpt.save(step_idx + 1, host_state,
-                                  blocking=blocking_save,
-                                  manifest=self._build_manifest(
-                                      step_idx + 1, host_state))
+                        ckpt.save(
+                            step_idx + 1, host_state, blocking=blocking_save,
+                            manifest=self._build_manifest(
+                                step_idx + 1, host_state),
+                            on_commit=partial(
+                                checkpoint_committed, step_idx + 1,
+                                blocking_save),
+                        )
                     if obs_on:
                         # the host-side cost of this save (gather + write for
                         # a blocking save; gather + handoff for an async one)
                         phases.add("checkpoint", time.perf_counter() - t_ck)
                     spans.finish(ck_span)
-                    events_log.emit(
-                        "checkpoint-committed", step=step_idx + 1,
-                        blocking=blocking_save or None,
-                    )
                 if preempt:
                     logger.warning("exiting on preemption after step %d", step_idx + 1)
                     events_log.emit("preempt-exit", step=step_idx + 1)

@@ -40,6 +40,9 @@ def main() -> int:
     # pre-import the whole training stack (flax/optax/orbax/models/data) —
     # JAX alone is under half the interpreter's import bill
     from . import checkpoint, cli, trainer  # noqa: F401
+    # ``checkpoint`` leaves its library to a job's first save (~12 s on a
+    # chip's host): a worker that waits for its job pays that here, ahead of it
+    import orbax.checkpoint  # noqa: F401
     from ..data import loader, synthetic  # noqa: F401
     from ..models import multimodal  # noqa: F401
 
