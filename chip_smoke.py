@@ -251,6 +251,97 @@ print(json.dumps({"device": device_report(),
 """
 
 
+#: the held share of a latent expert layer — the grouped path of
+#: ``models/moe.py`` (the share's own pairs sorted, gathered and multiplied by
+#: two grouped products an expert without a gate needs, ``held_row_bound``
+#: rows a pass, the latent projections around it) — against the masked plain
+#: form (every held expert on every row, float32, weights zero where the
+#: expert was not chosen; the SAME router product decides both, so no near-tie
+#: falls two ways): value and the input's gradient under a fixed cotangent,
+#: bf16 products against float32 ones
+LATENT_TOL = 2 ** -5
+
+#: (rows, d_model, latent, expert width, experts routed over, held, per token)
+LATENT_SHARE_PARITY_SNIPPET = r"""
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from finetune_controller_tpu.platform import device_report, enable_compile_cache
+from finetune_controller_tpu.models.lora import LoRADense
+from finetune_controller_tpu.models.moe import MoEMLP, held_row_bound
+
+enable_compile_cache()
+cases = []
+for n_case, (rows, d, latent, f, e, held, k) in enumerate(json.loads(sys.argv[1])):
+    bf16 = jnp.bfloat16
+    proj = lambda width: LoRADense(features=width, dtype=bf16, param_dtype=bf16,
+                                   parent=None)
+    layer = MoEMLP(d_model=d, d_ff=f, n_experts=e, top_k=k, dispatch="dropless",
+                   scoring="sigmoid", routed_scale=5.0, experts_held=(0, held),
+                   gated=False, aux_loss=False, dtype=bf16, param_dtype=bf16,
+                   fc1_latent_proj=proj(latent), fc2_latent_proj=proj(d))
+    rng = np.random.default_rng(n_case)
+    x, cot = (jnp.asarray(rng.standard_normal((1, rows, d)), bf16) for _ in "xc")
+    params = jax.jit(lambda: layer.init(jax.random.PRNGKey(n_case), x)["params"])()
+
+    def grouped(x, params):
+        return layer.apply({"params": params}, x, mutable=("moe_stats",))
+
+    def masked(x, params):
+        f32 = lambda t: t.astype(jnp.float32)
+        xt = f32(x).reshape(rows, d)
+        # the layer's own router product: the same choices on both sides
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "td,de->te", xt, f32(params["router"]["kernel"])))
+        _, chosen = jax.lax.top_k(scores, k)
+        w = jnp.take_along_axis(scores, chosen, axis=-1)
+        w = w / (w.sum(-1, keepdims=True) + 1e-20) * 5.0
+        mask = (jax.nn.one_hot(chosen, e, dtype=jnp.float32)
+                * w[..., None]).sum(1)[:, :held]
+        with jax.default_matmul_precision("highest"):
+            r = xt @ f32(params["fc1_latent_proj"]["kernel"])
+
+            def one(acc, xs):
+                up, down, col = xs
+                return acc + col[:, None] * (
+                    jnp.square(jax.nn.relu(r @ f32(up))) @ f32(down)), None
+
+            total = jax.lax.scan(one, jnp.zeros_like(r), (
+                params["experts"]["up_proj"]["kernel"],
+                params["experts"]["down_proj"]["kernel"], mask.T))[0]
+            out = total @ f32(params["fc2_latent_proj"]["kernel"])
+        return out.reshape(1, rows, d), mask
+
+    @jax.jit
+    def both(x, params, cot):
+        c32 = cot.astype(jnp.float32)
+
+        def under_the_cotangent(fn):
+            def weighed(t):
+                out, aux = fn(t, params)
+                return (out.astype(jnp.float32) * c32).sum(), (out, aux)
+            return jax.value_and_grad(weighed, has_aux=True)
+
+        (_, (got, stats)), got_dx = under_the_cotangent(grouped)(x)
+        (_, (want, mask)), want_dx = under_the_cotangent(masked)(x)
+        return got, stats, got_dx, want, mask, want_dx
+
+    got, stats, got_dx, want, mask, want_dx = both(x, params, cot)
+    err = lambda u, v: float(jnp.max(jnp.abs(u.astype(jnp.float32) - v))
+                             / jnp.max(jnp.abs(v)))
+    pairs = float(stats["moe_stats"]["pairs"][0])
+    cases.append({"shape": [rows, d, latent, f, e, held, k],
+                  "value_err": err(got, want),
+                  "grad_err": err(got_dx, want_dx.astype(jnp.float32)),
+                  "pairs": pairs, "pairs_by_the_mask": float((mask > 0).sum()),
+                  "row_bound": held_row_bound(rows * k, held, e),
+                  "pairs_over_bound": float(stats["moe_stats"]["pairs_over_bound"][0]),
+                  "finite": bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))
+                                 & jnp.all(jnp.isfinite(got_dx.astype(jnp.float32))))})
+print(json.dumps({"device": device_report(),
+                  "compiled": jax.default_backend() == "tpu", "cases": cases}))
+"""
+
+
 #: the joined LoRA product against the layer's old expression: the forms
 #: differ by where they round (apart: base, delta, sum; joined: once), by
 #: two bf16 ulps of the largest magnitude at most
@@ -346,6 +437,8 @@ def mode_config(tiny: bool, seed: int) -> dict:
             # the joined LoRA product against the layer's old expression
             # (rows, in, out, rank, quantisation block, scale)
             "lora_shapes": [[48, 64, 96, 4, 16, 2.0]],
+            # a held share of a latent expert layer against its masked form
+            "latent_shapes": [[64, 32, 16, 24, 16, 4, 4]],
         }
     return {
         "platform": "tpu", "model_name": "tinyllama-1.1b-lora",
@@ -378,6 +471,10 @@ def mode_config(tiny: bool, seed: int) -> dict:
         # one Mistral-width projection (gate / up) over an int4 base, 2,048
         # rows, rank 16: the adapter inside the base product's contraction
         "lora_shapes": [[2048, 4096, 14336, 16, 64, 2.0]],
+        # one expert layer of the pattern configuration at its published
+        # widths: 1,024 rows, top-22 of 512 experts of 1024 x 2688 in a
+        # 1024-wide latent of a 4096-wide state, 128 held
+        "latent_shapes": [[1024, 4096, 1024, 2688, 512, 128, 22]],
     }
 
 
@@ -827,6 +924,26 @@ def ssd_parity_phase(run_id: str, cfg: dict) -> dict:
     return rec["device"]
 
 
+def latent_share_parity_phase(run_id: str, cfg: dict) -> dict:
+    t0 = time.monotonic()
+    rec = parity_child(run_id, cfg, "latent share", LATENT_SHARE_PARITY_SNIPPET,
+                       json.dumps(cfg["latent_shapes"]))
+    worst = max(max(c["value_err"], c["grad_err"]) for c in rec["cases"])
+    check(worst <= LATENT_TOL,
+          f"held share's grouped path off the masked plain form by {worst} > "
+          f"{LATENT_TOL} of the largest magnitude: {rec['cases']}")
+    check(all(c["pairs"] == c["pairs_by_the_mask"] for c in rec["cases"]),
+          f"the share computed other pairs than the mask holds: {rec['cases']}")
+    say("latent-share-parity", time.monotonic() - t0, compiled=rec["compiled"],
+        tolerance=LATENT_TOL, worst_err=worst,
+        errs_by_shape={"x".join(map(str, c["shape"])):
+                       {"value": c["value_err"], "grad": c["grad_err"],
+                        "pairs": c["pairs"], "row_bound": c["row_bound"],
+                        "pairs_over_bound": c["pairs_over_bound"]}
+                       for c in rec["cases"]})
+    return rec["device"]
+
+
 def lora_parity_phase(run_id: str, cfg: dict) -> dict:
     t0 = time.monotonic()
     rec = parity_child(run_id, cfg, "lora", LORA_PARITY_SNIPPET,
@@ -849,6 +966,7 @@ def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     # here, in a minute
     grouped_device = grouped_parity_phase(run_id, cfg)
     ssd_device = ssd_parity_phase(run_id, cfg)
+    latent_device = latent_share_parity_phase(run_id, cfg)
     lora_device = lora_parity_phase(run_id, cfg)
     t0 = time.monotonic()
     server, api, log = start_server(work, run_id, cfg["platform"])
@@ -877,10 +995,10 @@ def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     say("shutdown", time.monotonic() - t1, survivors=[])
     parity_device = paged_parity_phase(run_id, cfg)
     check(train_device == serve_device == parity_device == grouped_device
-          == ssd_device == lora_device,
+          == ssd_device == latent_device == lora_device,
           f"children disagree on the device: trainer {train_device}, "
           f"serve worker {serve_device}, parity children {parity_device}, "
-          f"{grouped_device}, {ssd_device}, {lora_device}")
+          f"{grouped_device}, {ssd_device}, {latent_device}, {lora_device}")
     cache_dir, entries1 = cache_state()
     say("compile-cache", 0.0, dir=cache_dir,
         entries_before=entries0, entries_after=entries1)

@@ -393,8 +393,8 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     """Abstract param trees spanning every weight family the rule tables
     must cover: dense+LoRA (untied, so lm_head exists), QLoRA int4 scales,
     MoE experts + router, latent attention with a selection-biased router,
-    a state-space mixer beside attention, and the multimodal projector + ViT
-    tower.  All
+    a state-space mixer beside attention, a pattern of single-mixer layers
+    with experts in a latent, and the multimodal projector + ViT tower.  All
     ``eval_shape`` — no parameter memory is allocated."""
     global _VARIANT_CACHE
     if _VARIANT_CACHE is not None:
@@ -402,7 +402,8 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     import jax.numpy as jnp
 
     from ..models.llama import PRESETS, LlamaForCausalLM
-    from ..models.lora import HYBRID_TARGETS, MLA_TARGETS, LoRAConfig
+    from ..models.lora import (HYBRID_TARGETS, MLA_TARGETS, PATTERN_TARGETS,
+                               LoRAConfig)
     from ..models.multimodal import MM_PRESETS, LlavaForCausalLM
 
     tokens = jnp.zeros((1, 8), jnp.int32)
@@ -430,6 +431,12 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     )
     out["tiny-falcon-h1-test+lora"] = _shape_leaves(
         LlamaForCausalLM(cfg_ssm), tokens
+    )
+    cfg_pattern = PRESETS["tiny-nemotron-h-test"].replace(
+        lora=LoRAConfig(rank=4, targets=PATTERN_TARGETS)
+    )
+    out["tiny-nemotron-h-test+lora"] = _shape_leaves(
+        LlamaForCausalLM(cfg_pattern), tokens
     )
     mm = MM_PRESETS["tiny-mm-test"].replace(lora=LoRAConfig(rank=4))
     pixels = jnp.zeros(

@@ -156,12 +156,12 @@ def _hf_layout(cfg: LlamaConfig) -> tuple[str, str]:
     combinations no HF architecture encodes."""
     if (cfg.attention_kind != "gqa" or cfg.first_k_dense
             or cfg.n_shared_experts or cfg.moe_scoring != "softmax"
-            or cfg.ssm_d_inner):
+            or cfg.ssm_d_inner or cfg.layer_pattern):
         raise NotImplementedError(
             "latent attention, leading dense layers, shared-expert sigmoid "
-            "routing and a state-space mixer beside attention have no "
-            "transformers export yet (ROADMAP.md B); export the PEFT adapter "
-            "instead"
+            "routing, a state-space mixer beside attention and a pattern of "
+            "layer kinds have no transformers export yet (ROADMAP.md B); "
+            "export the PEFT adapter instead"
         )
     gemma_markers = (cfg.norm_offset, cfg.embed_scale, cfg.mlp_act != "silu")
     if any(gemma_markers):

@@ -92,6 +92,11 @@ def _map_llama_tensors(
 ) -> dict[str, Any]:
     """Map stripped ``(hf_name, array)`` pairs onto the Llama param tree
     (shared by the text-only loader and the LLaVA language-model half)."""
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            "a pattern of layer kinds has no checkpoint import yet: its "
+            "leaves are not one block's stacked over the layers "
+            "(ROADMAP.md B10)")
     L = cfg.n_layers
 
     # staging area: per-layer dicts to stack once everything is read

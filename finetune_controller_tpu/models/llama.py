@@ -2,7 +2,11 @@
 the block's parts chosen by the configuration: grouped-query or latent
 attention (``attention_kind``), a dense SwiGLU or an expert layer
 (``n_experts``), and ``first_k_dense`` leading dense layers before the
-scanned stack.  ONE top level: embedding -> layers -> final norm -> head.
+scanned stack — or, with ``layer_pattern``, a model that is a PATTERN of
+single-mixer layers (a state-space mixer, an expert layer or attention under
+one norm, one residual add, no MLP half), the pattern read as data
+(``LlamaConfig.pattern_runs``).  ONE top level: embedding -> layers -> final
+norm -> head.
 
 TPU-first choices:
   * layers run under ``nn.scan`` (one traced layer, stacked params) so XLA
@@ -82,6 +86,11 @@ def remat_policy_fn(name: str, also: tuple[str, ...] = ()):
     return jax.checkpoint_policies.save_only_these_names(*saveable[name], *also)
 
 
+#: a letter of ``LlamaConfig.layer_pattern`` -> the module name of the ONE
+#: mixer such a layer holds (a layer's kind reads from a profile's name stack)
+LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+
+
 @dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 32000
@@ -90,6 +99,8 @@ class LlamaConfig:
     n_heads: int = 32
     n_kv_heads: int = 4
     d_ff: int = 5632
+    #: 0.0 = attention WITHOUT positions: no rotary embedding (a model whose
+    #: state-space layers carry the order)
     rope_theta: float = 10000.0
     #: llama3-style RoPE frequency scaling (the Llama-3.1/3.2 long-context
     #: recipe; transformers ``rope_scaling: {"rope_type": "llama3"}``):
@@ -144,6 +155,17 @@ class LlamaConfig:
     #: leading layers that keep a dense MLP in a model whose other layers are
     #: expert layers; they lie outside the scanned stack, as ``layer_<i>``
     first_k_dense: int = 0
+    #: experts in a latent of this width: ``fc1_latent_proj`` (d_model ->
+    #: latent) makes the rows the experts take, ``fc2_latent_proj`` brings
+    #: their weighted sum back; the router and the shared expert stay on the
+    #: ``d_model``-wide state.  0 = experts at ``d_model``
+    moe_latent: int = 0
+    #: a model that is a pattern of single-mixer layers, one letter a layer:
+    #: ``M`` a state-space mixer (``ssm_*``), ``E`` an expert layer
+    #: (``n_experts`` ...), ``*`` grouped-query attention — each ONE norm, ONE
+    #: mixer, one residual add, and no MLP half.  "" = every layer a
+    #: :class:`Block` of mixer(s) then MLP
+    layer_pattern: str = ""
     # --- latent attention (MLA): queries and keys/values through low-rank
     # latents, a rotary part of ``qk_rope_head_dim`` beside a position-free
     # part of ``qk_nope_head_dim``, the rotary KEY shared by all heads, and
@@ -176,7 +198,8 @@ class LlamaConfig:
     #: with d_model 2048/3072); 0 = d_model // n_heads
     head_dim_override: int = 0
     #: MLP gate activation: "silu" (Llama SwiGLU) | "gelu" (Gemma GeGLU,
-    #: tanh-approximate like transformers' gelu_pytorch_tanh)
+    #: tanh-approximate like transformers' gelu_pytorch_tanh) | "relu2" (NO
+    #: gate: ``down(relu(up x)^2)``, in the MLP and in every expert)
     mlp_act: str = "silu"
     #: RMSNorm weight parameterisation: 0.0 = plain scale (Llama, ones-init);
     #: 1.0 = (1 + scale) with zeros-init (Gemma — HF stores the offset form)
@@ -266,6 +289,42 @@ class LlamaConfig:
                 f"{self.n_layers} {self.attention_kind} layers")
         return kinds
 
+    def pattern_runs(self) -> tuple[tuple[str, int], ...]:
+        """``layer_pattern`` as runs ``(unit, repeats)``, in order: at each
+        layer the repeated unit that covers the most layers from there (the
+        shortest such unit), or the one layer by itself where nothing repeats.
+        A run of two repeats or more is one scanned stack (``blocks``, a later
+        one ``blocks_<first layer>``) over its unit's layers; the others are
+        unrolled, ``layer_<i>``.  ``EMEMEMEMEM*`` -> ``(("EM", 5), ("*",
+        1))``.  () for a model without a pattern."""
+        pattern, n = self.layer_pattern, self.n_layers
+        if not pattern:
+            return ()
+        if set(pattern) - set(LAYER_KINDS) or len(pattern) != n:
+            raise ValueError(
+                f"layer_pattern {pattern!r}: one of {' '.join(LAYER_KINDS)} "
+                f"for each of the {n} layers")
+        if (self.attention_kind != "gqa" or self.first_k_dense
+                or self.index_topk or self.tie_embeddings
+                or ("M" in pattern and not self.ssm_d_inner)
+                or ("E" in pattern and not self.n_experts)):
+            raise ValueError(
+                "a pattern model has grouped-query attention, no leading "
+                "dense layer, no indexer, an untied head, and the sizes of "
+                f"every kind it names: {pattern!r}")
+        runs, at = [], 0
+        while at < n:
+            best = (pattern[at], 1)
+            for u in range(1, (n - at) // 2 + 1):
+                unit, r = pattern[at:at + u], 1
+                while pattern[at + r * u:at + (r + 1) * u] == unit:
+                    r += 1
+                if r > 1 and u * r > len(best[0]) * best[1]:
+                    best = (unit, r)
+            runs.append(best)
+            at += len(best[0]) * best[1]
+        return tuple(runs)
+
     def _indexer_params(self) -> int:
         """One indexer's: ``wq_b``, ``wk``, ``k_norm`` and ``weights_proj``."""
         hi, di = self.index_n_heads, self.index_head_dim
@@ -295,16 +354,32 @@ class LlamaConfig:
         return (self.d_model * (inner + channels + heads) + inner * self.d_model
                 + (self.ssm_d_conv + 1) * channels + 3 * heads + inner)
 
+    def _expert_layer_params(self, experts_counted: int) -> int:
+        """An expert layer's with ``experts_counted`` routed experts: those
+        (two matrices each without a gate, three with; at the latent's width
+        where there is one), the router, the selection bias, the two latent
+        projections and the shared expert."""
+        d, f = self.d_model, self.moe_d_ff or self.d_ff
+        matrices = 2 if self.mlp_act == "relu2" else 3
+        return (experts_counted * matrices * (self.moe_latent or d) * f
+                + d * self.n_experts
+                + (self.n_experts if self.moe_select_bias else 0)
+                + 2 * d * self.moe_latent
+                + matrices * d * f * self.n_shared_experts)
+
     def _count(self, experts_counted: int) -> int:
         """Stored parameters with ``experts_counted`` routed experts a layer."""
         d, v, L = self.d_model, self.vocab_size, self.n_layers
-        dense_mlp = 3 * d * self.d_ff
+        if self.pattern_runs():
+            # one norm and one mixer a layer, by kind
+            layer = {"M": self._mixer_params(), "*": self._attention_params(),
+                     "E": self._expert_layer_params(experts_counted)}
+            return (2 * v * d + d
+                    + sum(layer[kind] + d for kind in self.layer_pattern))
+        dense_mlp = (2 if self.mlp_act == "relu2" else 3) * d * self.d_ff
         per_layer = self._attention_params() + self._mixer_params() + 2 * d
         if self.n_experts:
-            f = self.moe_d_ff or self.d_ff
-            expert_mlp = (experts_counted * 3 * d * f + d * self.n_experts
-                          + (self.n_experts if self.moe_select_bias else 0)
-                          + 3 * d * f * self.n_shared_experts)
+            expert_mlp = self._expert_layer_params(experts_counted)
             mlps = (self.first_k_dense * dense_mlp
                     + (L - self.first_k_dense) * expert_mlp)
         else:
@@ -461,6 +536,22 @@ PRESETS: dict[str, LlamaConfig] = {
                          0.3535533905932738),
         mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
     ),
+    # the pattern family at toy size: five single-mixer layers ``EMEM*`` (a
+    # scanned pair twice, then attention by itself), attention without
+    # positions at a query group of 4, a Mamba-2 mixer alone under its norm
+    # (8 heads of 16 over a 16 x 8 state, B and C in 4 groups), sigmoid top-4
+    # of 16 squared-ReLU experts WITHOUT a gate in a 32-wide latent beside a
+    # shared expert of twice their width on the 64-wide state
+    "tiny-nemotron-h-test": LlamaConfig(
+        vocab_size=256, d_model=64, n_layers=5, layer_pattern="EMEM*",
+        n_heads=8, n_kv_heads=2, head_dim_override=16, rope_theta=0.0,
+        d_ff=24, max_seq_len=128, mlp_act="relu2",
+        ssm_n_heads=8, ssm_head_dim=16, ssm_d_state=8, ssm_n_groups=4,
+        ssm_d_conv=4, ssm_chunk=8,
+        n_experts=16, moe_top_k=4, moe_d_ff=24, moe_latent=32,
+        n_shared_experts=2, moe_scoring="sigmoid", moe_dispatch="dropless",
+        moe_routed_scale=5.0, router_aux_weight=0.0,
+    ),
 }
 
 
@@ -560,7 +651,10 @@ class RMSNorm(nn.Module):
         return (norm * (self.offset + scale.astype(jnp.float32))).astype(self.dtype)
 
 
-def _proj(cfg: LlamaConfig, name: str, features: int) -> LoRADense:
+def _proj(cfg: LlamaConfig, name: str, features: int, **module_kw) -> LoRADense:
+    """The projection ``name`` with the adapter the job gives it;
+    ``module_kw``: flax's own (``parent=None`` for a module handed to another
+    to adopt under the attribute it is given to)."""
     lora_on = cfg.lora.enabled_for(name)
     qkv_bias = cfg.attention_qkv_bias and name in ("q_proj", "k_proj", "v_proj")
     return LoRADense(
@@ -576,6 +670,7 @@ def _proj(cfg: LlamaConfig, name: str, features: int) -> LoRADense:
         quant_block=cfg.quant_block,
         tenant_slots=cfg.lora_tenant_slots,
         tenant_rank=cfg.lora_tenant_rank,
+        **module_kw,
     )
 
 
@@ -592,12 +687,16 @@ class Attention(nn.Module):
         k = _proj(cfg, "k_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
         v = _proj(cfg, "v_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
         k = times(k, cfg.key_multiplier)
-        with jax.named_scope("rope"):
-            inv_freqs = rope_inv_freqs(cfg)
-            q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), positions,
-                           inv_freqs=inv_freqs)
-            k = apply_rope(k.reshape(b, s, cfg.n_kv_heads, hd), positions,
-                           inv_freqs=inv_freqs)
+        if cfg.rope_theta:
+            with jax.named_scope("rope"):
+                inv_freqs = rope_inv_freqs(cfg)
+                q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), positions,
+                               inv_freqs=inv_freqs)
+                k = apply_rope(k.reshape(b, s, cfg.n_kv_heads, hd), positions,
+                               inv_freqs=inv_freqs)
+        else:       # attention without positions
+            q = q.reshape(b, s, cfg.n_heads, hd)
+            k = k.reshape(b, s, cfg.n_kv_heads, hd)
         v = v.reshape(b, s, cfg.n_kv_heads, hd)
         if decode:
             return self._decode_attention(q, k, v, deterministic,
@@ -1034,6 +1133,14 @@ class MLP(nn.Module):
         cfg = self.cfg
         d_ff = self.d_ff or cfg.d_ff
         gate_by, down_by = cfg.mlp_multipliers
+        if cfg.mlp_act == "relu2":
+            # no gate matrix: ``down(relu(up x)^2)``
+            up = checkpoint_name(
+                _proj(cfg, "up_proj", d_ff)(x, deterministic, adapter_ids),
+                "mlp_up")
+            out = times(_proj(cfg, "down_proj", cfg.d_model)(
+                jnp.square(nn.relu(up)), deterministic, adapter_ids), down_by)
+            return checkpoint_name(out, "mlp_down")
         gate = checkpoint_name(
             times(_proj(cfg, "gate_proj", d_ff)(x, deterministic, adapter_ids),
                   gate_by),
@@ -1054,6 +1161,9 @@ class Block(nn.Module):
     #: a layer of a model with an indexer, by kind (:class:`MLAttention`);
     #: the call then takes and returns the selection beside ``x``
     indexer: str | None = None
+    #: a layer of a pattern model, by its letter (``LAYER_KINDS``): ONE norm,
+    #: ONE mixer of that kind, one residual add, and no MLP half
+    kind: str | None = None
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, deterministic=True,
@@ -1063,6 +1173,19 @@ class Block(nn.Module):
         cfg = self.cfg
         if cfg.attention_kind not in ("gqa", "mla"):
             raise ValueError(f"unknown attention_kind {cfg.attention_kind!r}")
+        if self.kind is not None:
+            h = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="norm")(x)
+            if self.kind == "M":
+                from .ssm import Mamba2Mixer
+
+                return x + Mamba2Mixer(cfg, name="mamba")(
+                    h, segment_ids, deterministic, decode, adapter_ids)
+            if self.kind == "E":
+                return x + self._expert_layer(
+                    h, deterministic, layer, stacked_experts)
+            return x + Attention(cfg, name="attn")(
+                h, positions, segment_ids, deterministic, decode, page_table,
+                adapter_ids)
         attention = MLAttention if cfg.attention_kind == "mla" else Attention
         h = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="attn_norm")(x)
         if cfg.ssm_d_inner:
@@ -1079,36 +1202,48 @@ class Block(nn.Module):
             x = x + attn_out
         h = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="mlp_norm")(x)
         if cfg.n_experts and not self.dense_mlp:
-            from .moe import MoEMLP
-
-            expert_ff = cfg.moe_d_ff or cfg.d_ff
-            mlp_out = MoEMLP(
-                d_model=cfg.d_model,
-                d_ff=expert_ff,
-                n_experts=cfg.n_experts,
-                top_k=cfg.moe_top_k,
-                capacity_factor=cfg.capacity_factor,
-                dispatch=cfg.moe_dispatch,
-                scoring=cfg.moe_scoring,
-                select_bias=cfg.moe_select_bias,
-                routed_scale=cfg.moe_routed_scale,
-                experts_held=cfg.experts_held,
-                # parent=None: adopted by the expert layer under the name of
-                # its attribute (moe/shared/...), not by this block
-                shared=(MLP(cfg, d_ff=cfg.n_shared_experts * expert_ff,
-                            parent=None)
-                        if cfg.n_shared_experts else None),
-                aux_loss=cfg.router_aux_weight > 0,
-                dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype,
-                quantize_base=cfg.quantize_base,
-                quant_block=cfg.quant_block,
-                name="moe",
-            )(h, deterministic, layer, stacked_experts)
+            mlp_out = self._expert_layer(h, deterministic, layer, stacked_experts)
         else:
             mlp_out = MLP(cfg, name="mlp")(h, deterministic, adapter_ids)
         x = x + mlp_out
         return x if self.indexer is None else (x, selection)
+
+    def _expert_layer(self, h, deterministic, layer, stacked_experts):
+        from .moe import MoEMLP
+
+        cfg = self.cfg
+        expert_ff = cfg.moe_d_ff or cfg.d_ff
+        # parent=None: adopted by the expert layer under the name of its
+        # attribute (moe/shared/..., moe/fc1_latent_proj/...), not by this
+        # block
+        latent = {} if not cfg.moe_latent else dict(
+            fc1_latent_proj=_proj(cfg, "fc1_latent_proj", cfg.moe_latent,
+                                  parent=None),
+            fc2_latent_proj=_proj(cfg, "fc2_latent_proj", cfg.d_model,
+                                  parent=None))
+        return MoEMLP(
+            d_model=cfg.d_model,
+            d_ff=expert_ff,
+            n_experts=cfg.n_experts,
+            top_k=cfg.moe_top_k,
+            capacity_factor=cfg.capacity_factor,
+            dispatch=cfg.moe_dispatch,
+            scoring=cfg.moe_scoring,
+            select_bias=cfg.moe_select_bias,
+            routed_scale=cfg.moe_routed_scale,
+            experts_held=cfg.experts_held,
+            shared=(MLP(cfg, d_ff=cfg.n_shared_experts * expert_ff,
+                        parent=None)
+                    if cfg.n_shared_experts else None),
+            gated=cfg.mlp_act != "relu2",
+            aux_loss=cfg.router_aux_weight > 0,
+            dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+            quantize_base=cfg.quantize_base,
+            quant_block=cfg.quant_block,
+            name="moe",
+            **latent,
+        )(h, deterministic, layer, stacked_experts)
 
     def _attention_beside_mixer(self, h, positions, segment_ids, deterministic,
                                 decode, adapter_ids):
@@ -1151,6 +1286,10 @@ def make_block_stage_fn(cfg: LlamaConfig):
         raise NotImplementedError(
             "a model with an indexer has no pipeline path: a stage's first "
             "shared layers need the selection of the stage before")
+    if cfg.layer_pattern:
+        raise NotImplementedError(
+            "a pattern model has no pipeline path: a stage's layers are not "
+            "one block's leaves stacked (ROADMAP.md B10)")
     if cfg.ssm_d_inner:
         raise NotImplementedError(
             "a model with a state-space mixer has no pipeline path: "
@@ -1247,6 +1386,39 @@ class _ScanBlock(nn.Module):
         return y, None
 
 
+def _remat(cls, cfg: LlamaConfig, policy, prevent_cse: bool = False):
+    """``cls`` (a layer's module; args 4/5 = deterministic/decode, static)
+    under the configuration's rematerialisation, or as it is."""
+    if not (cfg.remat and policy is not None):
+        return cls
+    return nn.remat(cls, prevent_cse=prevent_cse, static_argnums=(4, 5),
+                    policy=policy)
+
+
+class _ScanUnit(nn.Module):
+    """One repeat of a pattern run's unit inside ``nn.scan``: the unit's
+    layers in turn, ``layer_<j>`` by their place in it, EACH under its own
+    remat — the loop then keeps one input a layer (not one a repeat), and a
+    backward pass holds one layer's intermediates, as a stack of like blocks
+    does.  The kinds' leaves are disjoint and of unlike shapes; each rides the
+    scan's axis under its own layer's name."""
+
+    cfg: LlamaConfig
+    unit: str
+
+    @nn.compact
+    def __call__(self, x, positions, segment_ids, deterministic=True,
+                 decode=False, page_table=None, adapter_ids=None,
+                 layer=None, stacked_experts=None):
+        cfg = self.cfg
+        block_cls = _remat(Block, cfg, remat_policy_fn(cfg.remat_policy))
+        for j, kind in enumerate(self.unit):
+            x = block_cls(cfg, kind=kind, name=f"layer_{j}")(
+                x, positions, segment_ids, deterministic, decode, page_table,
+                adapter_ids, layer, (stacked_experts or {}).get(f"layer_{j}"))
+        return x, None
+
+
 class LlamaForCausalLM(nn.Module):
     cfg: LlamaConfig
 
@@ -1287,12 +1459,12 @@ class LlamaForCausalLM(nn.Module):
         # where the layers keep the setting they were measured with
         qk, v = cfg.head_widths
         residuals = 2 * tokens.size * (cfg.n_heads + cfg.n_kv_heads) * (qk + v)
-        unrolled_cls = (
-            nn.remat(Block, prevent_cse=residuals > 2**30, static_argnums=(4, 5),
-                     policy=policy)
-            if cfg.remat and policy is not None
-            else Block
-        )
+        unrolled_cls = _remat(Block, cfg, policy, prevent_cse=residuals > 2**30)
+        if cfg.layer_pattern:
+            x = self._pattern_layers(
+                x, unrolled_cls, positions, segment_ids, deterministic, decode,
+                page_table, adapter_ids)
+            return self._head(embed, x)
         # the leading dense layers of an expert model are another kind of
         # block: a stack of their own, ``layer_<i>``, before the scanned one
         n_dense = cfg.first_k_dense if cfg.n_experts else 0
@@ -1312,7 +1484,10 @@ class LlamaForCausalLM(nn.Module):
             x = self._scanned_blocks(
                 x, selection, kinds[n_dense:], policy, positions, segment_ids,
                 deterministic, decode, page_table, adapter_ids)
+        return self._head(embed, x)
 
+    def _head(self, embed, x):
+        cfg = self.cfg
         x = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="final_norm")(x)
         if cfg.tie_embeddings:
             logits = x @ embed.embedding.astype(cfg.dtype).T
@@ -1325,6 +1500,45 @@ class LlamaForCausalLM(nn.Module):
             )(x)
         return times(logits.astype(cfg.logits_dtype or jnp.float32),
                      cfg.lm_head_multiplier)
+
+    def _pattern_layers(self, x, unrolled_cls, positions, segment_ids,
+                        deterministic, decode, page_table, adapter_ids):
+        """The layers of a pattern model (``LlamaConfig.pattern_runs``): a
+        run that repeats is ONE scanned stack over its unit's layers
+        (:class:`_ScanUnit`: ``blocks``, a later one ``blocks_<first
+        layer>``), a layer that does not is unrolled (``layer_<i>``) — driven
+        by the pattern string, whatever it is.  An expert layer of a stack is
+        handed the repeat's index and its own stacked kernels whole, as
+        ``_scanned_blocks`` hands them."""
+        cfg = self.cfg
+        if decode:
+            raise NotImplementedError(
+                "a pattern model has no decode path yet: serving it needs a "
+                "state cache and a key/value cache in one manager "
+                "(ROADMAP.md B13); train and evaluate only")
+        args = (positions, segment_ids, deterministic, decode, page_table,
+                adapter_ids)
+        at, stacks = 0, 0
+        for unit, repeats in cfg.pattern_runs():
+            if repeats == 1 or not cfg.scan_layers:
+                for kind in unit * repeats:
+                    x = unrolled_cls(cfg, kind=kind, name=f"layer_{at}")(x, *args)
+                    at += 1
+                continue
+            name = f"blocks_{at}" if stacks else "blocks"
+            stack = nn.scan(
+                _ScanUnit,
+                variable_axes={"params": 0, "lora": 0, "moe_aux": 0,
+                               "moe_stats": 0},
+                split_rngs={"params": True, "dropout": True},
+                in_axes=(nn.broadcast,) * 6 + (0, nn.broadcast),
+                length=repeats,
+            )(cfg, unit, name=name)
+            x, _ = stack(x, *args, jnp.arange(repeats),
+                         self._stacked_experts(name, unit))
+            at += len(unit) * repeats
+            stacks += 1
+        return x
 
     def _scanned_blocks(self, x, selection, kinds, policy, *args):
         """The scanned stack; ``kinds``: its layers' of a model with an
@@ -1342,14 +1556,7 @@ class LlamaForCausalLM(nn.Module):
         always did."""
         cfg = self.cfg
         length = cfg.n_layers - (cfg.first_k_dense if cfg.n_experts else 0)
-        block_cls = _ScanBlock
-        if cfg.remat and policy is not None:
-            block_cls = nn.remat(
-                _ScanBlock,
-                prevent_cse=False,
-                static_argnums=(4, 5),
-                policy=policy,
-            )
+        block_cls = _remat(_ScanBlock, cfg, policy)
         variable_axes = {"params": 0, "lora": 0, "moe_aux": 0,
                          "moe_stats": 0, "cache": 0, "tenants": 0}
         stacked_experts = self._stacked_experts()
@@ -1384,9 +1591,12 @@ class LlamaForCausalLM(nn.Module):
         carry, _ = stack(carry, *args, *more)
         return carry[0] if kinds else carry
 
-    def _stacked_experts(self):
-        """The scanned stack's three expert kernels whole, ``[L, E, ., .]``
-        each, read from this module's own ``params`` — or None where the
+    def _stacked_experts(self, stack: str = "blocks", unit: str = ""):
+        """The scanned stack's expert kernels whole (three of gated experts,
+        two without a gate), ``[L, E, ., .]``
+        each, read from this module's own ``params`` — for a pattern run's
+        ``stack`` of ``unit`` layers a dict of them by expert layer
+        (``layer_<j>``) — or None where the
         expert layer would not take them (``models/moe.py``: the dropless
         layer, its experts or its share of them unquantised), where the experts are
         trained (no adapters: their weight gradient would visit all ``L·E``
@@ -1396,9 +1606,17 @@ class LlamaForCausalLM(nn.Module):
                 and not cfg.quantize_base
                 and cfg.lora.rank > 0) or self.is_initializing():
             return None
-        experts = self.get_variable("params", "blocks")["block"]["moe"]["experts"]
-        return tuple(experts[name]["kernel"]
-                     for name in ("gate_proj", "up_proj", "down_proj"))
+        from .moe import expert_kernel_names
+
+        def kernels(layer: dict) -> tuple:
+            return tuple(layer["moe"]["experts"][name]["kernel"] for name in
+                         expert_kernel_names(cfg.mlp_act != "relu2"))
+
+        leaves = self.get_variable("params", stack)
+        if not cfg.layer_pattern:
+            return kernels(leaves["block"])
+        return {f"layer_{j}": kernels(leaves[f"layer_{j}"])
+                for j, kind in enumerate(unit) if kind == "E"} or None
 
     def init_variables(self, rng: jax.Array, batch: int = 1, seq: int = 8):
         tokens = jnp.zeros((batch, seq), jnp.int32)

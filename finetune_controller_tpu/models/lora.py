@@ -56,6 +56,24 @@ HYBRID_TARGETS = (
 )
 
 
+#: the projections of a pattern model's three layer kinds (``models/llama.py``
+#: ``LlamaConfig.layer_pattern``): attention's four, the mixer's two, the
+#: expert layer's two latent projections and its shared expert's two (routed
+#: experts and the router stay frozen)
+PATTERN_TARGETS = (
+    "q_proj",
+    "k_proj",
+    "v_proj",
+    "o_proj",
+    "in_proj",
+    "out_proj",
+    "fc1_latent_proj",
+    "fc2_latent_proj",
+    "up_proj",
+    "down_proj",
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class LoRAConfig:
     rank: int = 0            # 0 disables LoRA (full fine-tune)

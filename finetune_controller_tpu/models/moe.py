@@ -65,8 +65,17 @@ TPU-native design:
   gathered and multiplied, ``held_row_bound`` rows a pass: one pass where
   the router is balanced, as many as the load asks for where it is not
   (``_dropless_held``);
-- an optional shared expert (a dense SwiGLU every token passes, handed in as
+- an optional shared expert (a dense MLP every token passes, handed in as
   a module so its projections carry LoRA like any other);
+- experts WITHOUT a gate (``gated=False``): ``down(relu(up x)^2)``, two
+  stacked leaves and two grouped products a pass where a SwiGLU expert has
+  three, in all three dispatches (``_experts_of_rows``);
+- experts IN A LATENT (``fc1_latent_proj`` / ``fc2_latent_proj``, modules as
+  the shared expert is): the router scores the layer's input, the rows that
+  are dispatched, gathered, multiplied and combined are ``fc1_latent_proj``'s
+  output, and ``fc2_latent_proj`` brings the combined sum back — applied to a
+  held share's PARTIAL sum, which is what makes the shares of one layer add
+  up to the whole; the shared expert stays on the layer's input;
 - Switch-Transformer load-balancing aux loss, sown into the ``moe_aux``
   collection where the configuration asks for one (the trainer folds it into
   the objective), and five counters sown into ``moe_stats``:
@@ -115,8 +124,18 @@ class _StackedKernel(nn.Module):
             self, "kernel", self.shape, init, self.quant_block, self.dtype)
 
 
+#: the stacked leaves of a gated expert, in the order the kernels are handed
+#: about; an expert without a gate holds the last two
+EXPERT_KERNELS = ("gate_proj", "up_proj", "down_proj")
+
+
+def expert_kernel_names(gated: bool) -> tuple[str, ...]:
+    return EXPERT_KERNELS if gated else EXPERT_KERNELS[1:]
+
+
 class _Experts(nn.Module):
-    """The stacked kernels of the experts this layer holds."""
+    """The stacked kernels of the experts this layer holds: ``(gate, up,
+    down)``, or ``(up, down)`` of experts without a gate."""
 
     n_held: int
     d_model: int
@@ -125,6 +144,7 @@ class _Experts(nn.Module):
     param_dtype: Any
     quantize_base: bool = False
     quant_block: int = 64
+    gated: bool = True
 
     @nn.compact
     def __call__(self):
@@ -132,9 +152,25 @@ class _Experts(nn.Module):
         kw = dict(dtype=self.dtype, param_dtype=self.param_dtype,
                   quantize_base=self.quantize_base,
                   quant_block=self.quant_block)
-        return (_StackedKernel((e, d, f), name="gate_proj", **kw)(),
-                _StackedKernel((e, d, f), name="up_proj", **kw)(),
-                _StackedKernel((e, f, d), name="down_proj", **kw)())
+        return tuple(
+            _StackedKernel((e, f, d) if name == "down_proj" else (e, d, f),
+                           name=name, **kw)()
+            for name in expert_kernel_names(self.gated))
+
+
+def _experts_of_rows(rows, kernels, product):
+    """Every row through its own expert: ``down(silu(gate x) * up x)`` of
+    three kernels, ``down(relu(up x)^2)`` of two (an expert without a gate:
+    two products a pass where a gated one has three).  ``product(lhs,
+    kernel)``: the dispatch's own — grouped over sorted rows, or batched
+    over an expert's slots."""
+    if len(kernels) == 3:
+        w_gate, w_up, w_down = kernels
+        gate = product(rows, w_gate)
+        up = product(rows, w_up)
+        return product(nn.silu(gate) * up, w_down)
+    w_up, w_down = kernels
+    return product(jnp.square(nn.relu(product(rows, w_up))), w_down)
 
 
 class _Router(nn.Module):
@@ -415,8 +451,6 @@ def _held_pass(x, top_w, kernels, layer, order, sizes, start, bound: int):
     past the ``T·k`` pairs with indices no pair has); ``sizes`` are the held
     experts' pairs, whole; ``layer``: as ``_grouped_dot`` takes it."""
     t, k = top_w.shape
-    w_gate, w_up, w_down = kernels
-    dot = functools.partial(_grouped_dot, layer=layer)
     with jax.named_scope("moe_dispatch"):
         mine = sizes.sum()
         sizes = _sizes_in_rows(sizes, start, bound)
@@ -434,9 +468,8 @@ def _held_pass(x, top_w, kernels, layer, order, sizes, start, bound: int):
         # rows behind the last group are covered by no group: the compiler's
         # product writes zeros there, the Pallas one nothing — and nothing
         # reads them (``row_of_pair`` names none)
-        gate = dot(rows, w_gate, sizes)
-        up = dot(rows, w_up, sizes)
-        out_rows = dot(nn.silu(gate) * up, w_down, sizes)
+        out_rows = _experts_of_rows(rows, kernels, functools.partial(
+            _grouped_dot, sizes=sizes, layer=layer))
         out_rows = jnp.where(live[:, None], out_rows, 0)
     with jax.named_scope("moe_combine"):
         return _held_combine(out_rows, top_w, row_of_pair, pair_of_row)
@@ -504,6 +537,17 @@ class MoEMLP(nn.Module):
     #: the shared expert every token passes, or ``None`` (a module, so that
     #: its projections are the model's ordinary LoRA-carrying ones)
     shared: nn.Module | None = None
+    #: experts with a gate matrix (SwiGLU: three products a pass) or without
+    #: (``down(relu(up x)^2)``: two)
+    gated: bool = True
+    #: experts in a latent: the projection into it and the one back out
+    #: (modules, as ``shared`` is, under these names), or ``None`` for experts
+    #: at the router's width.  The router and the shared expert read the
+    #: layer's input; the rows dispatched, multiplied and combined are the
+    #: latent's, and ``fc2_latent_proj`` — linear, so the partial sums of the
+    #: shares of a layer add up — is applied to the combined sum
+    fc1_latent_proj: nn.Module | None = None
+    fc2_latent_proj: nn.Module | None = None
     #: sow the Switch load-balancing term into ``moe_aux``
     aux_loss: bool = True
     dtype: Any = jnp.bfloat16
@@ -551,9 +595,12 @@ class MoEMLP(nn.Module):
             onehot = jax.nn.one_hot(top_idx, e, dtype=jnp.float32)  # (T, k, E)
             load = onehot.sum((0, 1))                               # pairs an expert
 
+        if self.fc1_latent_proj is not None:
+            # the experts' rows: the latent's (no activation, no norm)
+            xt = self.fc1_latent_proj(x, deterministic).reshape(t, -1)
         kernels = _Experts(
-            n_held, d, self.d_ff, self.dtype, self.param_dtype,
-            self.quantize_base, self.quant_block, name="experts")()
+            n_held, xt.shape[1], self.d_ff, self.dtype, self.param_dtype,
+            self.quantize_base, self.quant_block, self.gated, name="experts")()
         # the Pallas kernel is a custom call and takes its operand whole, so
         # the slice the scan hands this body would be copied for it; the
         # compiler's own product fuses that slice and has nothing to save.
@@ -575,6 +622,10 @@ class MoEMLP(nn.Module):
             out, pairs = self._dropless(xt, top_idx, top_w, load, kernels)
         else:
             out, pairs = self._capacity(xt, top_idx, top_w, onehot, kernels)
+        if self.fc2_latent_proj is not None:
+            out = self.fc2_latent_proj(
+                out.astype(self.dtype).reshape(b, s, -1), deterministic
+            ).reshape(t, d)
         if self.shared is not None:
             out = out + self.shared(x, deterministic).reshape(t, d).astype(out.dtype)
 
@@ -595,7 +646,6 @@ class MoEMLP(nn.Module):
         whole leaves and this layer's experts are read in place there."""
         t, d = xt.shape
         k = self.top_k
-        w_gate, w_up, w_down = kernels
         with jax.named_scope("moe_dispatch"):
             order = jnp.argsort(top_idx.reshape(-1), stable=True).astype(jnp.int32)
             inverse = jnp.zeros_like(order).at[order].set(
@@ -603,10 +653,8 @@ class MoEMLP(nn.Module):
             sizes = load.astype(jnp.int32)
             rows = _rows_of_tokens(xt.astype(self.dtype), order, inverse, k)
         with jax.named_scope("experts"):
-            dot = functools.partial(_grouped_dot, layer=layer)
-            gate = dot(rows, w_gate, sizes)
-            up = dot(rows, w_up, sizes)
-            out_rows = dot(nn.silu(gate) * up, w_down, sizes)
+            out_rows = _experts_of_rows(rows, kernels, functools.partial(
+                _grouped_dot, sizes=sizes, layer=layer))
         self._sow_gmm_work(sizes, t * k)
         with jax.named_scope("moe_combine"):
             pair_out = _unsort(out_rows, order, inverse).reshape(t, k, d)
@@ -667,7 +715,6 @@ class MoEMLP(nn.Module):
     def _capacity(self, xt, top_idx, top_w, onehot, kernels):
         t, d = xt.shape
         e, k = self.n_experts, self.top_k
-        w_gate, w_up, w_down = kernels
         compute_dtype = self.dtype
         # static per-expert capacity (tokens), padded to a lane-friendly size
         capacity = max(8, math.ceil(t / e * self.capacity_factor * k))
@@ -708,10 +755,8 @@ class MoEMLP(nn.Module):
 
         # ---- expert compute (batched over the ep axis) ----------------------
         with jax.named_scope("experts"):
-            gate = jnp.einsum("ecd,edf->ecf", expert_in, w_gate)
-            up = jnp.einsum("ecd,edf->ecf", expert_in, w_up)
-            h = nn.silu(gate) * up
-            expert_out = jnp.einsum("ecf,efd->ecd", h, w_down)
+            expert_out = _experts_of_rows(
+                expert_in, kernels, functools.partial(jnp.einsum, "ecd,edf->ecf"))
 
         # combine: per routed pair, gather its slot's output row (invalid
         # pairs hit the zero row — identical to the dense combine, where

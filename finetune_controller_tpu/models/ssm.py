@@ -1,6 +1,8 @@
-"""The Mamba-2 state-space mixer of a hybrid block (``models/llama.py``:
-``Block`` runs it BESIDE attention under one norm), as ``models/moe.py`` is
-to the expert layer.
+"""The Mamba-2 state-space mixer, as ``models/moe.py`` is to the expert
+layer: of a hybrid block (``models/llama.py``: ``Block`` runs it BESIDE
+attention under one norm, with the family's muP multipliers) and, ALONE under
+its own norm, of an ``M`` layer of a pattern model (``Block(kind="M")``: no
+multiplier, so none of the products by one below is traced).
 
 For a row ``u`` of the block's normed input::
 

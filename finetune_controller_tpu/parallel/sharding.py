@@ -124,6 +124,12 @@ LLAMA_RULES = PartitionRules(
         (r"experts/(gate_proj|up_proj)/kernel", P(Ax.EXPERT, Ax.FSDP, Ax.TENSOR)),
         (r"experts/down_proj/kernel", P(Ax.EXPERT, Ax.TENSOR, Ax.FSDP)),
         (r"router/kernel", P(Ax.FSDP, None)),
+        # experts in a latent (models/moe.py): the projection into it feeds
+        # the rows that are dispatched to the experts whole, so its output
+        # stays whole over TP, as the latents of attention do; the projection
+        # back out takes the combined sum whole and splits what it writes
+        (r"fc1_latent_proj/kernel", P(Ax.FSDP, None)),
+        (r"fc2_latent_proj/kernel", P(None, Ax.FSDP)),
         # the selection bias: one number an expert, whole everywhere
         (r"router/bias", P()),
         # QLoRA int4 scales: (in/block, out) — the block dim is tiny, keep it
@@ -174,6 +180,10 @@ LLAMA_RULES = PartitionRules(
         (r"(q_a_proj|kv_a_proj_with_mqa|q_b_proj|kv_b_proj)/lora_a", P(Ax.FSDP, None)),
         (r"(q_a_proj|kv_a_proj_with_mqa)/lora_b", P()),
         (r"(q_b_proj|kv_b_proj)/lora_b", P(None, Ax.TENSOR)),
+        (r"fc1_latent_proj/lora_a", P(Ax.FSDP, None)),
+        (r"fc1_latent_proj/lora_b", P()),
+        (r"fc2_latent_proj/lora_a", P()),
+        (r"fc2_latent_proj/lora_b", P(None, Ax.FSDP)),
         (r"(o_proj|down_proj|out_proj)/lora_a", P(Ax.TENSOR, None)),
         (r"(o_proj|down_proj|out_proj)/lora_b", P(None, Ax.FSDP)),
         # norms, scales, biases — replicated

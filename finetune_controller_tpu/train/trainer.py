@@ -295,13 +295,15 @@ class Trainer:
             self.model = LlamaForCausalLM(model_cfg)
 
         self._pp = self.mesh.shape.get("pp", 1)
-        if getattr(model_cfg, "ssm_d_inner", 0) and (
+        if (getattr(model_cfg, "ssm_d_inner", 0)
+                or getattr(model_cfg, "layer_pattern", "")) and (
                 self._pp > 1 or self.mesh.shape.get("sp", 1) > 1):
             raise ValueError(
-                "a model with a state-space mixer trains with sp = pp = 1: a "
-                "scan over a split sequence needs a state hand-off between "
-                "members, and the pipeline's stage body does not hold the "
-                "mixer (ROADMAP.md B13)")
+                "a model with a state-space mixer, or one that is a pattern "
+                "of layer kinds, trains with sp = pp = 1: a scan over a split "
+                "sequence needs a state hand-off between members, and the "
+                "pipeline's stage body holds neither the mixer nor a stage of "
+                "unlike layers (ROADMAP.md B10, B13)")
         if self._pp > 1:
             from ..parallel.pipeline import validate_pp_mesh
 
@@ -1662,7 +1664,8 @@ class Trainer:
         as JAX reports it, the mesh, which attention implementation the step
         resolves to (with the flash kernels, how much score area they compute
         over what the causal triangle needs), the row tile of a dropless
-        expert model's grouped products where the Pallas kernel runs, a hybrid
+        expert model's grouped products where the Pallas kernel runs, a pattern
+        model's string, layers by kind, latent width and experts held, a hybrid
         model's state-space mixers (layers, chunks a row, state bytes a row),
         which adapted projections carry their adapter inside the base
         product, and the bytes the freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
@@ -1704,10 +1707,22 @@ class Trainer:
         if kinds:
             attrs["dsa_full_layers"] = kinds.count("full")
             attrs["dsa_shared_layers"] = kinds.count("shared")
+        if cfg.layer_pattern:
+            # a pattern model: the string as built, its layers by kind, the
+            # width of the latent its experts live in and the experts held
+            attrs["layer_pattern"] = cfg.layer_pattern
+            attrs["layers_by_kind"] = {
+                kind: cfg.layer_pattern.count(kind)
+                for kind in sorted(set(cfg.layer_pattern))}
+            if "E" in cfg.layer_pattern:
+                attrs["moe_latent_width"] = cfg.moe_latent
+                attrs["moe_experts_held"] = (
+                    cfg.experts_held or (0, cfg.n_experts))[1]
         if cfg.ssm_d_inner:
             # the state-space mixers: how many, the chain of chunk states a
             # row's scan walks in each, and the float32 state a row carries
-            attrs["ssm_layers"] = cfg.n_layers
+            attrs["ssm_layers"] = (cfg.layer_pattern.count("M")
+                                   if cfg.layer_pattern else cfg.n_layers)
             attrs["ssm_chunks_per_row"] = -(-self.cfg.seq_len // cfg.ssm_chunk)
             attrs["ssm_state_bytes_per_row"] = (
                 4 * cfg.ssm_n_heads * cfg.ssm_head_dim * cfg.ssm_d_state)

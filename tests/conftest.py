@@ -77,12 +77,13 @@ def tiny_job_spec(steps: int = 3):
 #: the tests under ``tests/benchmarks/`` that pin a ``BENCHMARK.json`` a later
 #: ``model_config`` PR appended to — three of PR 26's two cells (ISSUE 27),
 #: five of PR 27's three (ISSUE 32), four of PR 32's four (ISSUE 36), four of
-#: PR 36's 34 per-layer entries (ISSUE 38) — and that
+#: PR 36's 34 per-layer entries (ISSUE 38), six of PR 38's five cells
+#: (ISSUE 40) — and that
 #: only a ``benchmark`` PR may
 #: edit (a PR of another kind changes no file the benchmark already has):
 #: ``node id -> (why, the test that holds what it held)``.  The next
-#: ``benchmark`` PR edits the sixteen and deletes this table and the hook under
-#: it (ROADMAP.md, B1)
+#: ``benchmark`` PR edits the twenty-two and deletes this table and the hook
+#: under it (ROADMAP.md, B1)
 SUPERSEDED = {
     "tests/benchmarks/test_benchmark_manifest.py::"
     "test_the_real_manifest_has_its_two_cells_and_no_metric_by_default": (
@@ -160,6 +161,39 @@ SUPERSEDED = {
         ("test_the_superseded_pins_are_twelve_and_each_has_its_replacement",
          "pins this table's length at twelve",
          "test_the_superseded_pins_are_sixteen_and_each_has_its_replacement"),
+    )},
+    # ... and the two of ``test_benchmark_falcon_h1.py`` and four of
+    # ``test_benchmark_startup.py`` that pin PR 38's five-cell manifest, to
+    # which ISSUE 40 appends a sixth cell, a fifth configuration, three
+    # per-layer entries and its cell's name in twenty-two accepted ones
+    **{f"tests/benchmarks/test_benchmark_{file}.py::" + pin: (
+        why, "tests/benchmarks/test_benchmark_nemotron_h.py::" + held_by)
+       for file, pin, why, held_by in (
+        ("falcon_h1", "test_manifest_registers_and_loads_every_accepted_metric",
+         "pins every entry's cells to PR 36's five; ISSUE 40 appends its cell "
+         "to the neutral ones, the dense flash roofline, the loop's plumbing, "
+         "the mixer's two shares and the expert layer's two",
+         "test_manifest_registers_and_loads_every_accepted_metric"),
+        ("falcon_h1", "test_the_new_cell_is_the_one_the_issue_names",
+         "pins the mixer's two shares to the hybrid cell alone; the pattern "
+         "cell reports them too",
+         "test_the_hybrid_cell_is_still_the_one_issue_36_named"),
+        ("startup", "test_manifest_registers_and_loads_every_start_up_metric",
+         "pins the five start-up entries' cells to PR 38's five; the sixth "
+         "cell reports them too",
+         "test_manifest_registers_and_loads_every_start_up_metric"),
+        ("startup",
+         "test_the_real_manifest_has_its_five_cells_and_five_more_metrics_in_each",
+         "pins PR 38's five cells; ISSUE 40 adds a sixth",
+         "test_the_real_manifest_has_its_six_cells_and_no_metric_by_default"),
+        ("startup",
+         "test_the_accepted_entries_stand_first_and_the_start_up_ones_last",
+         "pins the lists' ends; ISSUE 40 appends its entries",
+         "test_the_accepted_entries_stand_first_and_the_new_ones_last"),
+        ("startup",
+         "test_the_superseded_pins_are_sixteen_and_each_has_its_replacement",
+         "pins this table's length at sixteen",
+         "test_the_superseded_pins_are_twenty_two_and_each_has_its_replacement"),
     )},
     "tests/benchmarks/test_benchmark_mla_dsa_moe.py::"
     "test_cells_report_the_neutral_metrics_and_their_own_and_no_count_that_overstates": (

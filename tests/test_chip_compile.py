@@ -90,6 +90,9 @@ FLASH_SHAPES = {
     "d128-b2-s8192": (2, 8192, 32, 8, 128),
     "qk192-v128-b2-s4096": (2, 4096, 32, 32, 192, 128),
     "d128-g5-b1-s8192": (1, 8192, 20, 4, 128),
+    # the pattern cell's one attention layer: 32 query heads over 2 key/value
+    # heads, a group of 16 (the dK/dV kernel's inner sweep is 16 x the q blocks)
+    "d128-g16-b1-s8192": (1, 8192, 32, 2, 128),
 }
 
 
@@ -98,9 +101,11 @@ FLASH_SHAPES = {
     ("d128-b2-s2048", False), ("qk192-v128-b2-s4096", False),
     ("d128-b8-s2048", False), ("d128-b2-s8192", False), ("d128-b2-s8192", True),
     ("d128-g5-b1-s8192", False), ("d128-g5-b1-s8192", True),
+    ("d128-g16-b1-s8192", False), ("d128-g16-b1-s8192", True),
 ], ids=["tinyllama-plain", "tinyllama-segments", "d128-plain", "qk192-v128",
         "mistral-2k-plain", "mistral-8k-plain", "mistral-8k-segments",
-        "hybrid-8k-plain", "hybrid-8k-segments"])
+        "hybrid-8k-plain", "hybrid-8k-segments",
+        "pattern-8k-plain", "pattern-8k-segments"])
 def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
     b, s, h, hkv, d, *rest = FLASH_SHAPES[shape]
     one = SingleDeviceSharding(v5e[0])
@@ -125,10 +130,29 @@ def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
 # ---------------------------------------------------------------------------
 
 
+#: a mixer at its configuration's published widths: the hybrid one (32 heads
+#: of 128 over 128 x 256 states, B and C in 2 groups, a muP multiplier on
+#: every segment) and the pattern one (128 heads of 64 over 64 x 128 states,
+#: B and C in 8 groups, no multiplier: four times the float32 decay matrices
+#: at the same state bytes)
+MIXER_WIDTHS = {
+    "hybrid": dict(
+        d_model=5120, ssm_n_heads=32, ssm_head_dim=128, ssm_d_state=256,
+        ssm_n_groups=2, ssm_in_multiplier=0.25,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738)),
+    "pattern": dict(
+        d_model=4096, ssm_n_heads=128, ssm_head_dim=64, ssm_d_state=128,
+        ssm_n_groups=8),
+}
+
+
+@pytest.mark.parametrize("family", list(MIXER_WIDTHS))
 @pytest.mark.parametrize("segments", [False, True], ids=["plain", "segments"])
-def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(v5e, segments):
-    """One block's mixer of the hybrid configuration — 32 heads of 128 over
-    128 x 256 states, chunks of 128, one row of 8,192 — with adapters on both
+def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(
+        v5e, segments, family):
+    """One layer's mixer at its configuration's published widths, chunks of
+    128, one row of 8,192, with adapters on both
     projections: value and gradients (the scan's transpose among them)
     compile for the chip, the carry across the 64 chunks is a loop of the
     program in BOTH passes, no Mosaic kernel is involved, and what the layer's
@@ -138,12 +162,9 @@ def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(v5e, segmen
     from finetune_controller_tpu.models.ssm import Mamba2Mixer
 
     cfg = LlamaConfig(
-        d_model=5120, dtype=BF16, ssm_n_heads=32,
-        ssm_head_dim=128, ssm_d_state=256, ssm_n_groups=2, ssm_d_conv=4,
-        ssm_chunk=128, ssm_in_multiplier=0.25,
-        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
-                         0.3535533905932738),
+        dtype=BF16, ssm_d_conv=4, ssm_chunk=128, **MIXER_WIDTHS[family],
         lora=LoRAConfig(rank=16, targets=HYBRID_TARGETS))
+    width = cfg.d_model
     mixer = Mamba2Mixer(cfg)
     one = SingleDeviceSharding(v5e[0])
 
@@ -154,13 +175,13 @@ def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(v5e, segmen
                 sharding=one), tree)
 
     variables = jax.eval_shape(lambda: mixer.init(
-        jax.random.PRNGKey(0), jnp.zeros((1, 8, 5120), BF16)))
+        jax.random.PRNGKey(0), jnp.zeros((1, 8, width), BF16)))
     assert sorted(variables["params"]) == [
         "A_log", "D", "conv1d", "dt_bias", "in_proj", "norm", "out_proj"]
     params = on_chip(variables["params"])        # the frozen base, as stored
     lora = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
         s.shape, s.dtype, sharding=one), variables["lora"])
-    u = jax.ShapeDtypeStruct((1, 8192, 5120), BF16, sharding=one)
+    u = jax.ShapeDtypeStruct((1, 8192, width), BF16, sharding=one)
     seg = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one)
 
     def loss(u, lora, params, seg):
@@ -199,12 +220,17 @@ EXPERT_SHAPES = {
     "joyai_4k": (65536, 256, 2048, 768, 4),
     # a pass of ``held_row_bound`` rows over the 16 experts held: 1,024 a group
     "glm_16k_share": (16384, 16, 6144, 2048, 4),
+    # a pass of ``held_row_bound`` rows (180,224 pairs, a quarter held) over
+    # the 128 experts held, in the 1024-wide latent: 352 rows a group under an
+    # even router; contractions of 1024 and 2688 for the first time
+    "nemotron_8k_share": (90112, 128, 1024, 2688, 5),
 }
 #: (rows, contraction, width) the rule gives ``(up, down)`` and, the same two
 #: swapped, their activation gradients
 EXPERT_TILES = {
     "joyai_4k": ((256, 2048, 768), (256, 768, 2048)),
     "glm_16k_share": ((512, 1024, 1024), (512, 1024, 1024)),
+    "nemotron_8k_share": ((512, 1024, 384), (512, 384, 1024)),
 }
 
 
@@ -399,6 +425,59 @@ def test_step_at_the_16k_cells_widths_copies_no_expert_kernel(v5e, monkeypatch):
     assert not _written(text, [(held, d, f), (held, f, d), (2, held, d, f),
                                (2, held, f, d), (2 * held, d, f),
                                (2 * held, f, d)])
+
+
+def test_step_at_the_pattern_cells_widths_copies_no_expert_kernel(v5e, monkeypatch):
+    """The pattern configuration at its published widths and the cell's rows
+    (``nemotron-3-super-lora.train-sft-8k``: 128 experts of 1024 x 2688 held
+    of 512, top-22, 1 x 8,192 tokens), cut to ``EMEM*`` — a scanned pair of an
+    expert layer in a latent and a mixer, twice, then attention without
+    positions at a head group of 16: the step compiles for the chip with the
+    grouped products (two an expert layer and pass), the three flash kernels,
+    and no array shaped like a layer's share of the experts, or like the
+    stack, written anywhere — the expert layer inside the scanned UNIT reads
+    its kernels in place in its own stacked leaf."""
+    from benchmarks.harness.manifest import Manifest
+    from finetune_controller_tpu.models import moe
+    from finetune_controller_tpu.models.llama import LlamaForCausalLM
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    conf = Manifest().config("nemotron-3-super-lora")
+    cfg = Manifest().program(conf).model_config(conf, max_seq_len=8192).replace(
+        n_layers=5, layer_pattern="EMEM*")
+    assert cfg.pattern_runs() == (("EM", 2), ("*", 1))
+    model = LlamaForCausalLM(cfg)
+    one = SingleDeviceSharding(v5e[0])
+    shapes = jax.eval_shape(lambda: model.init(
+        {"params": jax.random.PRNGKey(0)}, jnp.zeros((1, 8), jnp.int32)))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, BF16, sharding=one),
+        shapes["params"])
+    lora = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one),
+        shapes["lora"])
+    assert sorted(shapes["lora"]["blocks"]["layer_0"]["moe"]) == [
+        "fc1_latent_proj", "fc2_latent_proj", "shared"]
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one)
+
+    def loss(lora, params, tokens):
+        logits, sown = model.apply({"params": params, "lora": lora}, tokens,
+                                   mutable=("moe_stats",))
+        return jnp.mean(logits ** 2), moe.moe_counters(sown)
+
+    compiled = jax.jit(jax.value_and_grad(loss, has_aux=True)).lower(
+        lora, params, tokens).compile()
+    text = compiled.as_text()
+    # two grouped products a pass (forward, recomputed, activation gradients)
+    # and flash forward (twice: the unrolled layer is replayed too), dQ, dK/dV
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6 + 3
+    assert "ragged-dot" not in text
+    held, latent, f = cfg.experts_held[1], cfg.moe_latent, cfg.moe_d_ff
+    assert (held, latent, f) == (128, 1024, 2688)
+    assert moe.dropless_row_tile(8192 * 22, held, cfg.n_experts) == 512
+    assert not _written(text, [(held, latent, f), (held, f, latent),
+                               (2, held, latent, f), (2, held, f, latent),
+                               (2 * held, latent, f), (2 * held, f, latent)])
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,7 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 SMOKE = REPO / "chip_smoke.py"
 
-PHASES = ["grouped-parity", "ssd-parity", "lora-parity", "server", "submit", "train", "promote",
+PHASES = ["grouped-parity", "ssd-parity", "latent-share-parity", "lora-parity", "server", "submit", "train", "promote",
           "serve-load", "serve-generate", "shutdown", "paged-parity",
           "compile-cache", "total"]
 
@@ -111,6 +111,15 @@ def test_tiny_rehearsal_runs_every_phase_and_is_not_a_chip_pass():
     assert ssd["compiled"] is False
     assert ssd["worst_err"] <= ssd["tolerance"]
     assert ssd["errs_by_shape"]["44x4x8x2x6x8"]["chunks"] == 6
+    # a held share of a latent expert layer (its grouped path, two products
+    # an expert) against the masked plain form: value and the input's
+    # gradient, and the same pairs on both sides
+    latent = detail["latent-share-parity"]
+    assert latent["compiled"] is False
+    assert latent["worst_err"] <= latent["tolerance"]
+    (share,) = latent["errs_by_shape"].values()
+    assert set(latent["errs_by_shape"]) == {"64x32x16x24x16x4x4"}
+    assert 0 < share["pairs"] <= share["row_bound"] == 64 * 4
     # the joined LoRA product against the layer's old expression, bf16 over
     # an int4 base: value and gradients
     joined = detail["lora-parity"]
@@ -145,8 +154,8 @@ def test_a_failed_phase_exits_nonzero_and_prints_no_result(tmp_path):
     assert "unknown device 'cpu-test'" in out.stderr
     phases = [l for l in out.stdout.splitlines() if l.startswith("phase ")]
     assert [p.split(":")[0] for p in phases] == [
-        "phase grouped-parity", "phase ssd-parity", "phase lora-parity",
-        "phase server"]
+        "phase grouped-parity", "phase ssd-parity", "phase latent-share-parity",
+        "phase lora-parity", "phase server"]
     assert _result_lines(out.stdout) == []
     # and nothing it started is left behind
     leftovers = subprocess.run(
@@ -293,6 +302,27 @@ def test_full_mode_checks_the_chunked_scan_at_the_hybrid_cells_widths(smoke):
                      conf["mamba_chunk_size"]]
     assert shape[0] // shape[-1] == 8 and smoke.SSD_TOL == 2 ** -6
     assert "recurrence" in smoke.SSD_PARITY_SNIPPET and "ssd_chunked" in smoke.SSD_PARITY_SNIPPET
+
+
+def test_full_mode_checks_a_held_share_at_the_pattern_cells_widths(smoke):
+    """(rows, d_model, latent, expert width, experts, held, per token): one
+    expert layer of the pattern configuration as published — top-22 of 512
+    experts of 1024 x 2688 in a 1024-wide latent of a 4096-wide state, the
+    cell's 128 held — on 1,024 rows, the share's grouped path against the
+    masked plain form."""
+    import json as _json
+
+    conf = _json.loads(
+        (REPO / "benchmarks/configs/nemotron-3-super-lora.json").read_text())
+    (shape,) = smoke.mode_config(tiny=False, seed=0)["latent_shapes"]
+    assert shape == [1024, conf["hidden_size"], conf["moe_latent_size"],
+                     conf["moe_intermediate_size"],
+                     conf["published"]["n_routed_experts"],
+                     conf["n_routed_experts"], conf["num_experts_per_tok"]]
+    assert smoke.LATENT_TOL == 2 ** -5
+    for word in ("held_row_bound", "gated=False", "fc1_latent_proj",
+                 "fc2_latent_proj", "one_hot"):
+        assert word in smoke.LATENT_SHARE_PARITY_SNIPPET, word
 
 
 def test_full_mode_checks_the_joined_product_at_a_mistral_projections_width(smoke):

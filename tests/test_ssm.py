@@ -59,12 +59,21 @@ def _token_by_token(x, dt, a, b, c, d, runs=None):
     return (y + xg * d.reshape(g, h // g, 1)).reshape(bsz, s, h, p)
 
 
+#: (heads, head size, groups, state size): the hybrid family's shape in small
+#: (a head as wide as it likes, B and C in 2 groups) and the pattern family's
+#: (many narrow heads, a head size that is NOT the state's, B and C in 8
+#: groups of two heads each)
+HEAD_SHAPES = {"hybrid": dict(h=4, p=8, g=2, n=6), "pattern": dict(h=16, p=4, g=8, n=8)}
+
+
+@pytest.mark.parametrize("shape", list(HEAD_SHAPES))
 @pytest.mark.parametrize("seq", [64, 53, 8, 3], ids=lambda s: f"rows{s}")
-def test_chunked_scan_is_the_recurrence_forward_and_gradients(seq):
+def test_chunked_scan_is_the_recurrence_forward_and_gradients(seq, shape):
     """At decays near 1 the carry between chunks of 8 IS the result: the
     chunked form equals the token-by-token recurrence, values and gradients
-    of every input, whole chunks or a ragged last one."""
-    args = _scan_inputs(seq)
+    of every input, whole chunks or a ragged last one — at both families'
+    head shapes, across up to eight chunks."""
+    args = _scan_inputs(seq, **HEAD_SHAPES[shape])
 
     def loss(fn, *a):
         y = fn(*a)
@@ -81,10 +90,11 @@ def test_chunked_scan_is_the_recurrence_forward_and_gradients(seq):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3 * float(jnp.abs(b).max()))
 
 
-def test_the_carry_between_chunks_weighs_in_this_test():
+@pytest.mark.parametrize("shape", list(HEAD_SHAPES))
+def test_the_carry_between_chunks_weighs_in_this_test(shape):
     """The same inputs with every chunk's entering state dropped (each chunk
     alone) give another result by far: the test above holds the carry."""
-    x, dt, a, b, c, d = _scan_inputs(64)
+    x, dt, a, b, c, d = _scan_inputs(64, **HEAD_SHAPES[shape])
     d = jnp.zeros_like(d)           # the skip is no part of the state
     whole = ssm.ssd_chunked(x, dt, a, b, c, d, chunk=8)
     alone = jnp.concatenate([
