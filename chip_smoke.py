@@ -195,18 +195,21 @@ print(json.dumps({"device": device_report(), "compiled": on_tpu, "cases": cases}
 #: the token-by-token recurrence's result: bf16 operands, float32 sums
 SSD_TOL = 2 ** -6
 
-#: the state-space mixer's chunked scan (``models/ssm.py::ssd_chunked``: bf16
-#: products, float32 decays and states) against the recurrence itself, a token
-#: at a time in float32 (``benchmarks/reference/falcon_h1.py::recurrence``) —
+#: the state-space mixer's chunked scan AS THE MIXER RUNS IT
+#: (``ops/pallas/ssd_scan.py::ssd_scan``, the chooser's one function: the Pallas
+#: kernels on the chip, ``models/ssm.py::ssd_chunked`` off it; bf16 products,
+#: float32 decays and states) against the recurrence itself, a token at a
+#: time in float32 (``benchmarks/reference/falcon_h1.py::recurrence``) —
 #: values and the gradient of the inputs under a fixed cotangent, at the
 #: family's own ranges (``A`` in [1, 16], step sizes log-uniform in [0.001,
 #: 0.1]: decays near 1, so the carry between chunks is most of the result),
-#: rows (rows, heads, head size, groups, state size, chunk)
+#: rows (rows, heads, head size, groups, state size, chunk); each case says
+#: which form ran (``ssm_scan_impl``) and its heads a block
 SSD_PARITY_SNIPPET = r"""
 import json, sys
 import jax, jax.numpy as jnp, numpy as np
 from finetune_controller_tpu.platform import device_report, enable_compile_cache
-from finetune_controller_tpu.models import ssm
+from finetune_controller_tpu.ops.pallas.ssd_scan import ssd_scan, ssd_scan_impl
 from benchmarks.reference.falcon_h1 import recurrence
 
 enable_compile_cache()
@@ -222,7 +225,7 @@ for n_case, (s, h, p, g, n, chunk) in enumerate(json.loads(sys.argv[1])):
     d = jnp.ones((h,), jnp.float32)
 
     def chunked(x, dt, b, c):
-        return ssm.ssd_chunked(x, dt, a, b, c, d, chunk=chunk)
+        return ssd_scan(x, dt, a, b, c, d, chunk=chunk)
 
     def by_token(x, dt, b, c):
         x32 = x.astype(jnp.float32).reshape(1, s, g, h // g, p)
@@ -242,8 +245,10 @@ for n_case, (s, h, p, g, n, chunk) in enumerate(json.loads(sys.argv[1])):
     errs = [float(jnp.max(jnp.abs(u.astype(jnp.float32) - v.astype(jnp.float32)))
                   / jnp.max(jnp.abs(v.astype(jnp.float32))))
             for u, v in zip(got, want)]
+    impl, heads = ssd_scan_impl(h, p, g, n, chunk)
     cases.append({"shape": [s, h, p, g, n, chunk], "value_err": errs[0],
                   "grad_err": max(errs[1:]), "chunks": -(-s // chunk),
+                  "ssm_scan_impl": impl, "ssm_scan_heads_per_block": heads,
                   "finite": all(bool(jnp.all(jnp.isfinite(u.astype(jnp.float32))))
                                 for u in got)})
 print(json.dumps({"device": device_report(),
@@ -466,8 +471,10 @@ def mode_config(tiny: bool, seed: int) -> dict:
             [256, 256, 2048, 768, 1],
         ],
         # one block's scan of the hybrid configuration at its published
-        # widths: 1,024 rows, eight chunks of 128, 32 heads of 128 x 256 states
-        "ssd_shapes": [[1024, 32, 128, 2, 256, 128]],
+        # widths (32 heads of 128 x 256 states, B and C in 2 groups) and of
+        # the pattern one (128 heads of 64 x 128 states, 8 groups): 1,024
+        # rows, eight chunks of 128
+        "ssd_shapes": [[1024, 32, 128, 2, 256, 128], [1024, 128, 64, 8, 128, 128]],
         # one Mistral-width projection (gate / up) over an int4 base, 2,048
         # rows, rank 16: the adapter inside the base product's contraction
         "lora_shapes": [[2048, 4096, 14336, 16, 64, 2.0]],
@@ -916,11 +923,16 @@ def ssd_parity_phase(run_id: str, cfg: dict) -> dict:
     check(worst <= SSD_TOL,
           f"chunked scan off the token-by-token recurrence by {worst} > "
           f"{SSD_TOL} of the largest magnitude: {rec['cases']}")
+    impls = {c["ssm_scan_impl"] for c in rec["cases"]}
+    check(impls == ({"pallas"} if rec["compiled"] else {"xla"}),
+          f"the chooser took {sorted(impls)} for {rec['cases']}")
     say("ssd-parity", time.monotonic() - t0, compiled=rec["compiled"],
-        tolerance=SSD_TOL, worst_err=worst,
+        tolerance=SSD_TOL, worst_err=worst, ssm_scan_impl=impls.pop(),
         errs_by_shape={"x".join(map(str, c["shape"])):
                        {"value": c["value_err"], "grad": c["grad_err"],
-                        "chunks": c["chunks"]} for c in rec["cases"]})
+                        "chunks": c["chunks"],
+                        "heads_per_block": c["ssm_scan_heads_per_block"]}
+                       for c in rec["cases"]})
     return rec["device"]
 
 

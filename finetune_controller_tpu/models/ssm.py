@@ -17,15 +17,21 @@ For a row ``u`` of the block's normed input::
 ``mu`` is the family's per-segment muP vector (one multiplier each for z, x,
 B, C, dt); head ``i`` reads group ``i // (H / G)`` of B and C.
 
-**The recurrence runs in its chunked (SSD) form** (:func:`ssd_chunked`): within
-a chunk of ``Q`` rows the masked product ``(L o C B^T)(delta x)`` with ``L[t,
-s] = exp(sum_{s < r <= t} delta_r A)``, across chunks the ``P x N`` states, each
-carried by its chunk's total decay through a ``lax.scan`` over the chunks.
-Decays, cumulative sums and the carried states are float32; the products take
-their operands in the compute type and accumulate in float32, as every
-projection does.  A masked exponent is set to ``-inf`` BEFORE ``exp`` (a pair
-``s > t`` has a positive exponent that may overflow: zeroing it afterwards
-would give ``inf * 0``).
+**The recurrence runs in its chunked (SSD) form**: within a chunk of ``Q``
+rows the masked product ``(L o C B^T)(delta x)`` with ``L[t, s] = exp(sum_{s <
+r <= t} delta_r A)``, across chunks the ``P x N`` states, each carried by its
+chunk's total decay.  Decays, cumulative sums and the carried states are
+float32; the products take their operands in the compute type and accumulate
+in float32, as every projection does.  A masked exponent is set to ``-inf``
+BEFORE ``exp`` (a pair ``s > t`` has a positive exponent that may overflow:
+zeroing it afterwards would give ``inf * 0``).  **Where it runs**: the mixer
+calls ``ops/pallas/ssd_scan.py::ssd_scan``, which chooses — on a TPU, with no
+mesh of several devices and shapes that tile, two Pallas kernels (one walks a
+row's chunks with the states in VMEM scratch, one walks them in reverse for
+the backward pass: no decay, score or chunk state reaches HBM); everywhere
+else :func:`ssd_chunked` below, the same arithmetic in plain ``jnp`` with a
+``lax.scan`` over the chunks, which is also what the tests hold the kernels
+to.
 
 **Packed rows restart.**  ``segment_ids`` are read as runs: at a change of id
 a new document begins, the decay into it is zero (no state crosses) and the
@@ -56,6 +62,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas.ssd_scan import ssd_scan
 from .llama import _Leaves, _proj, times
 
 
@@ -254,8 +261,8 @@ class Mamba2Mixer(nn.Module):
             skip = leaves("D", scale=((h,), nn.initializers.ones_init()))["scale"]
             delta = jax.nn.softplus(
                 dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
-            y = ssd_chunked(x, delta, -jnp.exp(a_log.astype(jnp.float32)), b, c,
-                            skip, runs, chunk=cfg.ssm_chunk)
+            y = ssd_scan(x, delta, -jnp.exp(a_log.astype(jnp.float32)), b, c,
+                         skip, runs, chunk=cfg.ssm_chunk)
 
         with jax.named_scope("ssm_gate_norm"):
             scale = leaves("norm", scale=((inner,), nn.initializers.ones_init()))["scale"]

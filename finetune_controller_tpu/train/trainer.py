@@ -1719,6 +1719,9 @@ class Trainer:
                 attrs["moe_experts_held"] = (
                     cfg.experts_held or (0, cfg.n_experts))[1]
         if cfg.ssm_d_inner:
+            from ..ops.pallas.ssd_scan import ssd_scan_impl
+            from ..parallel.ring import ring_mesh
+
             # the state-space mixers: how many, the chain of chunk states a
             # row's scan walks in each, and the float32 state a row carries
             attrs["ssm_layers"] = (cfg.layer_pattern.count("M")
@@ -1726,6 +1729,14 @@ class Trainer:
             attrs["ssm_chunks_per_row"] = -(-self.cfg.seq_len // cfg.ssm_chunk)
             attrs["ssm_state_bytes_per_row"] = (
                 4 * cfg.ssm_n_heads * cfg.ssm_head_dim * cfg.ssm_d_state)
+            # the form the recurrence runs in (the Pallas kernels | the plain
+            # ``jnp`` one) and the heads a step of the kernels' grid holds:
+            # asked as the step's trace asks, under its mesh
+            with ring_mesh(self.mesh):
+                attrs["ssm_scan_impl"], attrs["ssm_scan_heads_per_block"] = (
+                    ssd_scan_impl(cfg.ssm_n_heads, cfg.ssm_head_dim,
+                                  cfg.ssm_n_groups, cfg.ssm_d_state,
+                                  cfg.ssm_chunk))
         return attrs
 
     def _lora_joined_projections(self) -> dict:

@@ -126,15 +126,16 @@ def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
 
 
 # ---------------------------------------------------------------------------
-# the state-space mixer (models/ssm.py): plain jnp the compiler must take
+# the state-space mixer (models/ssm.py): its recurrence is two Mosaic kernels
+# (ops/pallas/ssd_scan.py), the rest plain jnp the compiler must take
 # ---------------------------------------------------------------------------
 
 
 #: a mixer at its configuration's published widths: the hybrid one (32 heads
 #: of 128 over 128 x 256 states, B and C in 2 groups, a muP multiplier on
 #: every segment) and the pattern one (128 heads of 64 over 64 x 128 states,
-#: B and C in 8 groups, no multiplier: four times the float32 decay matrices
-#: at the same state bytes)
+#: B and C in 8 groups, no multiplier: four times the heads at the same state
+#: bytes, two heads a 128-lane tile)
 MIXER_WIDTHS = {
     "hybrid": dict(
         d_model=5120, ssm_n_heads=32, ssm_head_dim=128, ssm_d_state=256,
@@ -149,18 +150,64 @@ MIXER_WIDTHS = {
 
 @pytest.mark.parametrize("family", list(MIXER_WIDTHS))
 @pytest.mark.parametrize("segments", [False, True], ids=["plain", "segments"])
-def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(
+def test_scan_kernels_compile_for_v5e_at_published_shapes_forward_and_grad(
         v5e, segments, family):
+    """The chunked scan's kernels at the two published head shapes, one row
+    of 8,192 in chunks of 128, the block the chooser's rule gives (a group's
+    16 heads): the plain call is ONE kernel and writes no entering state;
+    value and the gradients of all six inputs are TWO (the forward that keeps
+    the bf16 entering states, the reverse walk), with and without document
+    boundaries."""
+    from finetune_controller_tpu.ops.pallas import ssd_scan
+
+    w = MIXER_WIDTHS[family]
+    h, p = w["ssm_n_heads"], w["ssm_head_dim"]
+    g, n = w["ssm_n_groups"], w["ssm_d_state"]
+    heads = ssd_scan.heads_per_block(h, p, g, n, 128)
+    assert heads == 16
+    one = SingleDeviceSharding(v5e[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    args = (on_chip((1, 8192, h, p), BF16), on_chip((1, 8192, h), jnp.float32),
+            on_chip((h,), jnp.float32), on_chip((1, 8192, g, n), BF16),
+            on_chip((1, 8192, g, n), BF16), on_chip((h,), jnp.float32),
+            on_chip((1, 8192), jnp.int32))
+
+    def scan(x, dt, a, b, c, d, runs):
+        return ssd_scan.ssd_scan_pallas(
+            x, dt, a, b, c, d, runs if segments else None, chunk=128,
+            heads_per_block=heads, interpret=False)
+
+    forward = jax.jit(scan).lower(*args).compile()
+    assert _custom_calls(forward) == 1
+    assert not _written(forward.as_text(), [(1, 64, n, h * p)])
+    compiled = jax.jit(jax.grad(
+        lambda *t: jnp.sum(scan(*t) ** 2), argnums=(0, 1, 2, 3, 4, 5))).lower(
+            *args).compile()
+    assert _custom_calls(compiled) == 2
+    # no float32 decay, score or chunk state of the jnp form is left: what the
+    # backward holds is y's cotangent, the entering states and the outputs
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
+
+
+@pytest.mark.parametrize("family", list(MIXER_WIDTHS))
+@pytest.mark.parametrize("segments", [False, True], ids=["plain", "segments"])
+def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(
+        v5e, segments, family, monkeypatch):
     """One layer's mixer at its configuration's published widths, chunks of
-    128, one row of 8,192, with adapters on both
-    projections: value and gradients (the scan's transpose among them)
-    compile for the chip, the carry across the 64 chunks is a loop of the
-    program in BOTH passes, no Mosaic kernel is involved, and what the layer's
+    128, one row of 8,192, with adapters on both projections, as the chip
+    traces it (``jax.default_backend`` answers ``tpu`` here, so the chooser
+    takes the kernels): value and gradients compile for the chip, the
+    recurrence is the scan's two Mosaic kernels — the carry across the 64
+    chunks is no loop of the program in either pass — and what the layer's
     backward pass holds stays under the 6 GB the step has for a block."""
     from finetune_controller_tpu.models.llama import LlamaConfig
     from finetune_controller_tpu.models.lora import HYBRID_TARGETS, LoRAConfig
     from finetune_controller_tpu.models.ssm import Mamba2Mixer
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = LlamaConfig(
         dtype=BF16, ssm_d_conv=4, ssm_chunk=128, **MIXER_WIDTHS[family],
         lora=LoRAConfig(rank=16, targets=HYBRID_TARGETS))
@@ -192,9 +239,9 @@ def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(
     compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
         u, lora, params, seg).compile()
     text = compiled.as_text()
-    assert _custom_calls(compiled) == 0
-    loops = re.findall(r"\bwhile\(", text)
-    assert len(loops) >= 2, "the carry's scan and its transpose are loops"
+    assert _custom_calls(compiled) == 2
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    assert not re.findall(r"\bwhile\(", text), "the carry is the kernels' grid"
     assert compiled.memory_analysis().temp_size_in_bytes < 6e9
 
 

@@ -111,6 +111,9 @@ def test_tiny_rehearsal_runs_every_phase_and_is_not_a_chip_pass():
     assert ssd["compiled"] is False
     assert ssd["worst_err"] <= ssd["tolerance"]
     assert ssd["errs_by_shape"]["44x4x8x2x6x8"]["chunks"] == 6
+    # off the chip the chooser keeps the plain form, and says so
+    assert ssd["ssm_scan_impl"] == "xla"
+    assert ssd["errs_by_shape"]["44x4x8x2x6x8"]["heads_per_block"] == 0
     # a held share of a latent expert layer (its grouped path, two products
     # an expert) against the masked plain form: value and the input's
     # gradient, and the same pairs on both sides
@@ -288,20 +291,30 @@ def test_full_mode_checks_the_grouped_product_at_both_expert_cells_shapes(smoke)
     assert all(m % 128 == 0 for m, *_ in shapes)     # the Pallas kernel's rows
 
 
-def test_full_mode_checks_the_chunked_scan_at_the_hybrid_cells_widths(smoke):
+def test_full_mode_checks_the_chunked_scan_at_both_mixer_cells_widths(smoke):
     """(rows, heads, head size, groups, state size, chunk): one block's scan of
     the hybrid configuration as published — 32 heads of 128 over 128 x 256
-    states, B and C in 2 groups, chunks of 128 — on 1,024 rows, eight chunks,
-    within a tolerance that bf16 products allow and a dropped carry does not."""
+    states, B and C in 2 groups, chunks of 128 — and of the pattern one — 128
+    heads of 64 over 64 x 128 states, 8 groups — on 1,024 rows, eight chunks,
+    within a tolerance that bf16 products allow and a dropped carry does not;
+    the snippet runs what the MIXER runs (the chooser's function: the kernels
+    on the chip) and says which form that was."""
     import json as _json
 
-    conf = _json.loads((REPO / "benchmarks/configs/falcon-h1-34b-lora.json").read_text())
-    (shape,) = smoke.mode_config(tiny=False, seed=0)["ssd_shapes"]
-    assert shape == [1024, conf["mamba_n_heads"], conf["mamba_d_head"],
-                     conf["mamba_n_groups"], conf["mamba_d_state"],
-                     conf["mamba_chunk_size"]]
-    assert shape[0] // shape[-1] == 8 and smoke.SSD_TOL == 2 ** -6
-    assert "recurrence" in smoke.SSD_PARITY_SNIPPET and "ssd_chunked" in smoke.SSD_PARITY_SNIPPET
+    published = {   # heads, head size, groups, state size, chunk: each file's keys
+        "falcon-h1-34b-lora": ("mamba_n_heads", "mamba_d_head", "mamba_n_groups",
+                               "mamba_d_state", "mamba_chunk_size"),
+        "nemotron-3-super-lora": ("mamba_num_heads", "mamba_head_dim", "n_groups",
+                                  "ssm_state_size", "chunk_size")}
+    shapes = smoke.mode_config(tiny=False, seed=0)["ssd_shapes"]
+    for shape, (name, keys) in zip(shapes, published.items(), strict=True):
+        conf = _json.loads((REPO / f"benchmarks/configs/{name}.json").read_text())
+        assert shape == [1024, *(conf[k] for k in keys)]
+        assert shape[0] // shape[-1] == 8
+    assert smoke.SSD_TOL == 2 ** -6
+    snippet = smoke.SSD_PARITY_SNIPPET
+    assert "recurrence" in snippet and "ssd_scan(x, dt, a, b, c, d" in snippet
+    assert "ssd_chunked" not in snippet and "ssm_scan_impl" in snippet
 
 
 def test_full_mode_checks_a_held_share_at_the_pattern_cells_widths(smoke):

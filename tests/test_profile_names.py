@@ -140,12 +140,18 @@ def test_dequant_scope_runs_forward_and_under_remat_only(op_names, proj, which):
                    for n in op_names)
 
 
+#: the head is built in a method the model calls on itself (``_head``, as
+#: ``_scanned_blocks`` above): flax names the call in the stack, and a program
+#: served from a compile cache written before the method existed lacks it
+HEAD = r"(?:LlamaForCausalLM\._head/)?"
+
+
 @pytest.mark.parametrize("pattern", [
     r"^jit\(_train_step\)/grad_accum/while/body/closed_call/jvp\(loss\)/",
     r"^jit\(_train_step\)/grad_accum/while/body/closed_call/transpose\(jvp\(loss\)\)/",
-    r"^jit\(_train_step\)/grad_accum/while/body/closed_call/jvp\(LlamaForCausalLM\)/final_norm/",
-    r"^jit\(_train_step\)/grad_accum/while/body/closed_call/jvp\(LlamaForCausalLM\)/lm_head/base_matmul/",
-    r"^jit\(_train_step\)/grad_accum/while/body/closed_call/transpose\(jvp\(LlamaForCausalLM\)\)/lm_head/base_matmul/",
+    r"^jit\(_train_step\)/grad_accum/while/body/closed_call/jvp\(LlamaForCausalLM\)/" + HEAD + "final_norm/",
+    r"^jit\(_train_step\)/grad_accum/while/body/closed_call/jvp\(LlamaForCausalLM\)/" + HEAD + "lm_head/base_matmul/",
+    r"^jit\(_train_step\)/grad_accum/while/body/closed_call/transpose\(jvp\(LlamaForCausalLM\)\)/" + HEAD + "lm_head/base_matmul/",
     r"^jit\(_train_step\)/grad_accum/while/body/closed_call/jvp\(LlamaForCausalLM\)/embed_tokens/",
     r"^jit\(_train_step\)/optimizer/",
     r"^jit\(_train_step\)/optimizer/jit\(clip\)/",

@@ -20,6 +20,7 @@ from benchmarks.reference.falcon_h1 import recurrence  # noqa: E402
 from finetune_controller_tpu.models import llama, ssm  # noqa: E402
 from finetune_controller_tpu.models.llama import PRESETS, LlamaForCausalLM  # noqa: E402
 from finetune_controller_tpu.models.lora import HYBRID_TARGETS, LoRAConfig  # noqa: E402
+from finetune_controller_tpu.ops.pallas import ssd_scan  # noqa: E402
 from finetune_controller_tpu.train.losses import next_token_loss  # noqa: E402
 
 TINY = PRESETS["tiny-falcon-h1-test"].replace(
@@ -31,7 +32,7 @@ MULTIPLIERS = ("embedding_multiplier", "lm_head_multiplier",
                "mlp_multipliers[0]", "mlp_multipliers[1]")
 
 
-def _scan_inputs(seq, seed=0, bsz=2, h=4, p=8, g=2, n=6):
+def _scan_inputs(seq, seed=0, bsz=2, h=4, p=8, g=2, n=6, steps=(1e-3, 1e-1)):
     """Inputs at the family's initialisation: ``A`` from [1, 16], step sizes
     log-uniform in [0.001, 0.1] — a row decays by 0.2 to 0.999, so a state
     crosses MANY chunks of 8."""
@@ -39,7 +40,7 @@ def _scan_inputs(seq, seed=0, bsz=2, h=4, p=8, g=2, n=6):
     x = rng.normal(size=(bsz, seq, h, p)).astype(np.float32)
     b = rng.normal(size=(bsz, seq, g, n)).astype(np.float32)
     c = rng.normal(size=(bsz, seq, g, n)).astype(np.float32)
-    dt = np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), (bsz, seq, h))).astype(np.float32)
+    dt = np.exp(rng.uniform(*np.log(steps), (bsz, seq, h))).astype(np.float32)
     a = -rng.uniform(1.0, 16.0, (h,)).astype(np.float32)
     d = rng.normal(size=(h,)).astype(np.float32)
     return tuple(jnp.asarray(t) for t in (x, dt, a, b, c, d))
@@ -172,6 +173,246 @@ def test_convolution_is_causal_and_sees_zeros_before_a_document():
                                rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(packed[:, 5:], ssm.causal_conv(x[:, 5:], w, bias),
                                rtol=1e-5, atol=1e-6)
+
+
+# ---- the Pallas kernels (ops/pallas/ssd_scan.py), interpreter mode -------------
+
+#: both families' head shapes cut small where a block of the kernels still
+#: tiles: a group's 16 heads kept, (heads, head size, groups, state size)
+KERNEL_SHAPES = {"hybrid": dict(h=16, p=128, g=1, n=256),
+                 "pattern": dict(h=32, p=64, g=2, n=128)}
+#: a chunk of the kernels is 128 rows, sixteen of the chunks above: step
+#: sizes a tenth of the family's keep a state alive across as many CHUNKS
+KERNEL_STEPS = (1e-4, 1e-2)
+ALL_SIX = (0, 1, 2, 3, 4, 5)
+
+
+def _kernel(*args, runs=None):
+    return ssd_scan.ssd_scan_pallas(
+        *args, runs, chunk=128, heads_per_block=16, interpret=True)
+
+
+def _weighed(fn, *args):
+    y = fn(*args)
+    return (y * jnp.cos(jnp.arange(y.size).reshape(y.shape))).sum(), y
+
+
+def _value_and_grads(fn, args):
+    (_, y), grads = jax.jit(jax.value_and_grad(
+        lambda *a: _weighed(fn, *a), argnums=ALL_SIX, has_aux=True))(*args)
+    return y, grads
+
+
+def _assert_close(got, want, rel):
+    """Within ``rel`` of the wanted array's largest magnitude."""
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=rel * float(jnp.abs(want).max()))
+
+
+#: documents packed into rows of 512 = four chunks of 128: a boundary inside
+#: a chunk, one at a chunk's edge, a whole chunk (rows 256..383) of another
+#: document than its neighbours, a document of one row
+KERNEL_RUNS = np.asarray([[0] * 70 + [1] * 58 + [2] * 128 + [3] * 128 + [4] * 128,
+                          [0] * 300 + [1] * 1 + [2] * 211])
+
+
+@pytest.mark.parametrize("shape", list(KERNEL_SHAPES))
+@pytest.mark.parametrize("seq", [512, 300, 128, 3], ids=lambda s: f"rows{s}")
+def test_scan_kernel_is_the_chunked_form_and_the_recurrence(seq, shape):
+    """The kernels in interpreter mode against ``ssd_chunked`` AND the
+    token-by-token recurrence: values and the gradients of all six inputs, at
+    decays near 1 across up to four chunks, whole chunks or a ragged last
+    one, one chunk, a row shorter than a chunk."""
+    args = _scan_inputs(seq, bsz=1, steps=KERNEL_STEPS, **KERNEL_SHAPES[shape])
+    got, g_got = _value_and_grads(_kernel, args)
+    form, g_form = _value_and_grads(
+        lambda *a: ssm.ssd_chunked(*a, chunk=128), args)
+    want, g_want = _value_and_grads(_token_by_token, args)
+    _assert_close(got, form, 1e-5)
+    _assert_close(got, want, 2e-4)
+    for mine, theirs, exact in zip(g_got, g_form, g_want):
+        _assert_close(mine, theirs, 2e-5)
+        _assert_close(mine, exact, 2e-3)
+
+
+@pytest.mark.parametrize("shape", list(KERNEL_SHAPES))
+def test_the_carry_between_chunks_weighs_in_the_kernels_test(shape):
+    """At the kernel tests' step sizes every chunk of 128 alone gives another
+    result by far: the tests above hold the state the kernel carries in VMEM."""
+    x, dt, a, b, c, d = _scan_inputs(512, bsz=1, steps=KERNEL_STEPS,
+                                     **KERNEL_SHAPES[shape])
+    d = jnp.zeros_like(d)
+    whole = _kernel(x, dt, a, b, c, d)
+    alone = jnp.concatenate([
+        ssm.ssd_chunked(*(t[:, i:i + 128] for t in (x, dt)), a,
+                        *(t[:, i:i + 128] for t in (b, c)), d, chunk=128)
+        for i in range(0, 512, 128)], axis=1)
+    gap = jnp.abs(whole - alone)[:, 128:].mean() / jnp.abs(whole).mean()
+    assert gap > 0.2, float(gap)
+
+
+@pytest.mark.parametrize("shape", list(KERNEL_SHAPES))
+def test_scan_kernel_restarts_at_document_boundaries(shape):
+    """``runs`` inside the kernels: a boundary inside a chunk, at a chunk's
+    edge, a whole chunk of another document and a document of one row zero
+    what ``ssd_chunked`` zeroes — values and all six gradients equal its own
+    and the recurrence's with the state zeroed at each boundary."""
+    args = _scan_inputs(512, seed=3, bsz=2, steps=KERNEL_STEPS,
+                        **KERNEL_SHAPES[shape])
+    runs = jnp.asarray(KERNEL_RUNS)
+    got, g_got = _value_and_grads(
+        lambda *a: _kernel(*a, runs=runs), args)
+    form, g_form = _value_and_grads(
+        lambda *a: ssm.ssd_chunked(*a, runs, chunk=128), args)
+    want, g_want = _value_and_grads(
+        lambda *a: _token_by_token(*a, runs), args)
+    _assert_close(got, form, 1e-5)
+    _assert_close(got, want, 2e-4)
+    for mine, theirs, exact in zip(g_got, g_form, g_want):
+        _assert_close(mine, theirs, 2e-5)
+        _assert_close(mine, exact, 2e-3)
+    # and without the runs the documents DO leak into each other
+    assert float(jnp.abs(_kernel(*args) - got).max()) > 1e-2
+
+
+@pytest.mark.parametrize("shape", list(KERNEL_SHAPES))
+def test_scan_kernel_rounds_where_the_chunked_form_rounds(shape):
+    """bf16 inputs: the kernels' products take the operands ``ssd_chunked``
+    rounds, so the two agree far inside what bf16 costs either against the
+    float32 recurrence — values and the gradients of all six inputs."""
+    x, dt, a, b, c, d = _scan_inputs(384, seed=5, bsz=1, steps=KERNEL_STEPS,
+                                     **KERNEL_SHAPES[shape])
+    args = (x.astype(jnp.bfloat16), dt, a, b.astype(jnp.bfloat16),
+            c.astype(jnp.bfloat16), d)
+    got, g_got = _value_and_grads(_kernel, args)
+    form, g_form = _value_and_grads(
+        lambda *a: ssm.ssd_chunked(*a, chunk=128), args)
+    assert got.dtype == form.dtype == jnp.float32
+    assert [g.dtype for g in g_got] == [g.dtype for g in g_form]
+    _assert_close(got, form, 2e-3)
+    for mine, theirs in zip(g_got, g_form):
+        _assert_close(mine.astype(jnp.float32), theirs.astype(jnp.float32), 2e-2)
+
+
+def test_scan_kernel_large_steps_never_overflow():
+    """Exponents up to 128 * 50 * 16 for a pair ``s > t``: excluded before
+    ``exp`` in the forward kernel and the reverse one, with and without
+    document boundaries."""
+    x, dt, a, b, c, d = _scan_inputs(256, seed=2, bsz=1, **KERNEL_SHAPES["pattern"])
+    dt = dt * 500.0
+    for runs in (None, jnp.asarray(KERNEL_RUNS[:1, :256])):
+        value, grads = jax.value_and_grad(
+            lambda x, dt: _kernel(x, dt, a, b, c, d, runs=runs).sum(),
+            argnums=(0, 1))(x, dt)
+        assert np.isfinite(value) and all(np.isfinite(g).all() for g in grads)
+
+
+def test_scan_kernel_sums_a_groups_blocks_of_heads():
+    """Eight heads a block where a group has sixteen: the group's two blocks
+    each make the scores, and their cotangents for B and C add up."""
+    args = _scan_inputs(256, seed=6, bsz=1, steps=KERNEL_STEPS,
+                        **KERNEL_SHAPES["pattern"])
+
+    def blocks_of(heads):
+        return lambda *a: ssd_scan.ssd_scan_pallas(
+            *a, chunk=128, heads_per_block=heads, interpret=True)
+
+    got, g_got = _value_and_grads(blocks_of(8), args)
+    want, g_want = _value_and_grads(blocks_of(16), args)
+    _assert_close(got, want, 1e-6)
+    for mine, theirs in zip(g_got, g_want):
+        _assert_close(mine, theirs, 1e-5)
+
+
+def test_a_kernels_call_is_built_once_and_its_body_traced_with_room():
+    """A scanned, rematerialised stack traces its mixer seven times a step:
+    the kernels' calls are built once for their shapes, so Pallas traces each
+    body once; and the body is traced from a frame larger than one of
+    CPython's 16 KiB chunks of frames (``call_with_room``: a kernel's body at
+    a chunk's end maps and unmaps a chunk at every call it makes)."""
+    from finetune_controller_tpu.ops.pallas import call_with_room
+
+    args = _scan_inputs(256, seed=7, bsz=1, **KERNEL_SHAPES["hybrid"])
+    jax.make_jaxpr(jax.grad(lambda *a: _kernel(*a).sum()))(*args)
+    built = [f.cache_info().misses
+             for f in (ssd_scan._forward_call, ssd_scan._backward_call)]
+    jax.make_jaxpr(jax.grad(lambda *a: 2 * _kernel(*a).sum()))(*args)
+    assert built == [f.cache_info().misses
+                     for f in (ssd_scan._forward_call, ssd_scan._backward_call)]
+    assert call_with_room(lambda a, b=1: a - b, 5, b=2) == 3
+    assert 8 * call_with_room.__code__.co_stacksize >= 2 * 16 * 1024
+
+
+#: (heads, head size, groups, state size, chunk) -> heads a block, or 0
+@pytest.mark.parametrize("dims,heads", [
+    ((128, 64, 8, 128, 128), 16),      # the pattern family as published
+    ((32, 128, 2, 256, 128), 16),      # the hybrid family as published
+    ((32, 64, 2, 128, 128), 16), ((16, 128, 1, 256, 256), 16),
+    ((4, 8, 2, 6, 8), 0),              # the tiny presets: nothing tiles
+    ((32, 128, 2, 256, 64), 0),        # a chunk that is no whole lane tile
+    ((32, 128, 2, 192, 128), 0),       # a state that is none
+    ((24, 64, 8, 128, 128), 0),        # three heads a group: no whole sublanes
+    ((64, 128, 2, 1024, 128), 8),      # a group's 32 x 128 x 1024 states: a quarter of it
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_heads_per_block_is_a_group_where_it_tiles_and_fits(dims, heads):
+    assert ssd_scan.heads_per_block(*dims) == heads
+
+
+def test_chooser_takes_the_kernels_on_a_tpu_without_a_mesh_only(devices8):
+    """``ssd_scan_impl``'s table: the CPU -> ``xla``; a described TPU with
+    no mesh or a one-device mesh -> ``pallas`` and a group's heads; a ``tp``
+    or ``fsdp`` mesh of several devices -> ``xla`` (a Mosaic call cannot be
+    partitioned); shapes that do not tile -> ``xla`` wherever."""
+    from finetune_controller_tpu.parallel.mesh import MeshSpec
+    from finetune_controller_tpu.parallel.ring import ring_mesh
+
+    pattern, hybrid = (128, 64, 8, 128, 128), (32, 128, 2, 256, 128)
+    assert ssd_scan.ssd_scan_impl(*pattern) == ("xla", 0)          # here: the CPU
+    assert ssd_scan.ssd_scan_impl(*pattern, backend="tpu") == ("pallas", 16)
+    assert ssd_scan.ssd_scan_impl(*hybrid, backend="tpu") == ("pallas", 16)
+    assert ssd_scan.ssd_scan_impl(4, 8, 2, 6, 8, backend="tpu") == ("xla", 0)
+    with ring_mesh(MeshSpec(fsdp=1).build(devices8[:1])):
+        assert ssd_scan.ssd_scan_impl(*hybrid, backend="tpu") == ("pallas", 16)
+    for spec, n_devices in ((MeshSpec(tp=2), 2), (MeshSpec(fsdp=2, tp=2), 4)):
+        with ring_mesh(spec.build(devices8[:n_devices])):
+            assert ssd_scan.ssd_scan_impl(*hybrid, backend="tpu") == ("xla", 0)
+
+
+def test_mixer_on_the_kernels_is_the_mixer_on_the_chunked_form(monkeypatch):
+    """``Mamba2Mixer`` calls the chooser's ONE function: made to choose the
+    kernels (interpreted here), a mixer whose shapes tile gives the output and
+    the gradients — input, adapters, and the frozen ``A_log``, ``dt_bias``
+    and ``D`` a full fine-tune trains — that it gives on ``ssd_chunked``,
+    packed documents included."""
+    cfg = TINY.replace(d_model=64, ssm_n_heads=16, ssm_head_dim=8,
+                       ssm_d_state=128, ssm_n_groups=1, ssm_chunk=128)
+    mixer = ssm.Mamba2Mixer(cfg)
+    u = jax.random.normal(jax.random.PRNGKey(1), (2, 200, 64))
+    seg = jnp.asarray(np.repeat([[1] * 90 + [2] * 110], 2, axis=0))
+    variables = mixer.init(jax.random.PRNGKey(0), u)
+    variables = {**variables, "lora": jax.tree.map(
+        lambda a: 0.05 * jax.random.normal(jax.random.PRNGKey(a.size), a.shape),
+        variables["lora"])}
+
+    def grads():      # a new function a call: traced again, the chooser asked again
+        return jax.jit(jax.value_and_grad(
+            lambda v, u: jnp.sum(jnp.sin(mixer.apply(v, u, seg))),
+            argnums=(0, 1)))(variables, u)
+
+    want = grads()
+    seen = []
+    monkeypatch.setattr(
+        ssd_scan, "ssd_scan_impl",
+        lambda h, p, g, n, chunk, **kw: seen.append((h, p, g, n, chunk))
+        or ("pallas", ssd_scan.heads_per_block(h, p, g, n, chunk)))
+    got = grads()
+    assert seen == [(16, 8, 1, 128, 128)]
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-4,
+                                   atol=2e-5 * float(jnp.abs(b).max()) + 1e-7)
+    moved = got[1][0]["params"]
+    assert all(float(jnp.abs(moved[k][leaf]).max()) > 0 for k, leaf in (
+        ("A_log", "bias"), ("dt_bias", "bias"), ("D", "scale")))
 
 
 # ---- the model -------------------------------------------------------------------
@@ -414,7 +655,7 @@ def test_mixer_refuses_a_sequence_split_over_sp(devices8):
 def test_trainer_steps_under_tensor_parallelism_as_on_one_device(devices8):
     """The mixer's partition rules (projections over ``tp`` and ``fsdp``, the
     small leaves whole): two steps on a 2 x 2 mesh give one device's losses,
-    and ``train-started`` carries the mixer's three counters."""
+    and ``train-started`` carries the mixer's counters."""
     from finetune_controller_tpu.parallel.mesh import MeshSpec
     from finetune_controller_tpu.train.trainer import TrainConfig, Trainer
 
@@ -438,6 +679,8 @@ def test_trainer_steps_under_tensor_parallelism_as_on_one_device(devices8):
     attrs = one._runtime_attrs()
     assert (attrs["ssm_layers"], attrs["ssm_chunks_per_row"],
             attrs["ssm_state_bytes_per_row"]) == (2, 3, 4 * 4 * 16 * 8)
+    # the form the recurrence runs in: off a TPU the plain one, no block
+    assert (attrs["ssm_scan_impl"], attrs["ssm_scan_heads_per_block"]) == ("xla", 0)
     mamba = state.frozen["params"]["blocks"]["block"]["mamba"]
     assert {mamba[k][leaf].dtype for k, leaf in (
         ("A_log", "bias"), ("D", "scale"), ("dt_bias", "bias"))} == {
