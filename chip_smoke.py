@@ -256,6 +256,77 @@ print(json.dumps({"device": device_report(),
 """
 
 
+#: the flash kernels under a window and beside a sink — ``ops/pallas/
+#: flash_attention.py`` compiled on the chip (interpreted off it), bf16 — against
+#: the XLA form (``ops/attention.py::xla_causal_attention``: an explicit mask,
+#: the sink one more column of a plain softmax) on the float32 values of the
+#: same operands, a query head at a time so that no ``[H, S, S]`` array exists:
+#: values and the gradients of q, k, v and the sink under a fixed cotangent.
+#: Cases (rows, query heads, key/value heads, q/k width, v width, window (0 =
+#: every earlier key), sink?); a window case says what its kernels compute
+#: over what the window needs (``flash_window_work_over_need``)
+WINDOW_TOL = 2 ** -5
+
+WINDOW_PARITY_SNIPPET = r"""
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+from finetune_controller_tpu.platform import device_report, enable_compile_cache
+from finetune_controller_tpu.ops.attention import xla_causal_attention
+from finetune_controller_tpu.ops.pallas.flash_attention import (
+    causal_work_over_need, flash_attention, window_work_over_need)
+
+enable_compile_cache()
+on_chip = jax.default_backend() == "tpu"
+dtype = jnp.bfloat16 if on_chip else jnp.float32
+cases = []
+for n_case, (s, h, hkv, d, dv, window, with_sink) in enumerate(json.loads(sys.argv[1])):
+    rng = np.random.default_rng(n_case)
+    q, k, v, cot = (jnp.asarray(rng.standard_normal(shape), dtype) for shape in
+                    ((1, s, h, d), (1, s, hkv, d), (1, s, hkv, dv), (1, s, h, dv)))
+    sink = jnp.asarray(rng.standard_normal((h,)), jnp.float32)
+    window = window or None
+
+    def kernels(q, k, v, sink):
+        return flash_attention(q, k, v, window=window,
+                               sink=sink if with_sink else None)
+
+    def xla_by_head(q, k, v, sink):
+        q, k, v = (a.astype(jnp.float32) for a in (q, k, v))
+        k, v = (jnp.repeat(a, h // hkv, axis=2) for a in (k, v))
+
+        @jax.checkpoint
+        def one(head):
+            qh, kh, vh, sh = head
+            with jax.default_matmul_precision("highest"):
+                return xla_causal_attention(
+                    qh[:, :, None], kh[:, :, None], vh[:, :, None], window=window,
+                    sink=sh[None] if with_sink else None)[:, :, 0]
+
+        out = jax.lax.map(one, (jnp.moveaxis(q, 2, 0), jnp.moveaxis(k, 2, 0),
+                                jnp.moveaxis(v, 2, 0), sink))
+        return jnp.moveaxis(out, 0, 2)
+
+    def both(f):
+        def run(q, k, v, sink, cot):
+            out, vjp = jax.vjp(f, q, k, v, sink)
+            return (out, *vjp(cot.astype(out.dtype)))
+        return jax.jit(run)
+
+    got, want = both(kernels)(q, k, v, sink, cot), both(xla_by_head)(q, k, v, sink, cot)
+    names = ("value", "dq", "dk", "dv", "dsink")[:5 if with_sink else 4]
+    errs = {name: float(jnp.max(jnp.abs(u.astype(jnp.float32) - w.astype(jnp.float32)))
+                        / jnp.max(jnp.abs(w.astype(jnp.float32))))
+            for name, u, w in zip(names, got, want)}
+    work = (window_work_over_need(s, window, head_widths=(d, dv)) if window
+            else causal_work_over_need(s, head_widths=(d, dv)))
+    cases.append({"shape": [s, h, hkv, d, dv, window or 0, int(with_sink)],
+                  "errs": errs, "work_over_need": work,
+                  "finite": all(bool(jnp.all(jnp.isfinite(u.astype(jnp.float32))))
+                                for u in got)})
+print(json.dumps({"device": device_report(), "compiled": on_chip, "cases": cases}))
+"""
+
+
 #: the held share of a latent expert layer — the grouped path of
 #: ``models/moe.py`` (the share's own pairs sorted, gathered and multiplied by
 #: two grouped products an expert without a gate needs, ``held_row_bound``
@@ -442,6 +513,9 @@ def mode_config(tiny: bool, seed: int) -> dict:
             # the joined LoRA product against the layer's old expression
             # (rows, in, out, rank, quantisation block, scale)
             "lora_shapes": [[48, 64, 96, 4, 16, 2.0]],
+            # a window call with a sink and a full call (rows, heads,
+            # key/value heads, q/k, v, window, sink?)
+            "window_shapes": [[40, 4, 2, 24, 16, 5, 1], [40, 4, 1, 24, 16, 0, 0]],
             # a held share of a latent expert layer against its masked form
             "latent_shapes": [[64, 32, 16, 24, 16, 4, 4]],
         }
@@ -478,6 +552,12 @@ def mode_config(tiny: bool, seed: int) -> dict:
         # one Mistral-width projection (gate / up) over an int4 base, 2,048
         # rows, rank 16: the adapter inside the base product's contraction
         "lora_shapes": [[2048, 4096, 14336, 16, 64, 2.0]],
+        # the window/full configuration's two attention calls as published
+        # (benchmarks/configs/mimo-v2-flash-lora.json): one row of 16,384, 64
+        # query heads of 192 beside v heads of 128 — over 8 key/value heads
+        # under a window of 128 keys beside a sink, over 4 with every earlier key
+        "window_shapes": [[16384, 64, 8, 192, 128, 128, 1],
+                          [16384, 64, 4, 192, 128, 0, 0]],
         # one expert layer of the pattern configuration at its published
         # widths: 1,024 rows, top-22 of 512 experts of 1024 x 2688 in a
         # 1024-wide latent of a 4096-wide state, 128 held
@@ -936,6 +1016,24 @@ def ssd_parity_phase(run_id: str, cfg: dict) -> dict:
     return rec["device"]
 
 
+def window_parity_phase(run_id: str, cfg: dict) -> dict:
+    t0 = time.monotonic()
+    rec = parity_child(run_id, cfg, "window", WINDOW_PARITY_SNIPPET,
+                       json.dumps(cfg["window_shapes"]))
+    worst = max(max(c["errs"].values()) for c in rec["cases"])
+    check(worst <= WINDOW_TOL,
+          f"flash kernels off the XLA form by {worst} > {WINDOW_TOL} of the "
+          f"largest magnitude: {rec['cases']}")
+    say("window-parity", time.monotonic() - t0, compiled=rec["compiled"],
+        tolerance=WINDOW_TOL, worst_err=worst,
+        errs_by_shape={"x".join(map(str, c["shape"])): c["errs"]
+                       for c in rec["cases"]},
+        flash_window_work_over_need={
+            "x".join(map(str, c["shape"])): round(c["work_over_need"], 4)
+            for c in rec["cases"] if c["shape"][5]})
+    return rec["device"]
+
+
 def latent_share_parity_phase(run_id: str, cfg: dict) -> dict:
     t0 = time.monotonic()
     rec = parity_child(run_id, cfg, "latent share", LATENT_SHARE_PARITY_SNIPPET,
@@ -978,6 +1076,7 @@ def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     # here, in a minute
     grouped_device = grouped_parity_phase(run_id, cfg)
     ssd_device = ssd_parity_phase(run_id, cfg)
+    window_device = window_parity_phase(run_id, cfg)
     latent_device = latent_share_parity_phase(run_id, cfg)
     lora_device = lora_parity_phase(run_id, cfg)
     t0 = time.monotonic()
@@ -1007,7 +1106,7 @@ def run_lifecycle(cfg: dict, work: Path, run_id: str, seed: int) -> dict:
     say("shutdown", time.monotonic() - t1, survivors=[])
     parity_device = paged_parity_phase(run_id, cfg)
     check(train_device == serve_device == parity_device == grouped_device
-          == ssd_device == latent_device == lora_device,
+          == ssd_device == window_device == latent_device == lora_device,
           f"children disagree on the device: trainer {train_device}, "
           f"serve worker {serve_device}, parity children {parity_device}, "
           f"{grouped_device}, {ssd_device}, {latent_device}, {lora_device}")

@@ -394,7 +394,8 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     must cover: dense+LoRA (untied, so lm_head exists), QLoRA int4 scales,
     MoE experts + router, latent attention with a selection-biased router,
     a state-space mixer beside attention, a pattern of single-mixer layers
-    with experts in a latent, and the multimodal projector + ViT tower.  All
+    with experts in a latent, a pattern of attention kinds (a window layer's
+    sink), and the multimodal projector + ViT tower.  All
     ``eval_shape`` — no parameter memory is allocated."""
     global _VARIANT_CACHE
     if _VARIANT_CACHE is not None:
@@ -437,6 +438,11 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     )
     out["tiny-nemotron-h-test+lora"] = _shape_leaves(
         LlamaForCausalLM(cfg_pattern), tokens
+    )
+    # whole blocks by their attention's kind: unlike k/v shapes, a sink leaf
+    cfg_kinds = PRESETS["tiny-mimo-v2-test"].replace(lora=LoRAConfig(rank=4))
+    out["tiny-mimo-v2-test+lora"] = _shape_leaves(
+        LlamaForCausalLM(cfg_kinds), tokens
     )
     mm = MM_PRESETS["tiny-mm-test"].replace(lora=LoRAConfig(rank=4))
     pixels = jnp.zeros(

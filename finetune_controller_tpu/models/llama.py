@@ -4,9 +4,11 @@ attention (``attention_kind``), a dense SwiGLU or an expert layer
 (``n_experts``), and ``first_k_dense`` leading dense layers before the
 scanned stack — or, with ``layer_pattern``, a model that is a PATTERN of
 single-mixer layers (a state-space mixer, an expert layer or attention under
-one norm, one residual add, no MLP half), the pattern read as data
-(``LlamaConfig.pattern_runs``).  ONE top level: embedding -> layers -> final
-norm -> head.
+one norm, one residual add, no MLP half) or of whole blocks that differ by
+their ATTENTION's kind (every earlier key | a window of keys beside a learned
+sink, each kind with its own key/value heads and RoPE base), the pattern read
+as data (``LlamaConfig.pattern_runs``).  ONE top level: embedding -> layers ->
+final norm -> head.
 
 TPU-first choices:
   * layers run under ``nn.scan`` (one traced layer, stacked params) so XLA
@@ -88,7 +90,10 @@ def remat_policy_fn(name: str, also: tuple[str, ...] = ()):
 
 #: a letter of ``LlamaConfig.layer_pattern`` -> the module name of the ONE
 #: mixer such a layer holds (a layer's kind reads from a profile's name stack)
-LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn"}
+LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "F": "attn", "W": "attn"}
+#: the letters of WHOLE blocks (attention, then the MLP or the experts) that
+#: differ by their attention's kind: ``F`` every earlier key, ``W`` a window
+BLOCK_KINDS = "FW"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,9 +168,25 @@ class LlamaConfig:
     #: a model that is a pattern of single-mixer layers, one letter a layer:
     #: ``M`` a state-space mixer (``ssm_*``), ``E`` an expert layer
     #: (``n_experts`` ...), ``*`` grouped-query attention — each ONE norm, ONE
-    #: mixer, one residual add, and no MLP half.  "" = every layer a
-    #: :class:`Block` of mixer(s) then MLP
+    #: mixer, one residual add, and no MLP half — or a pattern of whole
+    #: :class:`Block` s (attention, then the MLP or the experts) by their
+    #: attention's kind: ``F`` every earlier key, ``W`` the ``window_*`` kind.
+    #: "" = every layer a :class:`Block` of mixer(s) then MLP
     layer_pattern: str = ""
+    # --- the attention of a ``W`` layer: a query sees its own key and the
+    # ``sliding_window - 1`` before it, over ``window_kv_heads`` key/value
+    # heads (0 = ``n_kv_heads``) rotated at ``window_rope_theta`` (0 =
+    # ``rope_theta``), beside one learned float32 sink logit a head
+    # (``window_sink``) that joins a row's softmax and adds nothing to its sum
+    sliding_window: int = 0
+    window_kv_heads: int = 0
+    window_rope_theta: float = 0.0
+    window_sink: bool = False
+    #: rotary embedding on the FIRST ``rotary_dim`` columns of a q/k head
+    #: (half-split pairs within them), the rest unrotated; 0 = the whole head
+    rotary_dim: int = 0
+    #: a multiplier on grouped-query attention's values
+    attention_value_scale: float = 1.0
     # --- latent attention (MLA): queries and keys/values through low-rank
     # latents, a rotary part of ``qk_rope_head_dim`` beside a position-free
     # part of ``qk_nope_head_dim``, the rotary KEY shared by all heads, and
@@ -175,6 +196,8 @@ class LlamaConfig:
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
+    #: value heads of another width than the q/k heads (latent attention's;
+    #: grouped-query attention's where it is not 0)
     v_head_dim: int = 0
     #: rotate adjacent pairs (x[2i], x[2i+1]) instead of the two halves
     rope_interleave: bool = False
@@ -264,7 +287,7 @@ class LlamaConfig:
         """The (q/k, v) head sizes attention runs at."""
         if self.attention_kind == "mla":
             return (self.qk_nope_head_dim + self.qk_rope_head_dim, self.v_head_dim)
-        return (self.head_dim, self.head_dim)
+        return (self.head_dim, self.v_head_dim or self.head_dim)
 
     @property
     def image_size(self) -> int:
@@ -296,22 +319,35 @@ class LlamaConfig:
         A run of two repeats or more is one scanned stack (``blocks``, a later
         one ``blocks_<first layer>``) over its unit's layers; the others are
         unrolled, ``layer_<i>``.  ``EMEMEMEMEM*`` -> ``(("EM", 5), ("*",
-        1))``.  () for a model without a pattern."""
+        1))``.  A pattern of whole blocks (``BLOCK_KINDS``) may lead with
+        ``first_k_dense`` layers that keep the dense MLP: another kind of
+        layer, its letter in lower case here (``FWWWWFW`` with one ->
+        ``(("f", 1), ("W", 4), ("F", 1), ("W", 1))``).  () for a model
+        without a pattern."""
         pattern, n = self.layer_pattern, self.n_layers
         if not pattern:
             return ()
-        if set(pattern) - set(LAYER_KINDS) or len(pattern) != n:
+        blocks = not set(pattern) - set(BLOCK_KINDS)
+        if (set(pattern) - set(LAYER_KINDS) or len(pattern) != n
+                or (not blocks and set(pattern) & set(BLOCK_KINDS))):
             raise ValueError(
                 f"layer_pattern {pattern!r}: one of {' '.join(LAYER_KINDS)} "
-                f"for each of the {n} layers")
-        if (self.attention_kind != "gqa" or self.first_k_dense
-                or self.index_topk or self.tie_embeddings
+                f"for each of the {n} layers, whole blocks "
+                f"({' '.join(BLOCK_KINDS)}) or single-mixer layers and not both")
+        if (self.attention_kind != "gqa" or self.index_topk
+                or self.tie_embeddings
+                or (self.first_k_dense and not (blocks and self.n_experts))
                 or ("M" in pattern and not self.ssm_d_inner)
-                or ("E" in pattern and not self.n_experts)):
+                or ("E" in pattern and not self.n_experts)
+                or ("W" in pattern and not self.sliding_window)
+                or (blocks and self.ssm_d_inner)):
             raise ValueError(
-                "a pattern model has grouped-query attention, no leading "
-                "dense layer, no indexer, an untied head, and the sizes of "
-                f"every kind it names: {pattern!r}")
+                "a pattern model has grouped-query attention, a leading "
+                "dense layer only before whole blocks with experts, no "
+                "indexer, an untied head, and the sizes of every kind it "
+                f"names: {pattern!r}")
+        dense = self.first_k_dense
+        pattern = pattern[:dense].lower() + pattern[dense:]
         runs, at = [], 0
         while at < n:
             best = (pattern[at], 1)
@@ -331,7 +367,8 @@ class LlamaConfig:
         return (self.q_lora_rank * hi * di + self.d_model * di + 2 * di
                 + self.d_model * hi)
 
-    def _attention_params(self) -> int:
+    def _attention_params(self, window: bool = False) -> int:
+        """One attention's; ``window``: a ``W`` layer's."""
         d, h = self.d_model, self.n_heads
         if self.attention_kind == "mla":
             qk = self.qk_nope_head_dim + self.qk_rope_head_dim
@@ -341,8 +378,12 @@ class LlamaConfig:
                     + self.kv_lora_rank                              # kv_a, its norm
                     + self.kv_lora_rank * h * (self.qk_nope_head_dim + self.v_head_dim)
                     + h * self.v_head_dim * d)                       # o
-        hd = self.head_dim
-        return 2 * d * h * hd + 2 * d * self.n_kv_heads * hd
+        qk, v = self.head_widths
+        kv_heads, sinks = self.n_kv_heads, 0
+        if window:
+            kv_heads = self.window_kv_heads or kv_heads
+            sinks = h if self.window_sink else 0
+        return d * h * qk + d * kv_heads * (qk + v) + h * v * d + sinks
 
     def _mixer_params(self) -> int:
         """One state-space mixer's: both projections, the convolution and its
@@ -370,13 +411,22 @@ class LlamaConfig:
     def _count(self, experts_counted: int) -> int:
         """Stored parameters with ``experts_counted`` routed experts a layer."""
         d, v, L = self.d_model, self.vocab_size, self.n_layers
-        if self.pattern_runs():
-            # one norm and one mixer a layer, by kind
-            layer = {"M": self._mixer_params(), "*": self._attention_params(),
-                     "E": self._expert_layer_params(experts_counted)}
-            return (2 * v * d + d
-                    + sum(layer[kind] + d for kind in self.layer_pattern))
         dense_mlp = (2 if self.mlp_act == "relu2" else 3) * d * self.d_ff
+        if self.pattern_runs():
+            # one norm and one mixer a layer, by kind; a whole block two
+            # norms, its kind's attention and the MLP or the experts (the
+            # dense MLP in a leading layer: a lower-case letter of the runs)
+            experts = self._expert_layer_params(experts_counted)
+            mlp = experts if self.n_experts else dense_mlp
+            layer = {"M": self._mixer_params(), "*": self._attention_params(),
+                     "E": experts,
+                     "F": d + self._attention_params() + mlp,
+                     "W": d + self._attention_params(True) + mlp,
+                     "f": d + self._attention_params() + dense_mlp,
+                     "w": d + self._attention_params(True) + dense_mlp}
+            return (2 * v * d + d + sum(
+                repeats * sum(layer[kind] + d for kind in unit)
+                for unit, repeats in self.pattern_runs()))
         per_layer = self._attention_params() + self._mixer_params() + 2 * d
         if self.n_experts:
             expert_mlp = self._expert_layer_params(experts_counted)
@@ -552,11 +602,28 @@ PRESETS: dict[str, LlamaConfig] = {
         n_shared_experts=2, moe_scoring="sigmoid", moe_dispatch="dropless",
         moe_routed_scale=5.0, router_aux_weight=0.0,
     ),
+    # the window/full family at toy size: seven whole blocks ``FWWWWFW`` by
+    # their attention's kind — full layers over 2 key/value heads rotated at
+    # 5e6, window layers (4 keys) over 4 at 1e4 beside a sink a head —, q/k
+    # heads of 24 (the first 8 columns rotated) beside v heads of 16 scaled by
+    # 0.707, a leading dense layer, then sigmoid top-4 of 16 narrow experts
+    # with no shared one
+    "tiny-mimo-v2-test": LlamaConfig(
+        vocab_size=256, d_model=64, n_layers=7, layer_pattern="FWWWWFW",
+        n_heads=8, n_kv_heads=2, head_dim_override=24, v_head_dim=16,
+        rotary_dim=8, rope_theta=5e6, attention_value_scale=0.707,
+        sliding_window=4, window_kv_heads=4, window_rope_theta=1e4,
+        window_sink=True, d_ff=128, max_seq_len=128, first_k_dense=1,
+        n_experts=16, moe_top_k=4, moe_d_ff=32, moe_scoring="sigmoid",
+        moe_dispatch="dropless", router_aux_weight=0.0,
+    ),
 }
 
 
-def rope_inv_freqs(cfg: "LlamaConfig") -> jax.Array:
-    """Per-pair inverse frequencies, with optional llama3-style scaling.
+def rope_inv_freqs(cfg: "LlamaConfig", window: bool = False) -> jax.Array:
+    """Per-pair inverse frequencies, with optional llama3-style scaling; over
+    ``rotary_dim`` columns where a head is rotated in part, at a ``window``
+    layer's own base.
 
     The scaling partitions frequency space by wavelength against the
     original training context: wavelengths longer than
@@ -565,9 +632,10 @@ def rope_inv_freqs(cfg: "LlamaConfig") -> jax.Array:
     (local positional detail), and the band between interpolates smoothly —
     matching transformers' ``_compute_llama3_parameters``.
     """
-    half = cfg.head_dim // 2
+    half = (cfg.rotary_dim or cfg.head_dim) // 2
+    theta = (cfg.window_rope_theta if window else 0.0) or cfg.rope_theta
     freqs = 1.0 / (
-        cfg.rope_theta ** (jnp.arange(half, dtype=jnp.float32) / half)
+        theta ** (jnp.arange(half, dtype=jnp.float32) / half)
     )
     factor = cfg.rope_scaling_factor
     if not factor:
@@ -674,38 +742,67 @@ def _proj(cfg: LlamaConfig, name: str, features: int, **module_kw) -> LoRADense:
     )
 
 
+def _rotate_leading(x, positions, inv_freqs, rotary_dim: int):
+    """:func:`apply_rope` on the first ``rotary_dim`` columns of every head of
+    ``x`` (half-split pairs within them), the other columns as they are; the
+    whole head where ``rotary_dim`` is 0."""
+    if not rotary_dim:
+        return apply_rope(x, positions, inv_freqs=inv_freqs)
+    return jnp.concatenate(
+        [apply_rope(x[..., :rotary_dim], positions, inv_freqs=inv_freqs),
+         x[..., rotary_dim:]], axis=-1)
+
+
 class Attention(nn.Module):
     cfg: LlamaConfig
+    #: a ``W`` layer's attention: the configuration's ``window_*`` kind
+    window: bool = False
 
     @nn.compact
     def __call__(self, x, positions, segment_ids, deterministic=True,
                  decode=False, page_table=None, adapter_ids=None):
         cfg = self.cfg
         b, s, _ = x.shape
-        hd = cfg.head_dim
+        hd, vd = cfg.head_widths
+        kv_heads = (cfg.window_kv_heads if self.window else 0) or cfg.n_kv_heads
         q = _proj(cfg, "q_proj", cfg.n_heads * hd)(x, deterministic, adapter_ids)
-        k = _proj(cfg, "k_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
-        v = _proj(cfg, "v_proj", cfg.n_kv_heads * hd)(x, deterministic, adapter_ids)
+        k = _proj(cfg, "k_proj", kv_heads * hd)(x, deterministic, adapter_ids)
+        v = _proj(cfg, "v_proj", kv_heads * vd)(x, deterministic, adapter_ids)
         k = times(k, cfg.key_multiplier)
+        v = times(v, cfg.attention_value_scale)
         if cfg.rope_theta:
             with jax.named_scope("rope"):
-                inv_freqs = rope_inv_freqs(cfg)
-                q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), positions,
-                               inv_freqs=inv_freqs)
-                k = apply_rope(k.reshape(b, s, cfg.n_kv_heads, hd), positions,
-                               inv_freqs=inv_freqs)
+                inv_freqs = rope_inv_freqs(cfg, self.window)
+                q = _rotate_leading(q.reshape(b, s, cfg.n_heads, hd), positions,
+                                    inv_freqs, cfg.rotary_dim)
+                k = _rotate_leading(k.reshape(b, s, kv_heads, hd), positions,
+                                    inv_freqs, cfg.rotary_dim)
         else:       # attention without positions
             q = q.reshape(b, s, cfg.n_heads, hd)
-            k = k.reshape(b, s, cfg.n_kv_heads, hd)
-        v = v.reshape(b, s, cfg.n_kv_heads, hd)
+            k = k.reshape(b, s, kv_heads, hd)
+        v = v.reshape(b, s, kv_heads, vd)
         if decode:
+            if self.window or vd != hd:
+                raise NotImplementedError(
+                    "attention under a window, beside a sink or with value "
+                    "heads of their own width has no decode path yet: the "
+                    "cache would hold a window for such a layer (ROADMAP.md "
+                    "B9); train and evaluate only")
             return self._decode_attention(q, k, v, deterministic,
                                           page_table, adapter_ids)
         q = checkpoint_name(q, "attn_qkv")
         k = checkpoint_name(k, "attn_qkv")
         v = checkpoint_name(v, "attn_qkv")
+        more = {}
+        if self.window:
+            more["window"] = cfg.sliding_window
+            if cfg.window_sink:
+                # one float32 logit a head, whatever the parameters' type
+                more["sink"] = _Leaves(
+                    (("bias", (cfg.n_heads,), nn.initializers.zeros_init()),),
+                    jnp.float32, name="sink")()["bias"]
         out = causal_attention(
-            q, k, v, impl=cfg.attention_impl, segment_ids=segment_ids)
+            q, k, v, impl=cfg.attention_impl, segment_ids=segment_ids, **more)
         out = checkpoint_name(out, "attn_ctx")
         out = _proj(cfg, "o_proj", cfg.d_model)(
             out.reshape(b, s, -1), deterministic, adapter_ids)
@@ -1162,7 +1259,8 @@ class Block(nn.Module):
     #: the call then takes and returns the selection beside ``x``
     indexer: str | None = None
     #: a layer of a pattern model, by its letter (``LAYER_KINDS``): ONE norm,
-    #: ONE mixer of that kind, one residual add, and no MLP half
+    #: ONE mixer of that kind, one residual add, and no MLP half — or, a
+    #: letter of ``BLOCK_KINDS``, a whole block whose attention is that kind's
     kind: str | None = None
 
     @nn.compact
@@ -1173,7 +1271,7 @@ class Block(nn.Module):
         cfg = self.cfg
         if cfg.attention_kind not in ("gqa", "mla"):
             raise ValueError(f"unknown attention_kind {cfg.attention_kind!r}")
-        if self.kind is not None:
+        if self.kind is not None and self.kind not in BLOCK_KINDS:
             h = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset, name="norm")(x)
             if self.kind == "M":
                 from .ssm import Mamba2Mixer
@@ -1191,6 +1289,10 @@ class Block(nn.Module):
         if cfg.ssm_d_inner:
             x = x + self._attention_beside_mixer(
                 h, positions, segment_ids, deterministic, decode, adapter_ids)
+        elif self.kind is not None:
+            x = x + Attention(cfg, window=self.kind == "W", name="attn")(
+                h, positions, segment_ids, deterministic, decode,
+                page_table, adapter_ids)
         elif self.indexer is None:
             x = x + attention(cfg, name="attn")(
                 h, positions, segment_ids, deterministic, decode,
@@ -1401,7 +1503,8 @@ class _ScanUnit(nn.Module):
     remat — the loop then keeps one input a layer (not one a repeat), and a
     backward pass holds one layer's intermediates, as a stack of like blocks
     does.  The kinds' leaves are disjoint and of unlike shapes; each rides the
-    scan's axis under its own layer's name."""
+    scan's axis under its own layer's name.  A lower-case letter
+    (``pattern_runs``) is a whole block that keeps the dense MLP."""
 
     cfg: LlamaConfig
     unit: str
@@ -1413,7 +1516,8 @@ class _ScanUnit(nn.Module):
         cfg = self.cfg
         block_cls = _remat(Block, cfg, remat_policy_fn(cfg.remat_policy))
         for j, kind in enumerate(self.unit):
-            x = block_cls(cfg, kind=kind, name=f"layer_{j}")(
+            x = block_cls(cfg, kind=kind.upper(), dense_mlp=kind.islower(),
+                          name=f"layer_{j}")(
                 x, positions, segment_ids, deterministic, decode, page_table,
                 adapter_ids, layer, (stacked_experts or {}).get(f"layer_{j}"))
         return x, None
@@ -1453,12 +1557,15 @@ class LlamaForCausalLM(nn.Module):
         # args 4/5 = deterministic/decode (0 is self): static bools.  Outside
         # a scan the compiler may merge a layer's recomputation with its
         # forward pass — that is, keep attention's residuals (q, k, v, output)
-        # for the backward pass.  Forbidden where they pass 1 GiB a layer: 2.1
+        # for the backward pass.  Forbidden where they pass 1 GiB a layer: 2.6
         # GB at ONE 16,384-token row of 64 heads of 256, which that step has no
-        # room for; 0.34 GB at 2 x 4,096 rows of 32 heads of 192 beside 128,
-        # where the layers keep the setting they were measured with
+        # room for; 0.47 GB at 2 x 4,096 rows of 32 heads of 192 beside 128,
+        # where the layers keep the setting they were measured with; 1.25 GB
+        # at one 16,384-token row of 64 heads of 192 beside 128, a third of it
+        # the kernels' float32 logsumexp, which a tile pads to 128 lanes a row
         qk, v = cfg.head_widths
-        residuals = 2 * tokens.size * (cfg.n_heads + cfg.n_kv_heads) * (qk + v)
+        residuals = tokens.size * (
+            2 * (cfg.n_heads + cfg.n_kv_heads) * (qk + v) + 4 * 128 * cfg.n_heads)
         unrolled_cls = _remat(Block, cfg, policy, prevent_cse=residuals > 2**30)
         if cfg.layer_pattern:
             x = self._pattern_layers(
@@ -1522,7 +1629,9 @@ class LlamaForCausalLM(nn.Module):
         for unit, repeats in cfg.pattern_runs():
             if repeats == 1 or not cfg.scan_layers:
                 for kind in unit * repeats:
-                    x = unrolled_cls(cfg, kind=kind, name=f"layer_{at}")(x, *args)
+                    x = unrolled_cls(cfg, kind=kind.upper(),
+                                     dense_mlp=kind.islower(),
+                                     name=f"layer_{at}")(x, *args)
                     at += 1
                 continue
             name = f"blocks_{at}" if stacks else "blocks"
@@ -1616,7 +1725,8 @@ class LlamaForCausalLM(nn.Module):
         if not cfg.layer_pattern:
             return kernels(leaves["block"])
         return {f"layer_{j}": kernels(leaves[f"layer_{j}"])
-                for j, kind in enumerate(unit) if kind == "E"} or None
+                for j, kind in enumerate(unit)
+                if kind == "E" or kind in BLOCK_KINDS} or None
 
     def init_variables(self, rng: jax.Array, batch: int = 1, seq: int = 8):
         tokens = jnp.zeros((batch, seq), jnp.int32)

@@ -78,13 +78,18 @@ def xla_causal_attention(
     scale: float | None = None,
     segment_ids: jax.Array | None = None,
     selection: jax.Array | None = None,
+    window: int | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Causal (optionally segment-masked) GQA attention.
 
     Shapes: q (B, S, H, D); k (B, S, Hkv, D); v (B, S, Hkv, Dv) with
     H % Hkv == 0.  Dv may differ from D (latent attention's 192 / 128); the
     softmax scale comes from D.  ``selection`` (:func:`pack_selection`)
-    restricts every query, in all heads, to its own set of keys.  Returns
+    restricts every query, in all heads, to its own set of keys.  ``window``:
+    key ``s`` serves query ``t`` iff ``t - window < s <= t``.  ``sink`` (H,)
+    float32: one more column of logit ``sink[h]`` in every row's softmax,
+    dropped after it, so a row's weights sum to under 1.  Returns
     (B, S, H, Dv) in q.dtype.
     """
     b, s, h, d = q.shape
@@ -94,6 +99,8 @@ def xla_causal_attention(
 
     pos = jnp.arange(s)
     mask = pos[:, None] >= pos[None, :]  # (S, S) causal
+    if window is not None:
+        mask = mask & (pos[:, None] - pos[None, :] < window)
     mask = mask[None, None, None]
     if segment_ids is not None:
         seg = segment_ids[:, None, None, :, None] == segment_ids[:, None, None, None, :]
@@ -101,9 +108,16 @@ def xla_causal_attention(
     if selection is not None:
         mask = mask & unpack_selection(selection, s)[:, None, None]
     scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    if sink is None:
+        probs = jax.nn.softmax(scores, axis=-1)
+    else:
+        column = jnp.broadcast_to(
+            sink.astype(jnp.float32).reshape(1, hkv, h // hkv, 1, 1),
+            scores.shape[:-1] + (1,))
+        probs = jax.nn.softmax(
+            jnp.concatenate([scores, column], axis=-1), axis=-1)[..., :-1]
 
-    out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+    out = jnp.einsum("bkgst,btkd->bskgd", probs.astype(v.dtype), v)
     return out.reshape(b, s, h, v.shape[-1])
 
 
@@ -308,7 +322,8 @@ def paged_cache_attention(
     )
 
 
-def _flash_attention_on_mesh(q, k, v, segment_ids, selection=None) -> jax.Array:
+def _flash_attention_on_mesh(q, k, v, segment_ids, selection=None,
+                             window=None, sink=None) -> jax.Array:
     """The Pallas flash kernel, one call per device.
 
     The chip's compiler cannot partition a Mosaic kernel, so under a mesh of
@@ -322,14 +337,15 @@ def _flash_attention_on_mesh(q, k, v, segment_ids, selection=None) -> jax.Array:
     ``tp`` times over but is still correct.  The other axes (``sp``/``ep``/
     ``pp``) see replicated operands.  Where ``bare_mosaic_call_ok`` (no mesh,
     a single-device mesh, a caller already inside a ``shard_map`` body such
-    as a pipeline stage) the kernel is called bare.
+    as a pipeline stage) the kernel is called bare.  ``window`` is static; a
+    ``sink`` splits with the query heads.
     """
     from .pallas import bare_mosaic_call_ok
     from .pallas.flash_attention import flash_attention
 
     if bare_mosaic_call_ok():
         return flash_attention(q, k, v, segment_ids=segment_ids,
-                               selection=selection)
+                               selection=selection, window=window, sink=sink)
 
     from jax.sharding import PartitionSpec as P
 
@@ -352,12 +368,17 @@ def _flash_attention_on_mesh(q, k, v, segment_ids, selection=None) -> jax.Array:
         operands += (selection,)
         in_specs += (P(AxisNames.BATCH_AXES, None, None),)
 
+    if sink is not None:
+        operands += (sink,)
+        in_specs += (P(heads),)
+
     given = [name for name, operand in (("segment_ids", segment_ids),
-                                        ("selection", selection))
+                                        ("selection", selection),
+                                        ("sink", sink))
              if operand is not None]
 
     def local(q, k, v, *rest):
-        return flash_attention(q, k, v, **dict(zip(given, rest)))
+        return flash_attention(q, k, v, window=window, **dict(zip(given, rest)))
 
     return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=qkv_spec,
@@ -417,18 +438,29 @@ def causal_attention(
     impl: str = "xla",
     segment_ids: jax.Array | None = None,
     selection: jax.Array | None = None,
+    window: int | None = None,
+    sink: jax.Array | None = None,
 ) -> jax.Array:
     """Causal GQA attention by the implementation
     :func:`resolve_attention_impl` gives for ``impl`` and this row length;
     with ``selection`` (:func:`pack_selection`) over each query's own set of
-    keys, which the XLA path and the flash kernels take and the
-    sequence-parallel paths do not."""
+    keys, under a ``window`` of that many keys and beside a per-head ``sink``
+    logit (:func:`xla_causal_attention`), all of which the XLA path and the
+    flash kernels take and the sequence-parallel paths do not."""
     impl = resolve_attention_impl(impl, q.shape[1])
     if impl == "xla":
         return xla_causal_attention(
-            q, k, v, segment_ids=segment_ids, selection=selection)
+            q, k, v, segment_ids=segment_ids, selection=selection,
+            window=window, sink=sink)
     if impl == "pallas":
-        return _flash_attention_on_mesh(q, k, v, segment_ids, selection)
+        return _flash_attention_on_mesh(
+            q, k, v, segment_ids, selection, window, sink)
+    if window is not None or sink is not None:
+        raise NotImplementedError(
+            f"attention under a window or beside a sink has no {impl!r} path: "
+            "a shard's window reaches into the shard before it, and the sink "
+            "would have to join the merge of the hops' partial sums "
+            "(ROADMAP.md B9)")
     if selection is not None:
         raise NotImplementedError(
             f"attention over a selection of keys has no {impl!r} path: the "

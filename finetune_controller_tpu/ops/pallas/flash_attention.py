@@ -24,6 +24,30 @@ mask — so ``c(c+1)/2`` of its ``c²`` sub-tiles are computed
 (``causal_work_over_need`` is the count).  What is left out contributed an
 exact 0.0 to every sum and never raised a row's maximum.
 
+A second frontier: with a static ``window`` a key ``s`` serves a query ``t``
+iff ``t - window < s <= t``.  The inner axis of all three grids then sweeps
+ONLY the blocks the window reaches (``_window_blocks_back`` + 1 key blocks a
+query block in the forward and dQ kernels, as many query blocks a key block
+and group member in dK/dV), so a block wholly behind the window is neither
+computed nor fetched nor walked; how far a step's block lies behind the
+diagonal is static, so a block pair is computed in bands of the window's
+width rounded up to 128 lanes (``_window_band``, no wider than ``DIAG_TILE``),
+each against the tiles it admits: whole where every pair is valid, under a
+position-free mask where the causal or the trailing edge crosses
+(``_window_tile_spans``; ``window_work_over_need`` is the count: 2.0 at a
+window of 128).  A window call is traced under the names ``flash_swa_fwd``,
+``flash_swa_bwd_dq`` and ``flash_swa_bwd_dkv``; with no window the kernels trace
+the bodies, grids and index maps they always did.
+
+A sink: a per-head float32 logit ``b_h`` that joins each row's softmax
+normaliser (and so its logsumexp) and no output sum — the state every row's
+online softmax STARTS from in the forward kernel (``m = b_h``, ``l = 1``,
+``acc = 0``).  The backward kernels take no sink: ``p = exp(s - lse)`` with the
+``lse`` that holds it and ``ds = p (dp - delta)`` are the sums' own; its
+gradient, ``-sum_t exp(b_h - lse_t) delta_t``, is a few per-row terms outside
+the kernels (scope ``attn_sink``), which the compiler drops where the leaf is
+frozen.
+
 Differentiation is a full Pallas path under ``jax.custom_vjp``:
 
 * forward saves O(S) residuals — the output and the per-row logsumexp — never
@@ -135,6 +159,113 @@ def causal_work_over_need(
                 diagonal = c > 1 and iq == ik
                 area += (c * (c + 1) // 2) * (bq // c) ** 2 if diagonal else bq * bk
     return area / (seq * seq / 2)
+
+
+#: a window's bands are whole lanes wide
+WINDOW_LANES = 128
+
+
+def _window_band(window: int, block: int) -> int:
+    """Width of the bands a block pair under a window is computed in: the
+    window rounded up to whole lanes (a band then meets its own diagonal tile
+    and the ONE tile the trailing edge crosses), no wider than ``DIAG_TILE``
+    or the block; the whole block where that does not divide it."""
+    t = min(DIAG_TILE, block, WINDOW_LANES * pl.cdiv(window, WINDOW_LANES))
+    return block if block % t else t
+
+
+def _window_blocks_back(window: int, block: int) -> int:
+    """Key blocks behind a query block's own that its window reaches: the
+    first query of a block sees back to key ``- (window - 1)``."""
+    return pl.cdiv(window - 1, block)
+
+
+def _window_tile_spans(t: int, block: int, offset: int, window: int,
+                       key_bands: bool = False):
+    """The tiles of a square block pair a window admits, band by band, as
+    static ``(band, [(first, stop, lower, upper), ...])``.
+
+    ``offset`` = the pair's first query - its first key (0 on the diagonal,
+    a whole block a step behind it).  A tile's pairs lie at query - key =
+    ``d + e`` with ``d`` its own first query - first key and ``e`` = row -
+    column in ``(-t, t)``; the window admits ``0 <= d + e < window``.  A tile
+    with no such pair is skipped; one that holds only such pairs needs no
+    mask and joins its neighbours ``[first, stop)`` (in tiles); one an edge
+    crosses stands alone with ``lower`` (``e >= lower``: the causal edge)
+    and / or ``upper`` (``e <= upper``: the trailing edge) set.  Bands are of
+    rows; of keys with ``key_bands`` (the dK/dV kernel's accumulators)."""
+    n = block // t
+    for i in range(n):
+        spans = []
+        for c in range(n):
+            d = offset + ((c - i) if key_bands else (i - c)) * t
+            if d + t - 1 < 0 or d - (t - 1) > window - 1:
+                continue
+            lower = -d if d - (t - 1) < 0 else None
+            upper = window - 1 - d if d + t - 1 > window - 1 else None
+            whole = lower is None and upper is None
+            if whole and spans and spans[-1][1] == c and spans[-1][2:] == (None, None):
+                spans[-1] = (spans[-1][0], c + 1, None, None)
+            else:
+                spans.append((c, c + 1, lower, upper))
+        if spans:
+            yield i, spans
+
+
+def _window_steps(window: int, block: int, steps: int, key_bands: bool = False):
+    """``(band width, [blocks apart, ...])``: of the ``steps`` block pairs an
+    inner axis sweeps — 0, 1, ... blocks apart — those the window admits a
+    tile of (the farthest always; a pair between may hold none only where the
+    window is under a block)."""
+    t = _window_band(window, block)
+    return t, [apart for apart in range(steps) if any(
+        _window_tile_spans(t, block, apart * block, window, key_bands))]
+
+
+def _window_tiles(t, block, offset, window, segment_refs, key_bands=False):
+    """:func:`_window_tile_spans` as ``(rows, keys, mask)`` of the block pair,
+    ``mask`` None where every pair is valid.  An edge's mask is position-free
+    (``offset`` is static), so one compare serves every block it recurs in.
+    No mask for a padded tail: under a causal frontier a padded key serves
+    padded queries alone, whose rows are cut away, and a padded query's
+    cotangent and delta are zero."""
+    e = (jax.lax.broadcasted_iota(jnp.int32, (t, t), 0)
+         - jax.lax.broadcasted_iota(jnp.int32, (t, t), 1))
+    for i, spans in _window_tile_spans(t, block, offset, window, key_bands):
+        band = slice(i * t, (i + 1) * t)
+        for first, stop, lower, upper in spans:
+            other = slice(first * t, stop * t)
+            rows, keys = (other, band) if key_bands else (band, other)
+            mask = None
+            if lower is not None:
+                mask = e >= lower
+            if upper is not None:
+                mask = e <= upper if mask is None else mask & (e <= upper)
+            if segment_refs is not None:
+                same = _segment_mask(*segment_refs, rows, keys)
+                mask = same if mask is None else mask & same
+            yield rows, keys, mask
+
+
+def window_work_over_need(
+    seq: int, window: int, block: int | None = None,
+    head_widths: tuple[int, int] = (128, 128),
+) -> float:
+    """Score area the kernels compute under a ``window`` ÷ what the window
+    needs, ``sum_t min(t + 1, window)`` pairs a row and head — the count
+    beside :func:`causal_work_over_need`, as static: row length, window and
+    block (given, else what a call with heads of ``head_widths`` gets)."""
+    b = min(block or _default_block(*head_widths), seq)
+    t = _window_band(window, b)
+    blocks = _padded_len(seq, b, b) // b
+    area = 0
+    for back in range(min(_window_blocks_back(window, b), blocks - 1) + 1):
+        tiles = sum(stop - first
+                    for _, spans in _window_tile_spans(t, b, back * b, window)
+                    for first, stop, _, _ in spans)
+        area += (blocks - back) * tiles * t * t
+    w = min(window, seq)
+    return area / (w * (w + 1) // 2 + (seq - w) * w)
 
 
 def _default_block(qk_width: int, v_width: int) -> int:
@@ -278,16 +409,22 @@ def _fwd_kernel(
     qseg_ref,   # (1, 1, bq)
     kseg_ref,   # (1, 1, bk)
     *refs,      # [sel_ref (1, bq, 128): the selection's words, where one is
-                #  given;] then o_ref (1, 1, bq, dv), lse_ref (1, 1, bq, 1) and
-                #  the VMEM scratch acc_ref (bq, dv), m_ref, l_ref (bq, 1) f32
+                #  given;] [sink_ref (1, 1, 1) f32: the head's sink logit,
+                #  ``has_sink``;] then o_ref (1, 1, bq, dv), lse_ref (1, 1, bq,
+                #  1) and the VMEM scratch acc_ref (bq, dv), m_ref, l_ref
+                #  (bq, 1) f32
     seq_len: int,
     scale: float,
     use_segments: bool,
     exp_dtype: str = "float32",
     causal: bool = True,
     diag_tiles: int = 1,
+    window: int | None = None,
+    window_back: int = 0,
+    has_sink: bool = False,
 ):
-    sel_ref = refs[0] if len(refs) == 6 else None
+    sel_ref = refs[0] if len(refs) - has_sink == 6 else None
+    sink_ref = refs[-6] if has_sink else None
     o_ref, lse_ref, acc_ref, m_ref, l_ref = refs[-5:]
     iq, ik = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
@@ -298,8 +435,15 @@ def _fwd_kernel(
     @pl.when(ik == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        if has_sink:
+            # the sink is where every row's online softmax starts: one more
+            # column of logit b_h that joins the maximum and the normaliser
+            # (exp(b_h - b_h) = 1) and adds nothing to the output sum
+            m_ref[...] = jnp.broadcast_to(sink_ref[0], m_ref.shape)
+            l_ref[...] = jnp.ones_like(l_ref)
+        else:
+            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
     if causal:
         # causal frontier: this k block is live iff its first key position is
@@ -368,39 +512,53 @@ def _fwd_kernel(
         ) * scale  # (rows, keys) f32
 
     segment_refs = (qseg_ref, kseg_ref) if use_segments else None
-    if diag_tiles > 1:
-        # square blocks: a needed block left of the diagonal holds real keys
-        # only, so the one block that is not interior is the diagonal one
-        @pl.when(iq == ik)
-        def _compute_diagonal():
-            # a row band's tiles in ONE update: what an update costs a row
-            # (reductions across lanes, rescaling) is paid once a band
-            tiles = _diagonal_tiles(diag_tiles, bq, iq * bq, seq_len, segment_refs)
-            for rows, band in itertools.groupby(tiles, key=lambda tile: tile[0]):
-                _online_update([
-                    (_scores(rows, keys),
-                     _selected(sel_ref, ik, bq, bk, mask, rows, keys), keys)
-                    for _, keys, mask in band], rows)
+    if window is not None:
+        # the second frontier: the inner axis sweeps ``window_back + 1`` key
+        # blocks, step ``ik`` naming the one ``window_back - ik`` behind the
+        # query block's own.  How far behind is static at each step, so which
+        # tiles of the pair the window admits, and each edge's mask, are too
+        t, behind = _window_steps(window, bq, window_back + 1)
+        for back in behind:
+            @pl.when((ik == window_back - back) & (iq >= back))
+            def _compute_band(back=back):
+                tiles = _window_tiles(t, bq, back * bq, window, segment_refs)
+                for rows, band in itertools.groupby(tiles, key=lambda tile: tile[0]):
+                    _online_update([(_scores(rows, keys), mask, keys)
+                                    for _, keys, mask in band], rows)
     else:
-        @pl.when(needed & ~interior)
-        def _compute_masked():
-            s = _scores()
-            q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-            mask = k_pos < seq_len  # tail block: beyond-S lanes are padding
-            if causal:
-                mask &= q_pos >= k_pos
-            if use_segments:
-                mask &= _segment_mask(qseg_ref, kseg_ref)
-            _online_update([(s, _selected(sel_ref, ik, bq, bk, mask), ALL)])
+        if diag_tiles > 1:
+            # square blocks: a needed block left of the diagonal holds real keys
+            # only, so the one block that is not interior is the diagonal one
+            @pl.when(iq == ik)
+            def _compute_diagonal():
+                # a row band's tiles in ONE update: what an update costs a row
+                # (reductions across lanes, rescaling) is paid once a band
+                tiles = _diagonal_tiles(diag_tiles, bq, iq * bq, seq_len, segment_refs)
+                for rows, band in itertools.groupby(tiles, key=lambda tile: tile[0]):
+                    _online_update([
+                        (_scores(rows, keys),
+                         _selected(sel_ref, ik, bq, bk, mask, rows, keys), keys)
+                        for _, keys, mask in band], rows)
+        else:
+            @pl.when(needed & ~interior)
+            def _compute_masked():
+                s = _scores()
+                q_pos, k_pos = _block_positions(iq, ik, bq, bk)
+                mask = k_pos < seq_len  # tail block: beyond-S lanes are padding
+                if causal:
+                    mask &= q_pos >= k_pos
+                if use_segments:
+                    mask &= _segment_mask(qseg_ref, kseg_ref)
+                _online_update([(s, _selected(sel_ref, ik, bq, bk, mask), ALL)])
 
-    @pl.when(needed & interior)
-    def _compute_interior():
-        _online_update([(
-            _scores(),
-            _selected(sel_ref, ik, bq, bk, _segment_mask(qseg_ref, kseg_ref)
-                      if use_segments else None),
-            ALL,
-        )])
+        @pl.when(needed & interior)
+        def _compute_interior():
+            _online_update([(
+                _scores(),
+                _selected(sel_ref, ik, bq, bk, _segment_mask(qseg_ref, kseg_ref)
+                          if use_segments else None),
+                ALL,
+            )])
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -446,6 +604,30 @@ def _selection_operand(selection, s_pad: int, bq: int, bk: int):
     return selection, (1, bq, SELECTION_LANES), lambda ik: ik * bk // SELECTION_KEYS
 
 
+def _check_window(window, bq, bk, causal, selection):
+    """A window call is causal, over square blocks, with no selection."""
+    if window is None:
+        return
+    if window < 1 or not causal or bq != bk or selection is not None:
+        raise ValueError(
+            f"a window of {window} keys needs a causal call over square "
+            f"blocks (got {bq} x {bk}) and takes no selection")
+
+
+def _window_kv_block_index(iq, ik, back: int):
+    """The K/V block inner step ``ik`` of a window call's forward and dQ grids
+    names for query block ``iq``: the one ``back - ik`` behind it, and block 0
+    — the first a computing step wants — where there is none."""
+    return jnp.maximum(iq - (back - ik), 0)
+
+
+def _window_q_block_index(ik, j, nq: int, ahead: int):
+    """The q-side block inner step ``j`` of a window call's dK/dV grid names
+    for key block ``ik``: the one ``j % ahead`` ahead of it, and the last block
+    — already resident — past the rows' end."""
+    return jnp.minimum(ik + j % ahead, nq - 1)
+
+
 def _flash_forward(
     q: jax.Array,           # (B, S, H, D)
     k: jax.Array,           # (B, S, Hkv, D)
@@ -460,9 +642,15 @@ def _flash_forward(
     causal: bool = True,
     kv_segment_ids: jax.Array | None = None,
     selection: jax.Array | None = None,
+    sink: jax.Array | None = None,
+    window: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns (out (B, S, H, Dv), lse (B, H, S_pad, 1) f32).  The softmax
-    scale comes from the q/k head size; V keeps its own width in HBM."""
+    scale comes from the q/k head size; V keeps its own width in HBM.  ``sink``
+    (H,) float32: a logit a head that joins every row's normaliser (and so
+    its ``lse``) and no output sum.  ``window``: a key ``s`` serves a query
+    ``t`` iff ``t - window < s <= t``; the inner axis then sweeps the key
+    blocks a query block's window reaches and no other."""
     b, s, h, d = q.shape
     hkv, d_v = k.shape[2], v.shape[3]
     group = h // hkv
@@ -470,6 +658,7 @@ def _flash_forward(
 
     bq = min(block_q, s)
     bk = min(block_k, s)
+    _check_window(window, bq, bk, causal, selection)
     q, k, v, segment_ids, kv_segment_ids, s_pad = _pad_inputs(
         q, k, v, segment_ids, bq, bk, kv_segment_ids)
 
@@ -487,17 +676,29 @@ def _flash_forward(
     nk = pl.cdiv(s_pad, bk)
 
     kv = functools.partial(_kv_block_index, bq=bq, bk=bk, causal=causal)
+    kernel_kw = {}
+    if window is not None:
+        back = min(_window_blocks_back(window, bq), nq - 1)
+        kernel_kw = dict(window=window, window_back=back)
+        nk = back + 1
+        kv = functools.partial(_window_kv_block_index, back=back)
     sel_operands, sel_specs = (), []
     if selection is not None:
         selection, block, words_of = _selection_operand(selection, s_pad, bq, bk)
         sel_operands = (selection,)
         sel_specs = [pl.BlockSpec(
             block, lambda ib, ih, iq, ik: (ib, iq, words_of(kv(iq, ik))))]
+    if sink is not None:
+        sel_operands += (sink.astype(jnp.float32).reshape(h, 1, 1),)
+        sel_specs.append(pl.BlockSpec(
+            (1, 1, 1), lambda ib, ih, iq, ik: (ih, 0, 0)))
+        kernel_kw["has_sink"] = True
 
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, seq_len=s, scale=scale,
                           use_segments=use_segments, exp_dtype=exp_dtype,
-                          causal=causal, diag_tiles=_diag_tiles(bq, bk, causal)),
+                          causal=causal, diag_tiles=_diag_tiles(bq, bk, causal),
+                          **kernel_kw),
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -524,7 +725,7 @@ def _flash_forward(
             "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
-        name="flash_fwd",
+        name="flash_fwd" if window is None else "flash_swa_fwd",
     )(qt, kt, vt, seg3, kseg3, *sel_operands)
 
     return out.transpose(0, 2, 1, 3)[:, :s], lse
@@ -552,6 +753,8 @@ def _bwd_dq_kernel(
     exp_dtype: str = "float32",
     causal: bool = True,
     diag_tiles: int = 1,
+    window: int | None = None,
+    window_back: int = 0,
 ):
     sel_ref = refs[0] if len(refs) == 3 else None
     dq_ref, dq_acc = refs[-2:]
@@ -601,30 +804,41 @@ def _bwd_dq_kernel(
         )
 
     segment_refs = (qseg_ref, kseg_ref) if use_segments else None
-    if diag_tiles > 1:
-        # as in the forward kernel: the one non-interior block, in row bands
-        @pl.when(iq == ik)
-        def _compute_diagonal():
-            for rows, keys, mask in _diagonal_tiles(
-                    diag_tiles, bq, iq * bq, seq_len, segment_refs):
-                _update(_selected(sel_ref, ik, bq, bk, mask, rows, keys),
-                        rows, keys)
+    if window is not None:
+        # as in the forward kernel: step ``ik`` names the key block
+        # ``window_back - ik`` behind the query block's own
+        t, behind = _window_steps(window, bq, window_back + 1)
+        for back in behind:
+            @pl.when((ik == window_back - back) & (iq >= back))
+            def _compute_band(back=back):
+                for rows, keys, mask in _window_tiles(
+                        t, bq, back * bq, window, segment_refs):
+                    _update(mask, rows, keys)
     else:
-        @pl.when(needed & ~interior)
-        def _compute_masked():
-            q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-            mask = k_pos < seq_len
-            if causal:
-                mask &= q_pos >= k_pos
-            if use_segments:
-                mask &= _segment_mask(qseg_ref, kseg_ref)
-            _update(_selected(sel_ref, ik, bq, bk, mask))
+        if diag_tiles > 1:
+            # as in the forward kernel: the one non-interior block, in row bands
+            @pl.when(iq == ik)
+            def _compute_diagonal():
+                for rows, keys, mask in _diagonal_tiles(
+                        diag_tiles, bq, iq * bq, seq_len, segment_refs):
+                    _update(_selected(sel_ref, ik, bq, bk, mask, rows, keys),
+                            rows, keys)
+        else:
+            @pl.when(needed & ~interior)
+            def _compute_masked():
+                q_pos, k_pos = _block_positions(iq, ik, bq, bk)
+                mask = k_pos < seq_len
+                if causal:
+                    mask &= q_pos >= k_pos
+                if use_segments:
+                    mask &= _segment_mask(qseg_ref, kseg_ref)
+                _update(_selected(sel_ref, ik, bq, bk, mask))
 
-    @pl.when(needed & interior)
-    def _compute_interior():
-        _update(_selected(
-            sel_ref, ik, bq, bk,
-            _segment_mask(qseg_ref, kseg_ref) if use_segments else None))
+        @pl.when(needed & interior)
+        def _compute_interior():
+            _update(_selected(
+                sel_ref, ik, bq, bk,
+                _segment_mask(qseg_ref, kseg_ref) if use_segments else None))
 
     @pl.when(ik == nk - 1)
     def _finalize():
@@ -652,12 +866,15 @@ def _bwd_dkv_kernel(
     exp_dtype: str = "float32",
     causal: bool = True,
     diag_tiles: int = 1,
+    window: int | None = None,
 ):
     sel_ref = refs[0] if len(refs) == 5 else None
     dk_ref, dv_ref, dk_acc, dv_acc = refs[-4:]
     ik, j = pl.program_id(2), pl.program_id(3)
     n_inner = pl.num_programs(3)   # = group * n_q_blocks
-    iq = j % n_q_blocks            # q block within the current group member
+    # q block within the current group member; under a window ``n_q_blocks``
+    # is the blocks a key block serves and ``iq`` how far AHEAD of it
+    iq = j % n_q_blocks
     bk = k_ref.shape[2]
     bq = q_ref.shape[2]
     edt = jnp.dtype(exp_dtype)
@@ -712,38 +929,50 @@ def _bwd_dkv_kernel(
         )
 
     segment_refs = (qseg_ref, kseg_ref) if use_segments else None
-    masked = needed & ~interior
-    tail = seq_len % bq != 0
-    if diag_tiles > 1:
-        # the diagonal block in key bands (the accumulators are per key),
-        # each against the queries at or under its own diagonal tile
-        masked &= iq != ik
+    if window is not None:
+        # inner step ``iq`` of a group member names the q block ``iq`` ahead
+        # of the key block: static, so the tiles and the masks are.  Key
+        # bands, each against the query tiles its window reaches
+        t, before = _window_steps(window, bk, n_q_blocks, key_bands=True)
+        for ahead in before:
+            @pl.when((iq == ahead) & (ik + ahead < pl.num_programs(2)))
+            def _compute_band(ahead=ahead):
+                for rows, keys, mask in _window_tiles(
+                        t, bk, ahead * bk, window, segment_refs, key_bands=True):
+                    _update(mask, rows, keys)
+    else:
+        masked = needed & ~interior
+        tail = seq_len % bq != 0
+        if diag_tiles > 1:
+            # the diagonal block in key bands (the accumulators are per key),
+            # each against the queries at or under its own diagonal tile
+            masked &= iq != ik
 
-        @pl.when(iq == ik)
-        def _compute_diagonal():
-            for rows, keys, mask in _diagonal_tiles(
-                    diag_tiles, bk, ik * bk, seq_len, segment_refs, key_bands=True):
-                _update(_selected(sel_ref, ik, bq, bk, mask, rows, keys),
-                        rows, keys)
+            @pl.when(iq == ik)
+            def _compute_diagonal():
+                for rows, keys, mask in _diagonal_tiles(
+                        diag_tiles, bk, ik * bk, seq_len, segment_refs, key_bands=True):
+                    _update(_selected(sel_ref, ik, bq, bk, mask, rows, keys),
+                            rows, keys)
 
-    # with sub-tiled diagonals only a tail leaves whole blocks to mask: the
-    # last q block's padded queries, against every key block left of it
-    if diag_tiles == 1 or tail:
-        @pl.when(masked)
-        def _compute_masked():
-            q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-            mask = q_pos < seq_len
-            if causal:
-                mask &= q_pos >= k_pos
-            if use_segments:
-                mask &= _segment_mask(qseg_ref, kseg_ref)
-            _update(_selected(sel_ref, ik, bq, bk, mask))
+        # with sub-tiled diagonals only a tail leaves whole blocks to mask: the
+        # last q block's padded queries, against every key block left of it
+        if diag_tiles == 1 or tail:
+            @pl.when(masked)
+            def _compute_masked():
+                q_pos, k_pos = _block_positions(iq, ik, bq, bk)
+                mask = q_pos < seq_len
+                if causal:
+                    mask &= q_pos >= k_pos
+                if use_segments:
+                    mask &= _segment_mask(qseg_ref, kseg_ref)
+                _update(_selected(sel_ref, ik, bq, bk, mask))
 
-    @pl.when(needed & interior)
-    def _compute_interior():
-        _update(_selected(
-            sel_ref, ik, bq, bk,
-            _segment_mask(qseg_ref, kseg_ref) if use_segments else None))
+        @pl.when(needed & interior)
+        def _compute_interior():
+            _update(_selected(
+                sel_ref, ik, bq, bk,
+                _segment_mask(qseg_ref, kseg_ref) if use_segments else None))
 
     @pl.when(j == n_inner - 1)
     def _finalize():
@@ -755,8 +984,13 @@ def _flash_backward(
     q, k, v, segment_ids, out, lse, g,
     *, block_q: int, block_k: int, interpret: bool, use_segments: bool = True,
     exp_dtype: str = "float32", causal: bool = True, dlse=None,
-    kv_segment_ids=None, selection=None,
+    kv_segment_ids=None, selection=None, sink=None, window=None,
 ):
+    """``(dq, dk, dv, dsink)``; ``dsink`` None where no sink is given.  The
+    kernels take no sink: ``lse`` holds it, so ``p = exp(s - lse)`` and ``ds =
+    p (dp - delta)`` are the sums' own, and its gradient ``-sum_t exp(b_h -
+    lse_t) delta_t`` is a few per-row terms out here, which the compiler
+    drops where the leaf is frozen."""
     b, s, h, d = q.shape
     hkv, d_v = k.shape[2], v.shape[3]
     group = h // hkv
@@ -764,6 +998,7 @@ def _flash_backward(
 
     bq = min(block_q, s)
     bk = min(block_k, s)
+    _check_window(window, bq, bk, causal, selection)
     q_p, k_p, v_p, seg_p, kseg_p, s_pad = _pad_inputs(
         q, k, v, segment_ids, bq, bk, kv_segment_ids)
     g_p = jnp.pad(g, [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]) if s_pad != s else g
@@ -787,6 +1022,11 @@ def _flash_backward(
         # which is exactly ds = p·(dp − (delta − dlse)). Folding it into
         # delta means the backward kernels need no change at all.
         delta = delta - dlse
+    dsink = None
+    if sink is not None:
+        with jax.named_scope("attn_sink"):
+            share = jnp.exp(sink.astype(jnp.float32)[None, :, None, None] - lse)
+            dsink = -jnp.sum(share * delta, axis=(0, 2, 3)).astype(sink.dtype)
 
     seg3 = seg_p[:, None, :]  # (B, 1, S_pad) — see _flash_forward
     kseg3 = kseg_p[:, None, :]
@@ -798,6 +1038,15 @@ def _flash_backward(
 
     kv = functools.partial(_kv_block_index, bq=bq, bk=bk, causal=causal)
     qb = functools.partial(_q_block_index, nq=nq, bq=bq, bk=bk, causal=causal)
+    # the inner axes: every key block (dQ), every q block a group member (dK/dV)
+    dq_inner, dkv_inner, dq_kw, dkv_kw = nk, nq, {}, {}
+    if window is not None:
+        back = min(_window_blocks_back(window, bq), nq - 1)
+        dq_inner = dkv_inner = back + 1
+        dq_kw = dict(window=window, window_back=back)
+        dkv_kw = dict(window=window)
+        kv = functools.partial(_window_kv_block_index, back=back)
+        qb = functools.partial(_window_q_block_index, nq=nq, ahead=back + 1)
     sel_operands, dq_sel_specs, dkv_sel_specs = (), [], []
     if selection is not None:
         selection, block, words_of = _selection_operand(selection, s_pad, bq, bk)
@@ -810,8 +1059,8 @@ def _flash_backward(
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, seq_len=s, scale=scale,
                           use_segments=use_segments, exp_dtype=exp_dtype,
-                          causal=causal, diag_tiles=diag_tiles),
-        grid=(b, h, nq, nk),
+                          causal=causal, diag_tiles=diag_tiles, **dq_kw),
+        grid=(b, h, nq, dq_inner),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, iq, ik: (ib, ih // group, kv(iq, ik), 0)),
@@ -830,7 +1079,7 @@ def _flash_backward(
             "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "flash_swa_bwd_dq",
     )(qt, kt, vt, dot, lse, delta, seg3, kseg3, *sel_operands)
 
     # dK/dV: grid over KV heads; each instance owns one key block and the
@@ -838,29 +1087,29 @@ def _flash_backward(
     # accumulates in VMEM scratch — no per-q-head f32 partials in HBM.
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, n_q_blocks=nq, seq_len=s, scale=scale,
+            _bwd_dkv_kernel, n_q_blocks=dkv_inner, seq_len=s, scale=scale,
             use_segments=use_segments, exp_dtype=exp_dtype, causal=causal,
-            diag_tiles=diag_tiles,
+            diag_tiles=diag_tiles, **dkv_kw,
         ),
-        grid=(b, hkv, nk, group * nq),
+        grid=(b, hkv, nk, group * dkv_inner),
         in_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
             pl.BlockSpec((1, 1, bk, d_v), lambda ib, ih, ik, j: (ib, ih, ik, 0)),
             pl.BlockSpec(
                 (1, 1, bq, d),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // dkv_inner, qb(ik, j), 0),
             ),
             pl.BlockSpec(
                 (1, 1, bq, d_v),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // dkv_inner, qb(ik, j), 0),
             ),
             pl.BlockSpec(
                 (1, 1, bq, 1),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // dkv_inner, qb(ik, j), 0),
             ),
             pl.BlockSpec(
                 (1, 1, bq, 1),
-                lambda ib, ih, ik, j: (ib, ih * group + j // nq, qb(ik, j), 0),
+                lambda ib, ih, ik, j: (ib, ih * group + j // dkv_inner, qb(ik, j), 0),
             ),
             pl.BlockSpec((1, 1, bk), lambda ib, ih, ik, j: (ib, 0, ik)),
             pl.BlockSpec((1, 1, bq), lambda ib, ih, ik, j: (ib, 0, qb(ik, j))),
@@ -882,13 +1131,13 @@ def _flash_backward(
             "parallel", "parallel", "parallel", "arbitrary"
         ),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "flash_swa_bwd_dkv",
     )(kt, vt, qt, dot, lse, delta, kseg3, seg3, *sel_operands)
 
     dq = dq.transpose(0, 2, 1, 3)[:, :s]
     dk = dk.transpose(0, 2, 1, 3)[:, :s].astype(k.dtype)
     dv = dv.transpose(0, 2, 1, 3)[:, :s].astype(v.dtype)
-    return dq, dk, dv
+    return dq, dk, dv, dsink
 
 
 # ---------------------------------------------------------------------------
@@ -906,24 +1155,27 @@ def _flash_backward(
 # delta (see _flash_backward), keeping one backward implementation.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
-def _flash_attention_lse(q, k, v, segment_ids, kv_segment_ids, selection,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
+def _flash_attention_lse(q, k, v, segment_ids, kv_segment_ids, selection, sink,
                          block_q, block_k, interpret, use_segments, exp_dtype,
-                         causal):
+                         causal, window):
     out, lse = _flash_forward(
         q, k, v, segment_ids, block_q=block_q, block_k=block_k,
         interpret=interpret, use_segments=use_segments, exp_dtype=exp_dtype,
         causal=causal, kv_segment_ids=kv_segment_ids, selection=selection,
+        sink=sink, window=window,
     )
     return out, lse[:, :, : q.shape[1]]
 
 
-def _flash_lse_fwd(q, k, v, segment_ids, kv_segment_ids, selection, block_q,
-                   block_k, interpret, use_segments, exp_dtype, causal):
+def _flash_lse_fwd(q, k, v, segment_ids, kv_segment_ids, selection, sink,
+                   block_q, block_k, interpret, use_segments, exp_dtype, causal,
+                   window):
     out, lse = _flash_forward(
         q, k, v, segment_ids, block_q=block_q, block_k=block_k,
         interpret=interpret, use_segments=use_segments, exp_dtype=exp_dtype,
         causal=causal, kv_segment_ids=kv_segment_ids, selection=selection,
+        sink=sink, window=window,
     )
     # Named so a remat policy (models/llama.py remat_policy_fn, e.g.
     # "mlp_flash") can SAVE these residuals: under plain per-layer remat the
@@ -935,27 +1187,28 @@ def _flash_lse_fwd(q, k, v, segment_ids, kv_segment_ids, selection, block_q,
     res_out = checkpoint_name(out, "flash_out")
     res_lse = checkpoint_name(lse, "flash_lse")
     return (out, lse[:, :, : q.shape[1]]), (
-        q, k, v, segment_ids, kv_segment_ids, selection, res_out, res_lse,
+        q, k, v, segment_ids, kv_segment_ids, selection, sink, res_out, res_lse,
     )
 
 
 def _flash_lse_bwd(block_q, block_k, interpret, use_segments, exp_dtype,
-                   causal, residuals, g):
+                   causal, window, residuals, g):
     g_out, g_lse = g
-    q, k, v, segment_ids, kv_segment_ids, selection, out, lse = residuals
+    q, k, v, segment_ids, kv_segment_ids, selection, sink, out, lse = residuals
     s_pad = lse.shape[2]
     dlse = g_lse.astype(jnp.float32)
     if dlse.shape[2] != s_pad:
         dlse = jnp.pad(
             dlse, [(0, 0), (0, 0), (0, s_pad - dlse.shape[2]), (0, 0)]
         )
-    dq, dk, dv = _flash_backward(
+    dq, dk, dv, dsink = _flash_backward(
         q, k, v, segment_ids, out, lse, g_out,
         block_q=block_q, block_k=block_k, interpret=interpret,
         use_segments=use_segments, exp_dtype=exp_dtype, causal=causal,
         dlse=dlse, kv_segment_ids=kv_segment_ids, selection=selection,
+        sink=sink, window=window,
     )
-    return dq, dk, dv, None, None, None
+    return dq, dk, dv, None, None, None, dsink
 
 
 _flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -969,6 +1222,8 @@ def flash_attention_with_lse(
     segment_ids: jax.Array | None = None,
     kv_segment_ids: jax.Array | None = None,
     selection: jax.Array | None = None,
+    sink: jax.Array | None = None,
+    window: int | None = None,
     causal: bool = True,
     block_q: int | None = None,
     block_k: int | None = None,
@@ -984,7 +1239,12 @@ def flash_attention_with_lse(
     sequence shard. ``selection`` (``ops/attention.py::pack_selection``,
     (B, S, W) int32) cuts every query, in all its heads, to its own set of
     keys: one more operand of the three kernels, which mask by it; with none
-    they trace the bodies they always did.  Both outputs are differentiable.
+    they trace the bodies they always did.  ``window`` (a static count of keys:
+    key ``s`` serves query ``t`` iff ``t - window < s <= t``) and ``sink``
+    ((H,) float32, a learned logit a head that joins each row's normaliser and
+    logsumexp and no output sum; differentiable) are the module docstring's;
+    with neither the kernels trace the bodies they always did.  Both outputs
+    are differentiable.
 
     Unset ``block_q``/``block_k``/``exp_dtype`` resolve to the measured TPU
     defaults (see :func:`_resolve_tuning`).
@@ -1001,8 +1261,8 @@ def flash_attention_with_lse(
         kv_segment_ids = segment_ids
     return _flash_attention_lse(
         q, k, v, segment_ids.astype(jnp.int32),
-        kv_segment_ids.astype(jnp.int32), selection, block_q, block_k,
-        interpret, use_segments, exp_dtype, causal,
+        kv_segment_ids.astype(jnp.int32), selection, sink, block_q, block_k,
+        interpret, use_segments, exp_dtype, causal, window,
     )
 
 
@@ -1013,12 +1273,15 @@ def flash_attention(
     *,
     segment_ids: jax.Array | None = None,
     selection: jax.Array | None = None,
+    sink: jax.Array | None = None,
+    window: int | None = None,
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
     exp_dtype: str | None = None,
 ) -> jax.Array:
-    """Causal GQA flash attention. Shapes as ``ops.attention.causal_attention``.
+    """Causal GQA flash attention. Shapes as ``ops.attention.causal_attention``;
+    ``window`` and ``sink`` as :func:`flash_attention_with_lse` takes them.
 
     Unset ``block_q``/``block_k``/``exp_dtype`` resolve to the defaults
     (1024-token blocks; exp dtype follows the input dtype —
@@ -1028,8 +1291,8 @@ def flash_attention(
     ``_resolve_tuning`` from what it can observe (head size, row length).
     Blocks are capped to S at call time."""
     out, _ = flash_attention_with_lse(
-        q, k, v, segment_ids=segment_ids, selection=selection,
-        block_q=block_q, block_k=block_k, interpret=interpret,
+        q, k, v, segment_ids=segment_ids, selection=selection, sink=sink,
+        window=window, block_q=block_q, block_k=block_k, interpret=interpret,
         exp_dtype=exp_dtype,
     )
     return out

@@ -132,6 +132,10 @@ LLAMA_RULES = PartitionRules(
         (r"fc2_latent_proj/kernel", P(None, Ax.FSDP)),
         # the selection bias: one number an expert, whole everywhere
         (r"router/bias", P()),
+        # a window layer's sink (models/llama.py Attention): one float32 logit
+        # a query head, whole everywhere — the flash call's shard_map splits
+        # it with the heads (ops/attention.py)
+        (r"attn/sink/bias", P()),
         # QLoRA int4 scales: (in/block, out) — the block dim is tiny, keep it
         # whole and shard only the feature dim (must precede the kernel rules,
         # which would otherwise also match "kernel_scales")

@@ -440,7 +440,9 @@ class Trainer:
 
         def cast(path, x):
             name = str(path[-1]) if path else ""
-            if "scales" in name or x.dtype != jnp.float32:
+            # a window layer's sink stays float32: a softmax logit, 64 numbers
+            sink = len(path) > 1 and "sink" in str(path[-2])
+            if "scales" in name or x.dtype != jnp.float32 or sink:
                 return x
             return x.astype(dt)
 
@@ -1665,7 +1667,9 @@ class Trainer:
         resolves to (with the flash kernels, how much score area they compute
         over what the causal triangle needs), the row tile of a dropless
         expert model's grouped products where the Pallas kernel runs, a pattern
-        model's string, layers by kind, latent width and experts held, a hybrid
+        model's string, layers by kind, latent width and experts held (a
+        pattern of attention kinds: its string, layers by kind, window, sink
+        layers and the window kernels' work over their need), a hybrid
         model's state-space mixers (layers, chunks a row, state bytes a row),
         which adapted projections carry their adapter inside the base
         product, and the bytes the freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
@@ -1707,7 +1711,30 @@ class Trainer:
         if kinds:
             attrs["dsa_full_layers"] = kinds.count("full")
             attrs["dsa_shared_layers"] = kinds.count("shared")
-        if cfg.layer_pattern:
+        from ..models.llama import BLOCK_KINDS
+
+        if set(cfg.layer_pattern) & set(BLOCK_KINDS):
+            # a pattern of whole blocks by their attention's kind: the string
+            # as built, the layers of each kind, the window's keys, the
+            # layers that hold a sink, the experts held and, with the flash
+            # kernels, the score area a window call computes over its need
+            pattern = cfg.layer_pattern
+            attrs["attention_pattern"] = pattern
+            attrs["attention_layers_by_kind"] = {
+                kind: pattern.count(kind) for kind in sorted(set(pattern))}
+            attrs["attention_window"] = cfg.sliding_window
+            attrs["attention_sink_layers"] = (
+                pattern.count("W") if cfg.window_sink else 0)
+            if cfg.n_experts:
+                attrs["moe_experts_held"] = (
+                    cfg.experts_held or (0, cfg.n_experts))[1]
+            if self.attention_impl == "pallas" and "W" in pattern:
+                from ..ops.pallas.flash_attention import window_work_over_need
+
+                attrs["flash_window_work_over_need"] = window_work_over_need(
+                    self.cfg.seq_len, cfg.sliding_window,
+                    head_widths=cfg.head_widths)
+        elif cfg.layer_pattern:
             # a pattern model: the string as built, its layers by kind, the
             # width of the latent its experts live in and the experts held
             attrs["layer_pattern"] = cfg.layer_pattern

@@ -195,6 +195,32 @@ SUPERSEDED = {
          "pins this table's length at sixteen",
          "test_the_superseded_pins_are_twenty_two_and_each_has_its_replacement"),
     )},
+    # ... and the five of ``test_benchmark_nemotron_h.py`` that pin PR 40's
+    # six-cell manifest, to which ISSUE 43 appends a seventh cell, a sixth
+    # configuration, five per-layer entries and its cell's name in nineteen
+    # accepted ones
+    **{"tests/benchmarks/test_benchmark_nemotron_h.py::" + pin: (
+        why, "tests/benchmarks/test_benchmark_mimo_v2.py::" + held_by)
+       for pin, why, held_by in (
+        ("test_the_real_manifest_has_its_six_cells_and_no_metric_by_default",
+         "pins PR 40's six cells; ISSUE 43 adds a seventh",
+         "test_the_real_manifest_has_its_seven_cells_and_no_metric_by_default"),
+        ("test_manifest_registers_and_loads_every_accepted_metric",
+         "pins every entry's cells to PR 40's six; ISSUE 43 appends its cell "
+         "to the neutral ones, the flash kernels' share, the loop's plumbing "
+         "and the expert layer's two",
+         "test_manifest_registers_and_loads_every_accepted_metric"),
+        ("test_manifest_registers_and_loads_every_start_up_metric",
+         "pins the five start-up entries' cells to PR 40's six; the seventh "
+         "cell reports them too",
+         "test_manifest_registers_and_loads_every_start_up_metric"),
+        ("test_the_accepted_entries_stand_first_and_the_new_ones_last",
+         "pins the lists' ends; ISSUE 43 appends its entries",
+         "test_the_accepted_entries_stand_first_and_the_new_ones_last"),
+        ("test_the_superseded_pins_are_twenty_two_and_each_has_its_replacement",
+         "pins this table's length at twenty-two",
+         "test_the_superseded_pins_are_twenty_seven_and_each_has_its_replacement"),
+    )},
     "tests/benchmarks/test_benchmark_mla_dsa_moe.py::"
     "test_cells_report_the_neutral_metrics_and_their_own_and_no_count_that_overstates": (
         "pins the two expert cells' sets of per-layer metrics; ISSUE 38 adds "

@@ -125,6 +125,78 @@ def test_flash_forward_and_grad_compile_for_v5e(v5e, shape, segments):
     assert _custom_calls(compiled) == 3  # forward + dQ + dK/dV
 
 
+#: the window/full configuration's two attention calls as published (one row
+#: of 16,384, 64 query heads of 192 beside v heads of 128): over 8 key/value
+#: heads under a window of 128 keys beside a sink, over 4 with every earlier key
+WINDOW_SHAPES = {
+    "swa-w128-sink": (1, 16384, 64, 8, 192, 128, 128, True),
+    "gqa192-full": (1, 16384, 64, 4, 192, 128, None, False),
+    # a window past a block: three inner steps, whole blocks between the edges
+    "swa-w2500": (1, 8192, 32, 8, 128, 128, 2500, False),
+}
+
+
+@pytest.mark.parametrize("shape, segments", [
+    ("swa-w128-sink", False), ("swa-w128-sink", True), ("gqa192-full", False),
+    ("swa-w2500", True)], ids=["window-plain", "window-segments", "full-plain",
+                               "window-2500-segments"])
+def test_window_kernels_compile_for_v5e_at_published_shapes_forward_and_grad(
+        v5e, shape, segments):
+    """The three kernel bodies with a static window and a sink operand are
+    three Mosaic calls under their own names (``flash_swa_*``); the full
+    layers' call at the same widths keeps the names it always had."""
+    b, s, h, hkv, d, dv, window, with_sink = WINDOW_SHAPES[shape]
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((b, s, h, d), BF16, sharding=one)
+    k = jax.ShapeDtypeStruct((b, s, hkv, d), BF16, sharding=one)
+    v = jax.ShapeDtypeStruct((b, s, hkv, dv), BF16, sharding=one)
+    seg = jax.ShapeDtypeStruct((b, s), jnp.int32, sharding=one)
+    sink = jax.ShapeDtypeStruct((h,), jnp.float32, sharding=one)
+
+    def loss(q, k, v, sink, seg):
+        out = flash_attention(
+            q, k, v, segment_ids=seg if segments else None, window=window,
+            sink=sink if with_sink else None, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    wrt = (0, 1, 2, 3) if with_sink else (0, 1, 2)
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=wrt)).lower(
+        q, k, v, sink, seg).compile()
+    assert _custom_calls(compiled) == 3
+    text = compiled.as_text()
+    names = ("flash_swa_fwd", "flash_swa_bwd_dq", "flash_swa_bwd_dkv")
+    if window is None:
+        assert not any(name in text for name in names)
+        assert all(n in text for n in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"))
+    else:
+        assert all(name in text for name in names)
+
+
+def test_a_call_with_no_window_and_no_sink_traces_the_kernels_it_always_did():
+    """``window=None`` and no sink: the jaxpr of the call and its gradient —
+    kernel bodies, grids, index maps — holds no trace of the second frontier
+    (no ``flash_swa`` name, the causal grid of every key block), and a sink
+    alone adds ONE operand to the forward kernel and none to the backward's."""
+    q = jnp.zeros((1, 2048, 4, 64), BF16)
+    kv = jnp.zeros((1, 2048, 2, 64), BF16)
+
+    def grad_text(**kw):
+        def loss(q, k, v, sink):
+            out = flash_attention(q, k, v, interpret=False, **(
+                {"sink": sink} if kw.get("sink") else {}), window=kw.get("window"))
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(
+            q, kv, kv, jnp.zeros((4,), jnp.float32)))
+
+    plain = grad_text()
+    assert "flash_swa" not in plain and plain.count("pallas_call") == 3
+    assert "grid=(1, 4, 2, 2)" in plain            # every key block of two
+    sunk = grad_text(sink=True)
+    assert "flash_swa" not in sunk and "name=flash_fwd" in sunk
+    windowed = grad_text(window=128)
+    assert windowed.count("flash_swa_") >= 3 and "name=flash_fwd" not in windowed
+
+
 # ---------------------------------------------------------------------------
 # the state-space mixer (models/ssm.py): its recurrence is two Mosaic kernels
 # (ops/pallas/ssd_scan.py), the rest plain jnp the compiler must take

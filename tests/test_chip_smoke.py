@@ -25,7 +25,7 @@ import pytest
 REPO = Path(__file__).resolve().parents[1]
 SMOKE = REPO / "chip_smoke.py"
 
-PHASES = ["grouped-parity", "ssd-parity", "latent-share-parity", "lora-parity", "server", "submit", "train", "promote",
+PHASES = ["grouped-parity", "ssd-parity", "window-parity", "latent-share-parity", "lora-parity", "server", "submit", "train", "promote",
           "serve-load", "serve-generate", "shutdown", "paged-parity",
           "compile-cache", "total"]
 
@@ -114,6 +114,17 @@ def test_tiny_rehearsal_runs_every_phase_and_is_not_a_chip_pass():
     # off the chip the chooser keeps the plain form, and says so
     assert ssd["ssm_scan_impl"] == "xla"
     assert ssd["errs_by_shape"]["44x4x8x2x6x8"]["heads_per_block"] == 0
+    # the flash kernels under a window and beside a sink, interpreted here,
+    # against the XLA form: values and the gradients of q, k, v and the sink
+    window = detail["window-parity"]
+    assert window["compiled"] is False
+    assert window["worst_err"] <= window["tolerance"]
+    assert set(window["errs_by_shape"]) == {"40x4x2x24x16x5x1", "40x4x1x24x16x0x0"}
+    assert set(window["errs_by_shape"]["40x4x2x24x16x5x1"]) == {
+        "value", "dq", "dk", "dv", "dsink"}
+    assert set(window["errs_by_shape"]["40x4x1x24x16x0x0"]) == {
+        "value", "dq", "dk", "dv"}
+    assert list(window["flash_window_work_over_need"]) == ["40x4x2x24x16x5x1"]
     # a held share of a latent expert layer (its grouped path, two products
     # an expert) against the masked plain form: value and the input's
     # gradient, and the same pairs on both sides
@@ -157,8 +168,8 @@ def test_a_failed_phase_exits_nonzero_and_prints_no_result(tmp_path):
     assert "unknown device 'cpu-test'" in out.stderr
     phases = [l for l in out.stdout.splitlines() if l.startswith("phase ")]
     assert [p.split(":")[0] for p in phases] == [
-        "phase grouped-parity", "phase ssd-parity", "phase latent-share-parity",
-        "phase lora-parity", "phase server"]
+        "phase grouped-parity", "phase ssd-parity", "phase window-parity",
+        "phase latent-share-parity", "phase lora-parity", "phase server"]
     assert _result_lines(out.stdout) == []
     # and nothing it started is left behind
     leftovers = subprocess.run(
@@ -315,6 +326,32 @@ def test_full_mode_checks_the_chunked_scan_at_both_mixer_cells_widths(smoke):
     snippet = smoke.SSD_PARITY_SNIPPET
     assert "recurrence" in snippet and "ssd_scan(x, dt, a, b, c, d" in snippet
     assert "ssd_chunked" not in snippet and "ssm_scan_impl" in snippet
+
+
+def test_full_mode_checks_both_attention_kinds_at_the_published_shapes(smoke):
+    """(rows, query heads, key/value heads, q/k width, v width, window, sink?):
+    the window/full configuration's two attention calls as published — one row
+    of 16,384, 64 query heads of 192 beside v heads of 128, over 8 key/value
+    heads under a window of 128 keys beside a sink, over 4 with every earlier
+    key and none — the kernels against the XLA form a head at a time."""
+    import json as _json
+
+    conf = _json.loads(
+        (REPO / "benchmarks/configs/mimo-v2-flash-lora.json").read_text())
+    window, full = smoke.mode_config(tiny=False, seed=0)["window_shapes"]
+    assert window == [16384, conf["num_attention_heads"],
+                      conf["swa_num_key_value_heads"], conf["swa_head_dim"],
+                      conf["swa_v_head_dim"], conf["sliding_window"],
+                      int(conf["add_swa_attention_sink_bias"])]
+    assert full == [16384, conf["num_attention_heads"],
+                    conf["num_key_value_heads"], conf["head_dim"],
+                    conf["v_head_dim"], 0,
+                    int(conf["add_full_attention_sink_bias"])]
+    assert smoke.WINDOW_TOL == 2 ** -5
+    snippet = smoke.WINDOW_PARITY_SNIPPET
+    assert "flash_attention(q, k, v, window=window" in snippet
+    assert "xla_causal_attention" in snippet and "window_work_over_need" in snippet
+    assert '"dsink"' in snippet and "jax.checkpoint" in snippet
 
 
 def test_full_mode_checks_a_held_share_at_the_pattern_cells_widths(smoke):
