@@ -26,6 +26,7 @@ compute plane that replaces it.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -38,17 +39,26 @@ from ..ops.attention import causal_attention, pack_selection, selection_shape
 from .lora import LoRAConfig, LoRADense
 
 
-def remat_policy_fn(name: str, also: tuple[str, ...] = ()):
-    """Rematerialisation policy for per-layer ``nn.remat``/``jax.checkpoint``.
+#: names every remat policy keeps: integer data the backward pass reads and
+#: cannot differentiate — the indexer's selection, the expert layer's routing
+#: (``models/moe.py::_kept``).  A name no layer of a model carries saves
+#: nothing
+ALWAYS_KEPT = ("dsa_selection", "moe_routing")
+
+
+@functools.lru_cache(maxsize=None)
+def remat_policy_fn(name: str):
+    """Rematerialisation policy for per-layer ``nn.remat``/``jax.checkpoint``
+    — ONE function object a name, so that what JAX caches by a policy's
+    identity is found again by the next model built.
 
     ``"full"`` recomputes the whole layer forward in the backward pass (lowest
     HBM, ~2N extra FLOPs/token).  The named policies keep selected activation
     tensors (``checkpoint_name`` marks in ``Attention``/``MLP``) so the
     backward pass skips recomputing the matmuls that produced them — the
     standard TPU HBM-for-FLOPs dial.  Saved bytes per layer row grow in the
-    order attn < wide < matmuls; pick the biggest that fits HBM.  ``also``:
-    names a model keeps under every policy (the indexer's selection: integer
-    data the backward pass reads and cannot differentiate).
+    order attn < wide < matmuls; pick the biggest that fits HBM.  Every
+    policy keeps ``ALWAYS_KEPT`` besides.
     """
     saveable = {
         "full": (),
@@ -83,9 +93,8 @@ def remat_policy_fn(name: str, also: tuple[str, ...] = ()):
             f"unknown remat_policy {name!r}; one of "
             f"{['none', *saveable]}"
         )
-    if name == "full" and not also:
-        return jax.checkpoint_policies.nothing_saveable
-    return jax.checkpoint_policies.save_only_these_names(*saveable[name], *also)
+    return jax.checkpoint_policies.save_only_these_names(
+        *saveable[name], *ALWAYS_KEPT)
 
 
 #: a letter of ``LlamaConfig.layer_pattern`` -> the module name of the ONE
@@ -1160,8 +1169,8 @@ class MLAttention(nn.Module):
                         positions, segment_ids),
                     lambda: selection)
         # integer data with no cotangent: kept for the backward pass under
-        # every remat policy (``remat_policy_fn(..., also=)``), so the indexer
-        # and its top-k run once a step
+        # every remat policy (``ALWAYS_KEPT``), so the indexer and its top-k
+        # run once a step
         selection = checkpoint_name(selection, "dsa_selection")
         self.sow("dsa_stats", "selected_keys_mean",
                  jax.lax.population_count(selection).sum() / (x.shape[0] * x.shape[1]))
@@ -1552,8 +1561,7 @@ class LlamaForCausalLM(nn.Module):
         # unrolled layers, into the scanned stack and along its carry
         kinds = cfg.indexer_kinds()
         selection = None
-        policy = remat_policy_fn(
-            cfg.remat_policy, ("dsa_selection",) if kinds else ())
+        policy = remat_policy_fn(cfg.remat_policy)
         # args 4/5 = deterministic/decode (0 is self): static bools.  Outside
         # a scan the compiler may merge a layer's recomputation with its
         # forward pass — that is, keep attention's residuals (q, k, v, output)

@@ -42,6 +42,14 @@ TPU-native design:
     ``_unsort``): the transpose of a row gather is a scatter-add, which a
     TPU serialises.
 
+- the layer ROUTES ONCE A STEP: what routing makes that is integer — the
+  chosen experts, the sort of the pairs and its inverse, the rows a pair and
+  a pair the rows of a held share, an expert's pairs — is marked
+  ``moe_routing`` (``_kept``) and ``models/llama.py::remat_policy_fn`` keeps
+  that name under every policy, so a layer's replay in the backward pass
+  reads them and holds no ``top_k``, no sort and no scatter.  The scores and
+  the weights carry a cotangent and are the remat policy's business;
+
 - inside a scanned stack the dropless layer's three grouped products read
   the layer's experts IN PLACE in the stacked leaf ``(L, E, d, f)``
   (``_grouped_dot(..., layer)``: ``L·E`` groups, all empty but the layer's
@@ -98,6 +106,15 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+
+def _kept(routing: jax.Array) -> jax.Array:
+    """Integer data of the routing, which the backward pass reads and cannot
+    differentiate: kept for it under every remat policy
+    (``models/llama.py::remat_policy_fn``) — a few hundred KB a layer where
+    replaying it is a ``top_k``, a sort and two scatters."""
+    return checkpoint_name(routing, "moe_routing")
 
 
 class _StackedKernel(nn.Module):
@@ -453,16 +470,17 @@ def _held_pass(x, top_w, kernels, layer, order, sizes, start, bound: int):
     t, k = top_w.shape
     with jax.named_scope("moe_dispatch"):
         mine = sizes.sum()
-        sizes = _sizes_in_rows(sizes, start, bound)
+        sizes = _kept(_sizes_in_rows(sizes, start, bound))
         pair_of_row = jax.lax.dynamic_slice(order, (start,), (bound,))
         row = jnp.arange(bound, dtype=jnp.int32)
         live = row < mine - start
         # the row a pair landed in; ``bound`` (a zero row) for a pair of an
         # expert held elsewhere or of another pass
-        row_of_pair = jnp.full((t * k,), bound, jnp.int32).at[pair_of_row].set(
-            jnp.where(live, row, bound), mode="drop", unique_indices=True
-        ).reshape(t, k)
-        pair_of_row = jnp.minimum(pair_of_row, t * k - 1)
+        row_of_pair = _kept(
+            jnp.full((t * k,), bound, jnp.int32).at[pair_of_row].set(
+                jnp.where(live, row, bound), mode="drop", unique_indices=True
+            ).reshape(t, k))
+        pair_of_row = _kept(jnp.minimum(pair_of_row, t * k - 1))
         rows = _held_rows_of_tokens(x, pair_of_row // k, row_of_pair)
     with jax.named_scope("experts"):
         # rows behind the last group are covered by no group: the compiler's
@@ -588,12 +606,12 @@ class MoEMLP(nn.Module):
             scores = (jax.nn.sigmoid(logits) if self.scoring == "sigmoid"
                       else jax.nn.softmax(logits, axis=-1))         # (T, E)
             select = scores if bias is None else scores + bias.astype(jnp.float32)
-            _, top_idx = jax.lax.top_k(select, k)                   # (T, k)
+            top_idx = _kept(jax.lax.top_k(select, k)[1])            # (T, k)
             top_w = jnp.take_along_axis(scores, top_idx, axis=-1)
             top_w = top_w / (top_w.sum(-1, keepdims=True) + 1e-20)
             top_w = top_w * self.routed_scale
             onehot = jax.nn.one_hot(top_idx, e, dtype=jnp.float32)  # (T, k, E)
-            load = onehot.sum((0, 1))                               # pairs an expert
+            load = _kept(onehot.sum((0, 1)).astype(jnp.int32))      # pairs an expert
 
         if self.fc1_latent_proj is not None:
             # the experts' rows: the latent's (no activation, no norm)
@@ -647,20 +665,20 @@ class MoEMLP(nn.Module):
         t, d = xt.shape
         k = self.top_k
         with jax.named_scope("moe_dispatch"):
-            order = jnp.argsort(top_idx.reshape(-1), stable=True).astype(jnp.int32)
-            inverse = jnp.zeros_like(order).at[order].set(
-                jnp.arange(t * k, dtype=jnp.int32), unique_indices=True)
-            sizes = load.astype(jnp.int32)
+            order = _kept(
+                jnp.argsort(top_idx.reshape(-1), stable=True).astype(jnp.int32))
+            inverse = _kept(jnp.zeros_like(order).at[order].set(
+                jnp.arange(t * k, dtype=jnp.int32), unique_indices=True))
             rows = _rows_of_tokens(xt.astype(self.dtype), order, inverse, k)
         with jax.named_scope("experts"):
             out_rows = _experts_of_rows(rows, kernels, functools.partial(
-                _grouped_dot, sizes=sizes, layer=layer))
-        self._sow_gmm_work(sizes, t * k)
+                _grouped_dot, sizes=load, layer=layer))
+        self._sow_gmm_work(load, t * k)
         with jax.named_scope("moe_combine"):
             pair_out = _unsort(out_rows, order, inverse).reshape(t, k, d)
             out = jnp.einsum("tk,tkd->td", top_w,
                              pair_out.astype(jnp.float32))
-        return out, sizes.sum()
+        return out, load.sum()
 
     # ---- dropless, a share of the experts held: its own pairs alone -----------
 
@@ -686,9 +704,9 @@ class MoEMLP(nn.Module):
             # held experts first, in order: their pairs are the leading rows
             key = (top_idx.reshape(-1) - first) % e                 # (T·k,)
             order = jnp.argsort(key, stable=True).astype(jnp.int32)
-            order = jnp.concatenate([order, jnp.arange(
-                t * k, (further + 1) * bound, dtype=jnp.int32)])
-            sizes = jnp.roll(load, -first)[:n_held].astype(jnp.int32)
+            order = _kept(jnp.concatenate([order, jnp.arange(
+                t * k, (further + 1) * bound, dtype=jnp.int32)]))
+            sizes = jnp.roll(load, -first)[:n_held]
             mine = sizes.sum()
         x = xt.astype(self.dtype)
         out = _held_pass(x, top_w, kernels, layer, order, sizes, 0, bound)
@@ -741,13 +759,13 @@ class MoEMLP(nn.Module):
             n_slots = e * capacity
             # invalid pairs target index n_slots: OOB for the scatter
             # (dropped) and exactly the appended zero row for the combine
-            slot = jnp.where(valid, top_idx * capacity + pos_idx, n_slots)
+            slot = _kept(jnp.where(valid, top_idx * capacity + pos_idx, n_slots))
             t_ids = jnp.broadcast_to(jnp.arange(t)[:, None], (t, k))
             # empty slots keep sentinel T -> gather the appended zero row, so
             # unfilled capacity computes on zeros exactly as the dense dispatch
-            token_of_slot = jnp.full((n_slots,), t, jnp.int32).at[
+            token_of_slot = _kept(jnp.full((n_slots,), t, jnp.int32).at[
                 slot.reshape(-1)
-            ].set(t_ids.reshape(-1), mode="drop")
+            ].set(t_ids.reshape(-1), mode="drop"))
             xt_pad = jnp.concatenate(
                 [xt.astype(compute_dtype), jnp.zeros((1, d), compute_dtype)]
             )

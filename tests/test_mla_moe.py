@@ -234,6 +234,56 @@ def test_a_layer_without_the_selection_bias_is_the_layer_with_it_at_zero():
     np.testing.assert_array_equal(*grad)
 
 
+def _primitives(jaxpr, found=None):
+    """The names of every primitive in ``jaxpr`` and the jaxprs its equations
+    hold (a replayed layer is one ``remat2`` equation)."""
+    found = set() if found is None else found
+    for eqn in jaxpr.eqns:
+        found.add(eqn.primitive.name)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(inner, found)
+    return found
+
+
+ROUTES = {"dropless": {"dispatch": "dropless"},
+          "held": {"dispatch": "dropless", "experts_held": (2, 4)},
+          "capacity": {"dispatch": "capacity"}}
+
+
+@pytest.mark.parametrize("dispatch", list(ROUTES))
+def test_a_replayed_layer_reads_its_routing_and_routes_once(dispatch):
+    """Under ``remat_policy`` ``"full"`` the backward pass replays the layer
+    from the integers its forward pass left (``moe_routing``: the chosen
+    experts, the sort and its inverse or a share's rows, an expert's pairs):
+    it holds no ``top_k``, no ``sort`` and no ``scatter`` — under a policy
+    that keeps nothing it holds them, so the search can see them — and the
+    gradient is the one without remat, bit for bit."""
+    from finetune_controller_tpu.models.llama import remat_policy_fn
+
+    layer = MoEMLP(d_model=D, d_ff=F, n_experts=E, top_k=K, scoring="sigmoid",
+                   aux_loss=False, dtype=jnp.float32, **ROUTES[dispatch])
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 12, D), jnp.float32)
+    params = layer.init({"params": jax.random.PRNGKey(2)}, x)["params"]
+
+    def f(params, x):
+        return (layer.apply({"params": params}, x) ** 2).sum()
+
+    def backward(policy):
+        vjp = jax.vjp(jax.checkpoint(f, policy=policy), params, x)[1]
+        return vjp, _primitives(jax.make_jaxpr(vjp)(jnp.float32(1)).jaxpr)
+
+    vjp, replayed = backward(remat_policy_fn("full"))
+    assert not replayed & {"top_k", "sort", "scatter"}, replayed
+    assert {"remat2", "gather"} <= replayed
+    _, routed_again = backward(jax.checkpoint_policies.nothing_saveable)
+    assert {"top_k", "scatter"} <= routed_again
+    assert ("sort" in routed_again) == (dispatch != "capacity")
+    for got, want in zip(jax.tree.leaves(vjp(jnp.float32(1))),
+                         jax.tree.leaves(jax.grad(f, argnums=(0, 1))(params, x))):
+        assert float(jnp.abs(want).max()) > 0
+        np.testing.assert_array_equal(got, want)
+
+
 @pytest.mark.parametrize("tokens", [24, 2048], ids=["every_pair_within_the_bound",
                                                     "rows_cut_to_the_bound"])
 def test_shares_of_experts_held_add_up_to_the_uncut_layer(tokens):
@@ -535,8 +585,11 @@ def test_experts_in_place_counts_the_layers_that_took_it(monkeypatch, why):
     model, variables = _expert_model(**{**IN_PLACE, **SLICED}[why])
     if why != "on_the_cpu":
         _take_the_in_place_path(monkeypatch)
-    logits, _, counters = _logits_grads_counters(
-        model, variables, jnp.asarray(_tokens(2, 32)))
+    # the counters are the forward pass's; the gradients through the in-place
+    # product are the test above's
+    logits, sown = model.apply(variables, jnp.asarray(_tokens(2, 32)),
+                               mutable=("moe_stats",))
+    counters = moe.moe_counters(sown)
     assert np.isfinite(np.asarray(logits, np.float32)).all()
     assert float(counters["moe_experts_in_place"]) == (3 if why in IN_PLACE else 0)
     assert float(counters["moe_pairs"]) > 0

@@ -160,6 +160,37 @@ def test_remat_policies_are_numerically_identical():
         raise AssertionError("bogus remat_policy accepted")
 
 
+def test_full_remat_of_a_dense_model_saves_nothing(monkeypatch):
+    """Every policy keeps ``ALWAYS_KEPT`` — the indexer's selection, the
+    expert layer's routing — and no layer of a dense model carries either
+    name: its ``"full"`` policy traces what ``nothing_saveable`` does, value
+    and gradient, to the character (the text holds shapes and primitives; a
+    policy prints as a function at an address, which is cut out)."""
+    import re
+
+    from finetune_controller_tpu.models import llama
+
+    def traced():
+        cfg, model = _tiny(lora_rank=4, remat_policy="full")
+        vars_ = jax.eval_shape(
+            lambda: model.init_variables(jax.random.PRNGKey(0), batch=2, seq=16))
+        toks = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+
+        def loss(lora, params, toks):
+            return model.apply({"params": params, "lora": lora}, toks).mean()
+
+        text = str(jax.make_jaxpr(jax.value_and_grad(loss))(
+            vars_["lora"], vars_["params"], toks))
+        return re.sub(r"policy=<function \S+ at 0x[0-9a-f]+>", "policy=_", text)
+
+    kept = traced()
+    assert "remat2" in kept and "policy=_" in kept
+    assert llama.remat_policy_fn("full") is not jax.checkpoint_policies.nothing_saveable
+    monkeypatch.setattr(llama, "remat_policy_fn",
+                        lambda name: jax.checkpoint_policies.nothing_saveable)
+    assert traced() == kept
+
+
 def test_frozen_dtype_casts_base_params():
     """frozen_dtype='bfloat16' downcasts every float32 frozen base leaf in
     lora mode, the trainable adapters stay float32, and training steps to a
