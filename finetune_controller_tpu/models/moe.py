@@ -72,7 +72,10 @@ TPU-native design:
   chip holding a share of the experts runs.  Only the share's own pairs are
   gathered and multiplied, ``held_row_bound`` rows a pass: one pass where
   the router is balanced, as many as the load asks for where it is not
-  (``_dropless_held``);
+  (``_dropless_held``); the pass's rows are summed back at their tokens —
+  the combine, and the dispatch's gradient — with work that follows the
+  ROWS where a pass holds at most half the routed pairs, not ``k`` gathers
+  of ``T`` rows most of which read the zero row (``held_sum_form``);
 - an optional shared expert (a dense MLP every token passes, handed in as
   a module so its projections carry LoRA like any other);
 - experts WITHOUT a gate (``gated=False``): ``down(relu(up x)^2)``, two
@@ -101,7 +104,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -399,6 +402,40 @@ def dropless_row_tile(pairs: int, n_held: int, n_experts: int) -> int | None:
     return gmm_row_tile(rows, n_held) if _pallas_grouped_dot_ok(rows) else None
 
 
+#: the compiler under which ``held_sum_form``'s two readings were taken on
+#: the chip (PERF.md section 6, PR 46): which side of a cutoff wins is this
+#: compiler's fusions as much as arithmetic, so read both again under another
+SUM_FORMS_READ_UNDER = {"jax": "0.9.0", "libtpu": "0.0.34"}
+
+#: rows a block of the rows form's one-hot product sums: the matrix unit's
+#: own tile
+_SUM_BLOCK = 128
+
+
+def held_sum_form(tokens: int, k: int, bound: int) -> str:
+    """How a held pass sums its rows back at their tokens (the combine, and
+    the gradient of the dispatch's gather, which is the same sum without
+    weights): ``"rows"`` — the pass's ``bound`` rows gathered into token
+    order and summed there (``_sum_of_rows``: work in proportion to
+    ``bound``) — or ``"choices"`` — a gather of ``tokens`` rows a choice, each
+    added to a ``(tokens, d)`` float32 accumulator (``_sum_of_pairs``: in
+    proportion to ``tokens · k``).  From shapes alone: the rows form where a
+    pass holds at most half the routed pairs — every share of up to a quarter
+    of the experts, ``held_row_bound`` being twice an even share.  What the
+    v5e read, a sum alone (rows / choices, ms): at ``bound / (tokens · k)``
+    0.125 (16,384 rows for top-8 of 16,384 tokens, a sixteenth of the experts
+    held) 3.5 / 12.1 at 4,096 columns and 4.6 / 17.4 at 6,144; at 0.5 (90,112
+    rows for top-22 of 8,192 tokens, a quarter held) 3.1 / 6.0 at 1,024 — the
+    three cells' shapes, whose steps were read too.  Above it, alone only:
+    at 0.75, 3.1 / 3.8 (top-8 of 4,096 tokens at 4,096 columns) and 4.3 / 6.3
+    (top-22 of 8,192 at 1,024); at 1.0, a pass of every pair, 3.7 / 4.0 and
+    5.2 / 6.4 — the rows form still ahead, by less and less, and no step has
+    run it there, so the cutoff stays at the largest ratio a step was read
+    at.  A token's rows must also fit a block of the product."""
+    return ("rows" if 2 * bound <= tokens * k and k <= _SUM_BLOCK
+            else "choices")
+
+
 def _sum_of_pairs(rows, row_of_pair, weight=None):
     """``sum_j weight[t, j] * rows[row_of_pair[t, j]]`` in float32, ``(T, d)``;
     an index of ``len(rows)`` reads a zero row.  One gather of ``T`` rows a
@@ -411,43 +448,133 @@ def _sum_of_pairs(rows, row_of_pair, weight=None):
     return out
 
 
+class _ByToken(NamedTuple):
+    """A pass's rows in (token, choice) order for ``_sum_of_rows``: ``n``
+    places, the live rows first, in blocks of ``_SUM_BLOCK`` with one block
+    of dead places at the end.  Integers, kept with the rest of the routing."""
+
+    #: the row at each place, ``(n,)`` (a dead place's: any)
+    row: jax.Array
+    #: its (token, choice) pair as one index into ``T·k`` (a dead place's: any)
+    pair: jax.Array
+    #: which of its block's tokens it belongs to, counted from the block's
+    #: first place; ``-1`` for a dead place
+    slot: jax.Array
+    #: where a token's sum lands in the blocks' results, ``(T,)``: the slot of
+    #: its first row in that row's block (a token without a row: a slot of the
+    #: dead block, which sums nothing)
+    place: jax.Array
+    #: for every block after the first, the token its first place CONTINUES
+    #: from the block before (its rows straddle the two), or ``T``
+    straddler: jax.Array
+
+
+def _rows_by_token(pair_of_row, row_of_pair) -> _ByToken:
+    t, k = row_of_pair.shape
+    bound = pair_of_row.shape[0]
+    n = -(-bound // _SUM_BLOCK) * _SUM_BLOCK + _SUM_BLOCK
+    # the pairs of this pass in (token, choice) order ARE ``row_of_pair`` read
+    # flat, the others skipped: a running count places them, no sort
+    held = row_of_pair.reshape(-1) < bound
+    ahead = jnp.cumsum(held, dtype=jnp.int32)
+    elsewhere = n + jnp.arange(t * k, dtype=jnp.int32)    # dropped, and unique
+    row = jnp.zeros((n,), jnp.int32).at[jnp.where(held, ahead - 1, elsewhere)].set(
+        row_of_pair.reshape(-1), mode="drop", unique_indices=True)
+    pair = pair_of_row[row]
+    live = jnp.arange(n, dtype=jnp.int32) < ahead[-1]
+    token = jnp.where(live, pair // k, t)
+    # a block's tokens numbered from its first place's
+    opens = live & jnp.concatenate(
+        [jnp.ones((1,), bool), token[1:] != token[:-1]])
+    nth = jnp.cumsum(opens, dtype=jnp.int32).reshape(-1, _SUM_BLOCK)
+    slot = (nth - nth[:, :1]).reshape(n)
+    count = held.reshape(t, k).sum(1, dtype=jnp.int32)
+    first = jnp.minimum(ahead.reshape(t, k)[:, -1] - count, n - 1)
+    place = jnp.where(
+        count > 0, first // _SUM_BLOCK * _SUM_BLOCK + slot[first], n - 1)
+    token = token.reshape(-1, _SUM_BLOCK)
+    straddler = jnp.where(token[1:, 0] == token[:-1, -1], token[1:, 0], t)
+    return _ByToken(row, pair, jnp.where(live, slot, -1), place, straddler)
+
+
+def _sum_of_rows(rows, by_token: _ByToken, weight=None):
+    """``_sum_of_pairs``' sum over the rows that exist.  ONE gather puts the
+    pass's rows in token order; every block of ``_SUM_BLOCK`` places is then
+    summed into its own tokens by a product with the (weighted) one-hot of
+    ``slot`` — float32 weights on bf16 rows at the matrix unit's highest
+    precision, each product exact and accumulated in float32: the same terms
+    as ``_sum_of_pairs`` in another order —; one gather of ``T`` rows brings
+    each token the sum of its first row's block, and the token whose rows
+    straddle two blocks (at most one a block, ``k`` being no more than a
+    block) is added the second block's part."""
+    n, d = by_token.row.shape[0], rows.shape[1]
+    blocks = n // _SUM_BLOCK
+    slot = by_token.slot.reshape(blocks, 1, _SUM_BLOCK)
+    hot = slot == jnp.arange(_SUM_BLOCK, dtype=slot.dtype)[None, :, None]
+    # a dead row may hold anything (the Pallas product leaves it unwritten),
+    # and nothing times zero is not always zero
+    sorted_rows = jnp.where((by_token.slot >= 0)[:, None], rows[by_token.row], 0)
+    if weight is None:
+        onehot = hot.astype(rows.dtype)
+    else:
+        onehot = jnp.where(hot, weight.reshape(-1)[by_token.pair].reshape(
+            blocks, 1, _SUM_BLOCK).astype(jnp.float32), 0)
+        sorted_rows = sorted_rows.astype(jnp.float32)
+    part = jnp.einsum(
+        "bsr,brd->bsd", onehot, sorted_rows.reshape(blocks, _SUM_BLOCK, d),
+        precision=(jax.lax.Precision.HIGHEST
+                   if onehot.dtype == jnp.float32 else None),
+        preferred_element_type=jnp.float32)
+    return part.reshape(n, d)[by_token.place].at[by_token.straddler].add(
+        part[1:, 0], mode="drop")
+
+
+def _sum_at_tokens(rows, row_of_pair, by_token, weight=None):
+    """A pass's rows summed at their tokens, ``(T, d)`` float32, in the form
+    ``_held_pass`` chose: ``by_token`` is None for the choices form."""
+    if by_token is None:
+        return _sum_of_pairs(rows, row_of_pair, weight)
+    return _sum_of_rows(rows, by_token, weight)
+
+
 @jax.custom_vjp
-def _held_rows_of_tokens(x, token_of_row, row_of_pair):
+def _held_rows_of_tokens(x, token_of_row, row_of_pair, by_token):
     """``x[token_of_row]``: the tokens of the leading sorted pairs.
-    ``row_of_pair`` is the way back, ``(T, k)``: the row a pair landed in."""
+    ``row_of_pair`` is the way back, ``(T, k)``: the row a pair landed in;
+    ``by_token``: the rows in token order, or None (``held_sum_form``)."""
     return x[token_of_row]
 
 
 _held_rows_of_tokens.defvjp(
-    lambda x, token_of_row, row_of_pair: (x[token_of_row], row_of_pair),
-    # a token's pairs, found again and summed: gathers, not a scatter-add
-    lambda row_of_pair, g: (_sum_of_pairs(g, row_of_pair).astype(g.dtype),
-                            None, None))
+    lambda x, token_of_row, row_of_pair, by_token: (
+        x[token_of_row], (row_of_pair, by_token)),
+    # a token's pairs, found again and summed: no scatter-add of the rows
+    lambda res, g: (_sum_at_tokens(g, *res).astype(g.dtype), None, None, None))
 
 
 @jax.custom_vjp
-def _held_combine(rows, weight, row_of_pair, pair_of_row):
+def _held_combine(rows, weight, row_of_pair, pair_of_row, by_token):
     """``sum_j weight[t, j] * rows[row_of_pair[t, j]]``: the rows back at
     their tokens, weighted.  ``pair_of_row``: the (token, choice) pair, as one
-    index into ``T·k``, of every row."""
-    return _sum_of_pairs(rows, row_of_pair, weight)
+    index into ``T·k``, of every row; ``by_token`` as above."""
+    return _sum_at_tokens(rows, row_of_pair, by_token, weight)
 
 
-def _held_combine_fwd(rows, weight, row_of_pair, pair_of_row):
-    return (_sum_of_pairs(rows, row_of_pair, weight),
+def _held_combine_fwd(rows, weight, row_of_pair, pair_of_row, by_token):
+    return (_sum_at_tokens(rows, row_of_pair, by_token, weight),
             (rows, weight, row_of_pair, pair_of_row))
 
 
 def _held_combine_bwd(res, g):
     rows, weight, row_of_pair, pair_of_row = res
     k = weight.shape[1]
-    d_rows = (g[pair_of_row // k]
-              * weight.reshape(-1)[pair_of_row][:, None]).astype(rows.dtype)
-    padded = jnp.concatenate([rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
-    d_weight = jnp.stack(
-        [(padded[row_of_pair[:, j]].astype(jnp.float32) * g).sum(-1)
-         for j in range(k)], axis=1)
-    return d_rows, d_weight.astype(weight.dtype), None, None
+    g_rows = g[pair_of_row // k]
+    d_rows = (g_rows * weight.reshape(-1)[pair_of_row][:, None]).astype(rows.dtype)
+    # a pair's weight gradient is its row's: one reduction over the pass's
+    # rows, then the (T, k) layout by a gather of scalars
+    d_row = (rows.astype(jnp.float32) * g_rows).sum(-1)
+    d_weight = jnp.concatenate([d_row, jnp.zeros((1,), d_row.dtype)])[row_of_pair]
+    return d_rows, d_weight.astype(weight.dtype), None, None, None
 
 
 _held_combine.defvjp(_held_combine_fwd, _held_combine_bwd)
@@ -461,6 +588,29 @@ def _sizes_in_rows(sizes, start, bound: int):
             - jnp.clip(ends - sizes - start, 0, bound))
 
 
+def _pass_rows(order, sizes, start, bound: int, t: int, k: int, form: str):
+    """The integers of the pass over the rows ``[start, start + bound)`` of
+    the pairs sorted with the held experts' first, all kept: every held
+    expert's rows in the pass, ``pair_of_row`` (the pair, as one index into
+    ``T·k``, of every row), ``row_of_pair`` (the way back, ``(T, k)``; ``bound``
+    — a zero row — for a pair of an expert held elsewhere or of another
+    pass), which rows are ``live`` and, in the ``"rows"`` form
+    (``held_sum_form``), the rows in token order."""
+    mine = sizes.sum()
+    sizes = _kept(_sizes_in_rows(sizes, start, bound))
+    pair_of_row = jax.lax.dynamic_slice(order, (start,), (bound,))
+    row = jnp.arange(bound, dtype=jnp.int32)
+    live = row < mine - start
+    row_of_pair = _kept(
+        jnp.full((t * k,), bound, jnp.int32).at[pair_of_row].set(
+            jnp.where(live, row, bound), mode="drop", unique_indices=True
+        ).reshape(t, k))
+    pair_of_row = _kept(jnp.minimum(pair_of_row, t * k - 1))
+    by_token = (jax.tree.map(_kept, _rows_by_token(pair_of_row, row_of_pair))
+                if form == "rows" else None)
+    return sizes, pair_of_row, row_of_pair, live, by_token
+
+
 def _held_pass(x, top_w, kernels, layer, order, sizes, start, bound: int):
     """What the held experts give the rows ``[start, start + bound)`` of the
     pairs sorted with the held experts' first, back at their tokens: ``(T,
@@ -469,28 +619,18 @@ def _held_pass(x, top_w, kernels, layer, order, sizes, start, bound: int):
     experts' pairs, whole; ``layer``: as ``_grouped_dot`` takes it."""
     t, k = top_w.shape
     with jax.named_scope("moe_dispatch"):
-        mine = sizes.sum()
-        sizes = _kept(_sizes_in_rows(sizes, start, bound))
-        pair_of_row = jax.lax.dynamic_slice(order, (start,), (bound,))
-        row = jnp.arange(bound, dtype=jnp.int32)
-        live = row < mine - start
-        # the row a pair landed in; ``bound`` (a zero row) for a pair of an
-        # expert held elsewhere or of another pass
-        row_of_pair = _kept(
-            jnp.full((t * k,), bound, jnp.int32).at[pair_of_row].set(
-                jnp.where(live, row, bound), mode="drop", unique_indices=True
-            ).reshape(t, k))
-        pair_of_row = _kept(jnp.minimum(pair_of_row, t * k - 1))
-        rows = _held_rows_of_tokens(x, pair_of_row // k, row_of_pair)
+        sizes, pair_of_row, row_of_pair, live, by_token = _pass_rows(
+            order, sizes, start, bound, t, k, held_sum_form(t, k, bound))
+        rows = _held_rows_of_tokens(x, pair_of_row // k, row_of_pair, by_token)
     with jax.named_scope("experts"):
         # rows behind the last group are covered by no group: the compiler's
         # product writes zeros there, the Pallas one nothing — and nothing
-        # reads them (``row_of_pair`` names none)
+        # reads them (``row_of_pair`` names none, and the rows form masks them)
         out_rows = _experts_of_rows(rows, kernels, functools.partial(
             _grouped_dot, sizes=sizes, layer=layer))
         out_rows = jnp.where(live[:, None], out_rows, 0)
     with jax.named_scope("moe_combine"):
-        return _held_combine(out_rows, top_w, row_of_pair, pair_of_row)
+        return _held_combine(out_rows, top_w, row_of_pair, pair_of_row, by_token)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7,))

@@ -1691,7 +1691,8 @@ class Trainer:
             attrs["flash_causal_work_over_need"] = causal_work_over_need(
                 self.cfg.seq_len, head_widths=cfg.head_widths)
         if cfg.n_experts and cfg.moe_dispatch == "dropless":
-            from ..models.moe import dropless_row_tile
+            from ..models.moe import (
+                dropless_row_tile, held_row_bound, held_sum_form)
             from ..parallel.ring import ring_mesh
 
             # the grouped expert products' row tile (``moe_gmm_work_over_need``
@@ -1699,12 +1700,19 @@ class Trainer:
             # kernel runs: asked as the step's trace asks, under its mesh
             tokens = (self.cfg.batch_size // self.cfg.grad_accum_steps
                       * self.cfg.seq_len)
+            pairs = tokens * cfg.moe_top_k
+            held = (cfg.experts_held or (0, cfg.n_experts))[1]
             with ring_mesh(self.mesh):
-                tile = dropless_row_tile(
-                    tokens * cfg.moe_top_k,
-                    (cfg.experts_held or (0, cfg.n_experts))[1], cfg.n_experts)
+                tile = dropless_row_tile(pairs, held, cfg.n_experts)
             if tile:
                 attrs["moe_gmm_row_tile"] = tile
+            if held != cfg.n_experts:
+                # a held share: the rows of one pass over the routed pairs,
+                # and the form its per-token sums run in for those shapes
+                bound = held_row_bound(pairs, held, cfg.n_experts)
+                attrs["moe_held_sum_form"] = held_sum_form(
+                    tokens, cfg.moe_top_k, bound)
+                attrs["moe_held_rows_over_pairs"] = bound / pairs
         if cfg.lora.rank > 0 and self._pp == 1 and not self._is_multimodal:
             attrs["lora_joined_projections"] = self._lora_joined_projections()
         kinds = cfg.indexer_kinds()

@@ -422,6 +422,8 @@ def test_trainer_steps_under_a_mesh_as_on_one_device_and_reports_the_kinds(devic
     assert attrs["attention_layers_by_kind"] == {"F": 2, "W": 5}
     assert (attrs["attention_window"], attrs["attention_sink_layers"]) == (4, 5)
     assert attrs["moe_experts_held"] == 8 and "layer_pattern" not in attrs
+    assert attrs["moe_held_sum_form"] in ("rows", "choices")
+    assert 0 < attrs["moe_held_rows_over_pairs"] <= 1
     assert "flash_window_work_over_need" not in attrs       # the XLA form here
     assert attrs["lora_joined_projections"]["of"] == 19    # 7 + 3 x 4 by leaf
     assert float(metrics["moe_pairs"]) > 0 and "moe_load_max_over_mean" in metrics

@@ -325,6 +325,197 @@ def test_shares_of_experts_held_add_up_to_the_uncut_layer(tokens):
     np.testing.assert_allclose(total, whole, atol=1e-5)
 
 
+def _routed(tokens, k, e, held, forced=(), chosen=None, seed=3):
+    """A held share's pairs as ``MoEMLP._dropless_held`` sorts them: ``(bound,
+    order, sizes)``.  ``forced``: experts every token chooses; ``chosen``: the
+    experts of the leading tokens, a row each."""
+    scores = jax.random.uniform(jax.random.PRNGKey(seed), (tokens, e))
+    scores = scores.at[:, jnp.asarray(forced, jnp.int32)].add(2.0)
+    top_idx = jax.lax.top_k(scores, k)[1]
+    if chosen is not None:
+        top_idx = top_idx.at[:len(chosen)].set(jnp.asarray(chosen, jnp.int32))
+    first, n_held = held
+    bound = moe.held_row_bound(tokens * k, n_held, e)
+    order = jnp.argsort((top_idx.reshape(-1) - first) % e, stable=True).astype(jnp.int32)
+    order = jnp.concatenate([order, jnp.arange(
+        tokens * k, -(-tokens * k // bound) * bound + bound, dtype=jnp.int32)])
+    load = jnp.zeros((e,), jnp.int32).at[top_idx.reshape(-1)].add(1)
+    return bound, order, jnp.roll(load, -first)[:n_held]
+
+
+def _a_pass(tokens, k, start=0, **routing):
+    """One pass as ``_held_pass`` reads it, in both forms: ``(bound, {form:
+    its integers})``."""
+    bound, order, sizes = _routed(tokens, k, **routing)
+    return bound, {form: moe._pass_rows(order, sizes, start, bound, tokens, k, form)
+                   for form in ("rows", "choices")}
+
+
+#: name -> (``_a_pass`` arguments, what the pass must hold)
+HELD_PASSES = {
+    # the window/full and 16k cells' ratio: a sixteenth of the experts held
+    "t64_k8_a_sixteenth_held": (dict(tokens=64, k=8, e=16, held=(0, 1)), {}),
+    # the pattern cell's: top-22, a quarter held
+    "t32_k22_a_quarter_held": (dict(tokens=32, k=22, e=32, held=(4, 8)), {}),
+    "tokens_with_none_one_and_all_k_held": (
+        dict(tokens=64, k=8, e=16, held=(0, 8), chosen=[
+            list(range(8, 16)), [0, *range(9, 16)], list(range(8))]),
+        {"held_pairs_of_leading_tokens": [0, 1, 8]}),
+    # expert 0..3 take every token: 1,536 pairs and more in passes of 512
+    # rows, the second of which holds a token's rows of two experts
+    "a_later_pass": (dict(tokens=384, k=8, e=64, held=(0, 4), forced=(0, 1, 2, 3),
+                          start=512), {"live": 512, "most_rows_a_token": 2}),
+    # 1,600 pairs: the fourth pass holds 64
+    "the_last_pass_mostly_dead_rows": (
+        dict(tokens=400, k=8, e=64, held=(0, 4), forced=(0, 1, 2, 3), start=1536),
+        {"live": 64, "most_rows_a_token": 1}),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(HELD_PASSES))
+def test_a_held_pass_sums_its_rows_the_same_in_both_forms(case, dtype):
+    """(ISSUE 46) The per-token sums of a held pass — the combine, its two
+    gradients, and the gradient of the dispatch's gather — in the ``"rows"``
+    form (the pass's rows in token order, summed where they lie) and in the
+    ``"choices"`` form (a gather of ``T`` rows a choice) against the plain
+    ``(T, k, d)`` expression and its autodiff: the same float32 terms in
+    another order, so float32 inputs agree to 1e-6 of the sum's scale and
+    bf16 rows as closely (the accumulation is float32 either way).  Dead rows
+    hold NaN wherever the program may be handed anything there."""
+    kwargs, holds = HELD_PASSES[case]
+    bound, forms = _a_pass(**kwargs)
+    tokens, k, d = kwargs["tokens"], kwargs["k"], 24
+    _, pair_of_row, row_of_pair, live, _ = forms["choices"]
+    per_token = (row_of_pair < bound).sum(1)
+    assert 0 < int(live.sum()) == int(per_token.sum())
+    assert int(live.sum()) < bound or "live" in holds               # dead rows
+    assert int(live.sum()) == live.shape[0] or not bool(live[-1])
+    if "held_pairs_of_leading_tokens" in holds:
+        assert per_token[:3].tolist() == holds["held_pairs_of_leading_tokens"]
+    if "live" in holds:
+        assert int(live.sum()) == holds["live"]
+        assert int(per_token.max()) == holds["most_rows_a_token"]
+    rows = jax.random.normal(jax.random.PRNGKey(1), (bound, d)).astype(dtype)
+    rows = jnp.where(live[:, None], rows, 0)          # as ``_held_pass`` hands them
+    weight = jax.random.uniform(jax.random.PRNGKey(2), (tokens, k)) + 0.1
+    probe = jax.random.normal(jax.random.PRNGKey(4), (tokens, d))
+
+    def plain(rows, weight):
+        padded = jnp.concatenate([rows, jnp.zeros((1, d), rows.dtype)])
+        return jnp.einsum("tk,tkd->td", weight,
+                          padded[row_of_pair].astype(jnp.float32))
+
+    want = plain(rows, weight)
+    want_rows, want_weight = jax.grad(
+        lambda r, w: (plain(r, w) * probe).sum(), (0, 1))(rows, weight)
+    scale = float(jnp.abs(want).max())
+    for form, (_, pair_of_row, row_of_pair, live, by_token) in forms.items():
+        assert (by_token is None) == (form == "choices")
+
+        def combine(rows, weight):
+            return moe._held_combine(rows, weight, row_of_pair, pair_of_row, by_token)
+
+        np.testing.assert_allclose(combine(rows, weight), want, rtol=0,
+                                   atol=1e-6 * scale, err_msg=form)
+        got_rows, got_weight = jax.grad(
+            lambda r, w: (combine(r, w) * probe).sum(), (0, 1))(rows, weight)
+        np.testing.assert_allclose(
+            jnp.where(live[:, None], got_rows, 0).astype(jnp.float32),
+            want_rows.astype(jnp.float32), rtol=0,
+            atol=(1e-6 if dtype == jnp.float32 else 2 ** -8) * float(
+                jnp.abs(want_rows).max()), err_msg=form)
+        np.testing.assert_allclose(
+            got_weight, want_weight, rtol=0,
+            atol=1e-6 * float(jnp.abs(want_weight).max()), err_msg=form)
+
+        # the dispatch's gather and its gradient: a token's rows' cotangents
+        # summed, whatever the dead rows' hold
+        x = jax.random.normal(jax.random.PRNGKey(5), (tokens, d)).astype(dtype)
+        g = jax.random.normal(jax.random.PRNGKey(6), (bound, d)).astype(dtype)
+        gathered, vjp = jax.vjp(lambda x: moe._held_rows_of_tokens(
+            x, pair_of_row // k, row_of_pair, by_token), x)
+        np.testing.assert_array_equal(gathered, x[pair_of_row // k])
+        want_x = jnp.zeros((tokens, d), jnp.float32).at[pair_of_row // k].add(
+            jnp.where(live[:, None], g, 0).astype(jnp.float32))
+        got_x, = vjp(jnp.where(live[:, None], g, jnp.nan))
+        assert got_x.dtype == dtype
+        np.testing.assert_allclose(
+            got_x.astype(jnp.float32), want_x.astype(dtype).astype(jnp.float32),
+            rtol=0, atol=(1e-6 if dtype == jnp.float32 else 2 ** -7) * float(
+                jnp.abs(want_x).max()), err_msg=form)
+
+
+def test_the_sum_forms_were_read_under_the_installed_compiler():
+    """Which form wins at a ratio of rows to pairs is the compiler's fusions
+    as much as arithmetic (``PERF.md`` section 6, PR 46), and no CPU test can
+    see them move.  Under another compiler: time both forms at the three
+    cells' shapes again, then move ``held_sum_form``'s cutoff or this record."""
+    from importlib.metadata import version
+
+    assert moe.SUM_FORMS_READ_UNDER == {
+        "jax": version("jax"), "libtpu": version("libtpu")}
+
+
+def _gathers_of_width(jaxpr, width, found=None):
+    """The operand shapes of every ``gather`` of rows ``width`` wide in
+    ``jaxpr`` and the jaxprs its equations hold."""
+    found = [] if found is None else found
+    for eqn in jaxpr.eqns:
+        shape = eqn.invars[0].aval.shape if eqn.invars else ()
+        if eqn.primitive.name == "gather" and len(shape) == 2 and shape[1] == width:
+            found.append(shape)
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            _gathers_of_width(inner, width, found)
+    return found
+
+
+@pytest.mark.parametrize("form, gathers", [("rows", 6), ("choices", 1 + 8 + 1 + 8)])
+def test_a_held_layers_row_gathers_do_not_grow_with_the_choices(
+        monkeypatch, form, gathers):
+    """One held layer, forward and backward, at the window/full and 16k cells'
+    ratio (top-8, a sixteenth of the experts held: ``held_row_bound`` is the
+    tokens): in the rows form SIX gathers of rows ``d`` wide — the dispatch's
+    one; the combine's rows into token order and each token's sum out of the
+    blocks' results; the cotangent's rows; and the same two for the dispatch's
+    gradient — where the layer held 1 + 8 + 1 + 8 + 8 through PR 45
+    (``d_weight``'s eight went with either form: one reduction over the pass's
+    rows).  (ISSUE 46 asked for at most four, which a form that ends in ONE
+    gather gives — the library's transposed grouped product, whose float32
+    operand the kernel rounds to bf16: ``CHANGES.md`` PR 46; the form shipped
+    is plain ``jnp``: a gather into token order, a one-hot product a block, a
+    gather of each token's sum.)"""
+    tokens, k, d = 512, 8, 48
+    bound, order, sizes = _routed(tokens, k, e=16, held=(0, 1))
+    assert bound == tokens and moe.held_sum_form(tokens, k, bound) == "rows"
+    monkeypatch.setattr(moe, "held_sum_form", lambda *shapes: form)
+    x = jax.random.normal(jax.random.PRNGKey(1), (tokens, d), jnp.float32)
+    top_w = jax.random.uniform(jax.random.PRNGKey(2), (tokens, k))
+    kernels = tuple(jax.random.normal(jax.random.PRNGKey(i), shape) for i, shape in
+                    enumerate([(1, d, 40), (1, d, 40), (1, 40, d)]))
+    jaxpr = jax.make_jaxpr(jax.value_and_grad(
+        lambda x, w: (moe._held_pass(x, w, kernels, None, order, sizes, 0, bound)
+                      ** 2).sum(), (0, 1)))(x, top_w)
+    found = _gathers_of_width(jaxpr.jaxpr, d)
+    assert len(found) == gathers, found
+
+
+@pytest.mark.parametrize("cell, tokens, k, held, e, form", [
+    ("mimo-v2-flash-lora.train-sft-16k", 16384, 8, 16, 256, "rows"),
+    ("glm-5.2-lora.train-sft-16k", 16384, 8, 16, 256, "rows"),
+    ("nemotron-3-super-lora.train-sft-8k", 8192, 22, 128, 512, "rows")])
+def test_the_form_of_a_held_pass_sum_at_the_cells_shapes(cell, tokens, k, held, e, form):
+    """The chooser's answer at the three cells that hold a share: a pass of
+    16,384 rows for 131,072 pairs (an eighth) and the pattern cell's pass of
+    90,112 rows for 180,224 pairs (a half: the cutoff itself) take the rows
+    form — 3.5 against 12.1 ms a sum, 4.6 against 17.4 and 3.1 against 6.0 on
+    the chip (``PERF.md`` section 6, PR 46); a pass that holds every pair (a
+    toy's, or two chips a layer) stays on the choices form."""
+    assert moe.held_sum_form(2048, 2, 4096) == "choices"
+    bound = moe.held_row_bound(tokens * k, held, e)
+    assert moe.held_sum_form(tokens, k, bound) == form
+
+
 @pytest.mark.parametrize("count, forced, passes, in_place", [
     (1, [0], 2, False), (3, [0, 1], 2, False), (1, [], 1, False),
     (1, [0], 2, True)], ids=["two_passes", "two_passes_padded_rows",
@@ -414,6 +605,28 @@ def test_train_started_carries_the_row_tile_where_the_kernel_runs(monkeypatch):
     monkeypatch.setattr(moe, "_pallas_grouped_dot_ok", lambda rows: True)
     # a microbatch of 2 x 256 tokens x top-2 over 8 experts: 128 rows a group
     assert trainer._runtime_attrs()["moe_gmm_row_tile"] == 128
+
+
+@pytest.mark.parametrize("held, batch, form, share", [
+    (None, 4, None, None), ((0, 2), 4, "choices", 1.0), ((0, 1), 16, "rows", 0.25)],
+    ids=["all_experts", "a_share_of_few_pairs", "a_share"])
+def test_train_started_says_how_a_held_share_sums_its_rows(held, batch, form, share):
+    """``moe_held_sum_form`` is ``held_sum_form``'s answer at a microbatch's
+    tokens, and ``moe_held_rows_over_pairs`` a pass's rows over the routed
+    pairs (0.125 in the window/full and 16k cells, 0.5 in the pattern cell);
+    a model that holds every expert says neither."""
+    from finetune_controller_tpu.train.trainer import TrainConfig, Trainer
+
+    cfg = PRESETS["tiny-mla-moe-test"].replace(
+        lora=LoRAConfig(rank=4, targets=MLA_TARGETS), experts_held=held)
+    attrs = Trainer(cfg, TrainConfig(
+        mode="lora", total_steps=2, batch_size=batch, seq_len=128,
+        grad_accum_steps=2))._runtime_attrs()
+    assert attrs.get("moe_held_sum_form") == form
+    assert attrs.get("moe_held_rows_over_pairs") == share
+    if held:
+        tokens = batch // 2 * 128
+        assert share == moe.held_row_bound(tokens * 2, held[1], 8) / (tokens * 2)
 
 
 def test_train_started_says_which_projections_carry_their_adapter():
