@@ -114,7 +114,7 @@ def _decode_fns(model_type, dcfg):
         _DECODE_FNS_CACHE.move_to_end(key)
         return cached
     dmodel = model_type(cfg=dcfg)
-    mutable = ("cache", "moe_aux") if dcfg.n_experts else ("cache",)
+    mutable = ("cache", *dcfg.sown_in_eval)
 
     # one pair serves both families: fill takes pixels variadically (the
     # multimodal [image; text] prefix — cached_generate passes it only for

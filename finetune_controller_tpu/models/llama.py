@@ -103,6 +103,11 @@ LAYER_KINDS = {"M": "mamba", "E": "moe", "*": "attn", "F": "attn", "W": "attn"}
 #: the letters of WHOLE blocks (attention, then the MLP or the experts) that
 #: differ by their attention's kind: ``F`` every earlier key, ``W`` a window
 BLOCK_KINDS = "FW"
+#: the collections a forward pass may sow: the routers' load-balance terms,
+#: the expert layers' counters (``models/moe.py``) and the indexer's
+SOWN = ("moe_aux", "moe_stats", "dsa_stats")
+#: what a model that is not a dense text decoder hears of a ``pp`` axis
+PP_DENSE_TEXT_ONLY = "pipeline parallelism currently supports dense text models"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -460,6 +465,128 @@ class LlamaConfig:
         accounting must use this (6·N_active per token): counting idle
         experts would credit the chip with matmuls it never ran."""
         return self._count(self.moe_top_k if self.n_experts else 0)
+
+    # ---- what a trainer asks of a model (``train/trainer.py`` reads no field
+    # of a family: a new one answers here, ``LlavaConfig`` by its decoder) ----
+
+    @property
+    def sown(self) -> tuple[str, ...]:
+        """The collections a training pass makes mutable: ``SOWN`` where the
+        model sows at all (expert layers, an indexer), else none."""
+        return SOWN if self.n_experts or self.index_topk else ()
+
+    @property
+    def sown_in_eval(self) -> tuple[str, ...]:
+        """An evaluation reads no counter: an expert model's pass keeps the
+        router's collection mutable, as it has always been traced."""
+        return SOWN[:1] if self.n_experts else ()
+
+    def sown_readings(self, collections: dict) -> tuple[Any, dict]:
+        """``(aux_penalty, counters)`` of a training pass's sown
+        ``collections``: what the loss gains (the routers' load-balance terms
+        by ``router_aux_weight``; a model balanced by a selection bias sows
+        none, and the collection is skipped, not read as zero) and what the
+        step's metrics gain (``models/moe.py::moe_counters``,
+        :func:`dsa_counters`)."""
+        aux_penalty, counters = 0.0, {}
+        if not self.sown:
+            return aux_penalty, counters
+        from .moe import moe_aux_loss, moe_counters
+
+        if self.router_aux_weight:
+            aux_penalty = self.router_aux_weight * moe_aux_loss(collections)
+        if self.n_experts:
+            counters.update(jax.lax.stop_gradient(moe_counters(collections)))
+            if self.router_aux_weight:
+                counters["moe_aux"] = aux_penalty
+        if self.index_topk:
+            counters.update(dsa_counters(collections))
+        return aux_penalty, counters
+
+    @staticmethod
+    def keeps_dtype(path: tuple) -> bool:
+        """Whether the frozen leaf at ``path`` keeps its dtype under a base
+        stored in fewer bits: a quantised kernel's ``scales``, and a window
+        layer's sink (a softmax logit, 64 numbers)."""
+        name = str(path[-1]) if path else ""
+        return "scales" in name or (len(path) > 1 and "sink" in str(path[-2]))
+
+    def refuse_mesh(self, mesh_shape: dict) -> None:
+        """Raise for a mesh this model does not train on."""
+        pp, sp = mesh_shape.get("pp", 1), mesh_shape.get("sp", 1)
+        if (self.ssm_d_inner or self.layer_pattern) and (pp > 1 or sp > 1):
+            raise ValueError(
+                "a model with a state-space mixer, or one that is a pattern "
+                "of layer kinds, trains with sp = pp = 1: a scan over a split "
+                "sequence needs a state hand-off between members, and the "
+                "pipeline's stage body holds neither the mixer nor a stage of "
+                "unlike layers (ROADMAP.md B10, B13)")
+        if pp > 1 and self.n_experts:
+            raise ValueError(PP_DENSE_TEXT_ONLY)
+
+    def run_description(self, *, seq_len: int, tokens_per_microbatch: int,
+                        attention_impl: str, mesh, adapters: Any = None) -> dict:
+        """What the model says of itself at ``train-started``, each counter
+        from the chooser it reports, asked as the step's trace asks it — at a
+        microbatch's tokens, under the step's ``mesh``: with the flash kernels
+        the score area they compute over the causal triangle's; a dropless
+        expert model's grouped products (``models/moe.py``); which adapted
+        projections (``adapters``: the trainable tree's shapes) carry their
+        adapter inside the base product (``models/lora.py``); an indexer's
+        layers by kind; a pattern's string, layers by kind and what its kinds
+        hold; the state-space mixers (``models/ssm.py``)."""
+        from ..ops.pallas.flash_attention import (
+            causal_work_over_need, window_work_over_need)
+        from ..parallel.ring import ring_mesh
+        from . import lora, moe
+
+        attrs: dict[str, Any] = {}
+        pattern = self.layer_pattern
+        by_kind = {kind: pattern.count(kind) for kind in sorted(set(pattern))}
+        kinds = self.indexer_kinds()
+        with ring_mesh(mesh):
+            if attention_impl == "pallas":
+                attrs["flash_causal_work_over_need"] = causal_work_over_need(
+                    seq_len, head_widths=self.head_widths)
+            if self.n_experts:
+                attrs.update(moe.run_description(self, tokens_per_microbatch))
+            if (adapters is not None and self.lora.rank > 0
+                    and mesh.shape.get("pp", 1) == 1):
+                attrs["lora_joined_projections"] = lora.joined_projections(
+                    adapters, tokens_per_microbatch,
+                    dropout=self.lora.dropout > 0.0)
+            if kinds:
+                attrs["dsa_full_layers"] = kinds.count("full")
+                attrs["dsa_shared_layers"] = kinds.count("shared")
+            if set(pattern) & set(BLOCK_KINDS):
+                # whole blocks by their attention's kind: the window's keys,
+                # the layers that hold a sink, the experts held and, with the
+                # flash kernels, the score area a window call computes over
+                # its need
+                attrs["attention_pattern"] = pattern
+                attrs["attention_layers_by_kind"] = by_kind
+                attrs["attention_window"] = self.sliding_window
+                attrs["attention_sink_layers"] = (
+                    pattern.count("W") if self.window_sink else 0)
+                if self.n_experts:
+                    attrs["moe_experts_held"] = moe.n_held(self)
+                if attention_impl == "pallas" and "W" in pattern:
+                    attrs["flash_window_work_over_need"] = window_work_over_need(
+                        seq_len, self.sliding_window,
+                        head_widths=self.head_widths)
+            elif pattern:
+                # single-mixer layers: the width of the latent its experts
+                # live in and the experts held
+                attrs["layer_pattern"] = pattern
+                attrs["layers_by_kind"] = by_kind
+                if "E" in pattern:
+                    attrs["moe_latent_width"] = self.moe_latent
+                    attrs["moe_experts_held"] = moe.n_held(self)
+            if self.ssm_d_inner:
+                from . import ssm
+
+                attrs.update(ssm.run_description(self, seq_len))
+        return attrs
 
 
 # Architecture presets for the BASELINE.md configs (shapes per the public

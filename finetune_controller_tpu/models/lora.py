@@ -208,6 +208,28 @@ def joins_base_product(rows: int, in_features: int, rank: int, *,
             and 4 * rows >= 7 * (in_features + rank))
 
 
+def joined_projections(adapters: Any, tokens: int, *, dropout: bool) -> dict:
+    """How many of the adapted projections in ``adapters`` (a tree of the
+    adapter leaves' shapes) carry their adapter inside the base product, of
+    how many, and which keep the two apart: :func:`joins_base_product` asked
+    as the step's trace asks it — at each adapter's own widths, ``tokens`` a
+    microbatch, under the mesh in scope.  ``dropout`` between the adapter's
+    factors keeps every one apart."""
+    devices, sharded = mesh_splits()
+    joined = {}
+    for path, leaf in jax.tree_util.tree_leaves_with_path(adapters):
+        *module, name = (k.key for k in path)
+        if name == "lora_a":    # [(layers,) in, r]
+            joined["/".join(module)] = not dropout and joins_base_product(
+                tokens // devices, leaf.shape[-2], leaf.shape[-1],
+                sharded=sharded)
+    return {
+        "joined": sum(joined.values()),
+        "of": len(joined),
+        "apart": sorted(p for p, j in joined.items() if not j),
+    }
+
+
 class LoRADense(nn.Module):
     """Dense layer with an optional low-rank adapter branch.
 

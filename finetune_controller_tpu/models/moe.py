@@ -928,6 +928,32 @@ class MoEMLP(nn.Module):
         return out, valid.sum()
 
 
+def n_held(cfg) -> int:
+    """Experts a device holds: ``experts_held``'s count, else every one."""
+    return (cfg.experts_held or (0, cfg.n_experts))[1]
+
+
+def run_description(cfg, tokens: int) -> dict:
+    """What an expert model says of a dropless layer's grouped products at
+    ``train-started``, at ``tokens`` a microbatch and under the mesh in scope:
+    their row tile where the Pallas kernel runs (``moe_gmm_work_over_need``
+    among the step's counters is what it costs) and, of a held share, the
+    rows of one pass over the routed pairs and the form its per-token sums
+    run in for those shapes."""
+    if cfg.moe_dispatch != "dropless":
+        return {}
+    attrs: dict[str, Any] = {}
+    pairs, held = tokens * cfg.moe_top_k, n_held(cfg)
+    tile = dropless_row_tile(pairs, held, cfg.n_experts)
+    if tile:
+        attrs["moe_gmm_row_tile"] = tile
+    if held != cfg.n_experts:
+        bound = held_row_bound(pairs, held, cfg.n_experts)
+        attrs["moe_held_sum_form"] = held_sum_form(tokens, cfg.moe_top_k, bound)
+        attrs["moe_held_rows_over_pairs"] = bound / pairs
+    return attrs
+
+
 def moe_aux_loss(collections: dict) -> jax.Array:
     """Sum every sown load-balance term (scan stacks them per layer)."""
     leaves = jax.tree_util.tree_leaves(collections.get("moe_aux", {}))

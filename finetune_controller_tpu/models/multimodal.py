@@ -26,7 +26,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .llama import LlamaConfig, RMSNorm, _proj
+from .llama import PP_DENSE_TEXT_ONLY, LlamaConfig, RMSNorm, _proj
 from .lora import LoRAConfig
 
 
@@ -83,13 +83,29 @@ class LlavaConfig:
     def lora(self) -> LoRAConfig:
         return self.text.lora
 
+    # what a trainer asks of a model: the decoder's answers
     @property
-    def n_experts(self) -> int:
-        return self.text.n_experts
+    def sown(self) -> tuple[str, ...]:
+        return self.text.sown
 
     @property
-    def router_aux_weight(self) -> float:
-        return self.text.router_aux_weight
+    def sown_in_eval(self) -> tuple[str, ...]:
+        return self.text.sown_in_eval
+
+    def sown_readings(self, collections: dict) -> tuple[Any, dict]:
+        return self.text.sown_readings(collections)
+
+    keeps_dtype = staticmethod(LlamaConfig.keeps_dtype)
+
+    def refuse_mesh(self, mesh_shape: dict) -> None:
+        if mesh_shape.get("pp", 1) > 1:
+            raise ValueError(PP_DENSE_TEXT_ONLY)
+        self.text.refuse_mesh(mesh_shape)
+
+    def run_description(self, *, adapters: Any = None, **run) -> dict:
+        """The decoder's, its adapters aside (the trainable tree holds the
+        projector beside them)."""
+        return self.text.run_description(**run)
 
     @property
     def attention_impl(self) -> str:

@@ -62,8 +62,27 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.pallas.ssd_scan import ssd_scan
+from ..ops.pallas.ssd_scan import ssd_scan, ssd_scan_impl
 from .llama import _Leaves, _proj, times
+
+
+def run_description(cfg, seq_len: int) -> dict:
+    """What a model with state-space mixers says of them at ``train-started``:
+    how many, the chain of chunk states a row's scan walks in each, the
+    float32 state a row carries, and the form the recurrence runs in under
+    the mesh in scope (the Pallas kernels | the plain ``jnp`` one) with the
+    heads a step of the kernels' grid holds."""
+    impl, heads = ssd_scan_impl(cfg.ssm_n_heads, cfg.ssm_head_dim,
+                                cfg.ssm_n_groups, cfg.ssm_d_state, cfg.ssm_chunk)
+    return {
+        "ssm_layers": (cfg.layer_pattern.count("M") if cfg.layer_pattern
+                       else cfg.n_layers),
+        "ssm_chunks_per_row": -(-seq_len // cfg.ssm_chunk),
+        "ssm_state_bytes_per_row": (
+            4 * cfg.ssm_n_heads * cfg.ssm_head_dim * cfg.ssm_d_state),
+        "ssm_scan_impl": impl,
+        "ssm_scan_heads_per_block": heads,
+    }
 
 
 def document_runs(segment_ids: jax.Array) -> jax.Array:

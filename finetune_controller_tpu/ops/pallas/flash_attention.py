@@ -108,34 +108,29 @@ def _padded_len(s: int, bq: int, bk: int) -> int:
     return math.lcm(bq, bk) * pl.cdiv(s, math.lcm(bq, bk))
 
 
-def _diag_tiles(bq: int, bk: int, causal: bool) -> int:
+def _diag_tiles(bq: int, bk: int) -> int:
     """Sub-tiles a side of a block the causal diagonal crosses; 1 = the block
-    is computed whole under its mask (no diagonal, a block that is not square
-    or no wider than the sub-tile)."""
+    is computed whole under its mask (a block that is not square or no wider
+    than the sub-tile)."""
     t = min(DIAG_TILE, bq)
-    if causal and bq == bk and bq > t and bq % t == 0:
+    if bq == bk and bq > t and bq % t == 0:
         return bq // t
     return 1
 
 
-def _kv_block_index(iq, ik, bq: int, bk: int, causal: bool):
+def _kv_block_index(iq, ik, bq: int, bk: int):
     """The K/V (and key segment id) block step ``(iq, ik)`` of the forward and
     dQ grids names: its own where the causal frontier admits it, else the
     last admitted one — already resident, so nothing is copied."""
-    if not causal:
-        return ik
     return jnp.minimum(ik, ((iq + 1) * bq - 1) // bk)
 
 
-def _q_block_index(ik, j, nq: int, bq: int, bk: int, causal: bool):
+def _q_block_index(ik, j, nq: int, bq: int, bk: int):
     """The q-side (Q, dO, lse, delta, query segment id) block inner step ``j``
     of the dK/dV grid names for key block ``ik``: its own, ``j % nq``, where
     the frontier admits it, else the first admitted one — the block the next
     computing step wants."""
-    iq = j % nq
-    if not causal:
-        return iq
-    return jnp.maximum(iq, (ik * bk) // bq)
+    return jnp.maximum(j % nq, (ik * bk) // bq)
 
 
 def causal_work_over_need(
@@ -151,7 +146,7 @@ def causal_work_over_need(
     bq = min(block_q or _default_block(*head_widths), seq)
     bk = min(block_k or _default_block(*head_widths), seq)
     s_pad = _padded_len(seq, bq, bk)
-    c = _diag_tiles(bq, bk, True)
+    c = _diag_tiles(bq, bk)
     area = 0
     for iq in range(s_pad // bq):
         for ik in range(s_pad // bk):
@@ -417,7 +412,6 @@ def _fwd_kernel(
     scale: float,
     use_segments: bool,
     exp_dtype: str = "float32",
-    causal: bool = True,
     diag_tiles: int = 1,
     window: int | None = None,
     window_back: int = 0,
@@ -445,21 +439,15 @@ def _fwd_kernel(
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
 
-    if causal:
-        # causal frontier: this k block is live iff its first key position is
-        # <= the q block's last query position
-        needed = ik * bk <= (iq + 1) * bq - 1
-        # interior = every (q, k) pair in the block is causally valid AND
-        # inside the real sequence: the iota/compare/where mask passes can be
-        # skipped. The attention kernel is VPU-bound (S^2 elementwise vs
-        # 2dS^2 MXU flops at small head dims), so dropping mask passes on the
-        # ~N^2/2 interior blocks is a direct win at long sequence.
-        interior = ((ik + 1) * bk - 1 <= iq * bq) & ((ik + 1) * bk <= seq_len)
-    else:
-        # full (non-causal) attention — the ring-attention off-diagonal
-        # steps, where every key is in the query's global past
-        needed = ik * bk < seq_len
-        interior = (ik + 1) * bk <= seq_len
+    # causal frontier: this k block is live iff its first key position is
+    # <= the q block's last query position
+    needed = ik * bk <= (iq + 1) * bq - 1
+    # interior = every (q, k) pair in the block is causally valid AND inside
+    # the real sequence: the iota/compare/where mask passes can be skipped.
+    # The attention kernel is VPU-bound (S^2 elementwise vs 2dS^2 MXU flops at
+    # small head dims), so dropping mask passes on the ~N^2/2 interior blocks
+    # is a direct win at long sequence.
+    interior = ((ik + 1) * bk - 1 <= iq * bq) & ((ik + 1) * bk <= seq_len)
 
     def _online_update(parts, rows=ALL):
         """ONE online-softmax update of the block's ``rows`` (the state is per
@@ -544,9 +532,8 @@ def _fwd_kernel(
             def _compute_masked():
                 s = _scores()
                 q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-                mask = k_pos < seq_len  # tail block: beyond-S lanes are padding
-                if causal:
-                    mask &= q_pos >= k_pos
+                # tail block: beyond-S lanes are padding
+                mask = (k_pos < seq_len) & (q_pos >= k_pos)
                 if use_segments:
                     mask &= _segment_mask(qseg_ref, kseg_ref)
                 _online_update([(s, _selected(sel_ref, ik, bq, bk, mask), ALL)])
@@ -604,14 +591,14 @@ def _selection_operand(selection, s_pad: int, bq: int, bk: int):
     return selection, (1, bq, SELECTION_LANES), lambda ik: ik * bk // SELECTION_KEYS
 
 
-def _check_window(window, bq, bk, causal, selection):
-    """A window call is causal, over square blocks, with no selection."""
+def _check_window(window, bq, bk, selection):
+    """A window call is over square blocks, with no selection."""
     if window is None:
         return
-    if window < 1 or not causal or bq != bk or selection is not None:
+    if window < 1 or bq != bk or selection is not None:
         raise ValueError(
-            f"a window of {window} keys needs a causal call over square "
-            f"blocks (got {bq} x {bk}) and takes no selection")
+            f"a window of {window} keys needs square blocks (got {bq} x "
+            f"{bk}) and takes no selection")
 
 
 def _window_kv_block_index(iq, ik, back: int):
@@ -639,7 +626,6 @@ def _flash_forward(
     interpret: bool,
     use_segments: bool = True,
     exp_dtype: str = "float32",
-    causal: bool = True,
     kv_segment_ids: jax.Array | None = None,
     selection: jax.Array | None = None,
     sink: jax.Array | None = None,
@@ -658,7 +644,7 @@ def _flash_forward(
 
     bq = min(block_q, s)
     bk = min(block_k, s)
-    _check_window(window, bq, bk, causal, selection)
+    _check_window(window, bq, bk, selection)
     q, k, v, segment_ids, kv_segment_ids, s_pad = _pad_inputs(
         q, k, v, segment_ids, bq, bk, kv_segment_ids)
 
@@ -675,7 +661,7 @@ def _flash_forward(
     nq = pl.cdiv(s_pad, bq)
     nk = pl.cdiv(s_pad, bk)
 
-    kv = functools.partial(_kv_block_index, bq=bq, bk=bk, causal=causal)
+    kv = functools.partial(_kv_block_index, bq=bq, bk=bk)
     kernel_kw = {}
     if window is not None:
         back = min(_window_blocks_back(window, bq), nq - 1)
@@ -697,8 +683,7 @@ def _flash_forward(
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, seq_len=s, scale=scale,
                           use_segments=use_segments, exp_dtype=exp_dtype,
-                          causal=causal, diag_tiles=_diag_tiles(bq, bk, causal),
-                          **kernel_kw),
+                          diag_tiles=_diag_tiles(bq, bk), **kernel_kw),
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -751,7 +736,6 @@ def _bwd_dq_kernel(
     scale: float,
     use_segments: bool,
     exp_dtype: str = "float32",
-    causal: bool = True,
     diag_tiles: int = 1,
     window: int | None = None,
     window_back: int = 0,
@@ -768,13 +752,9 @@ def _bwd_dq_kernel(
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    if causal:
-        needed = ik * bk <= (iq + 1) * bq - 1
-        # all (q, k) pairs valid (see forward kernel): skip the mask passes
-        interior = ((ik + 1) * bk - 1 <= iq * bq) & ((ik + 1) * bk <= seq_len)
-    else:
-        needed = ik * bk < seq_len
-        interior = (ik + 1) * bk <= seq_len
+    needed = ik * bk <= (iq + 1) * bq - 1
+    # all (q, k) pairs valid (see forward kernel): skip the mask passes
+    interior = ((ik + 1) * bk - 1 <= iq * bq) & ((ik + 1) * bk <= seq_len)
 
     def _update(mask, rows=ALL, keys=ALL):
         # storage-dtype (bf16) matmul inputs + f32 accumulation — see the
@@ -827,9 +807,7 @@ def _bwd_dq_kernel(
             @pl.when(needed & ~interior)
             def _compute_masked():
                 q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-                mask = k_pos < seq_len
-                if causal:
-                    mask &= q_pos >= k_pos
+                mask = (k_pos < seq_len) & (q_pos >= k_pos)
                 if use_segments:
                     mask &= _segment_mask(qseg_ref, kseg_ref)
                 _update(_selected(sel_ref, ik, bq, bk, mask))
@@ -864,7 +842,6 @@ def _bwd_dkv_kernel(
     scale: float,
     use_segments: bool,
     exp_dtype: str = "float32",
-    causal: bool = True,
     diag_tiles: int = 1,
     window: int | None = None,
 ):
@@ -884,15 +861,10 @@ def _bwd_dkv_kernel(
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        # this q block contributes iff its last query can see the block's
-        # first key
-        needed = (iq + 1) * bq - 1 >= ik * bk
-        # all pairs causally valid AND no padded q rows: mask passes skippable
-        interior = ((ik + 1) * bk - 1 <= iq * bq) & ((iq + 1) * bq <= seq_len)
-    else:
-        needed = iq * bq < seq_len
-        interior = (iq + 1) * bq <= seq_len
+    # this q block contributes iff its last query can see the block's first key
+    needed = (iq + 1) * bq - 1 >= ik * bk
+    # all pairs causally valid AND no padded q rows: mask passes skippable
+    interior = ((ik + 1) * bk - 1 <= iq * bq) & ((iq + 1) * bq <= seq_len)
 
     def _update(mask, rows=ALL, keys=ALL):
         # storage-dtype (bf16) matmul inputs + f32 accumulation — see the
@@ -961,9 +933,7 @@ def _bwd_dkv_kernel(
             @pl.when(masked)
             def _compute_masked():
                 q_pos, k_pos = _block_positions(iq, ik, bq, bk)
-                mask = q_pos < seq_len
-                if causal:
-                    mask &= q_pos >= k_pos
+                mask = (q_pos < seq_len) & (q_pos >= k_pos)
                 if use_segments:
                     mask &= _segment_mask(qseg_ref, kseg_ref)
                 _update(_selected(sel_ref, ik, bq, bk, mask))
@@ -983,7 +953,7 @@ def _bwd_dkv_kernel(
 def _flash_backward(
     q, k, v, segment_ids, out, lse, g,
     *, block_q: int, block_k: int, interpret: bool, use_segments: bool = True,
-    exp_dtype: str = "float32", causal: bool = True, dlse=None,
+    exp_dtype: str = "float32", dlse=None,
     kv_segment_ids=None, selection=None, sink=None, window=None,
 ):
     """``(dq, dk, dv, dsink)``; ``dsink`` None where no sink is given.  The
@@ -998,7 +968,7 @@ def _flash_backward(
 
     bq = min(block_q, s)
     bk = min(block_k, s)
-    _check_window(window, bq, bk, causal, selection)
+    _check_window(window, bq, bk, selection)
     q_p, k_p, v_p, seg_p, kseg_p, s_pad = _pad_inputs(
         q, k, v, segment_ids, bq, bk, kv_segment_ids)
     g_p = jnp.pad(g, [(0, 0), (0, s_pad - s), (0, 0), (0, 0)]) if s_pad != s else g
@@ -1034,10 +1004,10 @@ def _flash_backward(
     nq = pl.cdiv(s_pad, bq)
     nk = pl.cdiv(s_pad, bk)
 
-    diag_tiles = _diag_tiles(bq, bk, causal)
+    diag_tiles = _diag_tiles(bq, bk)
 
-    kv = functools.partial(_kv_block_index, bq=bq, bk=bk, causal=causal)
-    qb = functools.partial(_q_block_index, nq=nq, bq=bq, bk=bk, causal=causal)
+    kv = functools.partial(_kv_block_index, bq=bq, bk=bk)
+    qb = functools.partial(_q_block_index, nq=nq, bq=bq, bk=bk)
     # the inner axes: every key block (dQ), every q block a group member (dK/dV)
     dq_inner, dkv_inner, dq_kw, dkv_kw = nk, nq, {}, {}
     if window is not None:
@@ -1059,7 +1029,7 @@ def _flash_backward(
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, seq_len=s, scale=scale,
                           use_segments=use_segments, exp_dtype=exp_dtype,
-                          causal=causal, diag_tiles=diag_tiles, **dq_kw),
+                          diag_tiles=diag_tiles, **dq_kw),
         grid=(b, h, nq, dq_inner),
         in_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
@@ -1088,7 +1058,7 @@ def _flash_backward(
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, n_q_blocks=dkv_inner, seq_len=s, scale=scale,
-            use_segments=use_segments, exp_dtype=exp_dtype, causal=causal,
+            use_segments=use_segments, exp_dtype=exp_dtype,
             diag_tiles=diag_tiles, **dkv_kw,
         ),
         grid=(b, hkv, nk, group * dkv_inner),
@@ -1149,32 +1119,31 @@ def _flash_backward(
 #
 # One vjp serves both public surfaces: the plain out-only path (a dropped
 # lse output gets a zero cotangent, and dlse=0 leaves the backward's delta
-# untouched — identical gradients) and the ring-attention inner, which
-# merges per-step partials across hops via their per-row logsumexp and
-# needs lse differentiable. The lse cotangent folds into the backward's
-# delta (see _flash_backward), keeping one backward implementation.
+# untouched — identical gradients) and a caller that merges partial results
+# through their per-row logsumexp and needs lse differentiable. The lse
+# cotangent folds into the backward's delta (see _flash_backward), keeping
+# one backward implementation.
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
 def _flash_attention_lse(q, k, v, segment_ids, kv_segment_ids, selection, sink,
                          block_q, block_k, interpret, use_segments, exp_dtype,
-                         causal, window):
+                         window):
     out, lse = _flash_forward(
         q, k, v, segment_ids, block_q=block_q, block_k=block_k,
         interpret=interpret, use_segments=use_segments, exp_dtype=exp_dtype,
-        causal=causal, kv_segment_ids=kv_segment_ids, selection=selection,
+        kv_segment_ids=kv_segment_ids, selection=selection,
         sink=sink, window=window,
     )
     return out, lse[:, :, : q.shape[1]]
 
 
 def _flash_lse_fwd(q, k, v, segment_ids, kv_segment_ids, selection, sink,
-                   block_q, block_k, interpret, use_segments, exp_dtype, causal,
-                   window):
+                   block_q, block_k, interpret, use_segments, exp_dtype, window):
     out, lse = _flash_forward(
         q, k, v, segment_ids, block_q=block_q, block_k=block_k,
         interpret=interpret, use_segments=use_segments, exp_dtype=exp_dtype,
-        causal=causal, kv_segment_ids=kv_segment_ids, selection=selection,
+        kv_segment_ids=kv_segment_ids, selection=selection,
         sink=sink, window=window,
     )
     # Named so a remat policy (models/llama.py remat_policy_fn, e.g.
@@ -1192,7 +1161,7 @@ def _flash_lse_fwd(q, k, v, segment_ids, kv_segment_ids, selection, sink,
 
 
 def _flash_lse_bwd(block_q, block_k, interpret, use_segments, exp_dtype,
-                   causal, window, residuals, g):
+                   window, residuals, g):
     g_out, g_lse = g
     q, k, v, segment_ids, kv_segment_ids, selection, sink, out, lse = residuals
     s_pad = lse.shape[2]
@@ -1204,7 +1173,7 @@ def _flash_lse_bwd(block_q, block_k, interpret, use_segments, exp_dtype,
     dq, dk, dv, dsink = _flash_backward(
         q, k, v, segment_ids, out, lse, g_out,
         block_q=block_q, block_k=block_k, interpret=interpret,
-        use_segments=use_segments, exp_dtype=exp_dtype, causal=causal,
+        use_segments=use_segments, exp_dtype=exp_dtype,
         dlse=dlse, kv_segment_ids=kv_segment_ids, selection=selection,
         sink=sink, window=window,
     )
@@ -1224,19 +1193,17 @@ def flash_attention_with_lse(
     selection: jax.Array | None = None,
     sink: jax.Array | None = None,
     window: int | None = None,
-    causal: bool = True,
     block_q: int | None = None,
     block_k: int | None = None,
     interpret: bool | None = None,
     exp_dtype: str | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Flash attention returning ``(out, lse)`` with ``lse`` (B, H, S, 1) f32.
+    """Causal flash attention returning ``(out, lse)`` with ``lse`` (B, H, S,
+    1) f32.
 
-    ``causal=False`` computes full (bidirectional) attention — the ring
-    off-diagonal steps, where every resident key is in the query's global
-    past. ``kv_segment_ids`` (default: same as ``segment_ids``) supports the
-    ring case where the resident K/V shard carries segments from another
-    sequence shard. ``selection`` (``ops/attention.py::pack_selection``,
+    ``kv_segment_ids`` (default: same as ``segment_ids``) gives the keys
+    segment ids of their own (no caller in the program passes it: ROADMAP.md
+    C3). ``selection`` (``ops/attention.py::pack_selection``,
     (B, S, W) int32) cuts every query, in all its heads, to its own set of
     keys: one more operand of the three kernels, which mask by it; with none
     they trace the bodies they always did.  ``window`` (a static count of keys:
@@ -1262,7 +1229,7 @@ def flash_attention_with_lse(
     return _flash_attention_lse(
         q, k, v, segment_ids.astype(jnp.int32),
         kv_segment_ids.astype(jnp.int32), selection, sink, block_q, block_k,
-        interpret, use_segments, exp_dtype, causal, window,
+        interpret, use_segments, exp_dtype, window,
     )
 
 

@@ -136,101 +136,6 @@ def _ring_attention_local(
     return out.astype(q.dtype)
 
 
-def _ring_attention_local_flash(
-    q: jax.Array,            # (B, S_local, H, D)
-    k: jax.Array,
-    v: jax.Array,
-    segment_ids: jax.Array,  # (B, S_local)
-    *,
-    axis_name: str,
-    have_segments: bool = True,
-) -> jax.Array:
-    """Ring attention with the PALLAS flash kernel as the per-step inner.
-
-    The XLA inner (:func:`_ring_attention_local`) materialises the
-    (S_local, S_local) score block in HBM every hop; this inner streams it
-    through VMEM instead (``ops.pallas.flash_attention``) and merges the
-    per-hop partial results through their per-row logsumexp — the standard
-    flash-combine identity::
-
-        lse = logaddexp(lse_a, lse_b)
-        out = exp(lse_a - lse) * out_a + exp(lse_b - lse) * out_b
-
-    Step 0 is always the device's own (diagonal) block — locally causal;
-    every later hop holds a shard that is globally either entirely past
-    (full attention) or entirely future (skipped) for a causal ring layout.
-    The lse cotangent is differentiable end-to-end (the kernel's
-    ``custom_vjp`` folds it into the backward's delta term).
-    """
-    # blocks and exp dtype are the kernel's defaults (_resolve_tuning), which
-    # also caps blocks to the per-hop length
-    from ..ops.pallas.flash_attention import flash_attention_with_lse as flash
-
-    n = axis_size(axis_name)
-    i = jax.lax.axis_index(axis_name)
-    b, s_local, h, d = q.shape
-
-    # segmentless corpora must not pay the per-interior-block segment-mask
-    # VPU pass — the kernel compiles it out when given no segment ids
-    qseg = segment_ids if have_segments else None
-
-    # step 0: the diagonal block — locally causal, local segments both sides
-    out0, lse0 = flash(q, k, v, segment_ids=qseg,
-                       kv_segment_ids=qseg, causal=True)
-    perm = [(j, (j + 1) % n) for j in range(n)]
-    rot = lambda o: jax.lax.ppermute(o, axis_name, perm)
-    carry0 = (
-        out0.astype(jnp.float32),
-        lse0,                                    # (B, H, S_local, 1) f32
-        rot(k), rot(v), rot(segment_ids),
-    )
-
-    def step(t, carry):
-        out_acc, lse_acc, k_blk, v_blk, kseg_blk = carry
-        src = (i - t) % n                        # whose K/V shard we hold
-
-        def useful(ops):
-            k_, v_, ks_ = ops
-            o, l = flash(
-                q, k_, v_,
-                segment_ids=qseg,
-                kv_segment_ids=ks_ if have_segments else None,
-                causal=False,
-            )
-            return o.astype(jnp.float32), l
-
-        def skipped(ops):
-            return (
-                jnp.zeros((b, s_local, h, d), jnp.float32),
-                jnp.full((b, h, s_local, 1), NEG_INF, jnp.float32),
-            )
-
-        # globally-past shard contributes; globally-future contributes nothing
-        out_i, lse_i = jax.lax.cond(src < i, useful, skipped,
-                                    (k_blk, v_blk, kseg_blk))
-
-        m = jnp.maximum(lse_acc, lse_i)
-        w_acc = jnp.exp(lse_acc - m)
-        w_i = jnp.exp(lse_i - m)
-        denom = w_acc + w_i
-        lse_new = m + jnp.log(denom)
-        # weights are (B, H, S, 1); outputs are (B, S, H, D)
-        wa = w_acc.transpose(0, 2, 1, 3)
-        wi = w_i.transpose(0, 2, 1, 3)
-        out_new = (out_acc * wa + out_i * wi) / denom.transpose(0, 2, 1, 3)
-
-        k_nxt, v_nxt, kseg_nxt = jax.lax.cond(
-            t < n - 1,
-            lambda ops: tuple(rot(o) for o in ops),
-            lambda ops: ops,
-            (k_blk, v_blk, kseg_blk),
-        )
-        return out_new, lse_new, k_nxt, v_nxt, kseg_nxt
-
-    out, *_ = jax.lax.fori_loop(1, n, step, carry0)
-    return out.astype(q.dtype)
-
-
 def ring_attention_sharded(
     q: jax.Array,
     k: jax.Array,
@@ -239,18 +144,15 @@ def ring_attention_sharded(
     segment_ids: jax.Array | None = None,
     mesh: Mesh | None = None,
     axis_name: str = AxisNames.SEQ,
-    inner: str = "xla",
 ) -> jax.Array:
     """Causal GQA attention with S sharded over ``axis_name``.
 
     Global shapes as ``ops.attention.causal_attention``; S must divide by the
     axis size. Batch stays sharded over the batch axes, heads replicated
     across ``sp`` (Ulysses-style head-sharding would instead all-to-all here).
-
-    ``inner`` picks the per-hop block kernel: ``"xla"`` (einsum + masked
-    softmax — materialises the (S/n)² score block per hop) or ``"flash"``
-    (Pallas streaming kernel + logsumexp merge; reached only from tests
-    until it has compiled for a chip and a cell measures it — ROADMAP.md C8).
+    The per-hop block kernel is einsum + masked softmax: it materialises the
+    (S/n)² score block per hop (a streaming inner comes with the four-chip
+    ``sp`` cell that measures it — ROADMAP.md C8).
     """
     mesh = mesh or _ring_mesh
     if mesh is None:
@@ -259,27 +161,16 @@ def ring_attention_sharded(
         from ..ops.attention import xla_causal_attention
 
         return xla_causal_attention(q, k, v, segment_ids=segment_ids)
-    have_segments = segment_ids is not None
     if segment_ids is None:
         segment_ids = jnp.zeros(q.shape[:2], jnp.int32)
-    if inner not in ("xla", "flash"):
-        raise ValueError(f"unknown ring inner {inner!r}: expected xla or flash")
-    local = (
-        partial(_ring_attention_local_flash, have_segments=have_segments)
-        if inner == "flash"
-        else _ring_attention_local
-    )
 
     qkv_spec = P(AxisNames.BATCH_AXES, axis_name, None, None)
     seg_spec = P(AxisNames.BATCH_AXES, axis_name)
 
     fn = shard_map(
-        partial(local, axis_name=axis_name),
+        partial(_ring_attention_local, axis_name=axis_name),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec),
         out_specs=qkv_spec,
-        # the pallas_call inside the flash inner declares no vma on its
-        # out_shapes, so the static varying-axes checker can't track it
-        check_vma=inner != "flash",
     )
     return fn(q, k, v, segment_ids.astype(jnp.int32))

@@ -10,9 +10,8 @@ sequence for its head subset, and a second all-to-all converts back.
 
 Trade-offs (why both strategies exist):
 
-* Ulysses does a single fused attention per device (the Pallas kernel at
-  full sequence length — best MXU shape, no per-hop merge math) at the cost
-  of two all-to-alls of the activations; ring never moves Q/out but moves
+* Ulysses does a single attention per device over the full sequence length
+  (no per-hop merge math) at the cost of two all-to-alls of the activations; ring never moves Q/out but moves
   K+V (n-1) times and fragments attention into n blocks.
 * Ulysses caps at ``sp | n_kv_heads`` (each device needs whole KV heads —
   GQA group alignment); ring has no head constraint. A 2-level hierarchy
@@ -49,10 +48,8 @@ def _ulysses_local(
     *,
     axis_name: str,
     have_segments: bool,
-    impl: str,
 ) -> jax.Array:
     from ..ops.attention import xla_causal_attention
-    from ..ops.pallas.flash_attention import flash_attention
 
     # seq-shard -> head-shard: split the head axis across sp, gather the
     # sequence axis (tiled all-to-all = the Ulysses/DeepSpeed layout swap)
@@ -68,10 +65,9 @@ def _ulysses_local(
         if have_segments else None
     )
 
-    # a shard_map body: the kernels are called directly (the Mosaic call
-    # bare), past the dispatch that would see the sp axis and choose "ring"
-    attend = flash_attention if impl == "pallas" else xla_causal_attention
-    out_h = attend(q_h, k_h, v_h, segment_ids=seg)
+    # a shard_map body: the kernel is called directly, past the dispatch
+    # that would see the sp axis and choose "ring"
+    out_h = xla_causal_attention(q_h, k_h, v_h, segment_ids=seg)
 
     # head-shard -> seq-shard: the inverse all-to-all
     return jax.lax.all_to_all(
@@ -87,22 +83,15 @@ def ulysses_attention_sharded(
     segment_ids: jax.Array | None = None,
     mesh: Mesh | None = None,
     axis_name: str = AxisNames.SEQ,
-    impl: str = "xla",
 ) -> jax.Array:
     """Causal GQA attention, S sharded over ``axis_name`` via head all-to-all.
 
     Global shapes as ``ops.attention.causal_attention``. Requires
     ``axis_size | n_kv_heads`` (and hence ``| n_heads``); callers wanting
-    more sp than KV heads should use ring attention. ``impl`` picks the
-    local kernel ("xla" | "pallas" — full-sequence shapes make the flash
-    kernel's streaming exactly as effective as in the unsharded case).
+    more sp than KV heads should use ring attention.  The local kernel is
+    ``xla_causal_attention`` (a flash inner comes with the four-chip ``sp``
+    cell that measures it — ROADMAP.md C8).
     """
-    if impl not in ("xla", "pallas"):
-        # re-entering a sharded impl ("ring"/"ulysses") inside shard_map
-        # would trace a nested shard_map and die with an opaque mesh error
-        raise ValueError(
-            f"unknown ulysses local kernel {impl!r}: expected xla or pallas"
-        )
     mesh = mesh or get_ring_mesh()
     if mesh is None:
         raise ValueError(
@@ -128,12 +117,9 @@ def ulysses_attention_sharded(
     seg_spec = P(AxisNames.BATCH_AXES, axis_name)
     fn = shard_map(
         partial(_ulysses_local, axis_name=axis_name,
-                have_segments=have_segments, impl=impl),
+                have_segments=have_segments),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec, seg_spec),
         out_specs=qkv_spec,
-        # only the pallas inner defeats the varying-axes checker (its
-        # out_shapes carry no vma); keep the static check for the XLA inner
-        check_vma=impl != "pallas",
     )
     return fn(q, k, v, segment_ids.astype(jnp.int32))

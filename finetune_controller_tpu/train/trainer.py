@@ -28,7 +28,7 @@ from flax.core import FrozenDict
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import platform
-from ..models.llama import LlamaConfig, LlamaForCausalLM
+from ..models.llama import SOWN, LlamaConfig, LlamaForCausalLM
 from ..obs import trace as obs_trace
 from ..obs.trace import annotate
 from ..ops.attention import resolve_attention_impl
@@ -295,23 +295,11 @@ class Trainer:
             self.model = LlamaForCausalLM(model_cfg)
 
         self._pp = self.mesh.shape.get("pp", 1)
-        if (getattr(model_cfg, "ssm_d_inner", 0)
-                or getattr(model_cfg, "layer_pattern", "")) and (
-                self._pp > 1 or self.mesh.shape.get("sp", 1) > 1):
-            raise ValueError(
-                "a model with a state-space mixer, or one that is a pattern "
-                "of layer kinds, trains with sp = pp = 1: a scan over a split "
-                "sequence needs a state hand-off between members, and the "
-                "pipeline's stage body holds neither the mixer nor a stage of "
-                "unlike layers (ROADMAP.md B10, B13)")
+        model_cfg.refuse_mesh(dict(self.mesh.shape))
         if self._pp > 1:
             from ..parallel.pipeline import validate_pp_mesh
 
             validate_pp_mesh(self.mesh)
-            if self._is_multimodal or model_cfg.n_experts:
-                raise ValueError(
-                    "pipeline parallelism currently supports dense text models"
-                )
             if not model_cfg.scan_layers:
                 raise ValueError("pp > 1 requires scan_layers=True (stacked params)")
             if model_cfg.n_layers % self._pp:
@@ -366,11 +354,10 @@ class Trainer:
     def _split(self, variables: FrozenDict) -> tuple[Any, Any]:
         """(frozen, trainable) per the training mode."""
         variables = dict(variables)
-        # drop the init-time sown aux collection: re-feeding it to apply would
-        # make flax append to the stale tuple and double-count the MoE aux loss
-        variables.pop("moe_aux", None)
-        variables.pop("moe_stats", None)
-        variables.pop("dsa_stats", None)
+        # drop what init sowed: re-feeding a collection to apply would make
+        # flax append to the stale tuple and double-count it
+        for collection in SOWN:
+            variables.pop(collection, None)
         if self.cfg.mode == "lora":
             if "lora" not in variables:
                 raise ValueError("mode='lora' but the model has no LoRA params; set lora.rank > 0")
@@ -431,18 +418,14 @@ class Trainer:
     def _cast_frozen(self, frozen: Any) -> Any:
         """Downcast float32 leaves of the frozen base to ``cfg.frozen_dtype``
         (lora mode only — full fine-tune keeps f32 master weights). Int4
-        packed kernels and their scales pass through untouched (non-f32
-        dtypes; the ``scales`` name guard is belt-and-braces for future
-        f32-scaled quant formats)."""
+        packed kernels pass through untouched (non-f32 dtypes), and so does
+        every leaf the model says keeps its dtype (``keeps_dtype``)."""
         if not self.cfg.frozen_dtype or self.cfg.mode != "lora":
             return frozen
         dt = jnp.dtype(self.cfg.frozen_dtype)
 
         def cast(path, x):
-            name = str(path[-1]) if path else ""
-            # a window layer's sink stays float32: a softmax logit, 64 numbers
-            sink = len(path) > 1 and "sink" in str(path[-2])
-            if "scales" in name or x.dtype != jnp.float32 or sink:
+            if x.dtype != jnp.float32 or self.model_cfg.keeps_dtype(path):
                 return x
             return x.astype(dt)
 
@@ -617,35 +600,20 @@ class Trainer:
         )
         if self._is_multimodal:
             apply_kw["pixels"] = batch.get("pixels")
-        aux_penalty = 0.0
-        sparse = getattr(self.model_cfg, "index_topk", 0)
-        if self.model_cfg.n_experts or sparse:
-            from ..models.moe import moe_aux_loss, moe_counters
-
+        sown = self.model_cfg.sown
+        if sown:
             logits, collections = self.model.apply(
-                variables, batch["tokens"],
-                mutable=("moe_aux", "moe_stats", "dsa_stats"), **apply_kw
-            )
-            # a model balanced by a selection bias has no auxiliary loss and
-            # sows none: the collection is skipped, not read as zero
-            aux_weight = self.model_cfg.router_aux_weight
-            if aux_weight:
-                aux_penalty = aux_weight * moe_aux_loss(collections)
+                variables, batch["tokens"], mutable=sown, **apply_kw)
         else:
             logits = self.model.apply(variables, batch["tokens"], **apply_kw)
+            collections = {}
         loss, metrics = next_token_loss(
             logits, batch["tokens"], batch.get("loss_mask")
         )
-        if self.model_cfg.n_experts:
-            metrics = dict(metrics, **jax.lax.stop_gradient(
-                moe_counters(collections)))
-            if aux_weight:
-                metrics["moe_aux"] = aux_penalty
-        if sparse:
-            from ..models.llama import dsa_counters
-
-            metrics = dict(metrics, **dsa_counters(collections))
-        return loss + aux_penalty, metrics
+        # what the model makes of what its pass sowed: a term of the loss and
+        # counters among the step's metrics
+        aux_penalty, counters = self.model_cfg.sown_readings(collections)
+        return loss + aux_penalty, dict(metrics, **counters)
 
     def _train_step(self, state: TrainState, batch: dict):
         dropout_rng = jax.random.fold_in(jax.random.PRNGKey(self.cfg.seed), state.step)
@@ -725,10 +693,10 @@ class Trainer:
         )
         if self._is_multimodal:
             apply_kw["pixels"] = batch.get("pixels")
-        if self.model_cfg.n_experts:
+        sown = self.model_cfg.sown_in_eval
+        if sown:
             logits, _ = self.model.apply(
-                variables, batch["tokens"], mutable=("moe_aux",), **apply_kw
-            )
+                variables, batch["tokens"], mutable=sown, **apply_kw)
         else:
             logits = self.model.apply(variables, batch["tokens"], **apply_kw)
         _, metrics = next_token_loss(
@@ -1664,141 +1632,25 @@ class Trainer:
     def _runtime_attrs(self) -> dict:
         """Where this run landed, for the ``train-started`` event: the device
         as JAX reports it, the mesh, which attention implementation the step
-        resolves to (with the flash kernels, how much score area they compute
-        over what the causal triangle needs), the row tile of a dropless
-        expert model's grouped products where the Pallas kernel runs, a pattern
-        model's string, layers by kind, latent width and experts held (a
-        pattern of attention kinds: its string, layers by kind, window, sink
-        layers and the window kernels' work over their need), a hybrid
-        model's state-space mixers (layers, chunks a row, state bytes a row),
-        which adapted projections carry their adapter inside the base
-        product, and the bytes the freshly-initialised state holds on each local device.  The control plane (and ``chip_smoke.py``) stays
-        off JAX and learns the device from this."""
+        resolves to, the bytes the freshly-initialised state holds on each
+        local device — and what the model says of itself for this job's sizes
+        (``run_description``: ``docs/observability.md`` has the counters by
+        the module that owns each).  The control plane (and ``chip_smoke.py``)
+        stays off JAX and learns the device from this."""
         from ..platform import device_report
 
-        attrs = {
+        return {
             **device_report(),
             "mesh": {k: v for k, v in self.mesh.shape.items() if v > 1},
             "attention_impl": self.attention_impl,
             "device_state_bytes": self._device_bytes("bytes_in_use"),
-        }
-        # the decoder's configuration (a multimodal model's is its ``text``)
-        cfg = getattr(self.model_cfg, "text", self.model_cfg)
-        if self.attention_impl == "pallas":
-            from ..ops.pallas.flash_attention import causal_work_over_need
-
-            # score area the flash kernels compute over the causal triangle's
-            attrs["flash_causal_work_over_need"] = causal_work_over_need(
-                self.cfg.seq_len, head_widths=cfg.head_widths)
-        if cfg.n_experts and cfg.moe_dispatch == "dropless":
-            from ..models.moe import (
-                dropless_row_tile, held_row_bound, held_sum_form)
-            from ..parallel.ring import ring_mesh
-
-            # the grouped expert products' row tile (``moe_gmm_work_over_need``
-            # among the step's counters is what it costs), where the Pallas
-            # kernel runs: asked as the step's trace asks, under its mesh
-            tokens = (self.cfg.batch_size // self.cfg.grad_accum_steps
-                      * self.cfg.seq_len)
-            pairs = tokens * cfg.moe_top_k
-            held = (cfg.experts_held or (0, cfg.n_experts))[1]
-            with ring_mesh(self.mesh):
-                tile = dropless_row_tile(pairs, held, cfg.n_experts)
-            if tile:
-                attrs["moe_gmm_row_tile"] = tile
-            if held != cfg.n_experts:
-                # a held share: the rows of one pass over the routed pairs,
-                # and the form its per-token sums run in for those shapes
-                bound = held_row_bound(pairs, held, cfg.n_experts)
-                attrs["moe_held_sum_form"] = held_sum_form(
-                    tokens, cfg.moe_top_k, bound)
-                attrs["moe_held_rows_over_pairs"] = bound / pairs
-        if cfg.lora.rank > 0 and self._pp == 1 and not self._is_multimodal:
-            attrs["lora_joined_projections"] = self._lora_joined_projections()
-        kinds = cfg.indexer_kinds()
-        if kinds:
-            attrs["dsa_full_layers"] = kinds.count("full")
-            attrs["dsa_shared_layers"] = kinds.count("shared")
-        from ..models.llama import BLOCK_KINDS
-
-        if set(cfg.layer_pattern) & set(BLOCK_KINDS):
-            # a pattern of whole blocks by their attention's kind: the string
-            # as built, the layers of each kind, the window's keys, the
-            # layers that hold a sink, the experts held and, with the flash
-            # kernels, the score area a window call computes over its need
-            pattern = cfg.layer_pattern
-            attrs["attention_pattern"] = pattern
-            attrs["attention_layers_by_kind"] = {
-                kind: pattern.count(kind) for kind in sorted(set(pattern))}
-            attrs["attention_window"] = cfg.sliding_window
-            attrs["attention_sink_layers"] = (
-                pattern.count("W") if cfg.window_sink else 0)
-            if cfg.n_experts:
-                attrs["moe_experts_held"] = (
-                    cfg.experts_held or (0, cfg.n_experts))[1]
-            if self.attention_impl == "pallas" and "W" in pattern:
-                from ..ops.pallas.flash_attention import window_work_over_need
-
-                attrs["flash_window_work_over_need"] = window_work_over_need(
-                    self.cfg.seq_len, cfg.sliding_window,
-                    head_widths=cfg.head_widths)
-        elif cfg.layer_pattern:
-            # a pattern model: the string as built, its layers by kind, the
-            # width of the latent its experts live in and the experts held
-            attrs["layer_pattern"] = cfg.layer_pattern
-            attrs["layers_by_kind"] = {
-                kind: cfg.layer_pattern.count(kind)
-                for kind in sorted(set(cfg.layer_pattern))}
-            if "E" in cfg.layer_pattern:
-                attrs["moe_latent_width"] = cfg.moe_latent
-                attrs["moe_experts_held"] = (
-                    cfg.experts_held or (0, cfg.n_experts))[1]
-        if cfg.ssm_d_inner:
-            from ..ops.pallas.ssd_scan import ssd_scan_impl
-            from ..parallel.ring import ring_mesh
-
-            # the state-space mixers: how many, the chain of chunk states a
-            # row's scan walks in each, and the float32 state a row carries
-            attrs["ssm_layers"] = (cfg.layer_pattern.count("M")
-                                   if cfg.layer_pattern else cfg.n_layers)
-            attrs["ssm_chunks_per_row"] = -(-self.cfg.seq_len // cfg.ssm_chunk)
-            attrs["ssm_state_bytes_per_row"] = (
-                4 * cfg.ssm_n_heads * cfg.ssm_head_dim * cfg.ssm_d_state)
-            # the form the recurrence runs in (the Pallas kernels | the plain
-            # ``jnp`` one) and the heads a step of the kernels' grid holds:
-            # asked as the step's trace asks, under its mesh
-            with ring_mesh(self.mesh):
-                attrs["ssm_scan_impl"], attrs["ssm_scan_heads_per_block"] = (
-                    ssd_scan_impl(cfg.ssm_n_heads, cfg.ssm_head_dim,
-                                  cfg.ssm_n_groups, cfg.ssm_d_state,
-                                  cfg.ssm_chunk))
-        return attrs
-
-    def _lora_joined_projections(self) -> dict:
-        """How many of the decoder's adapted projections carry their adapter
-        inside the base product, of how many, and which keep the two apart:
-        ``models/lora.py::joins_base_product`` asked as the step's trace asks
-        it — at each adapter's own widths, a microbatch's tokens, under the
-        step's mesh."""
-        from ..models import lora
-        from ..parallel.ring import ring_mesh
-
-        with ring_mesh(self.mesh):
-            devices, sharded = lora.mesh_splits()
-        rows = (self.cfg.batch_size // self.cfg.grad_accum_steps
-                * self.cfg.seq_len // devices)
-        joined = {}
-        for path, leaf in jax.tree_util.tree_leaves_with_path(
-                self._state_shapes.trainable):
-            *module, name = (k.key for k in path)
-            if name == "lora_a":    # [(layers,) in, r]
-                joined["/".join(module)] = not self._use_dropout and (
-                    lora.joins_base_product(
-                        rows, leaf.shape[-2], leaf.shape[-1], sharded=sharded))
-        return {
-            "joined": sum(joined.values()),
-            "of": len(joined),
-            "apart": sorted(p for p, j in joined.items() if not j),
+            **self.model_cfg.run_description(
+                seq_len=self.cfg.seq_len,
+                tokens_per_microbatch=(
+                    self.cfg.batch_size // self.cfg.grad_accum_steps
+                    * self.cfg.seq_len),
+                attention_impl=self.attention_impl, mesh=self.mesh,
+                adapters=self._state_shapes.trainable),
         }
 
     def _device_bytes(self, stat: str) -> list[int] | None:

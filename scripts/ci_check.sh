@@ -10,8 +10,9 @@
 # (docs/static_analysis.md).  The v2 run includes the project-wide pass
 # (call graph, lock discipline, RPC/metric conformance) under a 10s
 # wall-clock budget so the interprocedural engine can never rot into a
-# slow gate (budget also asserted, more precisely, in
-# tests/test_project_analysis.py).
+# slow gate.  The clock is held HERE, where the lint runs alone;
+# tests/test_project_analysis.py asserts the work that keeps it there (one
+# index, every file parsed once), which a loaded test machine cannot move.
 # Serve-fast: the continuous-batching inference suite (docs/serving.md) —
 # batching invariance is THE serving correctness anchor, and a broken
 # engine should fail in seconds, before the full tier-1 wall-clock.

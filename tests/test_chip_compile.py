@@ -780,3 +780,37 @@ def test_quantised_projection_holds_no_float32_kernel(v5e, shape, which):
         assert len(x_copies) == (shape == "up-4096x14336"), x_copies
         acts = len(x_copies) * 2 * 8 * 2048 * in_f
     assert temp - acts < 470e6 / 4, temp
+
+
+# ---------------------------------------------------------------------------
+# the proof a refactor rests on: a cell's step, lowered and hashed
+# ---------------------------------------------------------------------------
+
+
+def test_step_hash_follows_the_program_and_nothing_else(v5e):
+    """``scripts/step_hash.py``: the tiny fixture's training cell lowered
+    twice for the described v5e gives ONE hash, and a model with a layer more
+    another — so an equal pair of hashes at two commits says the step's
+    program did not change, and a changed program cannot hide."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from benchmarks.harness.manifest import Manifest
+
+    spec = importlib.util.spec_from_file_location(
+        "step_hash", root / "scripts/step_hash.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    manifest = Manifest(root / "tests/benchmarks/fixtures/BENCHMARK.tiny.json")
+    cell = "tiny-qlora.train-tiny"
+    first = tool.step_hash(manifest, cell)
+    assert first == tool.step_hash(manifest, cell)
+    assert jax.default_backend() == "cpu"      # the tool put it back
+    config = manifest.config
+    manifest.config = lambda name: dict(
+        config(name), num_hidden_layers=config(name)["num_hidden_layers"] + 1)
+    assert tool.step_hash(manifest, cell) != first

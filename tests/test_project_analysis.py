@@ -16,7 +16,6 @@ Four layers, mirroring ``tests/test_lint_rules.py``'s fixture discipline:
 
 import json
 import textwrap
-import time
 from pathlib import Path
 
 import pytest
@@ -855,11 +854,34 @@ def test_list_rules_includes_project_rules(capsys):
         assert rid in out
 
 
-def test_full_v2_pass_fits_the_ci_wall_clock_budget():
-    """scripts/ci_check.sh gives the lint stage 10 s for the whole package;
-    the interprocedural pass must not rot into a slow gate."""
-    t0 = time.perf_counter()
+def test_full_v2_pass_fits_the_ci_wall_clock_budget(monkeypatch):
+    """scripts/ci_check.sh gives the lint stage 10 s for the whole package,
+    where it runs alone; what keeps the pass inside that is asserted here as
+    WORK, which a loaded machine cannot move: the package is indexed once
+    and every file of it parsed once — the project index is the per-file
+    pass's parse cache, and no rule builds an index of its own."""
+    import ast
+    import collections
+
+    from finetune_controller_tpu.analysis import project
+
+    parsed: collections.Counter = collections.Counter()
+    indexed = []
+    parse, build = ast.parse, project.build_project
+
+    def counting_parse(source, filename="<unknown>", *args, **kwargs):
+        parsed[str(filename)] += 1
+        return parse(source, filename, *args, **kwargs)
+
+    def counting_build(*args, **kwargs):
+        indexed.append(args)
+        return build(*args, **kwargs)
+
+    monkeypatch.setattr(ast, "parse", counting_parse)
+    monkeypatch.setattr(project, "build_project", counting_build)
     result = lint_paths([str(PKG)])
-    elapsed = time.perf_counter() - t0
     assert result.errors == []
-    assert elapsed < 10.0, f"ftc-lint v2 took {elapsed:.1f}s on the package"
+    assert len(indexed) == 1
+    files = {str(p) for p in PKG.rglob("*.py")}
+    assert len(files) > 100 and set(parsed) == files
+    assert set(parsed.values()) == {1}, parsed.most_common(3)

@@ -141,7 +141,7 @@ def test_flash_diagonal_blocks_match_xla(monkeypatch, case):
     shape, (bq, bk), bounds, tiles = (
         (dict(s=64), (32, 32), (), 4) if masked_rows else DIAGONAL_CASES[case])
     q, k, v = _qkv(**shape)
-    assert fa._diag_tiles(bq, bk, True) == tiles
+    assert fa._diag_tiles(bq, bk) == tiles
     if masked_rows:
         # queries 36..43 (inside a diagonal block) are of a segment no key has
         seg = jnp.where((jnp.arange(64) >= 36) & (jnp.arange(64) < 44), 7, 0)
@@ -195,22 +195,20 @@ def test_flash_excluded_steps_name_a_resident_block(seq):
     excluded = 0
     for iq in range(n):
         for ik in range(n):
-            named = int(_kv_block_index(iq, ik, B, B, True))
+            named = int(_kv_block_index(iq, ik, B, B))
             if ik <= iq:
                 assert named == ik
             else:
                 excluded += 1
-                assert named == int(_kv_block_index(iq, ik - 1, B, B, True)) == iq
-            assert _kv_block_index(iq, ik, B, B, False) == ik
+                assert named == int(_kv_block_index(iq, ik - 1, B, B)) == iq
     assert excluded == n * (n - 1) // 2  # 1 of 4, 6 of 16, 28 of 64
     for ik in range(n):
         for j in reversed(range(group * n)):
-            named = int(_q_block_index(ik, j, n, B, B, True))
+            named = int(_q_block_index(ik, j, n, B, B))
             if j % n >= ik:
                 assert named == j % n
             else:
-                assert named == int(_q_block_index(ik, j + 1, n, B, B, True)) == ik
-            assert _q_block_index(ik, j, n, B, B, False) == j % n
+                assert named == int(_q_block_index(ik, j + 1, n, B, B)) == ik
 
 
 def test_flash_index_maps_with_blocks_that_differ():
@@ -227,10 +225,10 @@ def test_flash_index_maps_with_blocks_that_differ():
         for iq in range(nq):
             for ik in range(nk):
                 needed = ik * bk <= (iq + 1) * bq - 1
-                named = int(_kv_block_index(iq, ik, bq, bk, True))
+                named = int(_kv_block_index(iq, ik, bq, bk))
                 assert named == ik if needed else (
                     named < ik and named * bk <= (iq + 1) * bq - 1)
-                named = int(_q_block_index(ik, iq, nq, bq, bk, True))
+                named = int(_q_block_index(ik, iq, nq, bq, bk))
                 assert named == iq if needed else (
                     named > iq and (named + 1) * bq - 1 >= ik * bk)
 
@@ -258,24 +256,21 @@ def test_flash_whole_block_work_is_the_parents(monkeypatch):
 
 
 @pytest.mark.parametrize("call,dots", [
-    ("noncausal-1024", 4),       # masked + interior, whole blocks
-    ("causal-small-block", 4),   # falls back to the same two paths
+    ("causal-small-block", 4),   # masked + interior, whole blocks
     ("causal-1024", 16),         # 4 diagonal + 3 left updates, + interior
 ])
 def test_flash_forward_kernel_paths_by_products(call, dots):
-    """``causal=False`` (the ring / Ulysses off-diagonal hops) and blocks no
-    wider than the sub-tile trace the parent's forward kernel — two products
-    in each of its two paths — and only a sub-tiled causal call holds the
-    band loop's."""
+    """Blocks no wider than the sub-tile trace the whole-block forward kernel
+    — two products in each of its two paths — and only a sub-tiled call holds
+    the band loop's."""
     from finetune_controller_tpu.ops.pallas.flash_attention import (
         flash_attention_with_lse,
     )
 
-    causal = call != "noncausal-1024"
     s, block = (64, 16) if call == "causal-small-block" else (2048, None)
     q = jax.ShapeDtypeStruct((1, s, 2, 16), jnp.float32)
     text = str(jax.make_jaxpr(lambda q, k, v: flash_attention_with_lse(
-        q, k, v, causal=causal, block_q=block, block_k=block,
+        q, k, v, block_q=block, block_k=block,
         interpret=True))(q, q, q))
     assert text.count("dot_general") == dots
 
@@ -580,55 +575,6 @@ def test_qlora_model_trains_and_shrinks_memory(devices8):
     assert np.mean(losses[-5:]) < np.mean(losses[:5]) - 0.1, losses
 
 
-def test_ring_flash_inner_matches_xla_inner(devices8):
-    """The Pallas flash ring inner (per-hop streaming kernel + logsumexp
-    merge) matches both the XLA ring inner and the unsharded oracle,
-    forward and gradients, with packed-document segments."""
-    mesh = MeshSpec(dp=2, fsdp=1, sp=4).build(devices8)
-    q, k, v = _qkv(b=2, s=64)
-    seg = (jnp.arange(64)[None, :] // 24).astype(jnp.int32).repeat(2, 0)
-
-    ref = xla_causal_attention(q, k, v, segment_ids=seg)
-    out = ring_attention_sharded(
-        q, k, v, segment_ids=seg, mesh=mesh, inner="flash")
-    np.testing.assert_allclose(out, ref, atol=2e-5)
-
-    g_flash = jax.grad(
-        lambda q, k, v: (ring_attention_sharded(
-            q, k, v, segment_ids=seg, mesh=mesh, inner="flash") ** 2).sum(),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    g_ref = jax.grad(
-        lambda q, k, v: (xla_causal_attention(
-            q, k, v, segment_ids=seg) ** 2).sum(),
-        argnums=(0, 1, 2),
-    )(q, k, v)
-    for a, b in zip(g_flash, g_ref):
-        np.testing.assert_allclose(a, b, atol=1e-4)
-
-
-def test_flash_with_lse_full_attention_mode():
-    """causal=False kernel mode: full attention + differentiable lse."""
-    from finetune_controller_tpu.ops.pallas.flash_attention import (
-        flash_attention_with_lse,
-    )
-
-    q, k, v = _qkv(b=1, s=48)
-    out, lse = flash_attention_with_lse(
-        q, k, v, causal=False, block_q=16, block_k=16)
-    # full softmax reference
-    h, hkv = q.shape[2], k.shape[2]
-    g = h // hkv
-    qr = q.reshape(1, 48, hkv, g, -1) * q.shape[-1] ** -0.5
-    sc = jnp.einsum("bskgd,btkd->bkgst", qr, k).astype(jnp.float32)
-    ref_lse = jax.nn.logsumexp(sc, axis=-1, keepdims=True)
-    p = jnp.exp(sc - ref_lse)
-    ref = jnp.einsum("bkgst,btkd->bskgd", p, v).reshape(q.shape)
-    np.testing.assert_allclose(out, ref, atol=2e-5)
-    np.testing.assert_allclose(
-        lse, ref_lse.squeeze(-1).reshape(1, h, 48)[..., None], atol=2e-5)
-
-
 def test_ulysses_attention_matches_xla(devices8):
     """Ulysses SP (all-to-all head sharding) is bit-exact vs the unsharded
     oracle — the local kernel computes the same full-sequence attention."""
@@ -685,21 +631,3 @@ def test_ulysses_dispatch_through_model_config(devices8):
     )
     np.testing.assert_allclose(
         np.asarray(logits_u), np.asarray(logits_ref), atol=2e-4)
-
-
-def test_ring_unknown_inner_rejected(devices8):
-    mesh = MeshSpec(dp=2, fsdp=1, sp=4).build(devices8)
-    q, k, v = _qkv(b=2, s=64)
-    with pytest.raises(ValueError, match="unknown ring inner"):
-        ring_attention_sharded(q, k, v, mesh=mesh, inner="vulkan")
-
-
-def test_ulysses_unknown_local_kernel_rejected(devices8):
-    from finetune_controller_tpu.parallel.ulysses import (
-        ulysses_attention_sharded,
-    )
-
-    mesh = MeshSpec(dp=2, fsdp=1, sp=2).build(devices8[:4])
-    q, k, v = _qkv(b=2, s=64)
-    with pytest.raises(ValueError, match="unknown ulysses local kernel"):
-        ulysses_attention_sharded(q, k, v, mesh=mesh, impl="ring")
