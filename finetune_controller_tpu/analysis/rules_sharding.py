@@ -403,8 +403,8 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     import jax.numpy as jnp
 
     from ..models.llama import PRESETS, LlamaForCausalLM
-    from ..models.lora import (HYBRID_TARGETS, MLA_TARGETS, PATTERN_TARGETS,
-                               LoRAConfig)
+    from ..models.lora import (GATED_MIXER_TARGETS, HYBRID_TARGETS, MLA_TARGETS,
+                               PATTERN_TARGETS, LoRAConfig)
     from ..models.multimodal import MM_PRESETS, LlavaForCausalLM
 
     tokens = jnp.zeros((1, 8), jnp.int32)
@@ -443,6 +443,14 @@ def _validation_trees() -> dict[str, list[tuple[str, Any]]]:
     cfg_kinds = PRESETS["tiny-mimo-v2-test"].replace(lora=LoRAConfig(rank=4))
     out["tiny-mimo-v2-test+lora"] = _shape_leaves(
         LlamaForCausalLM(cfg_kinds), tokens
+    )
+    # whole blocks by their mixer's kind: an output gate beside q/k/v/o in
+    # both, q/k (and output) norm scales, every lightning head its own keys
+    cfg_mixers = PRESETS["tiny-minicpm-sala-test"].replace(
+        lora=LoRAConfig(rank=4, targets=GATED_MIXER_TARGETS)
+    )
+    out["tiny-minicpm-sala-test+lora"] = _shape_leaves(
+        LlamaForCausalLM(cfg_mixers), tokens
     )
     mm = MM_PRESETS["tiny-mm-test"].replace(lora=LoRAConfig(rank=4))
     pixels = jnp.zeros(

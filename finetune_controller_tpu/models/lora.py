@@ -74,6 +74,21 @@ PATTERN_TARGETS = (
 )
 
 
+#: the projections of a block of a sparse / lightning pattern
+#: (``models/llama.py::SparseAttention``, ``models/ssm.py::LightningMixer``):
+#: either mixer's four and its output gate, and the MLP's three
+GATED_MIXER_TARGETS = (
+    "q_proj",
+    "k_proj",
+    "v_proj",
+    "o_proj",
+    "o_gate",
+    "gate_proj",
+    "up_proj",
+    "down_proj",
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class LoRAConfig:
     rank: int = 0            # 0 disables LoRA (full fine-tune)

@@ -51,6 +51,12 @@ family's initialisation: decays near 1, a state that crosses many chunks.
 Training and evaluation only: ``decode=True`` raises (serving needs a state
 cache beside keys and values, ``ROADMAP.md`` B13), and so does a sequence
 split over ``sp`` (a scan over a split sequence needs a state hand-off).
+
+**Lightning linear attention is the same recurrence** (:class:`LightningMixer`,
+an ``L`` layer's mixer): ``S_t = lambda_h S_{t-1} + k_t^T v_t``, ``o_t = q_t
+S_t`` is ``x`` = v, ``B`` = k, ``C`` = q at a step size of 1, ``A_h = log
+lambda_h``, ``D`` = 0 — with every head its own ``B`` and ``C`` (``G = H``) and
+a decay fixed by the head's place, not learned.
 """
 
 from __future__ import annotations
@@ -63,7 +69,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.ssd_scan import ssd_scan, ssd_scan_impl
-from .llama import _Leaves, _proj, times
+from .llama import (_Leaves, _head_norm, _proj, apply_rope, gated_output,
+                    RMSNorm, times)
 
 
 def run_description(cfg, seq_len: int) -> dict:
@@ -83,6 +90,34 @@ def run_description(cfg, seq_len: int) -> dict:
         "ssm_scan_impl": impl,
         "ssm_scan_heads_per_block": heads,
     }
+
+
+def lightning_log_decay(heads: int) -> jax.Array:
+    """``log lambda_h = -2^(-8 h / H)``, ``h = 1 .. H``: Lightning
+    Attention-2's slopes, the same in every layer; float32."""
+    return -jnp.exp2(-8.0 * jnp.arange(1, heads + 1, dtype=jnp.float32) / heads)
+
+
+def lightning_run_description(cfg, seq_len: int) -> dict:
+    """What a model with lightning layers says of their scan at
+    ``train-started``: its form under the mesh in scope, the heads a step of
+    the kernels' grid holds, the chain of chunk states a row walks."""
+    impl, heads = ssd_scan_impl(
+        cfg.lightning_n_heads, cfg.lightning_head_dim, cfg.lightning_n_heads,
+        cfg.lightning_head_dim, cfg.ssm_chunk)
+    return {"lightning_scan_impl": impl, "lightning_heads_per_block": heads,
+            "lightning_chunks_per_row": -(-seq_len // cfg.ssm_chunk)}
+
+
+def _refuse_split_sequence(what: str) -> None:
+    """Raise under a mesh whose ``sp`` axis splits the rows."""
+    from ..parallel.ring import get_ring_mesh
+
+    mesh = get_ring_mesh()
+    if mesh is not None and mesh.shape.get("sp", 1) > 1:
+        raise NotImplementedError(
+            f"{what} has no sequence-parallel path: a scan over a split "
+            "sequence needs a state hand-off (ROADMAP.md B13)")
 
 
 def document_runs(segment_ids: jax.Array) -> jax.Array:
@@ -232,13 +267,7 @@ class Mamba2Mixer(nn.Module):
                 "the state-space mixer has no decode path yet: serving it "
                 "needs a state cache beside keys and values (ROADMAP.md B13); "
                 "train and evaluate only")
-        from ..parallel.ring import get_ring_mesh
-
-        mesh = get_ring_mesh()
-        if mesh is not None and mesh.shape.get("sp", 1) > 1:
-            raise NotImplementedError(
-                "the state-space mixer has no sequence-parallel path: a scan "
-                "over a split sequence needs a state hand-off (ROADMAP.md B13)")
+        _refuse_split_sequence("the state-space mixer")
         bsz, s, _ = u.shape
         h, p = cfg.ssm_n_heads, cfg.ssm_head_dim
         g, n = cfg.ssm_n_groups, cfg.ssm_d_state
@@ -288,3 +317,53 @@ class Mamba2Mixer(nn.Module):
             y = gated_group_norm(y.reshape(bsz, s, inner), z, scale, g,
                                  cfg.rms_eps).astype(cfg.dtype)
         return _proj(cfg, "out_proj", cfg.d_model)(y, deterministic, adapter_ids)
+
+
+class LightningMixer(nn.Module):
+    """An ``L`` layer's mixer, lightning linear attention: ``q, k, v = W x``
+    (``lightning_n_heads`` heads of ``lightning_head_dim`` each, every head
+    its own keys), a learned RMSNorm over each q and k head, half-split
+    rotary embedding at ``rope_theta`` on all of a head's columns, then
+    ``S_t = lambda_h S_{t-1} + k_t^T v_t``, ``o_t = head_dim^-0.5 q_t S_t``
+    with the state float32 and ``lambda_h`` fixed
+    (:func:`lightning_log_decay`) — :func:`ssd_scan` at ``x`` = v, ``B`` = k,
+    ``C`` = q, a step size of 1 and ``D`` = 0 —, a learned RMSNorm over all of
+    ``o``'s channels (``o_norm``), the output gate and ``o_proj``
+    (``models/llama.py::gated_output``).  The state restarts at a change of
+    ``segment_ids``.  Training and evaluation only."""
+
+    cfg: Any
+
+    @nn.compact
+    def __call__(self, u, positions, segment_ids=None, deterministic=True,
+                 decode=False, adapter_ids=None):
+        cfg = self.cfg
+        if decode:
+            raise NotImplementedError(
+                "lightning attention has no decode path yet: serving it needs "
+                "a state cache beside keys and values (ROADMAP.md B13); train "
+                "and evaluate only")
+        _refuse_split_sequence("lightning attention")
+        bsz, s, _ = u.shape
+        h, p = cfg.lightning_n_heads, cfg.lightning_head_dim
+
+        def heads(name):
+            return _proj(cfg, name, h * p)(u, deterministic, adapter_ids).reshape(
+                bsz, s, h, p)
+
+        q, k, v = heads("q_proj"), heads("k_proj"), heads("v_proj")
+        q, k = _head_norm(cfg, "q_norm")(q), _head_norm(cfg, "k_norm")(k)
+        if cfg.rope_theta:
+            with jax.named_scope("rope"):
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k = apply_rope(k, positions, cfg.rope_theta)
+        runs = None if segment_ids is None else document_runs(segment_ids)
+        with jax.named_scope("ssd_scan"):
+            y = ssd_scan(v, jnp.ones((bsz, s, h), jnp.float32),
+                         lightning_log_decay(h), k, q,
+                         jnp.zeros((h,), jnp.float32), runs, chunk=cfg.ssm_chunk)
+            y = (y * p ** -0.5).reshape(bsz, s, h * p)
+        y = RMSNorm(cfg.rms_eps, cfg.dtype, cfg.param_dtype, cfg.norm_offset,
+                    name="o_norm")(y)
+        return gated_output(cfg, y.astype(cfg.dtype), u, deterministic,
+                            adapter_ids)

@@ -62,12 +62,35 @@ def pack_selection(mask: jax.Array) -> jax.Array:
 
 
 def unpack_selection(selection: jax.Array, n_keys: int) -> jax.Array:
-    """:func:`pack_selection`'s inverse: ``(B, S, n_keys)`` bool."""
-    b, s, w = selection.shape
+    """:func:`pack_selection`'s inverse: ``(B, S, n_keys)`` bool (``(B, Hs, S,
+    n_keys)`` of a selection a key/value head)."""
+    *lead, w = selection.shape
     words = jax.lax.bitcast_convert_type(selection, jnp.uint32).reshape(
-        b, s, w // SELECTION_LANES, 1, SELECTION_LANES)
+        *lead, w // SELECTION_LANES, 1, SELECTION_LANES)
     bits = (words >> jnp.arange(32, dtype=jnp.uint32)[:, None]) & 1
-    return bits.reshape(b, s, -1)[:, :, :n_keys] != 0
+    return bits.reshape(*lead, -1)[..., :n_keys] != 0
+
+
+def pack_block_selection(blocks: jax.Array, block: int) -> jax.Array:
+    """A per-query set of BLOCKS of ``block`` keys, ``(..., S, n_blocks)``
+    bool, as :func:`pack_selection`'s words of the keys they hold, ``(..., S,
+    ceil(n_blocks * block / 4096) * 128)`` int32, without the per-key mask
+    ever existing: ``128 // block`` blocks lie side by side along a word
+    group's lanes, so a word is the bits of ``32`` blocks, ``128 // block``
+    apart, repeated over its block's lanes.  ``block`` divides 128."""
+    if SELECTION_LANES % block:
+        raise ValueError(f"a block of {block} keys does not divide "
+                         f"{SELECTION_LANES} lanes")
+    *lead, n = blocks.shape
+    side = SELECTION_LANES // block
+    groups = -(-n * block // SELECTION_KEYS)
+    blocks = jnp.pad(blocks, [(0, 0)] * len(lead) + [(0, groups * 32 * side - n)])
+    bits = blocks.reshape(*lead, groups, 32, side).astype(jnp.uint32)
+    words = jnp.sum(bits << jnp.arange(32, dtype=jnp.uint32)[:, None], axis=-2,
+                    dtype=jnp.uint32)                         # (..., groups, side)
+    words = jnp.repeat(words, block, axis=-1)
+    return jax.lax.bitcast_convert_type(
+        words.reshape(*lead, groups * SELECTION_LANES), jnp.int32)
 
 
 def xla_causal_attention(
@@ -86,7 +109,9 @@ def xla_causal_attention(
     Shapes: q (B, S, H, D); k (B, S, Hkv, D); v (B, S, Hkv, Dv) with
     H % Hkv == 0.  Dv may differ from D (latent attention's 192 / 128); the
     softmax scale comes from D.  ``selection`` (:func:`pack_selection`)
-    restricts every query, in all heads, to its own set of keys.  ``window``:
+    restricts every query, in all heads, to its own set of keys; (B, Hs, S,
+    W): each of ``Hs`` runs of query heads (a key/value head's group where
+    ``Hs`` = Hkv) to its own.  ``window``:
     key ``s`` serves query ``t`` iff ``t - window < s <= t``.  ``sink`` (H,)
     float32: one more column of logit ``sink[h]`` in every row's softmax,
     dropped after it, so a row's weights sum to under 1.  Returns
@@ -106,7 +131,13 @@ def xla_causal_attention(
         seg = segment_ids[:, None, None, :, None] == segment_ids[:, None, None, None, :]
         mask = mask & seg
     if selection is not None:
-        mask = mask & unpack_selection(selection, s)[:, None, None]
+        picked = unpack_selection(selection, s)
+        if selection.ndim == 3:
+            picked = picked[:, None, None]
+        else:           # a set for each run of h / Hs query heads
+            picked = jnp.repeat(picked, h // selection.shape[1], axis=1).reshape(
+                b, hkv, h // hkv, s, s)
+        mask = mask & picked
     scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     if sink is None:
         probs = jax.nn.softmax(scores, axis=-1)
@@ -364,9 +395,11 @@ def _flash_attention_on_mesh(q, k, v, segment_ids, selection=None,
         operands += (segment_ids,)
         in_specs += (P(AxisNames.BATCH_AXES, None),)
     if selection is not None:
-        # a query's set serves all its heads: whole on every ``tp`` member
+        # a query's set serves all its heads: whole on every ``tp`` member; a
+        # set a key/value head splits with the heads
         operands += (selection,)
-        in_specs += (P(AxisNames.BATCH_AXES, None, None),)
+        in_specs += (P(AxisNames.BATCH_AXES, None, None) if selection.ndim == 3
+                     else P(AxisNames.BATCH_AXES, heads, None, None),)
 
     if sink is not None:
         operands += (sink,)
@@ -443,8 +476,9 @@ def causal_attention(
 ) -> jax.Array:
     """Causal GQA attention by the implementation
     :func:`resolve_attention_impl` gives for ``impl`` and this row length;
-    with ``selection`` (:func:`pack_selection`) over each query's own set of
-    keys, under a ``window`` of that many keys and beside a per-head ``sink``
+    with ``selection`` (:func:`pack_selection`; with an axis of key/value
+    heads before the rows', a set for each head's group) over each query's
+    own set of keys, under a ``window`` of that many keys and beside a per-head ``sink``
     logit (:func:`xla_causal_attention`), all of which the XLA path and the
     flash kernels take and the sequence-parallel paths do not."""
     impl = resolve_attention_impl(impl, q.shape[1])

@@ -576,19 +576,31 @@ def _pad_inputs(q, k, v, segment_ids, bq, bk, kv_segment_ids=None):
     return q, k, v, segment_ids, kv_segment_ids, s_pad
 
 
-def _selection_operand(selection, s_pad: int, bq: int, bk: int):
+def _selection_operand(selection, s_pad: int, bq: int, bk: int, heads: int):
     """The selection's words (B, S, W) padded to the padded rows and to whole
-    groups of 4,096 keys, and the ``(block, of key block)`` its BlockSpecs
-    use: a (1, bq, 128) block of words holds the keys of 4,096 // bk blocks."""
+    groups of 4,096 keys, and the ``(block, of key block, of batch and query
+    head)`` its BlockSpecs use: a (1, bq, 128) block of words holds the keys
+    of 4,096 // bk blocks.  A selection a key/value head, (B, Hs, S, W), is
+    read as ``B * Hs`` rows' (no copy): query head ``ih`` of ``heads`` reads
+    the set of its group, ``ih // (heads // Hs)``."""
     if bk % SELECTION_LANES or SELECTION_KEYS % bk:
         raise ValueError(
             f"attention over a selection needs a key block that is a multiple "
             f"of {SELECTION_LANES} and divides {SELECTION_KEYS}, not {bk}")
+    row_of = lambda ib, ih: ib                                  # noqa: E731
+    if selection.ndim == 4:
+        sets = selection.shape[1]
+        if heads % sets:
+            raise ValueError(f"a selection for each of {sets} key/value heads "
+                             f"does not split {heads} query heads")
+        selection = selection.reshape((-1,) + selection.shape[2:])
+        row_of = lambda ib, ih: ib * sets + ih // (heads // sets)  # noqa: E731
     b, s, w = selection.shape
     width = pl.cdiv(s_pad, SELECTION_KEYS) * SELECTION_LANES
     if (s, w) != (s_pad, width):
         selection = jnp.pad(selection, [(0, 0), (0, s_pad - s), (0, width - w)])
-    return selection, (1, bq, SELECTION_LANES), lambda ik: ik * bk // SELECTION_KEYS
+    return (selection, (1, bq, SELECTION_LANES),
+            lambda ik: ik * bk // SELECTION_KEYS, row_of)
 
 
 def _check_window(window, bq, bk, selection):
@@ -670,10 +682,11 @@ def _flash_forward(
         kv = functools.partial(_window_kv_block_index, back=back)
     sel_operands, sel_specs = (), []
     if selection is not None:
-        selection, block, words_of = _selection_operand(selection, s_pad, bq, bk)
+        selection, block, words_of, row_of = _selection_operand(
+            selection, s_pad, bq, bk, h)
         sel_operands = (selection,)
         sel_specs = [pl.BlockSpec(
-            block, lambda ib, ih, iq, ik: (ib, iq, words_of(kv(iq, ik))))]
+            block, lambda ib, ih, iq, ik: (row_of(ib, ih), iq, words_of(kv(iq, ik))))]
     if sink is not None:
         sel_operands += (sink.astype(jnp.float32).reshape(h, 1, 1),)
         sel_specs.append(pl.BlockSpec(
@@ -1019,12 +1032,16 @@ def _flash_backward(
         qb = functools.partial(_window_q_block_index, nq=nq, ahead=back + 1)
     sel_operands, dq_sel_specs, dkv_sel_specs = (), [], []
     if selection is not None:
-        selection, block, words_of = _selection_operand(selection, s_pad, bq, bk)
+        selection, block, words_of, row_of = _selection_operand(
+            selection, s_pad, bq, bk, h)
         sel_operands = (selection,)
         dq_sel_specs = [pl.BlockSpec(
-            block, lambda ib, ih, iq, ik: (ib, iq, words_of(kv(iq, ik))))]
+            block, lambda ib, ih, iq, ik: (row_of(ib, ih), iq, words_of(kv(iq, ik))))]
+        # the dK/dV grid walks key/value heads: its member ``j // dkv_inner``
+        # is query head ``ih * group + j // dkv_inner``
         dkv_sel_specs = [pl.BlockSpec(
-            block, lambda ib, ih, ik, j: (ib, qb(ik, j), words_of(ik)))]
+            block, lambda ib, ih, ik, j: (
+                row_of(ib, ih * group + j // dkv_inner), qb(ik, j), words_of(ik)))]
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, seq_len=s, scale=scale,
@@ -1205,7 +1222,9 @@ def flash_attention_with_lse(
     segment ids of their own (no caller in the program passes it: ROADMAP.md
     C3). ``selection`` (``ops/attention.py::pack_selection``,
     (B, S, W) int32) cuts every query, in all its heads, to its own set of
-    keys: one more operand of the three kernels, which mask by it; with none
+    keys — (B, Hs, S, W): a set for each of ``Hs`` runs of query heads (a
+    key/value head's group picks its own), read in place through the index
+    maps —: one more operand of the three kernels, which mask by it; with none
     they trace the bodies they always did.  ``window`` (a static count of keys:
     key ``s`` serves query ``t`` iff ``t - window < s <= t``) and ``sink``
     ((H,) float32, a learned logit a head that joins each row's normaliser and

@@ -9,7 +9,10 @@ HBM; the recurrence needs ``x``, ``B``, ``C``, the step sizes and ``y`` once.
 Kernel structure: grid = (batch, block of heads, chunk), the chunk axis
 innermost and sequential.  A block of heads is ONE GROUP's (or a divisor of
 it where a group's states would not fit VMEM: :func:`heads_per_block`), so
-the group's scores ``C B^T`` are made once a chunk and shared by its heads.
+the group's scores ``C B^T`` are made once a chunk and shared by its heads —
+or, where a group is too few heads to tile by itself (linear attention: every
+head its own keys and queries, ``G = H``), a block SPANS whole groups and the
+body walks them one after another, each with its own ``B``, ``C`` and scores.
 The states of the block's heads, ``[N, heads * P]`` float32, live in VMEM
 scratch from one chunk of a row to the next.  A step of the grid::
 
@@ -78,13 +81,19 @@ VMEM_LIMIT = 64 * 2 ** 20
 MARK_ROWS = 8
 
 
-def _vmem_bytes(heads: int, p: int, n: int, chunk: int, itemsize: int) -> int:
+#: heads of a block that spans groups, at most: a body is unrolled a head at a
+#: time, and sixteen is what the blocks of one group trace and hold
+SPAN_HEADS = 16
+
+
+def _vmem_bytes(heads: int, p: int, n: int, chunk: int, itemsize: int,
+                groups: int = 1) -> int:
     """Bytes a step of the BACKWARD kernel (the larger) holds for a block of
-    ``heads``: every pipelined block twice, the scratch, the whole-block
-    float32 results of its products."""
+    ``heads`` over ``groups`` groups: every pipelined block twice, the
+    scratch, the whole-block float32 results of its products."""
     wide = heads * p
     blocks = chunk * wide * (2 * itemsize + 4) + n * wide * itemsize  # x, dx, dy, entering
-    blocks += 4 * chunk * n * 4                                       # b, c, db, dc
+    blocks += 4 * chunk * n * 4 * groups                              # b, c, db, dc
     scratch = n * wide * 4 + 2 * chunk * wide * itemsize
     results = 3 * chunk * wide * 4 + 2 * n * wide * 4 + 6 * chunk * chunk * 4
     return 2 * blocks + scratch + results
@@ -94,17 +103,29 @@ def heads_per_block(h: int, p: int, g: int, n: int, chunk: int,
                     itemsize: int = 2) -> int:
     """Heads a step of the grid holds: a whole group's where its states fit
     VMEM (:func:`_vmem_bytes`), else the largest divisor of a group that
-    does; 0 where nothing tiles — a block's columns ``heads * P`` must be whole
-    lanes (128) and whole lane tiles of heads, its rows of per-head scalars
-    whole sublanes (8, or all ``H``), the chunk and ``N`` whole lanes too."""
+    does, else — a group too few heads to tile, down to one head a group —
+    the most whole groups that do, up to ``SPAN_HEADS`` heads; 0 where nothing
+    tiles — a block's columns ``heads * P`` must be whole lanes (128) and
+    whole lane tiles of heads, its rows of per-head scalars whole sublanes
+    (8, or all ``H``), the chunk and ``N`` whole lanes too, and a group that
+    shares a block with others whole lane tiles by itself."""
     if h % g or chunk % 128 or n % 128 or (128 % p and p % 128):
         return 0
     hg = h // g
+
+    def tiles(heads, groups=1):
+        return ((heads * p) % 128 == 0 and (heads % 8 == 0 or heads == h)
+                and 3 * _vmem_bytes(heads, p, n, chunk, itemsize, groups)
+                <= 2 * VMEM_LIMIT)
+
     for heads in range(hg, 0, -1):
-        if (hg % heads == 0 and (heads * p) % 128 == 0
-                and (heads % 8 == 0 or heads == h)
-                and 3 * _vmem_bytes(heads, p, n, chunk, itemsize) <= 2 * VMEM_LIMIT):
+        if hg % heads == 0 and tiles(heads):
             return heads
+    if (hg * p) % 128:
+        return 0
+    for groups in range(min(h, SPAN_HEADS) // hg, 1, -1):
+        if g % groups == 0 and tiles(groups * hg, groups):
+            return groups * hg
     return 0
 
 
@@ -213,8 +234,31 @@ class _Chunk:
                           self.cs[:, j:j + 1] - self.cs_rows[j:j + 1, :])
 
 
+def _get(ref, at=None):
+    """The columns ``at`` of a block (None: all of it)."""
+    return ref[...] if at is None else ref[:, at]
+
+
+def _put(ref, at, value):
+    if at is None:
+        ref[...] = value
+    else:
+        ref[:, at] = value
+
+
+def _groups_of(heads: int, p: int, n: int, groups: int):
+    """``(first head, its x columns, its B / C columns)`` of each group a
+    block of ``heads`` spans; one group: the whole block, no slice taken."""
+    if groups == 1:
+        return [(0, None, None)]
+    per = heads // groups
+    return [(i * per, slice(i * per * p, (i + 1) * per * p),
+             slice(i * n, (i + 1) * n)) for i in range(groups)]
+
+
 def _fwd_kernel(d_ref, x_ref, b_ref, c_ref, dt_ref, cs_ref, *rest,
-                heads: int, p: int, has_runs: bool, emit_entering: bool):
+                heads: int, p: int, has_runs: bool, emit_entering: bool,
+                groups: int = 1):
     rest = list(rest)
     marks_ref = rest.pop(0) if has_runs else None
     y_ref = rest.pop(0)
@@ -232,35 +276,40 @@ def _fwd_kernel(d_ref, x_ref, b_ref, c_ref, dt_ref, cs_ref, *rest,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     ch = _Chunk(dt_ref[...], cs_ref[...], marks_ref[...] if has_runs else None, n)
-    bq, cq = b_ref[...], c_ref[...]
-    scores = _dot(cq, bq, _NT)                                  # (Q, Q)
-    entering = state_ref[...].astype(dtype)                     # (N, heads P)
-    if emit_entering:
-        entering_ref[...] = entering
-    read = _dot(cq, entering)                                   # (Q, heads P)
-    for j0 in range(0, heads, tile):
-        at = slice(j0 * p, j0 * p + width)
-        of = range(j0, j0 + tile)
-        x = x_ref[:, at].astype(jnp.float32)
-        fed = x * _of_heads([ch.dt[:, j:j + 1] for j in of], lane, p)
-        fedb = fed.astype(dtype)
-        y = _of_heads([_dot((scores * ch.decay(j)).astype(dtype), fedb)
-                       for j in of], lane, p)
-        y = y + read[:, at] * _of_heads(
-            [ch.weight[:, j:j + 1] for j in of], lane, p)
-        y_ref[:, at] = y + x * _of_heads(
-            [d_ref[first + j] for j in of], lane, p)
-        fedr_ref[:, at] = (fed * _of_heads(
-            [ch.reach[:, j:j + 1] for j in of], lane, p)).astype(dtype)
-    own = _dot(bq, fedr_ref[...], _TN)                          # (N, heads P)
-    for j0 in range(0, heads, tile):
-        at = slice(j0 * p, j0 * p + width)
-        state_ref[:, at] = state_ref[:, at] * _of_heads(
-            ch.through[j0:j0 + tile], lane_n, p) + own[:, at]
+    for head0, cols, gn in _groups_of(heads, p, n, groups):
+        base = head0 * p
+        bq, cq = _get(b_ref, gn), _get(c_ref, gn)
+        scores = _dot(cq, bq, _NT)                              # (Q, Q)
+        entering = _get(state_ref, cols).astype(dtype)          # (N, heads P)
+        if emit_entering:
+            _put(entering_ref, cols, entering)
+        read = _dot(cq, entering)                               # (Q, heads P)
+        tiles = range(head0, head0 + heads // groups, tile)
+        for j0 in tiles:
+            at = slice(j0 * p, j0 * p + width)
+            rel = slice(at.start - base, at.stop - base)
+            of = range(j0, j0 + tile)
+            x = x_ref[:, at].astype(jnp.float32)
+            fed = x * _of_heads([ch.dt[:, j:j + 1] for j in of], lane, p)
+            fedb = fed.astype(dtype)
+            y = _of_heads([_dot((scores * ch.decay(j)).astype(dtype), fedb)
+                           for j in of], lane, p)
+            y = y + read[:, rel] * _of_heads(
+                [ch.weight[:, j:j + 1] for j in of], lane, p)
+            y_ref[:, at] = y + x * _of_heads(
+                [d_ref[first + j] for j in of], lane, p)
+            fedr_ref[:, at] = (fed * _of_heads(
+                [ch.reach[:, j:j + 1] for j in of], lane, p)).astype(dtype)
+        own = _dot(bq, _get(fedr_ref, cols), _TN)               # (N, heads P)
+        for j0 in tiles:
+            at = slice(j0 * p, j0 * p + width)
+            state_ref[:, at] = state_ref[:, at] * _of_heads(
+                ch.through[j0:j0 + tile], lane_n, p) + own[
+                    :, at.start - base:at.stop - base]
 
 
 def _bwd_kernel(d_ref, x_ref, b_ref, c_ref, dt_ref, cs_ref, *rest,
-                heads: int, p: int, has_runs: bool):
+                heads: int, p: int, has_runs: bool, groups: int = 1):
     rest = list(rest)
     marks_ref = rest.pop(0) if has_runs else None
     (entering_ref, dy_ref, dx_ref, db_ref, dc_ref, ddt_ref, dcs_ref,
@@ -279,71 +328,76 @@ def _bwd_kernel(d_ref, x_ref, b_ref, c_ref, dt_ref, cs_ref, *rest,
         dstate_ref[...] = jnp.zeros_like(dstate_ref)
 
     ch = _Chunk(dt_ref[...], cs_ref[...], marks_ref[...] if has_runs else None, n)
-    bq, cq = b_ref[...], c_ref[...]
-    scores = _dot(cq, bq, _NT)
-    entering = entering_ref[...]                                # (N, heads P)
-    read = _dot(cq, entering)
-    # cotangent of the state that LEFT this chunk, and of what fed it
-    dstate = dstate_ref[...]
-    dleft = dstate.astype(dtype)                                # (N, heads P)
-    dfedr = _dot(bq, dleft)                                     # (Q, heads P)
-    # d(through) a column: sum over N of dstate o entering
-    kept = jnp.sum(dstate * entering.astype(jnp.float32), axis=0,
-                   keepdims=True)                               # (1, heads P)
-    dscores = jnp.zeros((chunk, chunk), jnp.float32)
-    ddt_cols = jnp.zeros((chunk, heads), jnp.float32)
-    dcs_cols = jnp.zeros((chunk, heads), jnp.float32)
     dcs_rows = []
-    for j0 in range(0, heads, tile):
-        at = slice(j0 * p, j0 * p + width)
-        of = range(j0, j0 + tile)
-        x = x_ref[:, at].astype(jnp.float32)
-        dy = dy_ref[:, at]
-        dyb = dy.astype(dtype)
-        step_size = _of_heads([ch.dt[:, j:j + 1] for j in of], lane, p)
-        reach = _of_heads([ch.reach[:, j:j + 1] for j in of], lane, p)
-        weight = _of_heads([ch.weight[:, j:j + 1] for j in of], lane, p)
-        fed = x * step_size
-        fedb = fed.astype(dtype)
-        fedr = fed * reach
-        fedr_ref[:, at] = fedr.astype(dtype)
-        dyw_ref[:, at] = (dy * weight).astype(dtype)
-        dfeds, ddecays = [], []
-        for j in of:
-            # within the chunk: y = weighed.bf16 @ fed.bf16
-            decay = ch.decay(j)
-            weighed = scores * decay
-            dweighed = _dot(_own_lanes(dyb, lane, j - j0, p), fedb, _NT)
-            dscores = dscores + dweighed * decay
-            ddecays.append(dweighed * weighed)                  # d/d(cs_t - cs_s)
-            dfeds.append(_dot(weighed.astype(dtype), dyb, _TN))
-        dfed = _of_heads(dfeds, lane, p) + dfedr[:, at] * reach
-        dx_ref[:, at] = (dfed * step_size + dy * _of_heads(
-            [d_ref[first + j] for j in of], lane, p)).astype(dx_ref.dtype)
-        # per-row terms of the tile, then each head's share of them
-        dreach = dfedr[:, at] * fedr
-        dweight = dy * read[:, at] * weight - dreach
-        ddt = dfed * x
-        through = _of_heads([t[:1] for t in ch.through[j0:j0 + tile]],
-                            lane[:1], p)
-        dtotal = jnp.sum(dreach, axis=0, keepdims=True) + through * kept[:, at]
-        for j in of:
-            own = functools.partial(_own_lanes, lane=lane, i=j - j0, p=p)
-            dcs = (jnp.sum(ddecays[j - j0], axis=1, keepdims=True)
-                   + jnp.sum(own(dweight), axis=1, keepdims=True)
-                   + jnp.where(last_row, jnp.sum(
-                       _own_lanes(dtotal, lane[:1], j - j0, p),
-                       axis=1, keepdims=True), 0.0))
-            dcs_cols = jnp.where(head == j, dcs, dcs_cols)
-            dcs_rows.append(-jnp.sum(ddecays[j - j0], axis=0, keepdims=True))
-            ddt_cols = jnp.where(
-                head == j, jnp.sum(own(ddt), axis=1, keepdims=True), ddt_cols)
-        dstate_ref[:, at] = dstate[:, at] * _of_heads(
-            ch.through[j0:j0 + tile], lane_n, p)
-    dsb = dscores.astype(dtype)
-    dstate_ref[...] += _dot(cq, dyw_ref[...], _TN)              # through the read
-    dc_ref[...] = _dot(dsb, bq) + _dot(dyw_ref[...], entering, _NT)
-    db_ref[...] = _dot(dsb, cq, _TN) + _dot(fedr_ref[...], dleft, _NT)
+    for head0, cols, gn in _groups_of(heads, p, n, groups):
+        base = head0 * p
+        bq, cq = _get(b_ref, gn), _get(c_ref, gn)
+        scores = _dot(cq, bq, _NT)
+        entering = _get(entering_ref, cols)                     # (N, heads P)
+        read = _dot(cq, entering)
+        # cotangent of the state that LEFT this chunk, and of what fed it
+        dstate = _get(dstate_ref, cols)
+        dleft = dstate.astype(dtype)                            # (N, heads P)
+        dfedr = _dot(bq, dleft)                                 # (Q, heads P)
+        # d(through) a column: sum over N of dstate o entering
+        kept = jnp.sum(dstate * entering.astype(jnp.float32), axis=0,
+                       keepdims=True)                           # (1, heads P)
+        dscores = jnp.zeros((chunk, chunk), jnp.float32)
+        if not head0:       # per-head columns of the whole block
+            ddt_cols = jnp.zeros((chunk, heads), jnp.float32)
+            dcs_cols = jnp.zeros((chunk, heads), jnp.float32)
+        for j0 in range(head0, head0 + heads // groups, tile):
+            at = slice(j0 * p, j0 * p + width)
+            rel = slice(at.start - base, at.stop - base)
+            of = range(j0, j0 + tile)
+            x = x_ref[:, at].astype(jnp.float32)
+            dy = dy_ref[:, at]
+            dyb = dy.astype(dtype)
+            step_size = _of_heads([ch.dt[:, j:j + 1] for j in of], lane, p)
+            reach = _of_heads([ch.reach[:, j:j + 1] for j in of], lane, p)
+            weight = _of_heads([ch.weight[:, j:j + 1] for j in of], lane, p)
+            fed = x * step_size
+            fedb = fed.astype(dtype)
+            fedr = fed * reach
+            fedr_ref[:, at] = fedr.astype(dtype)
+            dyw_ref[:, at] = (dy * weight).astype(dtype)
+            dfeds, ddecays = [], []
+            for j in of:
+                # within the chunk: y = weighed.bf16 @ fed.bf16
+                decay = ch.decay(j)
+                weighed = scores * decay
+                dweighed = _dot(_own_lanes(dyb, lane, j - j0, p), fedb, _NT)
+                dscores = dscores + dweighed * decay
+                ddecays.append(dweighed * weighed)              # d/d(cs_t - cs_s)
+                dfeds.append(_dot(weighed.astype(dtype), dyb, _TN))
+            dfed = _of_heads(dfeds, lane, p) + dfedr[:, rel] * reach
+            dx_ref[:, at] = (dfed * step_size + dy * _of_heads(
+                [d_ref[first + j] for j in of], lane, p)).astype(dx_ref.dtype)
+            # per-row terms of the tile, then each head's share of them
+            dreach = dfedr[:, rel] * fedr
+            dweight = dy * read[:, rel] * weight - dreach
+            ddt = dfed * x
+            through = _of_heads([t[:1] for t in ch.through[j0:j0 + tile]],
+                                lane[:1], p)
+            dtotal = jnp.sum(dreach, axis=0, keepdims=True) + through * kept[:, rel]
+            for j in of:
+                own = functools.partial(_own_lanes, lane=lane, i=j - j0, p=p)
+                dcs = (jnp.sum(ddecays[j - j0], axis=1, keepdims=True)
+                       + jnp.sum(own(dweight), axis=1, keepdims=True)
+                       + jnp.where(last_row, jnp.sum(
+                           _own_lanes(dtotal, lane[:1], j - j0, p),
+                           axis=1, keepdims=True), 0.0))
+                dcs_cols = jnp.where(head == j, dcs, dcs_cols)
+                dcs_rows.append(-jnp.sum(ddecays[j - j0], axis=0, keepdims=True))
+                ddt_cols = jnp.where(
+                    head == j, jnp.sum(own(ddt), axis=1, keepdims=True), ddt_cols)
+            dstate_ref[:, at] = dstate[:, rel] * _of_heads(
+                ch.through[j0:j0 + tile], lane_n, p)
+        dsb = dscores.astype(dtype)
+        _put(dstate_ref, cols, _get(dstate_ref, cols)            # through the read
+             + _dot(cq, _get(dyw_ref, cols), _TN))
+        _put(dc_ref, gn, _dot(dsb, bq) + _dot(_get(dyw_ref, cols), entering, _NT))
+        _put(db_ref, gn, _dot(dsb, cq, _TN) + _dot(_get(fedr_ref, cols), dleft, _NT))
     ddt_ref[...] = ddt_cols.T
     dcs_ref[...] = dcs_cols.T + jnp.concatenate(dcs_rows, axis=0)
 
@@ -357,27 +411,31 @@ _PARAMS = pltpu.CompilerParams(
 
 
 def _specs(heads: int, p: int, n: int, hg: int, chunk: int, nc: int,
-           has_runs: bool, reverse: bool):
+           has_runs: bool, reverse: bool, groups: int = 1):
     """Block specs of what both kernels read — ``d`` whole in SMEM, then
     ``x``, ``b``, ``c``, the step sizes, the cumulative sums and (with runs)
     the marks — and the makers of the specs that follow them: a chunk of a
     block of heads' ``width`` columns (``of_group``: of its GROUP's), of
     per-head rows, and the spec of a chunk's entering states.  ``reverse``
-    walks a row's chunks from the last."""
+    walks a row's chunks from the last.  A block that spans ``groups`` groups
+    reads their ``B`` and ``C`` side by side: block ``j``'s are the ``j``-th
+    run of ``groups * n`` columns."""
     def at(k):
         return nc - 1 - k if reverse else k
 
     def of_rows(width, of_group=False):
         return pl.BlockSpec(
             (None, chunk, width),
-            lambda i, j, k: (i, at(k), j * heads // hg if of_group else j))
+            lambda i, j, k: (i, at(k), j * heads // hg
+                             if of_group and groups == 1 else j))
 
     def per_head(rows=heads, shared=False):
         return pl.BlockSpec((None, None, rows, chunk),
                             lambda i, j, k: (i, at(k), 0 if shared else j, 0))
 
     specs = [pl.BlockSpec(memory_space=pltpu.SMEM), of_rows(heads * p),
-             of_rows(n, True), of_rows(n, True), per_head(), per_head()]
+             of_rows(groups * n, True), of_rows(groups * n, True), per_head(),
+             per_head()]
     if has_runs:
         specs.append(per_head(MARK_ROWS, shared=True))
     entering = pl.BlockSpec((None, None, n, heads * p),
@@ -396,7 +454,9 @@ def _forward_call(shape, dtype, groups: int, chunk: int, heads: int, p: int,
     step."""
     bsz, s, wide = shape
     nc, hg = s // chunk, wide // p // groups
-    specs, of_rows, _, entering = _specs(heads, p, n, hg, chunk, nc, has_runs, False)
+    spanned = max(1, heads // hg)
+    specs, of_rows, _, entering = _specs(
+        heads, p, n, hg, chunk, nc, has_runs, False, spanned)
     out_shape = [jax.ShapeDtypeStruct((bsz, s, wide), jnp.float32)]
     out_specs = [of_rows(heads * p)]
     if emit_entering:
@@ -404,7 +464,8 @@ def _forward_call(shape, dtype, groups: int, chunk: int, heads: int, p: int,
         out_specs.append(entering)
     return pl.pallas_call(
         functools.partial(call_with_room, _fwd_kernel, heads=heads, p=p,
-                          has_runs=has_runs, emit_entering=emit_entering),
+                          has_runs=has_runs, emit_entering=emit_entering,
+                          groups=spanned),
         grid=(bsz, wide // (heads * p), nc), in_specs=specs,
         out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((n, heads * p), jnp.float32),
@@ -418,20 +479,22 @@ def _backward_call(shape, dtype, groups: int, chunk: int, heads: int, p: int,
     """The backward kernel's call, built once as :func:`_forward_call`:
     cotangents of ``x`` (its type), of ``b`` and ``c`` — float32, one ``(B,
     S, N)`` slab a BLOCK of heads, which the caller sums over a group's
-    blocks — and of the step sizes and cumulative sums, ``(B, nc, H, Q)``."""
+    blocks (a block that spans groups: a slab a group) — and of the step
+    sizes and cumulative sums, ``(B, nc, H, Q)``."""
     bsz, s, wide = shape
     nc, blocks, hg = s // chunk, wide // (heads * p), wide // p // groups
+    spanned = max(1, heads // hg)
     specs, of_rows, per_head, entering = _specs(
-        heads, p, n, hg, chunk, nc, has_runs, True)
+        heads, p, n, hg, chunk, nc, has_runs, True, spanned)
     per_row = jax.ShapeDtypeStruct((bsz, nc, wide // p, chunk), jnp.float32)
-    per_block = jax.ShapeDtypeStruct((bsz, s, blocks * n), jnp.float32)
+    per_block = jax.ShapeDtypeStruct((bsz, s, blocks * spanned * n), jnp.float32)
     return pl.pallas_call(
         functools.partial(call_with_room, _bwd_kernel, heads=heads, p=p,
-                          has_runs=has_runs),
+                          has_runs=has_runs, groups=spanned),
         grid=(bsz, blocks, nc),
         in_specs=specs + [entering, of_rows(heads * p)],
-        out_specs=[of_rows(heads * p), of_rows(n), of_rows(n), per_head(),
-                   per_head()],
+        out_specs=[of_rows(heads * p), of_rows(spanned * n),
+                   of_rows(spanned * n), per_head(), per_head()],
         out_shape=[jax.ShapeDtypeStruct(shape, dtype), per_block, per_block,
                    per_row, per_row],
         scratch_shapes=[pltpu.VMEM((n, heads * p), jnp.float32),

@@ -144,6 +144,12 @@ LLAMA_RULES = PartitionRules(
         # attention projections (kernel and int4-packed kernel share layout)
         (r"(q_proj|k_proj|v_proj)/kernel", P(Ax.FSDP, Ax.TENSOR)),
         (r"o_proj/kernel", P(Ax.TENSOR, Ax.FSDP)),
+        # the output gate of block-sparse and lightning attention
+        # (models/llama.py gated_output): a column a channel of the mixer's
+        # output, split with the heads as q_proj's
+        (r"o_gate/kernel", P(Ax.FSDP, Ax.TENSOR)),
+        (r"o_gate/lora_a", P(Ax.FSDP, None)),
+        (r"o_gate/lora_b", P(None, Ax.TENSOR)),
         # the state-space mixer (models/ssm.py): its input projection splits
         # by output feature and its output projection by input feature, as
         # attention's do (heads over TP); the convolution, the per-head

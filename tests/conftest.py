@@ -78,11 +78,12 @@ def tiny_job_spec(steps: int = 3):
 #: ``model_config`` PR appended to — three of PR 26's two cells (ISSUE 27),
 #: five of PR 27's three (ISSUE 32), four of PR 32's four (ISSUE 36), four of
 #: PR 36's 34 per-layer entries (ISSUE 38), six of PR 38's five cells
-#: (ISSUE 40) — and that
+#: (ISSUE 40), five of PR 40's six (ISSUE 43), five of PR 43's seven
+#: (ISSUE 49) — and that
 #: only a ``benchmark`` PR may
 #: edit (a PR of another kind changes no file the benchmark already has):
 #: ``node id -> (why, the test that holds what it held)``.  The next
-#: ``benchmark`` PR edits the twenty-two and deletes this table and the hook
+#: ``benchmark`` PR edits the thirty-two and deletes this table and the hook
 #: under it (ROADMAP.md, B1)
 SUPERSEDED = {
     "tests/benchmarks/test_benchmark_manifest.py::"
@@ -220,6 +221,31 @@ SUPERSEDED = {
         ("test_the_superseded_pins_are_twenty_two_and_each_has_its_replacement",
          "pins this table's length at twenty-two",
          "test_the_superseded_pins_are_twenty_seven_and_each_has_its_replacement"),
+    )},
+    # ... and the five of ``test_benchmark_mimo_v2.py`` that pin PR 43's
+    # seven-cell manifest, to which ISSUE 49 appends an eighth cell, a seventh
+    # configuration, seven per-layer entries and its cell's name in seventeen
+    # accepted ones
+    **{"tests/benchmarks/test_benchmark_mimo_v2.py::" + pin: (
+        why, "tests/benchmarks/test_benchmark_minicpm_sala.py::" + held_by)
+       for pin, why, held_by in (
+        ("test_the_real_manifest_has_its_seven_cells_and_no_metric_by_default",
+         "pins PR 43's seven cells; ISSUE 49 adds an eighth",
+         "test_the_real_manifest_has_its_eight_cells_and_no_metric_by_default"),
+        ("test_manifest_registers_and_loads_every_accepted_metric",
+         "pins every entry's cells to PR 43's seven; ISSUE 49 appends its cell "
+         "to the neutral ones, the flash kernels' share and the loop's plumbing",
+         "test_manifest_registers_and_loads_every_accepted_metric"),
+        ("test_manifest_registers_and_loads_every_start_up_metric",
+         "pins the five start-up entries' cells to PR 43's seven; the eighth "
+         "cell reports them too",
+         "test_manifest_registers_and_loads_every_start_up_metric"),
+        ("test_the_accepted_entries_stand_first_and_the_new_ones_last",
+         "pins the lists' ends; ISSUE 49 appends its entries",
+         "test_the_accepted_entries_stand_first_and_the_new_ones_last"),
+        ("test_the_superseded_pins_are_twenty_seven_and_each_has_its_replacement",
+         "pins this table's length at twenty-seven",
+         "test_the_superseded_pins_are_thirty_two_and_each_has_its_replacement"),
     )},
     "tests/benchmarks/test_benchmark_mla_dsa_moe.py::"
     "test_cells_report_the_neutral_metrics_and_their_own_and_no_count_that_overstates": (

@@ -264,6 +264,57 @@ def test_scan_kernels_compile_for_v5e_at_published_shapes_forward_and_grad(
     assert compiled.memory_analysis().temp_size_in_bytes < 1.3e9
 
 
+@pytest.mark.parametrize("segments", [False, True], ids=["plain", "segments"])
+def test_sparse_and_lightning_calls_compile_for_v5e_at_published_shapes(v5e, segments):
+    """The sparse / lightning configuration's two calls at their published
+    shapes, one row of 32,768: the flash kernels over a selection for each of
+    the 2 key/value heads (32 query heads of 128, ``(1, 2, S, 1024)`` words
+    read in place) and the scan where every one of 32 heads of 128 has its
+    own keys and queries (blocks of 16 heads that span 16 groups, a chain of
+    256 chunk states) — value and gradients, three kernels and two."""
+    from finetune_controller_tpu.ops.pallas import ssd_scan
+
+    one = SingleDeviceSharding(v5e[0])
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    s = 32768
+    seg = on_chip((1, s), jnp.int32)
+
+    def attend(q, k, v, words, seg):
+        out = flash_attention(q, k, v, selection=words, interpret=False,
+                              segment_ids=seg if segments else None)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.value_and_grad(attend, argnums=(0, 1, 2))).lower(
+        on_chip((1, s, 32, 128), BF16), on_chip((1, s, 2, 128), BF16),
+        on_chip((1, s, 2, 128), BF16), on_chip((1, 2, s, 1024), jnp.int32),
+        seg).compile()
+    assert _custom_calls(compiled) == 3
+    # the words are read where they lie: no second copy of their 268 MB
+    assert not [line for line in compiled.as_text().splitlines()
+                if re.search(r" = s32\[(1,)?2,32768,1024\]", line)
+                and not re.search(r" (parameter|get-tuple-element|bitcast)\(", line)]
+
+    heads = ssd_scan.heads_per_block(32, 128, 32, 128, 128)
+    assert heads == 16
+
+    def scan(v, k, q, runs):
+        y = ssd_scan.ssd_scan_pallas(
+            v, jnp.ones((1, s, 32), jnp.float32),
+            -jnp.exp2(-jnp.arange(1.0, 33.0) / 4), k, q,
+            jnp.zeros((32,), jnp.float32), runs if segments else None,
+            chunk=128, heads_per_block=heads, interpret=False)
+        return jnp.sum(y ** 2)
+
+    heads_of = on_chip((1, s, 32, 128), BF16)
+    compiled = jax.jit(jax.grad(scan, argnums=(0, 1, 2))).lower(
+        heads_of, heads_of, heads_of, seg).compile()
+    assert _custom_calls(compiled) == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.6e9
+
+
 @pytest.mark.parametrize("family", list(MIXER_WIDTHS))
 @pytest.mark.parametrize("segments", [False, True], ids=["plain", "segments"])
 def test_mixer_compiles_for_v5e_at_published_widths_forward_and_grad(

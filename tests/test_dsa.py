@@ -431,3 +431,88 @@ def test_without_a_selection_the_kernels_trace_the_parents_bodies(cell):
     for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
         assert f"name={name}" in text and f"name={name}" in sparse
     assert sparse != text
+
+
+# ---- a selection for each key/value head (ISSUE 49) -------------------------------
+
+
+def _per_head_inputs(seq=256, heads=4, kv_heads=2, d=32, block=8, seed=0):
+    """q, k, v in float32 and a selection of BLOCKS for each key/value head's
+    group, ``(1, kv_heads, S, S / block)`` bool, a query's own block in it."""
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(2, seq, heads, d)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, seq, kv_heads, d)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, seq, kv_heads, d)), jnp.float32)
+    blocks = rng.random((2, kv_heads, seq, seq // block)) < 0.4
+    blocks |= np.arange(seq)[:, None] // block == np.arange(seq // block)[None, :]
+    return q, k, v, blocks
+
+
+@pytest.mark.parametrize("segments", [False, True], ids=["whole", "segments"])
+def test_a_selection_for_each_key_value_head_through_the_kernels_and_the_xla_form(
+        segments):
+    """``(B, Hkv, S, W)`` words: each key/value head's group of query heads
+    masks by its OWN set, in the three kernels (interpret mode: the words
+    read in place through the index maps) and in the XLA form, which is the
+    per-head call with that head's set, head by head."""
+    from finetune_controller_tpu.ops.attention import pack_block_selection
+
+    q, k, v, blocks = _per_head_inputs()
+    selection = pack_block_selection(jnp.asarray(blocks), 8)
+    assert selection.shape == (2, 2, 256, 128)
+    seg = jnp.asarray(np.repeat([[0] * 100 + [1] * 156], 2, 0)) if segments else None
+
+    def weighed(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return (out * jnp.cos(jnp.arange(out.size).reshape(out.shape))).sum(), out
+        return jax.value_and_grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    (_, want), g_want = weighed(lambda q, k, v: xla_causal_attention(
+        q, k, v, selection=selection, segment_ids=seg))
+    (_, got), g_got = weighed(lambda q, k, v: flash_attention(
+        q, k, v, selection=selection, segment_ids=seg, block_q=128, block_k=128,
+        interpret=True))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    for g, w in zip(g_got, g_want):
+        np.testing.assert_allclose(g, w, rtol=2e-4, atol=5e-5)
+    mask = np.repeat(blocks, 8, axis=-1)
+    for head in range(4):
+        group = head // 2
+        alone = xla_causal_attention(
+            q[:, :, head:head + 1], k[:, :, group:group + 1],
+            v[:, :, group:group + 1], segment_ids=seg,
+            selection=pack_selection(jnp.asarray(mask[:, group])))
+        np.testing.assert_array_equal(alone[:, :, 0], want[:, :, head])
+
+
+def test_one_set_for_all_heads_is_still_the_parents_call():
+    """The 16k sparse cell hands ``(B, S, W)`` words: value and gradient of
+    its flash call (one row of 16,384, 64 heads of 256 beside 256, document
+    marks) trace the parent's program to the character — sha256 of the jaxpr
+    text from ``git archive`` of PR 47's tree (711fafd)."""
+    import hashlib
+
+    b, s, h, d = 1, 16384, 64, 256
+    shaped = jax.ShapeDtypeStruct
+    qkv = shaped((b, s, h, d), jnp.bfloat16)
+
+    def loss(q, k, v, words, seg):
+        return jnp.sum(flash_attention(
+            q, k, v, interpret=False, selection=words, segment_ids=seg
+        ).astype(jnp.float32) ** 2)
+
+    text = str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        qkv, qkv, qkv, shaped((b, s, 4 * 128), jnp.int32), shaped((b, s), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "ce948666acc069f01730b73c2e20a7fe7036e0bc2e7eba3ee1001fe1554600ab")
+
+
+def test_a_selection_that_does_not_split_the_query_heads_is_refused():
+    from finetune_controller_tpu.ops.attention import pack_block_selection
+
+    q, k, v, blocks = _per_head_inputs(heads=4, kv_heads=1)
+    three = pack_block_selection(jnp.asarray(np.repeat(blocks, 3, axis=1)), 8)
+    with pytest.raises(ValueError, match="key/value heads"):
+        flash_attention(q, k, v, selection=three, block_q=128, block_k=128,
+                        interpret=True)

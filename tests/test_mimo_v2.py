@@ -384,7 +384,7 @@ def test_param_counts_at_the_published_keys_and_at_the_cut():
     assert (cut.rope_theta, cut.window_rope_theta) == (5e6, 1e4)
     import dataclasses
 
-    assert len(dataclasses.fields(cut)) == 80      # ROADMAP.md C6
+    assert len(dataclasses.fields(cut)) == 91      # ROADMAP.md C6 (80 before PR 49)
 
 
 def test_trainer_steps_under_a_mesh_as_on_one_device_and_reports_the_kinds(devices8):

@@ -38,6 +38,7 @@ MODELS = {
     "tiny-falcon-h1-test": lambda: PRESETS["tiny-falcon-h1-test"],
     "tiny-nemotron-h-test": lambda: PRESETS["tiny-nemotron-h-test"],
     "tiny-mimo-v2-test": lambda: PRESETS["tiny-mimo-v2-test"],
+    "tiny-minicpm-sala-test": lambda: PRESETS["tiny-minicpm-sala-test"],
     "tiny-dsa-moe": _indexer_model,
     "tiny-mm-test": lambda: MM_PRESETS["tiny-mm-test"],
 }
@@ -122,6 +123,35 @@ RECORDED = {
          'mesh': {},
          'moe_experts_held': 16,
          'moe_gmm_row_tile': 128},
+    ('tiny-minicpm-sala-test', False):
+        {'attention_impl': 'xla',
+         'layer_pattern': 'SLLLLLLS',
+         'layers_by_kind': {'L': 6, 'S': 2},
+         'lightning_chunks_per_row': 16,
+         'lightning_heads_per_block': 0,
+         'lightning_scan_impl': 'xla',
+         'lora_joined_projections': {'apart': [], 'joined': 21, 'of': 21},
+         'mesh': {},
+         'sparse_block': 8,
+         'sparse_blocks_kept': 6,
+         'sparse_dense_len': 32,
+         'sparse_kv_groups': 2,
+         'sparse_window': 16},
+    ('tiny-minicpm-sala-test', True):
+        {'attention_impl': 'pallas',
+         'flash_causal_work_over_need': 2.0,
+         'layer_pattern': 'SLLLLLLS',
+         'layers_by_kind': {'L': 6, 'S': 2},
+         'lightning_chunks_per_row': 16,
+         'lightning_heads_per_block': 0,
+         'lightning_scan_impl': 'xla',
+         'lora_joined_projections': {'apart': [], 'joined': 21, 'of': 21},
+         'mesh': {},
+         'sparse_block': 8,
+         'sparse_blocks_kept': 6,
+         'sparse_dense_len': 32,
+         'sparse_kv_groups': 2,
+         'sparse_window': 16},
     ('tiny-mla-moe-test', False):
         {'attention_impl': 'xla',
          'lora_joined_projections': {'apart': [], 'joined': 8, 'of': 8},
@@ -198,9 +228,9 @@ def test_train_started_is_the_parents_literal(name, as_chip, monkeypatch):
 FAMILY_FIELDS = {"n_experts", "layer_pattern", "experts_held", "indexer_kinds",
                  "sliding_window", "head_widths", "router_aux_weight",
                  "first_k_dense"}
-FAMILY_PREFIXES = ("index_", "ssm_", "window_", "moe_")
+FAMILY_PREFIXES = ("index_", "ssm_", "window_", "moe_", "sparse_", "lightning_")
 #: the sown collections and a module of a model, by their names
-MODEL_NAMES = {"moe_aux", "moe_stats", "dsa_stats", "sink"}
+MODEL_NAMES = {"moe_aux", "moe_stats", "dsa_stats", "sparse_stats", "sink"}
 
 
 def test_the_trainer_reads_no_family_field_and_names_no_part_of_a_model():

@@ -188,8 +188,11 @@ ALL_SIX = (0, 1, 2, 3, 4, 5)
 
 
 def _kernel(*args, runs=None):
+    x, b = args[0], args[3]
+    heads = ssd_scan.heads_per_block(x.shape[2], x.shape[3], *b.shape[2:], 128)
+    assert heads in (8, 16)
     return ssd_scan.ssd_scan_pallas(
-        *args, runs, chunk=128, heads_per_block=16, interpret=True)
+        *args, runs, chunk=128, heads_per_block=heads, interpret=True)
 
 
 def _weighed(fn, *args):
@@ -343,10 +346,114 @@ def test_a_kernels_call_is_built_once_and_its_body_traced_with_room():
     assert 8 * call_with_room.__code__.co_stacksize >= 2 * 16 * 1024
 
 
+# ---- every head its own B and C (G = H): blocks that span groups -------------------
+
+#: lightning linear attention's shape cut small (every head a group of one:
+#: a block of 8 heads spans 8 groups) and two heads a group of 64-wide heads
+#: (a group fills one lane tile: a block of 16 heads spans 8 groups)
+SPANNING_SHAPES = {"lightning": dict(h=8, p=128, g=8, n=128),
+                   "pairs": dict(h=16, p=64, g=8, n=128)}
+
+
+def _lightning_inputs(seq, shape, seed=0):
+    """``_scan_inputs`` as the lightning mixer calls the scan: a step size of
+    1, ``D`` = 0 and the fixed slopes — the last head's decay is 0.996 a row,
+    0.61 a chunk: its state crosses MANY chunks."""
+    x, dt, a, b, c, d = _scan_inputs(seq, seed, bsz=1, **shape)
+    return (x, jnp.ones_like(dt), ssm.lightning_log_decay(shape["h"]), b, c,
+            jnp.zeros_like(d))
+
+
+@pytest.mark.parametrize("seq,shape", [(640, "lightning"), (300, "lightning"),
+                                       (300, "pairs")])
+def test_scan_kernel_at_a_group_a_head_is_the_recurrence_token_by_token(seq, shape):
+    """The kernels in interpreter mode where a block of heads SPANS groups,
+    against ``ssd_chunked`` and the token-by-token recurrence: values and the
+    gradients of all six inputs, at the lightning mixer's decays across five
+    chunks (a ragged last one at 300 rows), with and without document
+    boundaries."""
+    args = _lightning_inputs(seq, SPANNING_SHAPES[shape])
+    runs = None if seq == 640 else jnp.asarray(
+        [[0] * 70 + [1] * 58 + [2] * 128 + [3] * 44])
+    got, g_got = _value_and_grads(lambda *a: _kernel(*a, runs=runs), args)
+    form, g_form = _value_and_grads(
+        lambda *a: ssm.ssd_chunked(*a, runs, chunk=128), args)
+    want, g_want = _value_and_grads(lambda *a: _token_by_token(*a, runs), args)
+    _assert_close(got, form, 1e-5)
+    _assert_close(got, want, 2e-4)
+    for mine, theirs, exact in zip(g_got, g_form, g_want):
+        _assert_close(mine, theirs, 2e-5)
+        _assert_close(mine, exact, 2e-3)
+    if runs is None:    # the carry weighs: each chunk alone is another result
+        alone = jnp.concatenate([
+            ssm.ssd_chunked(*(t[:, i:i + 128] for t in args[:2]), args[2],
+                            *(t[:, i:i + 128] for t in args[3:5]), args[5],
+                            chunk=128) for i in range(0, 640, 128)], axis=1)
+        assert float(jnp.abs(got - alone)[:, 128:].mean()
+                     / jnp.abs(got).mean()) > 0.2
+
+
+#: sha256 of the jaxpr text of value_and_grad of the kernels' call at the two
+#: accepted cells' shapes (heads, head size, groups, state), one row of 8,192
+#: in bf16, with and without document marks — taken from ``git archive`` of
+#: PR 47's tree (711fafd) by the function below: a block of one group traces
+#: the body, the grid and the index maps it always did
+PARENT_SCAN_JAXPRS = {
+    "hybrid": ((32, 128, 2, 256), {
+        False: "72f67078267afcfb2ea9eafb2a19741869304bde40183ed0bda3f6beb27609e7",
+        True: "a2b649671715e1702a801e5bf83c5ca0e83eba2f7154ac878484e35056de823b"}),
+    "pattern": ((128, 64, 8, 128), {
+        False: "c2079f5e125d5b10439e56996d22085f5ac4fb44ba717c6dac93b9da8f6c6c2e",
+        True: "b8caf6a69fc1fa3fbd0cee3f78d5dda58b1b6618fe377bba21b833544c983f93"}),
+}
+
+
+def _scan_jaxpr(h, p, g, n, marks: bool, rows=8192) -> str:
+    shaped = jax.ShapeDtypeStruct
+    x = shaped((1, rows, h, p), jnp.bfloat16)
+    dt = shaped((1, rows, h), jnp.float32)
+    b = shaped((1, rows, g, n), jnp.bfloat16)
+    a = shaped((h,), jnp.float32)
+    runs = (shaped((1, rows), jnp.int32),) if marks else ()
+
+    def loss(x, dt, b, c, a, d, *runs):
+        y = ssd_scan.ssd_scan_pallas(
+            x, dt, a, b, c, d, runs[0] if runs else None, chunk=128,
+            heads_per_block=ssd_scan.heads_per_block(h, p, g, n, 128),
+            interpret=False)
+        return jnp.sum(y ** 2)
+
+    return str(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))(
+        x, dt, b, b, a, a, *runs))
+
+
+@pytest.mark.parametrize("marks", [False, True], ids=["whole", "documents"])
+@pytest.mark.parametrize("cell", list(PARENT_SCAN_JAXPRS))
+def test_a_block_of_one_group_traces_the_parents_kernels(cell, marks):
+    """Adapting, not a path: at the accepted cells' shapes the chooser still
+    gives a group's 16 heads and the calls trace the parent's program to the
+    character (shapes, primitives, index maps and kernel names; no file names
+    or line numbers).  The lightning shape's call is another text under the
+    same two names."""
+    import hashlib
+
+    dims, parents = PARENT_SCAN_JAXPRS[cell]
+    text = _scan_jaxpr(*dims, marks)
+    assert hashlib.sha256(text.encode()).hexdigest() == parents[marks]
+    spanning = _scan_jaxpr(32, 128, 32, 128, marks, rows=1024)
+    for name in ("ssd_scan_fwd", "ssd_scan_bwd"):
+        assert f"name={name}" in text and f"name={name}" in spanning
+
+
 #: (heads, head size, groups, state size, chunk) -> heads a block, or 0
 @pytest.mark.parametrize("dims,heads", [
     ((128, 64, 8, 128, 128), 16),      # the pattern family as published
     ((32, 128, 2, 256, 128), 16),      # the hybrid family as published
+    ((32, 128, 32, 128, 128), 16),     # lightning attention as published: 16 groups of one
+    ((8, 128, 8, 128, 128), 8),        # all eight heads, eight groups
+    ((16, 64, 8, 128, 128), 16),       # two heads of 64 a group: a lane tile each
+    ((8, 64, 8, 128, 128), 0),         # a head of 64 a group: half a lane tile
+    ((24, 128, 24, 128, 128), 8),      # 16 does not divide 24 groups: three blocks of 8
     ((32, 64, 2, 128, 128), 16), ((16, 128, 1, 256, 256), 16),
     ((4, 8, 2, 6, 8), 0),              # the tiny presets: nothing tiles
     ((32, 128, 2, 256, 64), 0),        # a chunk that is no whole lane tile
@@ -370,6 +477,8 @@ def test_chooser_takes_the_kernels_on_a_tpu_without_a_mesh_only(devices8):
     assert ssd_scan.ssd_scan_impl(*pattern) == ("xla", 0)          # here: the CPU
     assert ssd_scan.ssd_scan_impl(*pattern, backend="tpu") == ("pallas", 16)
     assert ssd_scan.ssd_scan_impl(*hybrid, backend="tpu") == ("pallas", 16)
+    assert ssd_scan.ssd_scan_impl(32, 128, 32, 128, 128, backend="tpu") == (
+        "pallas", 16)                   # lightning attention: every head a group
     assert ssd_scan.ssd_scan_impl(4, 8, 2, 6, 8, backend="tpu") == ("xla", 0)
     with ring_mesh(MeshSpec(fsdp=1).build(devices8[:1])):
         assert ssd_scan.ssd_scan_impl(*hybrid, backend="tpu") == ("pallas", 16)
