@@ -847,6 +847,12 @@ PRESETS: dict[str, LlamaConfig] = {
 }
 
 
+def plain_inv_freqs(theta: float, half: int) -> jax.Array:
+    """The plain schedule's ``half`` per-pair inverse frequencies at base
+    ``theta``."""
+    return 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+
+
 def rope_inv_freqs(cfg: "LlamaConfig", window: bool = False) -> jax.Array:
     """Per-pair inverse frequencies, with optional llama3-style scaling; over
     ``rotary_dim`` columns where a head is rotated in part, at a ``window``
@@ -861,9 +867,7 @@ def rope_inv_freqs(cfg: "LlamaConfig", window: bool = False) -> jax.Array:
     """
     half = (cfg.rotary_dim or cfg.head_dim) // 2
     theta = (cfg.window_rope_theta if window else 0.0) or cfg.rope_theta
-    freqs = 1.0 / (
-        theta ** (jnp.arange(half, dtype=jnp.float32) / half)
-    )
+    freqs = plain_inv_freqs(theta, half)
     factor = cfg.rope_scaling_factor
     if not factor:
         return freqs
@@ -879,13 +883,85 @@ def rope_inv_freqs(cfg: "LlamaConfig", window: bool = False) -> jax.Array:
     )
 
 
+def _rotation(x, positions, inv_freqs, lo: int, hi: int, interleave: bool,
+              back: bool = False) -> jax.Array:
+    """THE arithmetic of the rotary embedding: the columns ``[lo, hi)`` of
+    every head of ``x`` ``(B, S, H, D)`` rotated by ``positions * inv_freqs``
+    (by the negated angle where ``back``), every other column as it is.
+    Angles, ``cos`` / ``sin``, the products and their sum in float32, ONE
+    rounding to ``x.dtype``."""
+    angles = positions[..., None].astype(jnp.float32) * inv_freqs  # (B, S, half)
+    cos = jnp.cos(angles)[:, :, None, :]
+    sin = jnp.sin(angles)[:, :, None, :]
+    if back:
+        sin = -sin
+    t = x[..., lo:hi]
+    if interleave:
+        x1, x2 = t[..., 0::2], t[..., 1::2]
+        out = jnp.stack(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        ).reshape(t.shape)
+    else:
+        half = (hi - lo) // 2
+        x1, x2 = t[..., :half], t[..., half:]
+        out = jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+        )
+    out = out.astype(x.dtype)
+    before = [x[..., :lo]] if lo else []
+    after = [x[..., hi:]] if hi < x.shape[-1] else []
+    if not (before or after):
+        return out
+    return jnp.concatenate([*before, out, *after], axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def rotate_columns(x: jax.Array, positions: jax.Array, inv_freqs: jax.Array,
+                   lo: int, hi: int, interleave: bool) -> jax.Array:
+    """Rotary embedding of the columns ``[lo, hi)`` of every head of ``x``
+    ``(B, S, H, D)`` — half-split pairs ``(t[i], t[i + (hi - lo)/2])`` within
+    them, or the adjacent pairs ``(t[2i], t[2i+1])`` where ``interleave`` (the
+    layout latent-attention checkpoints store) — the other columns as they
+    are; ``positions``: (B, S), ``inv_freqs``: ``(hi - lo) / 2`` frequencies.
+
+    The rotation carries its own backward rule: the transpose of a rotation
+    by an angle is the rotation by the negated angle, so a cotangent goes
+    through the FORWARD's program with the sine negated — float32 inside, one
+    rounding, one read of the cotangent and one write — where the transposes
+    autodiff makes of the slices and joins pad each part to the head's width
+    and sum the padded arrays.  Nothing of ``x``'s size is kept for it:
+    ``cos`` / ``sin`` are rebuilt from ``positions`` and ``inv_freqs``, which
+    get no gradient.  The rule opens the scope ``rope`` itself."""
+    return _rotation(x, positions, inv_freqs, lo, hi, interleave)
+
+
+def _rotate_fwd(x, positions, inv_freqs, lo, hi, interleave):
+    return (_rotation(x, positions, inv_freqs, lo, hi, interleave),
+            (positions, inv_freqs))
+
+
+def _rotate_bwd(lo, hi, interleave, res, g):
+    positions, inv_freqs = res
+    with jax.named_scope("rope"):
+        dx = _rotation(g, positions, inv_freqs, lo, hi, interleave, back=True)
+        # written ONCE, in the cotangent's type: a consumer that widens it (a
+        # q/k norm's backward) would have the compiler write float32 from
+        # this fusion instead, twice the bytes (+1.7 GB at one 32,768-token
+        # row of 32 heads of 128: PERF.md section 6, PR 50)
+        dx = jax.lax.optimization_barrier(dx)
+    return dx, None, None       # integer positions, constant frequencies
+
+
+rotate_columns.defvjp(_rotate_fwd, _rotate_bwd)
+
+
 def apply_rope(
     x: jax.Array, positions: jax.Array, theta: float | None = None,
     *, inv_freqs: jax.Array | None = None, interleave: bool = False,
 ) -> jax.Array:
-    """Rotary embedding. x: (B, S, H, D), positions: (B, S).  ``interleave``
-    rotates the adjacent pairs ``(x[2i], x[2i+1])`` (the layout latent-
-    attention checkpoints store) instead of ``(x[i], x[i + D/2])``.
+    """The rotation of whole heads in its plain form, differentiated by
+    autodiff (what :func:`rotate_columns` is held to).  x: (B, S, H, D),
+    positions: (B, S).
 
     Pass exactly one of ``theta`` (plain schedule) or ``inv_freqs``
     (precomputed, e.g. :func:`rope_inv_freqs` with llama3 scaling) — a
@@ -895,25 +971,9 @@ def apply_rope(
     if (theta is None) == (inv_freqs is None):
         raise ValueError("pass exactly one of theta or inv_freqs")
     d = x.shape[-1]
-    half = d // 2
     if inv_freqs is None:
-        freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
-    else:
-        freqs = inv_freqs
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (B, S, half)
-    cos = jnp.cos(angles)[:, :, None, :]
-    sin = jnp.sin(angles)[:, :, None, :]
-    if interleave:
-        x1, x2 = x[..., 0::2], x[..., 1::2]
-        out = jnp.stack(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-        ).reshape(x.shape)
-        return out.astype(x.dtype)
-    x1, x2 = x[..., :half], x[..., half:]
-    out = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
-    )
-    return out.astype(x.dtype)
+        inv_freqs = plain_inv_freqs(theta, d // 2)
+    return _rotation(x, positions, inv_freqs, 0, d, interleave)
 
 
 def times(x: jax.Array, by: float) -> jax.Array:
@@ -970,14 +1030,11 @@ def _proj(cfg: LlamaConfig, name: str, features: int, **module_kw) -> LoRADense:
 
 
 def _rotate_leading(x, positions, inv_freqs, rotary_dim: int):
-    """:func:`apply_rope` on the first ``rotary_dim`` columns of every head of
-    ``x`` (half-split pairs within them), the other columns as they are; the
-    whole head where ``rotary_dim`` is 0."""
-    if not rotary_dim:
-        return apply_rope(x, positions, inv_freqs=inv_freqs)
-    return jnp.concatenate(
-        [apply_rope(x[..., :rotary_dim], positions, inv_freqs=inv_freqs),
-         x[..., rotary_dim:]], axis=-1)
+    """:func:`rotate_columns` on the first ``rotary_dim`` columns of every
+    head of ``x`` (half-split pairs within them), the other columns as they
+    are; the whole head where ``rotary_dim`` is 0."""
+    return rotate_columns(x, positions, inv_freqs, 0,
+                          rotary_dim or x.shape[-1], False)
 
 
 class Attention(nn.Module):
@@ -1341,11 +1398,11 @@ def index_selection(cfg: LlamaConfig, leaves: dict, c_q, x, positions,
          + leaves["k_bias"].astype(jnp.float32)).astype(cfg.dtype)
     weights = (x @ leaves["weights_proj"]).astype(jnp.float32) * (hi * di) ** -0.5
     with jax.named_scope("rope"):
+        inv_freqs = plain_inv_freqs(cfg.rope_theta, dr // 2)
+
         def rotated(t):
-            return jnp.concatenate([
-                apply_rope(t[..., :dr], positions, cfg.rope_theta,
-                           interleave=cfg.index_rope_interleave),
-                t[..., dr:]], axis=-1)
+            return rotate_columns(t, positions, inv_freqs, 0, dr,
+                                  cfg.index_rope_interleave)
 
         q, k = rotated(q), rotated(k[:, :, None, :])[:, :, 0]
     with jax.named_scope("index_scores"):
@@ -1633,11 +1690,11 @@ class MLAttention(nn.Module):
             norm("kv_a_norm")(c_kv), deterministic, adapter_ids
         ).reshape(b, s, h, dn + dv)
         with jax.named_scope("rope"):
-            q_rope = apply_rope(q[..., dn:], positions, cfg.rope_theta,
-                                interleave=cfg.rope_interleave)
-            k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
-                                interleave=cfg.rope_interleave)
-            q = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+            inv_freqs = plain_inv_freqs(cfg.rope_theta, dr // 2)
+            q = rotate_columns(q, positions, inv_freqs, dn, dn + dr,
+                               cfg.rope_interleave)
+            k_rope = rotate_columns(k_rope[:, :, None, :], positions, inv_freqs,
+                                    0, dr, cfg.rope_interleave)
             k = jnp.concatenate(
                 [kv[..., :dn], jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
         v = kv[..., dn:]

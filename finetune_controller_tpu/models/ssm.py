@@ -69,8 +69,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.pallas.ssd_scan import ssd_scan, ssd_scan_impl
-from .llama import (_Leaves, _head_norm, _proj, apply_rope, gated_output,
-                    RMSNorm, times)
+from .llama import (_Leaves, _head_norm, _proj, gated_output,
+                    plain_inv_freqs, rotate_columns, RMSNorm, times)
 
 
 def run_description(cfg, seq_len: int) -> dict:
@@ -355,8 +355,9 @@ class LightningMixer(nn.Module):
         q, k = _head_norm(cfg, "q_norm")(q), _head_norm(cfg, "k_norm")(k)
         if cfg.rope_theta:
             with jax.named_scope("rope"):
-                q = apply_rope(q, positions, cfg.rope_theta)
-                k = apply_rope(k, positions, cfg.rope_theta)
+                inv_freqs = plain_inv_freqs(cfg.rope_theta, p // 2)
+                q = rotate_columns(q, positions, inv_freqs, 0, p, False)
+                k = rotate_columns(k, positions, inv_freqs, 0, p, False)
         runs = None if segment_ids is None else document_runs(segment_ids)
         with jax.named_scope("ssd_scan"):
             y = ssd_scan(v, jnp.ones((bsz, s, h), jnp.float32),
